@@ -121,15 +121,6 @@ def _phases(timer, wall, traffic=None):
     return out
 
 
-def _traffic(bst):
-    """traffic_spec of the trained Booster's learner, or None (dense
-    builder / unexpected internals — the bench must not fail on it)."""
-    try:
-        return bst.inner.learner.traffic_spec()
-    except Exception:
-        return None
-
-
 def run_higgs(lgb, n_rows, timer):
     from lightgbm_tpu import obs
     with obs.wall("higgs/datagen") as w:
@@ -160,7 +151,7 @@ def run_higgs(lgb, n_rows, timer):
         bst = lgb.train(dict(params), ds, num_boost_round=N_ITER)
         obs.sync(bst.inner.train_score.score)
     train_s = w.seconds
-    phases = _phases(timer, train_s, _traffic(bst))
+    phases = _phases(timer, train_s, bst.inner.learner.traffic_spec())
     (_, _, auc, _), = bst.eval_train()
     return ((n_rows * N_ITER) / train_s, auc, train_s, warmup_s, t_gen,
             t_cons, phases)
@@ -194,7 +185,7 @@ def run_mslr(lgb, timer):
         bst = lgb.train(dict(params), ds, num_boost_round=RANK_ITER)
         obs.sync(bst.inner.train_score.score)
     train_s = w.seconds
-    phases = _phases(timer, train_s, _traffic(bst))
+    phases = _phases(timer, train_s, bst.inner.learner.traffic_spec())
     evals = {name: v for (_, name, v, _) in bst.eval_train()}
     ndcg = evals.get("ndcg@10", next(iter(evals.values())))
     return ((RANK_ROWS * RANK_ITER) / train_s, ndcg, train_s, warmup_s,
@@ -260,7 +251,7 @@ def run_goss(lgb):
             bst = lgb.train(dict(p), ds, num_boost_round=GOSS_ITER)
             obs.sync(bst.inner.train_score.score)
         out[mode] = wl.seconds
-        tr = _traffic(bst) or {}
+        tr = bst.inner.learner.traffic_spec() or {}
         eff[mode] = tr.get("effective_rows", 0)
     return {
         "goss_off_s": round(out["off"], 3),
@@ -275,10 +266,9 @@ def run_goss(lgb):
 
 
 def main():
-    import jax
-    jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     import lightgbm_tpu as lgb
+    from lightgbm_tpu import runtime
+    runtime.enable_compile_cache()
     from lightgbm_tpu.utils.timer import global_timer
 
     if TRACE_PATH:
@@ -295,6 +285,7 @@ def main():
                 % (N_ROWS, NUM_LEAVES, MAX_BIN, N_ITER, auc, h_train,
                    h_warm, h_gen, h_cons),
         "vs_baseline": round(h_tp / HIGGS_BASELINE, 4),
+        "device": runtime.device_identity(),
         "train_breakdown": h_ph,
     }
     if not SKIP_2M:
@@ -306,7 +297,7 @@ def main():
                 "M rows*iters/s (N=%d; auc=%.4f; train=%.1fs warmup=%.1fs)"
                 % (N2_ROWS, auc2, tr2, wm2))
             result["vs_baseline_2m"] = round(tp2 / HIGGS_BASELINE, 4)
-        except Exception as e:  # pragma: no cover - report, don't fail
+        except Exception as e:  # pragma: no cover - recorded here, non-zero exit at the end
             result["error_2m"] = "%s: %s" % (type(e).__name__, str(e)[:200])
     if not SKIP_RANK:
         try:
@@ -321,7 +312,7 @@ def main():
                    r_train, r_warm, r_gen, r_cons))
             result["rank_vs_baseline"] = round(r_tp / MSLR_BASELINE, 4)
             result["rank_train_breakdown"] = r_ph
-        except Exception as e:  # pragma: no cover - report, don't fail
+        except Exception as e:  # pragma: no cover - recorded here, non-zero exit at the end
             result["rank_error"] = "%s: %s" % (type(e).__name__, str(e)[:200])
     if not SKIP_SERVE:
         try:
@@ -342,19 +333,19 @@ def main():
             result["serve_p99_ms"] = sb["closed_loop_p99_ms"]
             result["serve_p999_ms"] = sb["closed_loop_p999_ms"]
             result["serve_hist_buckets"] = sb["closed_loop_hist_buckets"]
-        except Exception as e:  # pragma: no cover - report, don't fail
+        except Exception as e:  # pragma: no cover - recorded here, non-zero exit at the end
             result["serve_error"] = "%s: %s" % (type(e).__name__,
                                                 str(e)[:200])
     if not SKIP_LINEAR:
         try:
             result.update(run_linear(lgb))
-        except Exception as e:  # pragma: no cover - report, don't fail
+        except Exception as e:  # pragma: no cover - recorded here, non-zero exit at the end
             result["linear_error"] = "%s: %s" % (type(e).__name__,
                                                  str(e)[:200])
     if not SKIP_GOSS:
         try:
             result.update(run_goss(lgb))
-        except Exception as e:  # pragma: no cover - report, don't fail
+        except Exception as e:  # pragma: no cover - recorded here, non-zero exit at the end
             result["goss_error"] = "%s: %s" % (type(e).__name__,
                                                str(e)[:200])
     # full structured-counter view of the run (dataset cache traffic, fused
@@ -378,7 +369,7 @@ def main():
                        "throughput_M": result["value"],
                        "train_breakdown": h_ph})
             result["ledger_path"] = LEDGER_PATH
-        except Exception as e:  # pragma: no cover - report, don't fail
+        except Exception as e:  # pragma: no cover - recorded here, non-zero exit at the end
             result["ledger_error"] = "%s: %s" % (type(e).__name__,
                                                  str(e)[:200])
     if TRACE_PATH:
@@ -386,6 +377,11 @@ def main():
         result["trace_path"] = TRACE_PATH
         result["trace_events"] = tracer.dump(TRACE_PATH)
     print(json.dumps(result))
+    failed = sorted(k for k in result
+                    if k.endswith("_error") or k.startswith("error_"))
+    if failed:
+        # the JSON line above still carries each phase's error text
+        sys.exit("bench phases failed: " + ", ".join(failed))
 
 
 if __name__ == "__main__":

@@ -373,9 +373,10 @@ class Config:
     #   row-index byte planes, g/h/c bytes) and segment histograms gather
     #   the bin planes through the permuted row-index plane. Cuts partition
     #   HBM traffic ~(F+12)/17-fold (~2.4x at F=28, ~8.8x at F=137) and
-    #   grows bit-identical trees. auto: on when the resolved layout is
-    #   planes on a TPU backend; on: force (requires a planes-capable
-    #   config — errors with tpu_work_layout=rows or int8 histograms).
+    #   grows bit-identical trees. auto: off — the gathered histogram
+    #   measured 1.8-3.6x slower per iteration than plain planes on a v5e
+    #   (PERF.md, PR 21); on: force (requires a planes-capable config —
+    #   errors with tpu_work_layout=rows or int8 histograms).
     tpu_split_kernel: str = "auto"   # auto|off|on: one-kernel split — ONE
     #   pallas_call per split running partition + smaller-child histogram
     #   + split scan as sequential phases (planes/resident layouts only),

@@ -2,12 +2,16 @@
 
 The shared library builds on first use with the baked-in g++ (pybind11 is
 not available in this image; the flat C ABI + ctypes mirrors how the
-reference's python package binds its C API, basic.py ctypes). io.py falls
-back to the pure-Python parser when no compiler is present.
+reference's python package binds its C API, basic.py ctypes). The cached
+``.so`` is named by the hash of the source and flags that built it, so an
+edited source never loads a stale library. Without a compiler io.py and
+dataset.py use their Python/numpy paths — with a warning, and
+:func:`binning_status` says which one a run got.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -32,8 +36,12 @@ def _build_lib(src_name: str = "parser.cpp",
     out_dir = os.environ.get("LIGHTGBM_TPU_CACHE") or os.path.join(
         os.path.expanduser("~"), ".cache", "lightgbm_tpu")
     os.makedirs(out_dir, exist_ok=True)
-    out = os.path.join(out_dir, lib_name)
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + repr(tuple(extra_flags)).encode()).hexdigest()[:16]
+    stem, ext = os.path.splitext(lib_name)
+    out = os.path.join(out_dir, "%s-%s%s" % (stem, digest, ext))
+    if os.path.exists(out):
         return out
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=out_dir)
     os.close(fd)
@@ -42,7 +50,8 @@ def _build_lib(src_name: str = "parser.cpp",
     try:
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
-        Log.debug("native build unavailable (%s): %s", src_name, e)
+        Log.warning("native build of %s unavailable (%s); using the Python "
+                    "path", src_name, e)
         return None
     if r.returncode != 0:
         Log.warning("native build of %s failed; using the Python path:\n%s",
@@ -122,6 +131,13 @@ def parse_file(path: str,
 
 _BIN_LIB: Optional[ctypes.CDLL] = None
 _BIN_TRIED = False
+
+
+def binning_status() -> str:
+    """"native" when the threaded bin applier built and loaded, else
+    "numpy" (the build warning says why) — the host-time cliff a run
+    should state, not hide."""
+    return "native" if get_binning_lib() is not None else "numpy"
 
 
 def get_binning_lib() -> Optional[ctypes.CDLL]:

@@ -27,9 +27,9 @@ from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 BASELINE_NAME = "lint_baseline.json"
 
 #: repo-relative roots linted by default (ISSUE 4 scope: the package, the
-#: perf-harness scripts, and the bench driver; tests are free to use raw
-#: timers and host syncs).
-DEFAULT_PATHS = ("lightgbm_tpu", "scripts", "bench.py")
+#: perf-harness scripts, the bench driver and the chip smoke; tests are
+#: free to use raw timers and host syncs).
+DEFAULT_PATHS = ("lightgbm_tpu", "scripts", "bench.py", "chip_smoke.py")
 
 _DISABLE_RE = re.compile(
     r"#\s*graftlint:\s*disable(?:=([A-Za-z0-9_,\- ]+))?")

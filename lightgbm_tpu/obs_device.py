@@ -96,14 +96,6 @@ def reset() -> None:
         _state.hbm_last.clear()
 
 
-def _first_cost(cost) -> Dict[str, Any]:
-    """``Compiled.cost_analysis()`` returns a dict (new jax) or a
-    one-element list of dicts (0.4.x); normalize to one dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost if isinstance(cost, dict) else {}
-
-
 def on_compile(name: str, fn, args, kwargs) -> None:
     """Record the device cost of a freshly compiled tracked-jit signature.
 
@@ -120,7 +112,7 @@ def on_compile(name: str, fn, args, kwargs) -> None:
     try:
         with suppress_backend_compiles():
             compiled = fn.lower(*args, **kwargs).compile()
-        cost = _first_cost(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
         entry = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed",
@@ -165,7 +157,7 @@ def on_compile(name: str, fn, args, kwargs) -> None:
                     int(entry["output_bytes"]))
     telemetry.gauge("device_cost/generated_code_bytes/" + name,
                     int(entry["generated_code_bytes"]))
-    telemetry.record("device_cost_capture", name=name, **entry)
+    telemetry.record("device_cost_capture", jit=name, **entry)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ allocation, ``span()`` returns a shared no-op context manager, and
 ``tests/test_trace.py`` pins the off-path overhead compile-budget style.
 
 Spans are HOST-side: inside a jit trace ``phase_begin`` refuses to
-record (via ``jax.core.trace_state_clean``), so ``trace_phase`` sites
+record (via ``jax.core.trace_ctx.is_top_level``), so ``trace_phase`` sites
 that live in traced code cost nothing at runtime and do not pollute the
 recorder with trace-time measurements.  Device-side attribution stays
 with ``jax.named_scope`` / the XLA profiler — but the fused finalize
@@ -126,10 +126,6 @@ class _SpanCtx(object):
         return False
 
 
-def _trace_state_clean_fallback():
-    return True
-
-
 class SpanTracer(object):
     """Thread-aware span tracer with a bounded flight-recorder ring.
 
@@ -151,7 +147,7 @@ class SpanTracer(object):
         self._epoch = monotonic()
         self._ids = itertools.count(1)
         self._tls = threading.local()
-        self._trace_state_clean = _trace_state_clean_fallback
+        self._at_top_level = None   # bound by configure() when on
         # fleet process identity: stamped into chrome_trace process_name
         # so merged multi-process exports keep nodes distinguishable
         self.identity_role = None
@@ -172,11 +168,10 @@ class SpanTracer(object):
         if self.serve_on or self.train_on:
             # host spans must not record while jax is tracing a function:
             # that would measure trace time once per compile, not runtime.
-            try:
-                from jax.core import trace_state_clean
-                self._trace_state_clean = trace_state_clean
-            except Exception:
-                self._trace_state_clean = _trace_state_clean_fallback
+            # Imported here so a tracer that stays off never imports jax;
+            # a jax without it fails loudly instead of recording in traces.
+            from jax.core import trace_ctx
+            self._at_top_level = trace_ctx.is_top_level
         return self
 
     def new_trace_id(self):
@@ -256,7 +251,7 @@ class SpanTracer(object):
         when train tracing is off or a jit trace is in flight."""
         if not self.train_on:
             return None
-        if not self._trace_state_clean():
+        if not self._at_top_level():
             return None
         return self.begin(name)
 

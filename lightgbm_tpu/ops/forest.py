@@ -35,15 +35,9 @@ from typing import List, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas is optional at import time (CPU meshes use the XLA path)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    if not hasattr(pltpu, "HBM"):  # older jax spells these differently
-        pltpu.HBM = pltpu.ANY
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover
-    pl = pltpu = None
 
 #: Row-tile width of one kernel program. Bucket rungs need not be
 #: multiples of it — the wrapper pads (padding rows route harmlessly and
@@ -276,10 +270,10 @@ def forest_predict_impl(bins: jax.Array, X: jax.Array, fp: ForestPack, *,
     One kernel program per row tile; all node tables resident. ``X`` is
     only an operand when ``has_linear`` (it is ignored — and never
     shipped into VMEM — otherwise). N is padded up to the tile multiple
-    and sliced back.
+    and sliced back. ``interpret=None`` follows the explicit
+    ``LGBTPU_PALLAS_INTERPRET`` flag like every other kernel — never the
+    backend: off a TPU without the flag this fails to lower, loudly.
     """
-    if pl is None:  # pragma: no cover - pallas always importable in CI
-        raise RuntimeError("pallas unavailable: forest kernel cannot run")
     n, F = bins.shape
     R, T = fp.slot.shape
     L = fp.value_of_slot.shape[1]
@@ -295,7 +289,8 @@ def forest_predict_impl(bins: jax.Array, X: jax.Array, fp: ForestPack, *,
     npad = n + pad
     grid = npad // tile
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from .partition import _INTERPRET
+        interpret = _INTERPRET
 
     def kernel(*refs):
         out_ref = refs[-1]

@@ -48,17 +48,11 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import trace_phase
 
-try:  # pallas is optional at import time (CPU test meshes use the XLA path)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    if not hasattr(pltpu, "HBM"):  # pre-0.5 jax (CPU test meshes)
-        pltpu.HBM = pltpu.ANY
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover
-    pl = pltpu = None
 
 # CPU-mesh validation hook: run the pallas kernels under the pallas
 # interpreter (tests/test_work_layout.py). Kernels that read the dst plane

@@ -23,15 +23,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas is optional at import time (CPU meshes use the XLA path)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    if not hasattr(pltpu, "HBM"):  # older jax spells these differently
-        pltpu.HBM = pltpu.ANY
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover
-    pl = pltpu = None
 
 # SMEM table layout: per round r the columns are
 #   0 col      matrix column to read (bundle group or feature)

@@ -45,18 +45,6 @@ from ..utils.log import Log
 DATA_AXIS = "data"
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    # jax >= 0.6 exposes shard_map at top level (check_vma); older releases
-    # only have the experimental module (check_rep). Replication checking is
-    # off either way: the learners do their own collectives through Comm.
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     devs = jax.devices()
     if n_devices is not None:
@@ -120,23 +108,26 @@ class _MeshTreeLearner(SerialTreeLearner):
                                      ((0, self.padded_n - n), (0, 0)))
                 self.bins = jax.device_put(jnp.asarray(bins_np),
                                            self.row_sharding)
-            row_spec = P(DATA_AXIS)
         else:
             self.padded_n = n
             self.bins = jax.device_put(self.bins, self.rep_sharding)
-            row_spec = P()
 
         if self.comm_mode != "data" and not self.use_partition():
             Log.fatal("tree_learner=%s requires the partitioned builder "
                       "(max_bin <= 256)", self.comm_mode)
-        inner = self.make_build_fn()
-        data_spec = P(DATA_AXIS) if self.rows_sharded else P()
-        sharded = _shard_map(
-            inner, mesh=mesh,
-            in_specs=(data_spec, data_spec, P(), P(), P(), P()),
-            out_specs=_tree_log_specs(row_spec),
-        )
-        self._build = track_jit("mesh/build", jax.jit(sharded))
+        self._build = track_jit("mesh/build",
+                                jax.jit(self.sharded_build(mesh)))
+
+    def sharded_build(self, mesh: Mesh):
+        """The tree builder shard_map'd over ``mesh`` (normally the
+        learner's own; the AOT pre-flight re-wraps it over a TPU topology's
+        mesh). Replication checking off: the learners do their own
+        collectives through Comm."""
+        spec = P(DATA_AXIS) if self.rows_sharded else P()
+        return jax.shard_map(
+            self.make_build_fn(), mesh=mesh,
+            in_specs=(spec, spec, P(), P(), P(), P()),
+            out_specs=_tree_log_specs(spec), check_vma=False)
 
     def _make_comm(self, axis: Optional[str]) -> Comm:
         return Comm(axis, mode=self.comm_mode,

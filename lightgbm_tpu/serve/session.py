@@ -28,6 +28,7 @@ import numpy as np
 
 from ..obs import telemetry, track_jit
 from ..obs_trace import tracer
+from ..ops import partition
 from ..ops.forest import forest_predict_impl
 from ..ops.predict import predict_raw_impl
 from ..utils.log import LightGBMError, Log
@@ -284,10 +285,12 @@ class PredictSession:
                         xchunk = np.concatenate(
                             [xchunk,
                              np.zeros((b - rows, nf), np.float32)])
+                    # the interpret flag is read per call so it keys the
+                    # jit cache (tests flip it; nothing else may)
                     score = _forest_bucket(
                         jnp.asarray(chunk), jnp.asarray(xchunk), fp,
                         num_class=self._K, has_cat=f_cat,
-                        has_linear=f_lin)
+                        has_linear=f_lin, interpret=partition._INTERPRET)
                 else:
                     score = _predict_bucket(jnp.asarray(chunk), pack,
                                             num_class=self._K,

@@ -18,8 +18,8 @@ BIG_N = int(sys.argv[1]) if len(sys.argv) > 1 else 30_000_000
 
 def main():
     import jax
-    jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from lightgbm_tpu.runtime import enable_compile_cache
+    enable_compile_cache()
     import lightgbm_tpu as lgb
     from bench import make_higgs_like
 

@@ -19,6 +19,12 @@
 #                       the region control-plane suite: remote write
 #                       surface, multi-endpoint failover, ingest
 #                       forwarding, snapshot bootstrap)
+#   check.sh --aot      lint + lint tests + the sandbox AOT pre-flight
+#                       (tests/test_aot_tpu.py, slow: the real TPU
+#                       compiler over what auto resolves to on a v5e
+#                       and over every selectable kernel; no chip
+#                       needed, one libtpu process at a time). Run it
+#                       after touching ops/, learner.py or fused.py.
 #   check.sh --slo      everything above, plus the closed-loop serving
 #                       SLO bench gated against SLO_BASELINE.json
 #   check.sh --ledger   everything above, plus the run-ledger regression
@@ -31,11 +37,13 @@ cd "$(dirname "$0")/.."
 LINT_ARGS=""
 RUN_SUBSET=1
 RUN_FLEET=0
+RUN_AOT=0
 RUN_SLO=0
 RUN_LEDGER=0
 case "$1" in
     --fast)   LINT_ARGS="--changed"; RUN_SUBSET=0 ;;
     --fleet)  RUN_SUBSET=0; RUN_FLEET=1 ;;
+    --aot)    RUN_SUBSET=0; RUN_AOT=1 ;;
     --slo)    RUN_SLO=1 ;;
     --ledger) RUN_LEDGER=1 ;;
 esac
@@ -62,6 +70,11 @@ if [ "$RUN_FLEET" = 1 ]; then
         tests/test_fleet.py tests/test_failover.py \
         tests/test_fleet_obs.py tests/test_control.py \
         tests/test_online.py tests/test_serve.py
+fi
+
+if [ "$RUN_AOT" = 1 ]; then
+    echo "== AOT pre-flight: compile for v5e without a chip =="
+    JAX_PLATFORMS=cpu python -m pytest -q -rxXs tests/test_aot_tpu.py
 fi
 
 if [ "$RUN_SLO" = 1 ]; then

@@ -16,7 +16,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from lightgbm_tpu.runtime import enable_compile_cache
+enable_compile_cache()
 
 from lightgbm_tpu.ops.partition import pack_rows, work_spec
 

@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from lightgbm_tpu.runtime import enable_compile_cache
+enable_compile_cache()
 
 from lightgbm_tpu import obs
 from lightgbm_tpu.linear.fit import fit_leaves

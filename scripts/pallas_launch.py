@@ -11,7 +11,8 @@ from lightgbm_tpu import obs
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from lightgbm_tpu.runtime import enable_compile_cache
+enable_compile_cache()
 
 REPS = 254
 

@@ -14,7 +14,8 @@ import numpy as np
 
 from lightgbm_tpu import obs
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from lightgbm_tpu.runtime import enable_compile_cache
+enable_compile_cache()
 
 from lightgbm_tpu.ops.partition import (guard_rows, pack_rows,
                                         partition_segment_fused, work_spec)

@@ -30,8 +30,8 @@ from lightgbm_tpu import obs
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from lightgbm_tpu.runtime import enable_compile_cache
+enable_compile_cache()
 
 ALIGN = 32
 

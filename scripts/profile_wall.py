@@ -21,8 +21,8 @@ BLOCK = int(os.environ.get("BENCH_BLOCK", 20))
 
 def main():
     import jax
-    jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from lightgbm_tpu.runtime import enable_compile_cache
+    enable_compile_cache()
     from lightgbm_tpu import obs
     with obs.wall("profile/import") as w:
         import lightgbm_tpu as lgb

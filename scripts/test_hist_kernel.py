@@ -10,8 +10,8 @@ import numpy as np
 
 from lightgbm_tpu import obs
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from lightgbm_tpu.runtime import enable_compile_cache
+enable_compile_cache()
 
 from lightgbm_tpu.ops.histogram import hist16_segment, hist_pallas_segment
 from lightgbm_tpu.ops.partition import pack_rows, work_spec

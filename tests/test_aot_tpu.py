@@ -1,0 +1,235 @@
+"""Sandbox pre-flight: does Mosaic/XLA compile our programs for a v5e?
+
+The installed libtpu compiles for a TPU topology with no chip attached, so
+"will it compile on the chip" is answerable here. ``runtime.on_tpu`` is
+replaced so every ``auto`` knob resolves as it does on a TPU, the programs
+are lowered against ``ShapeDtypeStruct``s placed on a ``v5e:2x2`` topology
+device, and ``.compile()`` runs the real TPU compiler. Compile-only: this
+says nothing about results, hangs or speed (``chip_smoke.py`` does, on the
+chip). Interpreter parity never predicted these verdicts.
+
+The refused programs are ``xfail(strict=True)`` carrying the compiler's first
+line, so a repair flips the case visibly. All of them are ``auto -> off``.
+
+libtpu takes ``/tmp/libtpu_lockfile``: one such process at a time.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import lightgbm_tpu as lgb
+from bench import make_higgs_like, make_mslr_like
+from lightgbm_tpu import fused, runtime
+from lightgbm_tpu.ops import partition
+
+pytestmark = pytest.mark.slow
+
+N_BIN, N_RANK = 200_000, 300_000      # F = 28 and 137
+BASE = {"num_leaves": 255, "max_bin": 255, "verbosity": -1}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        from jax.experimental import topologies
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it is locked by another process
+        pytest.skip("no TPU topology can be made here: %s: %s"
+                    % (type(e).__name__, str(e).splitlines()[0][:200]))
+    assert "v5" in t.devices[0].device_kind.lower(), t.devices[0].device_kind
+    return t
+
+
+@pytest.fixture(autouse=True)
+def as_on_tpu(monkeypatch):
+    """Resolve and trace as a TPU does. jit's trace caches do not key on
+    the patched function, so they are dropped on the way in and out."""
+    assert not partition._INTERPRET
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    jax.clear_caches()
+    fused._BLOCK_CACHE.clear()
+    yield
+    jax.clear_caches()
+    fused._BLOCK_CACHE.clear()
+
+
+@pytest.fixture(scope="module")
+def ds_binary():
+    X, y = make_higgs_like(N_BIN)
+    ds = lgb.Dataset(X, label=y)
+    ds.construct()
+    return ds
+
+
+@pytest.fixture(scope="module")
+def ds_rank():
+    X, y, group = make_mslr_like(N_RANK)
+    ds = lgb.Dataset(X, label=y, group=group)
+    ds.construct()
+    return ds
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype,
+                                       sharding=sharding), tree)
+
+
+def compile_block(topo, ds, params, k=5):
+    """AOT-compile FusedTrainer's k-iteration block; returns (resolved
+    build kwargs, Compiled)."""
+    g = lgb.Booster(dict(BASE, **params), ds).inner
+    assert g.supports_fused()
+    ft = fused.FusedTrainer(g)
+    args = (g.train_score.score, ft._used_dev(), g._key, jnp.int32(0),
+            g.learner.bins, g.learner.meta,
+            fused._obj_array_state(g.objective))
+    sds = _abstract(args, SingleDeviceSharding(topo.devices[0]))
+    return g.learner.build_kwargs(), ft._block_fn(k).lower(*sds).compile()
+
+
+def resolved(kw):
+    return (kw["work_layout"], kw["part_kernel"], kw["hist_kernel"],
+            kw["split_kernel"], kw["hist_mxu"])
+
+
+# ------------------------------------------------- what auto picks on a TPU
+
+def test_default_binary_block_compiles(topo, ds_binary):
+    kw, c = compile_block(topo, ds_binary, {"objective": "binary"})
+    assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+    assert c.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_default_lambdarank_block_compiles(topo, ds_rank):
+    kw, _ = compile_block(topo, ds_rank, {"objective": "lambdarank"})
+    assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+
+
+def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
+                                                      cpu_mesh_devices):
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.parallel.mesh import DataParallelTreeLearner
+    cfg = Config.from_params(dict(BASE, objective="binary",
+                                  tree_learner="data"))
+    binned = ds_binary.construct()
+    lrn = DataParallelTreeLearner(
+        cfg, binned, Mesh(np.asarray(cpu_mesh_devices[:4]), ("data",)))
+    assert resolved(lrn.build_kwargs())[:2] == ("planes", "pallas")
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    n, f = lrn.padded_n, binned.num_features
+    sds = jax.ShapeDtypeStruct
+    low = jax.jit(lrn.sharded_build(mesh)).lower(
+        sds((n, f), jnp.uint8, sharding=rows),
+        sds((n, 3), jnp.float32, sharding=rows),
+        _abstract(lrn.meta, rep), sds((f,), jnp.bool_, sharding=rep),
+        _abstract(jax.random.PRNGKey(0), rep),
+        sds((f,), jnp.bool_, sharding=rep))
+    txt = low.as_text()     # what Comm asks for; XLA may rewrite it
+    assert "all_reduce" in txt and "reduce_scatter" in txt
+    low.compile()
+
+
+# ------------------------------------ selectable paths the compiler accepts
+
+@pytest.mark.parametrize("name,params,expect", [
+    ("rows_fused_partition",            # the pre-round program
+     {"tpu_work_layout": "rows"}, ("rows", "pallas", "xla", "off", "off")),
+    ("resident",                        # auto's pick until PR 21 timed it
+     {"tpu_resident_state": "on"}, ("resident", "pallas", "xla", "off", "off")),
+    ("planes_pallas_hist", {"tpu_hist_kernel": "pallas"},
+     ("planes", "pallas", "pallas", "off", "off")),
+    ("rows_pallas_hist",
+     {"tpu_work_layout": "rows", "tpu_hist_kernel": "pallas"},
+     ("rows", "pallas", "pallas", "off", "off")),
+    ("rows_hist_mxu_f32",
+     {"tpu_work_layout": "rows", "tpu_hist_mxu": "on"},
+     ("rows", "pallas", "xla", "off", "on")),
+    ("goss_compact",
+     {"data_sample_strategy": "goss", "top_rate": 0.2, "other_rate": 0.1,
+      "tpu_goss_compact": "on"}, ("planes", "pallas", "xla", "off", "off")),
+])
+def test_selectable_path_compiles(topo, ds_binary, name, params, expect):
+    kw, _ = compile_block(topo, ds_binary, dict(params, objective="binary"))
+    assert resolved(kw) == expect, name
+    if name == "goss_compact":
+        assert kw["goss_compact_rows"] > 0
+
+
+@pytest.fixture(scope="module")
+def small_model():
+    """Trained for real on the CPU: module scope, so it is built before the
+    function-scoped ``as_on_tpu`` patch goes in."""
+    X, y = make_higgs_like(4_000)
+    return lgb.train({"objective": "binary", "num_leaves": 31,
+                      "verbosity": -1}, lgb.Dataset(X, label=y),
+                     num_boost_round=8), X
+
+
+def test_serving_predict_compiles(topo, small_model):
+    from lightgbm_tpu.serve import session
+    bst, X = small_model
+    pack, has_cat, has_linear = bst.inner._packed_model(0, 8)
+    sh = SingleDeviceSharding(topo.devices[0])
+    session._predict_bucket.lower(
+        jax.ShapeDtypeStruct((65_536, X.shape[1]), jnp.float32, sharding=sh),
+        _abstract(pack, sh), num_class=1, has_cat=has_cat,
+        has_linear=has_linear).compile()
+
+
+# ----------------------------------------- what the compiler refuses today
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
+    "Mosaic: 'Loads are only allowed on VMEM and SMEM references.' — "
+    "ops/partition.py one_kernel_split loads the whole (F, Npad) HBM ref"))
+def test_one_kernel_split_resident_compiles(topo, ds_binary):
+    kw, _ = compile_block(topo, ds_binary, {
+        "objective": "binary", "tpu_split_kernel": "on",
+        "tpu_resident_state": "on"})
+    assert resolved(kw)[:4] == ("resident", "pallas", "xla", "on")
+
+
+@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=(
+    "Mosaic: 'Unimplemented primitive in Pallas TPU lowering for "
+    "KernelType.TC: dynamic_slice.' — the one-kernel split's in-kernel "
+    "histogram window"))
+def test_one_kernel_split_planes_compiles(topo, ds_binary):
+    kw, _ = compile_block(topo, ds_binary, {"objective": "binary",
+                                            "tpu_split_kernel": "on"})
+    assert resolved(kw)[:4] == ("planes", "pallas", "xla", "on")
+
+
+@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=(
+    "Mosaic: 'Unimplemented primitive in Pallas TPU lowering for "
+    "KernelType.TC: dynamic_slice.' — ops/forest.py indexes its node tables "
+    "by round in-kernel (with u8 bins it stops earlier, at 'Unsupported "
+    "cast: uint8 -> float32')"))
+def test_forest_kernel_compiles(topo, small_model):
+    from lightgbm_tpu.serve import session
+    bst, X = small_model
+    fp, has_cat, has_linear = bst.inner._forest_model(0, 8)
+    sh = SingleDeviceSharding(topo.devices[0])
+    n, f = 4_096, X.shape[1]
+    session._forest_bucket.lower(
+        jax.ShapeDtypeStruct((n, f), jnp.int32, sharding=sh),   # _bin_rows
+        jax.ShapeDtypeStruct((n, f), jnp.float32, sharding=sh),
+        _abstract(fp, sh), num_class=1, has_cat=has_cat,
+        has_linear=has_linear, interpret=False).compile()
+
+
+@pytest.mark.xfail(strict=True, raises=jax.errors.JaxRuntimeError, reason=(
+    "XLA: 'RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... "
+    "Scoped allocation with size 102.41M and limit 100.00M' — "
+    "ops/histogram.py hist_mxu_segment in int8 mode against its own "
+    "vmem_limit_bytes"))
+def test_hist_mxu_quantized_compiles(topo, ds_binary):
+    kw, _ = compile_block(topo, ds_binary, {
+        "objective": "binary", "tpu_hist_mxu": "on",
+        "use_quantized_grad": True})
+    assert kw["hist_mxu"] == "on" and kw["hist_mode"] == "int8"

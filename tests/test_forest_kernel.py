@@ -27,8 +27,16 @@ sys.path.insert(0, REPO)
 
 import lightgbm_tpu as lgb  # noqa: E402
 from lightgbm_tpu import obs  # noqa: E402
+from lightgbm_tpu.ops import partition  # noqa: E402
 from lightgbm_tpu.serve import PredictSession  # noqa: E402
 from lightgbm_tpu.utils.log import LightGBMError  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _interpret(monkeypatch):
+    """The kernel runs under the Pallas interpreter only because this says
+    so; nothing infers it from the backend."""
+    monkeypatch.setattr(partition, "_INTERPRET", True)
 
 
 def _grid(rng, n, f):

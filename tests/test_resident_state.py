@@ -342,16 +342,23 @@ def test_resident_on_rejects_int8(rng):
         SerialTreeLearner(cfg, ds)
 
 
-def test_resident_auto_stays_planes_on_cpu(rng):
-    """auto only turns resident on for TPU backends: the gather has no
-    payoff without HBM bandwidth pressure, and CPU meshes keep the plain
-    planes path (resident+CPU mesh fallback)."""
+def test_resident_auto_stays_planes(rng, monkeypatch):
+    """auto never turns resident on — not on a CPU mesh and, since the
+    first v5e timing (PERF.md, PR 21: the gathered histogram is 1.8-3.6x
+    slower per iteration than plain planes), not on a TPU either; ``on``
+    still forces it."""
+    from lightgbm_tpu import runtime
     from lightgbm_tpu.learner import SerialTreeLearner
 
     cfg, ds = _mini_ds(rng, {"tpu_resident_state": "auto",
                              "tpu_work_layout": "planes"})
     kw = SerialTreeLearner(cfg, ds).build_kwargs()
     assert kw["work_layout"] == "planes"
+    with monkeypatch.context() as m:
+        m.setattr(runtime, "on_tpu", lambda: True)
+        cfg, ds = _mini_ds(rng, {})          # every knob auto, as on a TPU
+        kw = SerialTreeLearner(cfg, ds).build_kwargs()
+        assert (kw["work_layout"], kw["part_kernel"]) == ("planes", "pallas")
     cfg, ds = _mini_ds(rng, {"tpu_resident_state": "on",
                              "tpu_work_layout": "planes"})
     lrn = SerialTreeLearner(cfg, ds)

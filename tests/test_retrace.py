@@ -159,12 +159,14 @@ def test_second_same_shape_linear_predict_zero_compiles():
     assert obs.telemetry.counter("serve/bucket_hit") == 2
 
 
-def test_forest_kernel_same_bucket_zero_compiles():
+def test_forest_kernel_same_bucket_zero_compiles(monkeypatch):
     """ISSUE 16: the forest-at-once path rides the same bucket contract —
     after the first dispatch warms a rung, repeat forest predicts pay
     ZERO tracked compiles, ZERO backend compiles, and ZERO node-table
     rebuilds (the serve/forest_build counter)."""
+    from lightgbm_tpu.ops import partition
     from lightgbm_tpu.serve import PredictSession
+    monkeypatch.setattr(partition, "_INTERPRET", True)   # no Mosaic on CPU
     X, y = _data()
     ds = lgb.Dataset(X, label=y)
     bst = lgb.train(dict(PARAMS), ds, num_boost_round=5)
