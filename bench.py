@@ -90,7 +90,7 @@ def _phases(timer, wall, traffic=None):
                    with block i+1's launch, so this is the un-hidden part),
       transfer_s = fused/logs_transfer — the pure device->host log pull,
       host_s     = block trace/compile + async dispatch + per-tree model
-                   reconstruction + dataset construction.
+                   reconstruction + the booster's init.
 
     The legacy per-phase keys stay alongside for trend continuity.
 
@@ -100,7 +100,7 @@ def _phases(timer, wall, traffic=None):
     wall-time self-check."""
     t = timer.times
     host_keys = ("fused/block_fn", "fused/dispatch", "fused/host_trees",
-                 "dataset construction")
+                 "train/booster_init")
     keys = host_keys + ("fused/device_wait", "fused/logs_transfer")
     out = {k.split("/")[-1]: round(t.get(k, 0.0), 3) for k in keys}
     out["device_s"] = round(t.get("fused/device_wait", 0.0), 3)

@@ -15,7 +15,14 @@ import numpy as np
 from .config import Config, resolve_aliases
 from .dataset import BinnedDataset, construct_dataset
 from .boosting import GBDT, create_boosting
+from .obs import TimerMark, host_phase, telemetry
 from .utils.log import Log, LightGBMError
+
+
+# part of the dataset_construct record -> the timer of its host phase
+_CONSTRUCT_PARTS = {"total_s": "construct/total", "copy_s": "construct/copy",
+                    "find_bins_s": "construct/find_bins",
+                    "bin_rows_s": "construct/bin_rows"}
 
 
 def _to_2d(data) -> np.ndarray:
@@ -176,24 +183,34 @@ class Dataset:
             self._constructed = ds
             self._used_params = merged
             return self._constructed
-        if hasattr(self.data, "tocsc"):     # scipy sparse: stays O(nnz)
-            X = self.data
-        else:
-            X = _to_2d(self.data)
-        feature_names = None
-        if isinstance(self.feature_name, (list, tuple)):
-            feature_names = list(self.feature_name)
-        elif hasattr(self.data, "columns"):
-            feature_names = [str(c) for c in self.data.columns]
-        cat = self.categorical_feature
-        if cat == "auto":
-            auto_cats = _pandas_categorical_columns(self.data)
-            cat = auto_cats if auto_cats else None
+        # the reference's own construction is a record of its own
         ref_binned = self.reference.construct(params) if self.reference else None
-        self._constructed = construct_dataset(
-            X, cfg, label=self.label, weight=self.weight, group=self.group,
-            init_score=self.init_score, feature_names=feature_names,
-            categorical_feature=cat, reference=ref_binned)
+        mark = TimerMark(_CONSTRUCT_PARTS)
+        with host_phase("lgbtpu/construct"):
+            if hasattr(self.data, "tocsc"):     # scipy sparse: stays O(nnz)
+                X = self.data
+            else:
+                with host_phase("lgbtpu/construct_copy"):
+                    X = _to_2d(self.data)
+            feature_names = None
+            if isinstance(self.feature_name, (list, tuple)):
+                feature_names = list(self.feature_name)
+            elif hasattr(self.data, "columns"):
+                feature_names = [str(c) for c in self.data.columns]
+            cat = self.categorical_feature
+            if cat == "auto":
+                auto_cats = _pandas_categorical_columns(self.data)
+                cat = auto_cats if auto_cats else None
+            self._constructed = construct_dataset(
+                X, cfg, label=self.label, weight=self.weight,
+                group=self.group, init_score=self.init_score,
+                feature_names=feature_names, categorical_feature=cat,
+                reference=ref_binned)
+        parts = mark.grown()
+        total = parts.pop("total_s")
+        telemetry.record("dataset_construct", total_s=total,
+                         other_s=total - sum(parts.values()),
+                         rows=X.shape[0], features=X.shape[1], **parts)
         self._used_params = merged
         if self.free_raw_data:
             self.data = None

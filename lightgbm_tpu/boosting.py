@@ -23,7 +23,7 @@ from .dataset import BinnedDataset
 from .learner import (SerialTreeLearner, TreeLog, assign_leaves,
                       leaf_values_by_row)
 from .metric import Metric, create_metrics
-from .obs import track_jit
+from .obs import host_phase, trace_phase, track_jit
 from .objective import ObjectiveFunction, create_objective
 from .tree import Tree
 from .utils.log import Log
@@ -36,7 +36,6 @@ from functools import partial as _partial
 def _score_add(score, lv, leaf_assign, scale, class_id):
     """One fused launch per tree contribution (kept jitted: the eager form
     retraced per op and dominated DART/rollback wall-clock)."""
-    from .obs import trace_phase
     with trace_phase("lgbtpu/score_update"):
         vals = leaf_values_by_row(lv, leaf_assign, lv.shape[0]) * scale
         if score.ndim > 1:
@@ -107,6 +106,8 @@ class GBDT:
         # model. Re-entrant because _rebuild_scores bumps the version
         # from inside locked sections.
         self._cache_lock = threading.RLock()
+        # engine.train's obs.JobStart, until the first dispatch writes it
+        self._job_start = None
         if train_set is not None:
             self._setup(train_set)
 
@@ -118,7 +119,8 @@ class GBDT:
         from . import obs_device
         obs_device.configure(cost_enabled=cfg.obs_device_cost)
         self.objective = create_objective(cfg)
-        self.objective.init(train_set.metadata)
+        with host_phase("lgbtpu/objective_init"):
+            self.objective.init(train_set.metadata)
         self.num_tree_per_iteration = self.objective.num_model_per_iteration
         self.metrics = create_metrics(cfg, self.objective.name)
         from .parallel.mesh import create_tree_learner, make_mesh
@@ -127,7 +129,8 @@ class GBDT:
             import jax as _jax
             if len(_jax.devices()) > 1:
                 mesh = make_mesh()
-        self.learner = create_tree_learner(cfg, train_set, mesh)
+        with host_phase("lgbtpu/learner_init"):   # uploads included
+            self.learner = create_tree_learner(cfg, train_set, mesh)
         n = train_set.num_data
         # boost_from_average (reference: gbdt.cpp:333; distributed mean is a
         # psum at objective level — labels are row-sharded the same way)
@@ -147,18 +150,8 @@ class GBDT:
                 base, jnp.float32)
         self._inbag = jnp.ones((n,), jnp.float32)
         self._cegb_used = np.zeros(train_set.num_features, dtype=bool)
-        self._setup_grad_fn()
-
-    def _setup_grad_fn(self) -> None:
-        obj = self.objective
-
-        @jax.jit
-        def grads(score, it):
-            if obj.needs_iter:
-                return obj.get_gradients(score, it)
-            return obj.get_gradients(score)
-
-        self._grad_fn = track_jit("boosting/grads", grads)
+        self._grad_fn = track_jit("boosting/grads",
+                                  jax.jit(self.objective.gradients))
 
     def add_valid(self, name: str, valid_set: BinnedDataset) -> None:
         vs = ScoreTracker(valid_set.num_data, self.num_tree_per_iteration,
@@ -258,19 +251,21 @@ class GBDT:
         if self._sampler_fn is None:
             self._amp = None
             return
-        g = grad if grad.ndim == 1 else jnp.sum(jnp.abs(grad), axis=1)
-        h = hess if hess.ndim == 1 else jnp.sum(jnp.abs(hess), axis=1)
-        inbag, amp = self._sampler_fn(None, it, g, h)
+        with trace_phase("lgbtpu/sample"):
+            g = grad if grad.ndim == 1 else jnp.sum(jnp.abs(grad), axis=1)
+            h = hess if hess.ndim == 1 else jnp.sum(jnp.abs(hess), axis=1)
+            inbag, amp = self._sampler_fn(None, it, g, h)
         self._inbag = inbag
         self._amp = amp if cfg.data_sample_strategy == "goss" else None
 
     def _tree_channels(self, grad: jax.Array, hess: jax.Array, k: int) -> jax.Array:
-        g = grad if grad.ndim == 1 else grad[:, k]
-        h = hess if hess.ndim == 1 else hess[:, k]
-        if getattr(self, "_amp", None) is not None:
-            g, h = g * self._amp, h * self._amp
-        m = self._inbag
-        return jnp.stack([g * m, h * m, m], axis=1)
+        with trace_phase("lgbtpu/sample"):
+            g = grad if grad.ndim == 1 else grad[:, k]
+            h = hess if hess.ndim == 1 else hess[:, k]
+            if getattr(self, "_amp", None) is not None:
+                g, h = g * self._amp, h * self._amp
+            m = self._inbag
+            return jnp.stack([g * m, h * m, m], axis=1)
 
     def _feature_mask(self, it: int) -> jax.Array:
         cfg = self.config
@@ -278,9 +273,10 @@ class GBDT:
         if not hasattr(self, "_fmask_fn"):
             from .fused import make_feature_mask_fn
             self._fmask_fn = make_feature_mask_fn(cfg, nf)
-        if self._fmask_fn is None:
-            return jnp.ones((nf,), bool)
-        return self._fmask_fn(it)
+        with trace_phase("lgbtpu/sample"):
+            if self._fmask_fn is None:
+                return jnp.ones((nf,), bool)
+            return self._fmask_fn(it)
 
     # --------------------------------------------------------------- training
     def train_one_iter(self, grad: Optional[np.ndarray] = None,

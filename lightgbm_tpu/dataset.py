@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from .config import Config
+from .obs import host_phase
 from .ops.binning import (
     BIN_CATEGORICAL,
     BIN_NUMERICAL,
@@ -366,107 +367,113 @@ def construct_dataset(
         ds.feature_names = reference.feature_names
         ds.monotone_constraints = reference.monotone_constraints
         ds.feature_penalty = reference.feature_penalty
-        ds.binned = _extract_binned(X, ds, nthreads=int(config.num_threads))
+        with host_phase("lgbtpu/construct_bin_rows"):
+            ds.binned = _extract_binned(X, ds,
+                                        nthreads=int(config.num_threads))
         ds.metadata = Metadata(num_data, label, weight, group, init_score)
         if config.linear_tree:
             ds.raw_numeric = _raw_numeric(X, ds)
         return ds
 
-    cat_idx = set(_resolve_categorical(categorical_feature if categorical_feature is not None
-                                       else config.categorical_feature,
-                                       num_total, ds.feature_names))
+    # everything that reads only a sample of the rows: bin mappers, trivial
+    # features, the EFB bundles
+    with host_phase("lgbtpu/construct_find_bins"):
+        cat_idx = set(_resolve_categorical(categorical_feature if categorical_feature is not None
+                                           else config.categorical_feature,
+                                           num_total, ds.feature_names))
 
-    # ---- sampling for bin finding (reference: bin_construct_sample_cnt,
-    # dataset_loader.cpp:903 SampleTextDataFromFile) ----
-    sample_cnt = min(num_data, int(config.bin_construct_sample_cnt))
-    rng = np.random.RandomState(config.data_random_seed)
-    if sample_cnt < num_data:
-        sample_idx = rng.choice(num_data, size=sample_cnt, replace=False)
-        sample_idx.sort()
-    else:
-        sample_idx = np.arange(num_data)
-    if sparse:
-        import scipy.sparse as sp
-        Xs_csc = sp.csc_matrix(sp.csr_matrix(X)[sample_idx])
+        # ---- sampling for bin finding (reference: bin_construct_sample_cnt,
+        # dataset_loader.cpp:903 SampleTextDataFromFile) ----
+        sample_cnt = min(num_data, int(config.bin_construct_sample_cnt))
+        rng = np.random.RandomState(config.data_random_seed)
+        if sample_cnt < num_data:
+            sample_idx = rng.choice(num_data, size=sample_cnt, replace=False)
+            sample_idx.sort()
+        else:
+            sample_idx = np.arange(num_data)
+        if sparse:
+            import scipy.sparse as sp
+            Xs_csc = sp.csc_matrix(sp.csr_matrix(X)[sample_idx])
 
-        def sample_col(f: int) -> np.ndarray:
-            # nonzeros only; find_bin counts the rest as implicit zeros
-            return np.asarray(
-                Xs_csc.data[Xs_csc.indptr[f]:Xs_csc.indptr[f + 1]], np.float64)
+            def sample_col(f: int) -> np.ndarray:
+                # nonzeros only; find_bin counts the rest as implicit zeros
+                return np.asarray(
+                    Xs_csc.data[Xs_csc.indptr[f]:Xs_csc.indptr[f + 1]], np.float64)
 
-        def sample_nz_mask(f: int) -> np.ndarray:
-            mask = np.zeros(sample_cnt, dtype=bool)
-            mask[Xs_csc.indices[Xs_csc.indptr[f]:Xs_csc.indptr[f + 1]]] = True
-            return mask
-    else:
-        X_sample = np.asarray(X[sample_idx], dtype=np.float64)
+            def sample_nz_mask(f: int) -> np.ndarray:
+                mask = np.zeros(sample_cnt, dtype=bool)
+                mask[Xs_csc.indices[Xs_csc.indptr[f]:Xs_csc.indptr[f + 1]]] = True
+                return mask
+        else:
+            X_sample = np.asarray(X[sample_idx], dtype=np.float64)
 
-        def sample_col(f: int) -> np.ndarray:
-            return X_sample[:, f]
+            def sample_col(f: int) -> np.ndarray:
+                return X_sample[:, f]
 
-        def sample_nz_mask(f: int) -> np.ndarray:
-            col = X_sample[:, f]
-            return np.abs(np.nan_to_num(col, nan=1.0)) > 1e-35
+            def sample_nz_mask(f: int) -> np.ndarray:
+                col = X_sample[:, f]
+                return np.abs(np.nan_to_num(col, nan=1.0)) > 1e-35
 
-    # per-feature max_bin override (reference: max_bin_by_feature, config.h)
-    max_bin_by_feature = config.max_bin_by_feature
-    min_split_data = 0
-    if config.feature_pre_filter:
-        # features that cannot split given min_data_in_leaf are trivial
-        min_split_data = int(config.min_data_in_leaf * sample_cnt / max(1, num_data))
+        # per-feature max_bin override (reference: max_bin_by_feature, config.h)
+        max_bin_by_feature = config.max_bin_by_feature
+        min_split_data = 0
+        if config.feature_pre_filter:
+            # features that cannot split given min_data_in_leaf are trivial
+            min_split_data = int(config.min_data_in_leaf * sample_cnt / max(1, num_data))
 
-    forced_bounds = _load_forced_bins(config.forcedbins_filename, num_total)
+        forced_bounds = _load_forced_bins(config.forcedbins_filename, num_total)
 
-    mappers: List[BinMapper] = []
-    used: List[int] = []
-    for f in range(num_total):
-        mb = (max_bin_by_feature[f] if f < len(max_bin_by_feature) else config.max_bin)
-        m = find_bin(
-            sample_col(f),
-            sample_cnt,
-            mb,
-            config.min_data_in_bin,
-            bin_type=BIN_CATEGORICAL if f in cat_idx else BIN_NUMERICAL,
-            use_missing=config.use_missing,
-            zero_as_missing=config.zero_as_missing,
-            min_split_data=min_split_data,
-            forced_bounds=forced_bounds.get(f),
+        mappers: List[BinMapper] = []
+        used: List[int] = []
+        for f in range(num_total):
+            mb = (max_bin_by_feature[f] if f < len(max_bin_by_feature) else config.max_bin)
+            m = find_bin(
+                sample_col(f),
+                sample_cnt,
+                mb,
+                config.min_data_in_bin,
+                bin_type=BIN_CATEGORICAL if f in cat_idx else BIN_NUMERICAL,
+                use_missing=config.use_missing,
+                zero_as_missing=config.zero_as_missing,
+                min_split_data=min_split_data,
+                forced_bounds=forced_bounds.get(f),
+            )
+            if m.is_trivial:
+                continue
+            mappers.append(m)
+            used.append(f)
+        if not mappers:
+            Log.warning("All features are trivial; training will produce constant predictions")
+        ds.bin_mappers = mappers
+        ds.used_feature_indices = used
+
+        # ---- EFB bundling decision (reference: dataset.cpp:239 FastFeatureBundling) ----
+        ds.groups, ds.feature_to_group, ds.feature_group_offset = _make_groups(
+            sample_nz_mask, sample_cnt, used, mappers,
+            # bundles are capped at 256 bins so the matrix stays uint8; with
+            # max_bin > 256 single features already need uint16 — skip bundling
+            enable_bundle=config.enable_bundle and config.max_bin <= 256,
+            max_conflict_rate=float(getattr(config, "max_conflict_rate", 0.0)),
         )
-        if m.is_trivial:
-            continue
-        mappers.append(m)
-        used.append(f)
-    if not mappers:
-        Log.warning("All features are trivial; training will produce constant predictions")
-    ds.bin_mappers = mappers
-    ds.used_feature_indices = used
+        ds.max_bins_per_feature = max((g.num_bins for g in ds.groups), default=1)
 
-    # ---- EFB bundling decision (reference: dataset.cpp:239 FastFeatureBundling) ----
-    ds.groups, ds.feature_to_group, ds.feature_group_offset = _make_groups(
-        sample_nz_mask, sample_cnt, used, mappers,
-        # bundles are capped at 256 bins so the matrix stays uint8; with
-        # max_bin > 256 single features already need uint16 — skip bundling
-        enable_bundle=config.enable_bundle and config.max_bin <= 256,
-        max_conflict_rate=float(getattr(config, "max_conflict_rate", 0.0)),
-    )
-    ds.max_bins_per_feature = max((g.num_bins for g in ds.groups), default=1)
+        # monotone constraints / feature penalties mapped to used features
+        if config.monotone_constraints:
+            mc = np.zeros(len(used), dtype=np.int8)
+            for i, f in enumerate(used):
+                if f < len(config.monotone_constraints):
+                    mc[i] = np.sign(config.monotone_constraints[f])
+            if np.any(mc != 0):
+                ds.monotone_constraints = mc
+        if config.feature_contri:
+            fp = np.ones(len(used), dtype=np.float32)
+            for i, f in enumerate(used):
+                if f < len(config.feature_contri):
+                    fp[i] = config.feature_contri[f]
+            ds.feature_penalty = fp
 
-    # monotone constraints / feature penalties mapped to used features
-    if config.monotone_constraints:
-        mc = np.zeros(len(used), dtype=np.int8)
-        for i, f in enumerate(used):
-            if f < len(config.monotone_constraints):
-                mc[i] = np.sign(config.monotone_constraints[f])
-        if np.any(mc != 0):
-            ds.monotone_constraints = mc
-    if config.feature_contri:
-        fp = np.ones(len(used), dtype=np.float32)
-        for i, f in enumerate(used):
-            if f < len(config.feature_contri):
-                fp[i] = config.feature_contri[f]
-        ds.feature_penalty = fp
-
-    ds.binned = _extract_binned(X, ds, nthreads=int(config.num_threads))
+    with host_phase("lgbtpu/construct_bin_rows"):
+        ds.binned = _extract_binned(X, ds, nthreads=int(config.num_threads))
     ds.metadata = Metadata(num_data, label, weight, group, init_score)
     if config.linear_tree:
         ds.raw_numeric = _raw_numeric(X, ds)

@@ -13,7 +13,7 @@ import numpy as np
 from .basic import Booster, Dataset
 from .callback import CallbackEnv, EarlyStopException, early_stopping, log_evaluation
 from .config import resolve_aliases
-from .obs import telemetry, trace_phase
+from .obs import JobStart, host_phase, telemetry
 from .utils.log import Log, LightGBMError
 
 
@@ -30,11 +30,21 @@ def train(
     keep_training_booster: bool = True,
 ) -> Booster:
     """Train a booster (reference: engine.py:14)."""
+    job = JobStart()    # writes the job_start record at the first dispatch
+    with host_phase("lgbtpu/train"):
+        return _train(job, params, train_set, num_boost_round, valid_sets,
+                      valid_names, fobj, feval, init_model, callbacks)
+
+
+def _train(job, params, train_set, num_boost_round, valid_sets, valid_names,
+           fobj, feval, init_model, callbacks) -> Booster:
     params = resolve_aliases(dict(params))
     num_boost_round = int(params.pop("num_iterations", num_boost_round))
     if fobj is not None:
         params.setdefault("objective", "none")
     early_rounds = params.pop("early_stopping_round", 0)
+    verbosity = int(params.get("verbosity", 1))
+    job.verbose = verbosity > 0
 
     from .utils.timer import global_timer
     if params.get("machines") or int(params.get("num_machines", 1)) > 1:
@@ -44,8 +54,9 @@ def train(
             "(lightgbm_tpu.parallel.distributed.init_distributed + "
             "tree_learner=data)")
 
-    with global_timer.timed("dataset construction"):
+    with host_phase("lgbtpu/booster_init"):
         booster = Booster(params, train_set)
+    job.init_done()
     if init_model is not None:
         init = init_model if isinstance(init_model, Booster) else \
             Booster(model_file=init_model)
@@ -77,7 +88,6 @@ def train(
         callbacks.append(early_stopping(int(early_rounds),
                                         first_metric_only=bool(
                                             params.get("first_metric_only", False))))
-    verbosity = int(params.get("verbosity", 1))
     auto_callbacks = []
     if verbosity > 0 and not any(getattr(c, "order", None) == 10 for c in callbacks):
         auto_cb = log_evaluation(int(params.get("metric_freq", 1)))
@@ -99,11 +109,11 @@ def train(
         end = begin + num_boost_round
         stopped = False
         scheduled = begin  # iter_ lags by the in-flight pipelined block
+        booster.inner._job_start = job
         try:
             while scheduled < end:
                 k = min(block, end - scheduled)
-                with global_timer.timed("fused boosting block"), \
-                        trace_phase("lgbtpu/train_block"):
+                with host_phase("lgbtpu/train_block"):
                     stopped = booster.inner.train_block(k)
                 if stopped:
                     break
@@ -119,6 +129,7 @@ def train(
             # the fused path pipelines host tree reconstruction one block
             # behind the device; finalize the in-flight block
             stopped = booster.inner.finish_fused("train_end") or stopped
+        booster.inner._job_start = None     # a job that dispatched nothing
         if stopped:
             Log.warning("Stopped training because there are no more leaves "
                         "that meet the split requirements")
@@ -137,9 +148,9 @@ def train(
         for cb in callbacks_before:
             cb(CallbackEnv(booster, params, it, begin,
                            begin + num_boost_round, None, telemetry))
-        with global_timer.timed("boosting iteration"), \
-                trace_phase("lgbtpu/train_iter"):
+        with host_phase("lgbtpu/train_iter"):
             stop = booster.update(fobj=fobj)
+        job.dispatched("eager")     # writes once, after the first iteration
         # periodic model snapshots for resume (reference: gbdt.cpp:277
         # SaveModelToFile(model.snapshot_iter_N) every snapshot_freq iters)
         if snapshot_freq > 0 and (it + 1) % snapshot_freq == 0:
@@ -152,7 +163,7 @@ def train(
                 with open(dump, "w") as f:
                     json.dump(telemetry.snapshot(), f, indent=2)
         evals = []
-        with global_timer.timed("metric eval"):
+        with host_phase("lgbtpu/metric_eval"):
             if has_train_in_valid:
                 evals.extend(booster.eval_train(feval))
             evals.extend(booster.eval_valid(feval))

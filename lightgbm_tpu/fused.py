@@ -26,8 +26,7 @@ import numpy as np
 from . import obs_device, runtime
 from .config import Config
 from .learner import SerialTreeLearner, TreeLog, leaf_values_by_row
-from .obs import sync, telemetry, trace_phase, track_jit
-from .utils.timer import global_timer
+from .obs import host_phase, sync, telemetry, trace_phase, track_jit
 
 # Process-wide cache of jitted block functions. A Booster's jitted callables
 # die with the Booster, so back-to-back train() calls with identical
@@ -293,28 +292,27 @@ class FusedTrainer:
 
         def one_iter(sampler, bins, bins_t, bins_res, meta, score, cegb_used,
                      wbuf, key, it):
-            if obj.needs_iter:
-                g, h = obj.get_gradients(score, it)
-            else:
-                g, h = obj.get_gradients(score)
-            if sampler is not None:
-                inbag, amp = sampler(key, it, g, h)
-            else:
-                inbag = amp = None
-            if fmask_fn is not None:
-                fmask = fmask_fn(it)
-            else:
-                fmask = jnp.ones((nf,), bool)
+            g, h = obj.gradients(score, it)
+            with trace_phase("lgbtpu/sample"):
+                if sampler is not None:
+                    inbag, amp = sampler(key, it, g, h)
+                else:
+                    inbag = amp = None
+                if fmask_fn is not None:
+                    fmask = fmask_fn(it)
+                else:
+                    fmask = jnp.ones((nf,), bool)
             logs = []
             for c in range(K):
-                gc = g if g.ndim == 1 else g[:, c]
-                hc = h if h.ndim == 1 else h[:, c]
-                if inbag is not None:
-                    gc, hc = gc * amp * inbag, hc * amp * inbag
-                    cnt = inbag
-                else:
-                    cnt = jnp.ones_like(gc)
-                ghc = jnp.stack([gc, hc, cnt], axis=1)
+                with trace_phase("lgbtpu/sample"):
+                    gc = g if g.ndim == 1 else g[:, c]
+                    hc = h if h.ndim == 1 else h[:, c]
+                    if inbag is not None:
+                        gc, hc = gc * amp * inbag, hc * amp * inbag
+                        cnt = inbag
+                    else:
+                        cnt = jnp.ones_like(gc)
+                    ghc = jnp.stack([gc, hc, cnt], axis=1)
                 if wspec is not None:
                     log, wbuf = build(
                         bins, ghc, meta, fmask,
@@ -325,9 +323,12 @@ class FusedTrainer:
                     log = build(bins, ghc, meta, fmask,
                                 jax.random.fold_in(key, it * 131 + c),
                                 cegb_used)
-                valid_r = jnp.arange(log.feature.shape[0]) < log.num_splits
-                cegb_used = cegb_used.at[
-                    jnp.where(valid_r, log.feature, nf)].set(True, mode="drop")
+                with trace_phase("lgbtpu/tree_log"):
+                    valid_r = jnp.arange(log.feature.shape[0]) \
+                        < log.num_splits
+                    cegb_used = cegb_used.at[
+                        jnp.where(valid_r, log.feature, nf)].set(
+                            True, mode="drop")
                 with trace_phase("lgbtpu/score_update"):
                     vals = log.leaf_value * jnp.float32(lr)
                     upd = leaf_values_by_row(vals, log.row_leaf,
@@ -337,8 +338,11 @@ class FusedTrainer:
                         score = score.at[:, c].add(upd)
                     else:
                         score = score + upd
-                logs.append(_small(log, learner.hp.has_categorical))
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *logs) if K > 1 else logs[0]
+                with trace_phase("lgbtpu/tree_log"):
+                    logs.append(_small(log, learner.hp.has_categorical))
+            with trace_phase("lgbtpu/tree_log"):
+                stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *logs) \
+                    if K > 1 else logs[0]
             return score, cegb_used, wbuf, stacked
 
         @jax.jit
@@ -351,44 +355,48 @@ class FusedTrainer:
             for a, v in ostate.items():
                 setattr(obj, a, v)
             try:
-                if balanced:
-                    sampler = make_balanced_sampler(cfg, obj.label)
-                else:
-                    sampler = make_sampler(cfg, score.shape[0])
-                # one ping-pong work buffer allocated per block and carried
-                # across the k trees (a fresh alloc+zero per tree costs
-                # ~260 MB of HBM writes at 2M rows). The spec is layout-
-                # aware: (2, Npad, W) row-major or (2, W, Npad) transposed
-                # planes (learner.work_buf_spec / tpu_work_layout) — this
-                # loop never looks inside the buffer
-                wbuf = jnp.zeros(wspec[0], wspec[1]) \
-                    if wspec is not None else jnp.zeros((), jnp.uint8)
-                # transposed bins for the per-tree routing pass, computed
-                # once per block (loop-invariant; ~20 ms at 2M x 28). When
-                # the Pallas route kernel applies, hoist its padded
-                # (F, npad/128, 128) block form so no per-tree pad/reshape
-                # copy rides inside the scan body.
-                bins_t = None
-                if wspec is not None:
-                    from .ops.route import ROUTE_BLOCK_ROWS
-                    bins_t = bins.T
-                    if not learner.hp.has_categorical and runtime.on_tpu():
-                        n_ = bins.shape[0]
-                        npad = ((n_ + ROUTE_BLOCK_ROWS - 1)
-                                // ROUTE_BLOCK_ROWS) * ROUTE_BLOCK_ROWS
-                        if npad != n_:
-                            bins_t = jnp.pad(bins_t,
-                                             ((0, 0), (0, npad - n_)))
-                        bins_t = bins_t.reshape(bins.shape[1],
-                                                npad // 128, 128)
-                # resident bin planes for tpu_resident_state: uploaded once
-                # per block in ORIGINAL row order; the per-split partition
-                # only permutes the slim route/ridx/g/h/c payload and the
-                # histogram gathers bins through the row-index plane.
-                bins_res = None
-                if rspec is not None:
-                    from .ops.partition import resident_bin_planes
-                    bins_res = resident_bin_planes(bins, *rspec)
+                with trace_phase("lgbtpu/sample"):
+                    if balanced:
+                        sampler = make_balanced_sampler(cfg, obj.label)
+                    else:
+                        sampler = make_sampler(cfg, score.shape[0])
+                with trace_phase("lgbtpu/block_setup"):
+                    # one ping-pong work buffer allocated per block and
+                    # carried across the k trees (a fresh alloc+zero per
+                    # tree costs ~260 MB of HBM writes at 2M rows). The spec
+                    # is layout-aware: (2, Npad, W) row-major or (2, W, Npad)
+                    # transposed planes (learner.work_buf_spec /
+                    # tpu_work_layout) — this loop never looks inside it
+                    wbuf = jnp.zeros(wspec[0], wspec[1]) \
+                        if wspec is not None else jnp.zeros((), jnp.uint8)
+                    # transposed bins for the per-tree routing pass,
+                    # computed once per block (loop-invariant; ~20 ms at
+                    # 2M x 28). When the Pallas route kernel applies, hoist
+                    # its padded (F, npad/128, 128) block form so no
+                    # per-tree pad/reshape copy rides inside the scan body.
+                    bins_t = None
+                    if wspec is not None:
+                        from .ops.route import ROUTE_BLOCK_ROWS
+                        bins_t = bins.T
+                        if not learner.hp.has_categorical \
+                                and runtime.on_tpu():
+                            n_ = bins.shape[0]
+                            npad = ((n_ + ROUTE_BLOCK_ROWS - 1)
+                                    // ROUTE_BLOCK_ROWS) * ROUTE_BLOCK_ROWS
+                            if npad != n_:
+                                bins_t = jnp.pad(bins_t,
+                                                 ((0, 0), (0, npad - n_)))
+                            bins_t = bins_t.reshape(bins.shape[1],
+                                                    npad // 128, 128)
+                    # resident bin planes for tpu_resident_state: uploaded
+                    # once per block in ORIGINAL row order; the per-split
+                    # partition only permutes the slim route/ridx/g/h/c
+                    # payload and the histogram gathers bins through the
+                    # row-index plane.
+                    bins_res = None
+                    if rspec is not None:
+                        from .ops.partition import resident_bin_planes
+                        bins_res = resident_bin_planes(bins, *rspec)
 
                 def body(carry, i):
                     score, used, wbuf = carry
@@ -426,7 +434,7 @@ class FusedTrainer:
         contributed zero score in-graph via the num_splits mask), so model
         and score stay consistent for rollback/continued training."""
         gbdt = self.gbdt
-        with global_timer.timed("fused/block_fn"):
+        with host_phase("lgbtpu/fused_block_fn"):
             fn = self._block_fn(k)
         prev = self._pending
         # iter_ only advances when a block is FINALIZED (keeps iter_ and
@@ -439,12 +447,15 @@ class FusedTrainer:
         # the real device wait is the logs transfer in _finalize)
         telemetry.count("fused/blocks_dispatched")
         telemetry.count("fused/iters_dispatched", k)
-        with global_timer.timed("fused/dispatch"), \
-                trace_phase("lgbtpu/fused_dispatch"):
-            (score, used), logs = fn(pre_score, pre_used,
-                                     gbdt._key, jnp.int32(it0),
-                                     self.learner.bins, self.learner.meta,
-                                     _obj_array_state(gbdt.objective))
+        args = (pre_score, pre_used, gbdt._key, jnp.int32(it0),
+                self.learner.bins, self.learner.meta,
+                _obj_array_state(gbdt.objective))
+        with host_phase("lgbtpu/fused_dispatch"):
+            (score, used), logs = fn.dispatch(*args)
+        job, gbdt._job_start = gbdt._job_start, None
+        if job is not None:     # the first block of an lgb.train call
+            job.dispatched("fused")
+        fn.after_call(args, {})     # compile count, cost capture
         gbdt.train_score.score = score
         self._cegb_used_dev = used
         if self.config.obs_check_finite != "off":
@@ -535,14 +546,11 @@ class FusedTrainer:
             # pure host<-device payload pull. Pipelining is preserved:
             # _finalize waits on the PREVIOUS block while the freshly
             # dispatched one executes.
-            with global_timer.timed("fused/device_wait"), \
-                    trace_phase("lgbtpu/fused_device_wait"):
+            with host_phase("lgbtpu/fused_device_wait"):
                 sync(logs)
-            with global_timer.timed("fused/logs_transfer"), \
-                    trace_phase("lgbtpu/fused_flush"):
+            with host_phase("lgbtpu/fused_flush"):
                 host = jax.device_get(logs)
-            obs_device.maybe_sample_hbm()   # block-boundary HBM watermark
-            with global_timer.timed("fused/host_trees"):
+            with host_phase("lgbtpu/fused_host_trees"):
                 for i in range(k):
                     all_constant = True
                     for c in range(K):
@@ -557,13 +565,18 @@ class FusedTrainer:
         except BaseException:
             self._rollback(pre_score, pre_used)
             raise
-        # atomic commit: models/iter_/version move together only on full
-        # success, under the model lock so serving never packs mid-commit
-        with gbdt._cache_lock:
-            gbdt.models.extend(trees)
-            gbdt.iter_ += k
-            gbdt._bump_model_version()
-        self._count_trees(trees)
+        with host_phase("lgbtpu/fused_commit"):
+            # atomic commit: models/iter_/version move together only on
+            # full success, under the model lock so serving never packs
+            # mid-commit
+            with gbdt._cache_lock:
+                gbdt.models.extend(trees)
+                gbdt.iter_ += k
+                gbdt._bump_model_version()
+            # dispatched - finalized = the iterations in flight
+            telemetry.count("fused/iters_finalized", k)
+            obs_device.maybe_sample_hbm()   # block-boundary HBM watermark
+            self._count_trees(trees)
         return last_iter_constant
 
     def _count_trees(self, trees) -> None:

@@ -741,7 +741,8 @@ def build_tree_partitioned(
                              "a carried work_buf (its M-sized shape is the "
                              "cond's common work signature)")
         m = goss_compact_rows
-        bins_c, ghc_c, c_in = compact_rows_by_inbag(bins, ghc, m)
+        with trace_phase("lgbtpu/sample"):
+            bins_c, ghc_c, c_in = compact_rows_by_inbag(bins, ghc, m)
         sub = dict(
             num_leaves=num_leaves, num_bin=num_bin, max_depth=max_depth,
             feature_fraction_bynode=feature_fraction_bynode,
@@ -759,11 +760,13 @@ def build_tree_partitioned(
             # root sums come from the DENSE ghc: the row reduce's strided
             # accumulators would regroup f32 additions over the compacted
             # array (+/-1 ulp — enough to flip near-tie splits)
+            with trace_phase("lgbtpu/tree_state"):
+                root_sum_dense = jnp.sum(ghc, axis=0)
             return build_tree_partitioned(
                 bins_c, ghc_c, meta, feature_mask, key, cegb_used, hp,
                 work_buf=work_buf, bins_t=None, bins_res=None,
                 route_bins=(bins, bins_t),
-                root_sum_in=jnp.sum(ghc, axis=0), **sub)
+                root_sum_in=root_sum_dense, **sub)
 
         def _dense(_):
             # fresh internal N-sized buffers; the carried M-sized work_buf
@@ -906,19 +909,21 @@ def build_tree_partitioned(
         else:
             with trace_phase("lgbtpu/pack"):
                 work0 = pack_rows(jnp.pad(bins, pad), jnp.pad(ghc, pad))
-        if work_buf is not None:
-            # reuse the caller's ping-pong pair (fused blocks carry it
-            # across trees): only plane 0's used columns need writing —
-            # stale bytes elsewhere are never consumed (blends commit only
-            # valid rows, and the histogram/route reads touch only the
-            # used columns)
-            work = work_buf.at[0, :, :work0.shape[1]].set(work0)
-        else:
-            if work0.shape[1] < buf_width:
-                # the fused kernel DMAs whole 128-lane tiles; pad row width
-                work0 = jnp.pad(work0,
-                                ((0, 0), (0, buf_width - work0.shape[1])))
-            work = jnp.stack([work0, jnp.zeros_like(work0)])  # (2, Npad, W)
+        with trace_phase("lgbtpu/pack"):
+            if work_buf is not None:
+                # reuse the caller's ping-pong pair (fused blocks carry it
+                # across trees): only plane 0's used columns need writing —
+                # stale bytes elsewhere are never consumed (blends commit
+                # only valid rows, and the histogram/route reads touch only
+                # the used columns)
+                work = work_buf.at[0, :, :work0.shape[1]].set(work0)
+            else:
+                if work0.shape[1] < buf_width:
+                    # the fused kernel DMAs whole 128-lane tiles; pad row
+                    # width
+                    work0 = jnp.pad(
+                        work0, ((0, 0), (0, buf_width - work0.shape[1])))
+                work = jnp.stack([work0, jnp.zeros_like(work0)])  # (2,Npad,W)
         part_fn = partition_segment_fused if fused_part else partition_segment
 
     def hist_of(work, plane, start, cnt):
@@ -1078,9 +1083,10 @@ def build_tree_partitioned(
                         node_depth=depth, adv_bounds=adv_b)
 
     # ---- init: root ----
-    root_sum_loc = jnp.sum(ghc, axis=0) if root_sum_in is None \
-        else root_sum_in
-    root_sum = comm.root(root_sum_loc)
+    with trace_phase("lgbtpu/tree_state"):
+        root_sum_loc = jnp.sum(ghc, axis=0) if root_sum_in is None \
+            else root_sum_in
+        root_sum = comm.root(root_sum_loc)
     if planes:
         # folded into the pack pass above (bit-identical accumulation to
         # hist_of over the root segment: same chunking, same einsum order)
@@ -1089,46 +1095,47 @@ def build_tree_partitioned(
         with trace_phase("lgbtpu/root_hist"):
             root_hist, work = hist_of(work, jnp.int32(0), jnp.int32(guard),
                                       jnp.int32(n))
-    # the pool is kept FLAT per leaf: 4-D pools make XLA's layout
-    # assignment disagree between the while carry and the gather/update
-    # consumers, inserting a full pool copy per split (measured 2x430 us at
-    # F=137); a 2-D (L, G*B*3) pool has one canonical layout
-    hist_pool = jnp.zeros((num_leaves, num_grp * bm * 3), jnp.float32)
-    hist_pool = hist_pool.at[0].set(root_hist.reshape(-1))
-    leaf_sum = jnp.zeros((num_leaves, 3), jnp.float32).at[0].set(root_sum)
-    leaf_sum_loc = jnp.zeros((num_leaves, 3), jnp.float32).at[0].set(
-        root_sum_loc)
-    leaf_out = jnp.zeros((num_leaves,), jnp.float32).at[0].set(
-        calc_leaf_output(root_sum[0], root_sum[1], hp))
-    leaf_depth = jnp.zeros((num_leaves,), jnp.int32)
-    leaf_lower = jnp.full((num_leaves,), -jnp.inf, jnp.float32)
-    leaf_upper = jnp.full((num_leaves,), jnp.inf, jnp.float32)
-    leaf_used = jnp.zeros((num_leaves, num_feat), bool)
-    leaf_start = jnp.zeros((num_leaves,), jnp.int32).at[0].set(guard)
-    leaf_cnt = jnp.zeros((num_leaves,), jnp.int32).at[0].set(n)
-    leaf_parity = jnp.zeros((num_leaves,), jnp.int32)
-    tree_used0 = cegb_used.astype(bool)
-    if hp.mono_advanced:
-        adv0 = _adv_init(num_leaves, num_feat, num_bin, meta)
-    elif hp.has_monotone and hp.mono_intermediate:
-        # intermediate's neighbor refresh needs only the (L, F) bin boxes
-        adv0 = _adv_boxes_init(num_leaves, num_feat, meta)
-    else:
-        adv0 = ()
-    if hp.mono_advanced:
-        node_best_pair = jax.vmap(
-            node_best, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None, None,
-                                None, 0))
-    else:
-        node_best_pair = jax.vmap(
-            node_best, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None, None, None))
+    with trace_phase("lgbtpu/tree_state"):
+        # the pool is kept FLAT per leaf: 4-D pools make XLA's layout
+        # assignment disagree between the while carry and the gather/update
+        # consumers, inserting a full pool copy per split (measured 2x430 us at
+        # F=137); a 2-D (L, G*B*3) pool has one canonical layout
+        hist_pool = jnp.zeros((num_leaves, num_grp * bm * 3), jnp.float32)
+        hist_pool = hist_pool.at[0].set(root_hist.reshape(-1))
+        leaf_sum = jnp.zeros((num_leaves, 3), jnp.float32).at[0].set(root_sum)
+        leaf_sum_loc = jnp.zeros((num_leaves, 3), jnp.float32).at[0].set(
+            root_sum_loc)
+        leaf_out = jnp.zeros((num_leaves,), jnp.float32).at[0].set(
+            calc_leaf_output(root_sum[0], root_sum[1], hp))
+        leaf_depth = jnp.zeros((num_leaves,), jnp.int32)
+        leaf_lower = jnp.full((num_leaves,), -jnp.inf, jnp.float32)
+        leaf_upper = jnp.full((num_leaves,), jnp.inf, jnp.float32)
+        leaf_used = jnp.zeros((num_leaves, num_feat), bool)
+        leaf_start = jnp.zeros((num_leaves,), jnp.int32).at[0].set(guard)
+        leaf_cnt = jnp.zeros((num_leaves,), jnp.int32).at[0].set(n)
+        leaf_parity = jnp.zeros((num_leaves,), jnp.int32)
+        tree_used0 = cegb_used.astype(bool)
+        if hp.mono_advanced:
+            adv0 = _adv_init(num_leaves, num_feat, num_bin, meta)
+        elif hp.has_monotone and hp.mono_intermediate:
+            # intermediate's neighbor refresh needs only the (L, F) bin boxes
+            adv0 = _adv_boxes_init(num_leaves, num_feat, meta)
+        else:
+            adv0 = ()
+        if hp.mono_advanced:
+            node_best_pair = jax.vmap(
+                node_best, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None, None,
+                                    None, 0))
+        else:
+            node_best_pair = jax.vmap(
+                node_best, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None, None, None))
 
-    # the root's initial search rides the SAME batched callable as the
-    # per-round two-child refresh (batch of 1): one traced split-scan chain
-    # serves every node_best call instead of compiling a second unbatched
-    # variant of the whole reduce-window/select pipeline
-    root_ix = jnp.array([0], jnp.int32)
-    best = _empty_best(num_leaves, num_bin)
+        # the root's initial search rides the SAME batched callable as the
+        # per-round two-child refresh (batch of 1): one traced split-scan chain
+        # serves every node_best call instead of compiling a second unbatched
+        # variant of the whole reduce-window/select pipeline
+        root_ix = jnp.array([0], jnp.int32)
+        best = _empty_best(num_leaves, num_bin)
     with trace_phase("lgbtpu/split_scan"):
         root_info = node_best_pair(
             0, root_ix, root_hist[None], root_sum[None], root_sum_loc[None],
@@ -1137,255 +1144,260 @@ def build_tree_partitioned(
             *((jax.tree.map(lambda a: a[None],
                             _adv_bounds_of(adv0, jnp.int32(0))),)
               if hp.mono_advanced else ()))
-    best = jax.tree.map(lambda b, v: b.at[root_ix].set(v), best, root_info)
-    log = TreeLog(
-        num_splits=jnp.int32(0),
-        split_leaf=jnp.zeros((max_splits,), jnp.int32),
-        feature=jnp.zeros((max_splits,), jnp.int32),
-        bin=jnp.zeros((max_splits,), jnp.int32),
-        kind=jnp.zeros((max_splits,), jnp.int32),
-        default_left=jnp.zeros((max_splits,), bool),
-        gain=jnp.zeros((max_splits,), jnp.float32),
-        left_sum=jnp.zeros((max_splits, 3), jnp.float32),
-        right_sum=jnp.zeros((max_splits, 3), jnp.float32),
-        go_left=jnp.zeros((max_splits, num_bin), bool),
-        miss_bin=jnp.zeros((max_splits,), jnp.int32),
-        movable=jnp.zeros((max_splits,), bool),
-        leaf_value=leaf_out,
-        leaf_sum=leaf_sum,
-        row_leaf=jnp.zeros((n,), jnp.int32),
-    )
+    with trace_phase("lgbtpu/tree_state"):
+        best = jax.tree.map(lambda b, v: b.at[root_ix].set(v), best, root_info)
+        log = TreeLog(
+            num_splits=jnp.int32(0),
+            split_leaf=jnp.zeros((max_splits,), jnp.int32),
+            feature=jnp.zeros((max_splits,), jnp.int32),
+            bin=jnp.zeros((max_splits,), jnp.int32),
+            kind=jnp.zeros((max_splits,), jnp.int32),
+            default_left=jnp.zeros((max_splits,), bool),
+            gain=jnp.zeros((max_splits,), jnp.float32),
+            left_sum=jnp.zeros((max_splits, 3), jnp.float32),
+            right_sum=jnp.zeros((max_splits, 3), jnp.float32),
+            go_left=jnp.zeros((max_splits, num_bin), bool),
+            miss_bin=jnp.zeros((max_splits,), jnp.int32),
+            movable=jnp.zeros((max_splits,), bool),
+            leaf_value=leaf_out,
+            leaf_sum=leaf_sum,
+            row_leaf=jnp.zeros((n,), jnp.int32),
+        )
 
-    def depth_ok(depth):
-        if max_depth <= 0:
-            return jnp.bool_(True)
-        return depth < max_depth
+        def depth_ok(depth):
+            if max_depth <= 0:
+                return jnp.bool_(True)
+            return depth < max_depth
 
-    force_live = jnp.bool_(n_forced > 0)
-    carry0 = (jnp.int32(0), work, leaf_start, leaf_cnt, leaf_parity,
-              hist_pool, leaf_sum, leaf_sum_loc, leaf_out, leaf_depth,
-              leaf_lower, leaf_upper, best, log, leaf_used, tree_used0,
-              force_live, adv0)
+        force_live = jnp.bool_(n_forced > 0)
+        carry0 = (jnp.int32(0), work, leaf_start, leaf_cnt, leaf_parity,
+                  hist_pool, leaf_sum, leaf_sum_loc, leaf_out, leaf_depth,
+                  leaf_lower, leaf_upper, best, log, leaf_used, tree_used0,
+                  force_live, adv0)
 
     def cond(carry):
         r, best, log, force_live = carry[0], carry[12], carry[13], carry[16]
-        forcing = force_live & (r < n_forced) if n_forced else False
-        return (log.num_splits < max_splits) & (r < max_splits + n_forced) \
-            & ((jnp.max(best.gain) > 0.0) | forcing)
+        with trace_phase("lgbtpu/tree_state"):
+            forcing = force_live & (r < n_forced) if n_forced else False
+            return (log.num_splits < max_splits) & (r < max_splits + n_forced) \
+                & ((jnp.max(best.gain) > 0.0) | forcing)
 
     def body(carry):
         (r, work, leaf_start, leaf_cnt, leaf_parity, hist_pool, leaf_sum,
          leaf_sum_loc, leaf_out, leaf_depth, leaf_lower, leaf_upper, best,
          log, leaf_used, tree_used, force_live, adv) = carry
-        leaf = jnp.argmax(best.gain).astype(jnp.int32)
-        info: SplitInfo = jax.tree.map(lambda a: a[leaf], best)
-        if n_forced:
-            # forced splits (reference: serial_tree_learner.cpp:450
-            # ForceSplits) — same protocol as build_tree
-            f_leaf, f_feat, f_bin = forced
+        with trace_phase("lgbtpu/tree_state"):
+            leaf = jnp.argmax(best.gain).astype(jnp.int32)
+            info: SplitInfo = jax.tree.map(lambda a: a[leaf], best)
+            if n_forced:
+                # forced splits (reference: serial_tree_learner.cpp:450
+                # ForceSplits) — same protocol as build_tree
+                f_leaf, f_feat, f_bin = forced
 
-            def pick_forced(_):
-                ri = jnp.minimum(r, n_forced - 1)
-                fl = f_leaf[ri]
-                # voting keeps hist_pool LOCAL; a forced split must still be
-                # identical on every shard (default_left/gain derive from
-                # missing mass), so globalize the leaf histogram first. The
-                # cond predicate is replicated, so the psum is uniform.
-                hg_forced = comm.psum(hist_pool[fl]) \
-                    if (voting or comm.hist_scatter) else hist_pool[fl]
-                hg_forced = hg_forced.reshape(num_grp, bm, 3)
-                fi = find_best_split(
-                    feat_view(hg_forced, leaf_sum[fl]),
-                    leaf_sum[fl], meta,
-                    jnp.arange(num_feat) == f_feat[ri], hp,
-                    parent_output=leaf_out[fl], leaf_lower=leaf_lower[fl],
-                    leaf_upper=leaf_upper[fl],
-                    rand_threshold=jnp.full((num_feat,), f_bin[ri], jnp.int32),
-                    node_depth=leaf_depth[fl],
-                    adv_bounds=(_adv_bounds_of(adv, fl)
-                                if hp.mono_advanced else None))
-                ok = fi.gain > -jnp.inf
-                return (jnp.where(ok, fl, leaf),
-                        jax.tree.map(lambda a, b: jnp.where(ok, a, b), fi, info),
-                        ok)
+                def pick_forced(_):
+                    ri = jnp.minimum(r, n_forced - 1)
+                    fl = f_leaf[ri]
+                    # voting keeps hist_pool LOCAL; a forced split must still be
+                    # identical on every shard (default_left/gain derive from
+                    # missing mass), so globalize the leaf histogram first. The
+                    # cond predicate is replicated, so the psum is uniform.
+                    hg_forced = comm.psum(hist_pool[fl]) \
+                        if (voting or comm.hist_scatter) else hist_pool[fl]
+                    hg_forced = hg_forced.reshape(num_grp, bm, 3)
+                    fi = find_best_split(
+                        feat_view(hg_forced, leaf_sum[fl]),
+                        leaf_sum[fl], meta,
+                        jnp.arange(num_feat) == f_feat[ri], hp,
+                        parent_output=leaf_out[fl], leaf_lower=leaf_lower[fl],
+                        leaf_upper=leaf_upper[fl],
+                        rand_threshold=jnp.full((num_feat,), f_bin[ri], jnp.int32),
+                        node_depth=leaf_depth[fl],
+                        adv_bounds=(_adv_bounds_of(adv, fl)
+                                    if hp.mono_advanced else None))
+                    ok = fi.gain > -jnp.inf
+                    return (jnp.where(ok, fl, leaf),
+                            jax.tree.map(lambda a, b: jnp.where(ok, a, b), fi, info),
+                            ok)
 
-            use_forced = force_live & (r < n_forced)
-            leaf, info, force_live = jax.lax.cond(
-                use_forced, pick_forced,
-                lambda _: (leaf, info, jnp.bool_(False)), operand=None)
-        s = log.num_splits
-        new_leaf = s + 1
+                use_forced = force_live & (r < n_forced)
+                leaf, info, force_live = jax.lax.cond(
+                    use_forced, pick_forced,
+                    lambda _: (leaf, info, jnp.bool_(False)), operand=None)
+            s = log.num_splits
+            new_leaf = s + 1
 
-        if hp.has_monotone and (hp.mono_intermediate or hp.mono_advanced):
-            # the stored best split was evaluated under the bounds current
-            # at the leaf's LAST evaluation; neighbor refreshes may have
-            # tightened them since. The reference re-searches affected
-            # leaves (GoDownToFindLeavesToUpdate -> RecomputeBestSplit);
-            # we keep the chosen split but re-clamp its outputs against the
-            # parent's CURRENT bounds and re-enforce sibling order — the
-            # committed values then respect every earlier neighbor, which
-            # is what the soundness induction needs.
-            mono_f = meta.monotone[info.feature]
-            if hp.mono_advanced:
-                lo_l, up_l, lo_r, up_r = _adv_bounds_of(adv, leaf)
-                wl = jnp.clip(info.left_output,
-                              lo_l[info.feature, info.bin],
-                              up_l[info.feature, info.bin])
-                wr = jnp.clip(info.right_output,
-                              lo_r[info.feature, info.bin],
-                              up_r[info.feature, info.bin])
+            if hp.has_monotone and (hp.mono_intermediate or hp.mono_advanced):
+                # the stored best split was evaluated under the bounds current
+                # at the leaf's LAST evaluation; neighbor refreshes may have
+                # tightened them since. The reference re-searches affected
+                # leaves (GoDownToFindLeavesToUpdate -> RecomputeBestSplit);
+                # we keep the chosen split but re-clamp its outputs against the
+                # parent's CURRENT bounds and re-enforce sibling order — the
+                # committed values then respect every earlier neighbor, which
+                # is what the soundness induction needs.
+                mono_f = meta.monotone[info.feature]
+                if hp.mono_advanced:
+                    lo_l, up_l, lo_r, up_r = _adv_bounds_of(adv, leaf)
+                    wl = jnp.clip(info.left_output,
+                                  lo_l[info.feature, info.bin],
+                                  up_l[info.feature, info.bin])
+                    wr = jnp.clip(info.right_output,
+                                  lo_r[info.feature, info.bin],
+                                  up_r[info.feature, info.bin])
+                else:
+                    lo_p, up_p = leaf_lower[leaf], leaf_upper[leaf]
+                    wl = jnp.clip(info.left_output, lo_p, up_p)
+                    wr = jnp.clip(info.right_output, lo_p, up_p)
+                swap = ((mono_f > 0) & (wl > wr)) | ((mono_f < 0) & (wl < wr))
+                wl, wr = jnp.where(swap, wr, wl), jnp.where(swap, wl, wr)
+                info = info._replace(left_output=wl, right_output=wr)
+
+            if n_forced:
+                valid = info.gain > -jnp.inf
+
+                def sel(a, b):
+                    """Commit only when the round produced a valid split."""
+                    return jnp.where(valid, a, b)
             else:
-                lo_p, up_p = leaf_lower[leaf], leaf_upper[leaf]
-                wl = jnp.clip(info.left_output, lo_p, up_p)
-                wr = jnp.clip(info.right_output, lo_p, up_p)
-            swap = ((mono_f > 0) & (wl > wr)) | ((mono_f < 0) & (wl < wr))
-            wl, wr = jnp.where(swap, wr, wl), jnp.where(swap, wl, wr)
-            info = info._replace(left_output=wl, right_output=wr)
+                # Without forced splits the loop cond guarantees the picked
+                # leaf's gain is positive, so every round commits. Skipping the
+                # where() means no update reads the OLD pool value after the
+                # write — without this, XLA cannot prove the dynamic-update-
+                # slices on the 22 MB hist_pool in-place and inserts two full
+                # copies per split (~72 ms/tree at 255 leaves, profiled).
+                valid = jnp.bool_(True)
 
-        if n_forced:
-            valid = info.gain > -jnp.inf
+                def sel(a, b):
+                    return a
 
-            def sel(a, b):
-                """Commit only when the round produced a valid split."""
-                return jnp.where(valid, a, b)
-        else:
-            # Without forced splits the loop cond guarantees the picked
-            # leaf's gain is positive, so every round commits. Skipping the
-            # where() means no update reads the OLD pool value after the
-            # write — without this, XLA cannot prove the dynamic-update-
-            # slices on the 22 MB hist_pool in-place and inserts two full
-            # copies per split (~72 ms/tree at 255 leaves, profiled).
-            valid = jnp.bool_(True)
-
-            def sel(a, b):
-                return a
-
-        # ---- physical partition of the parent's segment ----
-        # (invalid rounds write garbage into dead regions of the other
-        # plane — harmless, since parity/segments only commit when valid)
-        start = leaf_start[leaf]
-        cnt = leaf_cnt[leaf]
-        parity = leaf_parity[leaf]
-        split_col = bundle["group"][info.feature] if bundle is not None \
-            else info.feature
-        # smaller child by GLOBAL in-bag count, so all shards agree
-        # (serial_tree_learner.cpp:418) — known BEFORE the partition runs,
-        # which is what lets the one-kernel path histogram the right child
-        # inside the same launch
-        left_smaller = info.left_sum[2] <= info.right_sum[2]
+            # ---- physical partition of the parent's segment ----
+            # (invalid rounds write garbage into dead regions of the other
+            # plane — harmless, since parity/segments only commit when valid)
+            start = leaf_start[leaf]
+            cnt = leaf_cnt[leaf]
+            parity = leaf_parity[leaf]
+            split_col = bundle["group"][info.feature] if bundle is not None \
+                else info.feature
+            # smaller child by GLOBAL in-bag count, so all shards agree
+            # (serial_tree_learner.cpp:418) — known BEFORE the partition runs,
+            # which is what lets the one-kernel path histogram the right child
+            # inside the same launch
+            left_smaller = info.left_sum[2] <= info.right_sum[2]
         if not one_kernel:
             with trace_phase("lgbtpu/partition"):
                 work, lt = part_fn(work, parity, start, cnt, split_col,
                                    route_table(info), ch=part_chunk)
-        new_parity = 1 - parity
+        with trace_phase("lgbtpu/tree_state"):
+            new_parity = 1 - parity
 
-        # ---- record ----
-        log = log._replace(
-            num_splits=sel(new_leaf, log.num_splits),
-            split_leaf=log.split_leaf.at[s].set(sel(leaf, log.split_leaf[s])),
-            feature=log.feature.at[s].set(sel(info.feature, log.feature[s])),
-            bin=log.bin.at[s].set(sel(info.bin, log.bin[s])),
-            kind=log.kind.at[s].set(sel(info.kind, log.kind[s])),
-            default_left=log.default_left.at[s].set(
-                sel(info.default_left, log.default_left[s])),
-            gain=log.gain.at[s].set(sel(info.gain, log.gain[s])),
-            left_sum=log.left_sum.at[s].set(sel(info.left_sum, log.left_sum[s])),
-            right_sum=log.right_sum.at[s].set(
-                sel(info.right_sum, log.right_sum[s])),
-            go_left=log.go_left.at[s].set(sel(info.go_left, log.go_left[s])),
-            miss_bin=log.miss_bin.at[s].set(
-                sel(meta.missing_bin[info.feature], log.miss_bin[s])),
-            movable=log.movable.at[s].set(
-                sel(meta.movable_missing[info.feature], log.movable[s])),
-        )
+            # ---- record ----
+            log = log._replace(
+                num_splits=sel(new_leaf, log.num_splits),
+                split_leaf=log.split_leaf.at[s].set(sel(leaf, log.split_leaf[s])),
+                feature=log.feature.at[s].set(sel(info.feature, log.feature[s])),
+                bin=log.bin.at[s].set(sel(info.bin, log.bin[s])),
+                kind=log.kind.at[s].set(sel(info.kind, log.kind[s])),
+                default_left=log.default_left.at[s].set(
+                    sel(info.default_left, log.default_left[s])),
+                gain=log.gain.at[s].set(sel(info.gain, log.gain[s])),
+                left_sum=log.left_sum.at[s].set(sel(info.left_sum, log.left_sum[s])),
+                right_sum=log.right_sum.at[s].set(
+                    sel(info.right_sum, log.right_sum[s])),
+                go_left=log.go_left.at[s].set(sel(info.go_left, log.go_left[s])),
+                miss_bin=log.miss_bin.at[s].set(
+                    sel(meta.missing_bin[info.feature], log.miss_bin[s])),
+                movable=log.movable.at[s].set(
+                    sel(meta.movable_missing[info.feature], log.movable[s])),
+            )
 
-        # ---- segment bookkeeping ----
-        def seg_update(lt, leaf_start, leaf_cnt, leaf_parity):
-            leaf_start = leaf_start.at[new_leaf].set(
-                sel(start + lt, leaf_start[new_leaf]))
-            leaf_cnt = leaf_cnt.at[leaf].set(sel(lt, cnt)) \
-                .at[new_leaf].set(sel(cnt - lt, leaf_cnt[new_leaf]))
-            leaf_parity = leaf_parity.at[leaf].set(sel(new_parity, parity)) \
-                .at[new_leaf].set(sel(new_parity, leaf_parity[new_leaf]))
-            return leaf_start, leaf_cnt, leaf_parity
+            # ---- segment bookkeeping ----
+            def seg_update(lt, leaf_start, leaf_cnt, leaf_parity):
+                leaf_start = leaf_start.at[new_leaf].set(
+                    sel(start + lt, leaf_start[new_leaf]))
+                leaf_cnt = leaf_cnt.at[leaf].set(sel(lt, cnt)) \
+                    .at[new_leaf].set(sel(cnt - lt, leaf_cnt[new_leaf]))
+                leaf_parity = leaf_parity.at[leaf].set(sel(new_parity, parity)) \
+                    .at[new_leaf].set(sel(new_parity, leaf_parity[new_leaf]))
+                return leaf_start, leaf_cnt, leaf_parity
 
-        if not one_kernel:
-            leaf_start, leaf_cnt, leaf_parity = seg_update(
-                lt, leaf_start, leaf_cnt, leaf_parity)
+            if not one_kernel:
+                leaf_start, leaf_cnt, leaf_parity = seg_update(
+                    lt, leaf_start, leaf_cnt, leaf_parity)
 
-        # ---- stats bookkeeping ----
-        leaf_sum = leaf_sum.at[leaf].set(sel(info.left_sum, leaf_sum[leaf])) \
-            .at[new_leaf].set(sel(info.right_sum, leaf_sum[new_leaf]))
-        leaf_out = leaf_out.at[leaf].set(sel(info.left_output, leaf_out[leaf])) \
-            .at[new_leaf].set(sel(info.right_output, leaf_out[new_leaf]))
-        d = leaf_depth[leaf] + 1
-        leaf_depth = leaf_depth.at[leaf].set(sel(d, leaf_depth[leaf])) \
-            .at[new_leaf].set(sel(d, leaf_depth[new_leaf]))
-        if hp.has_monotone and hp.mono_advanced:
-            pass  # per-threshold bounds handled via _adv_commit below
-        elif hp.has_monotone and hp.mono_intermediate:
-            # intermediate: children inherit the parent's scalar bounds,
-            # then BOTH children broadcast their committed outputs as
-            # bounds to every box-overlapping leaf wholly below/above them
-            # in each monotone dimension. The broadcast includes the
-            # sibling constraint (left is wholly below right on the split
-            # feature) AND the reference's neighbor refresh
-            # (monotone_constraints.hpp:463 GoDownToFindLeavesToUpdate) —
-            # without which a neighbor's later sub-split can drop below an
-            # earlier committed output (observed monotonicity violations).
-            lo_p, up_p = leaf_lower[leaf], leaf_upper[leaf]
-            leaf_lower = leaf_lower.at[new_leaf].set(
-                sel(lo_p, leaf_lower[new_leaf]))
-            leaf_upper = leaf_upper.at[new_leaf].set(
-                sel(up_p, leaf_upper[new_leaf]))
-            rng_lo, rng_hi = adv
-            rng_lo, rng_hi, box_l, box_r = _adv_child_boxes(
-                rng_lo, rng_hi, sel, leaf, new_leaf, info)
-            adv = (rng_lo, rng_hi)
-            monov = meta.monotone[None, :]                  # (1, F)
-            inc = monov > 0
-            dec = monov < 0
-            valid_b = sel(jnp.bool_(True), jnp.bool_(False))
-            for (c_rlo, c_rhi), out in ((box_l, info.left_output),
-                                        (box_r, info.right_output)):
-                ov_exc = _adv_overlap_except(rng_lo, rng_hi, c_rlo, c_rhi)
-                below = rng_hi <= c_rlo[None, :]            # wholly below C
-                above = rng_lo >= c_rhi[None, :]            # wholly above C
-                hi_m = jnp.any(ov_exc & ((inc & below) | (dec & above)),
-                               axis=1) & valid_b            # (L,)
-                lo_m = jnp.any(ov_exc & ((inc & above) | (dec & below)),
-                               axis=1) & valid_b
-                leaf_upper = jnp.where(hi_m, jnp.minimum(leaf_upper, out),
-                                       leaf_upper)
-                leaf_lower = jnp.where(lo_m, jnp.maximum(leaf_lower, out),
-                                       leaf_lower)
-        elif hp.has_monotone:
-            # basic bounds both children by the split midpoint (reference:
-            # monotone_constraints.hpp:327 BasicLeafConstraints)
-            mono = meta.monotone[info.feature]
-            bl = br = (info.left_output + info.right_output) * 0.5
-            lo_l, up_l = leaf_lower[leaf], leaf_upper[leaf]
-            new_up_l = jnp.where(mono > 0, jnp.minimum(up_l, bl), up_l)
-            new_lo_r = jnp.where(mono > 0, jnp.maximum(lo_l, br), lo_l)
-            new_lo_l = jnp.where(mono < 0, jnp.maximum(lo_l, bl), lo_l)
-            new_up_r = jnp.where(mono < 0, jnp.minimum(up_l, br), up_l)
-            leaf_lower = leaf_lower.at[leaf].set(sel(new_lo_l, lo_l)) \
-                .at[new_leaf].set(sel(new_lo_r, leaf_lower[new_leaf]))
-            leaf_upper = leaf_upper.at[leaf].set(sel(new_up_l, up_l)) \
-                .at[new_leaf].set(sel(new_up_r, leaf_upper[new_leaf]))
+            # ---- stats bookkeeping ----
+            leaf_sum = leaf_sum.at[leaf].set(sel(info.left_sum, leaf_sum[leaf])) \
+                .at[new_leaf].set(sel(info.right_sum, leaf_sum[new_leaf]))
+            leaf_out = leaf_out.at[leaf].set(sel(info.left_output, leaf_out[leaf])) \
+                .at[new_leaf].set(sel(info.right_output, leaf_out[new_leaf]))
+            d = leaf_depth[leaf] + 1
+            leaf_depth = leaf_depth.at[leaf].set(sel(d, leaf_depth[leaf])) \
+                .at[new_leaf].set(sel(d, leaf_depth[new_leaf]))
+            if hp.has_monotone and hp.mono_advanced:
+                pass  # per-threshold bounds handled via _adv_commit below
+            elif hp.has_monotone and hp.mono_intermediate:
+                # intermediate: children inherit the parent's scalar bounds,
+                # then BOTH children broadcast their committed outputs as
+                # bounds to every box-overlapping leaf wholly below/above them
+                # in each monotone dimension. The broadcast includes the
+                # sibling constraint (left is wholly below right on the split
+                # feature) AND the reference's neighbor refresh
+                # (monotone_constraints.hpp:463 GoDownToFindLeavesToUpdate) —
+                # without which a neighbor's later sub-split can drop below an
+                # earlier committed output (observed monotonicity violations).
+                lo_p, up_p = leaf_lower[leaf], leaf_upper[leaf]
+                leaf_lower = leaf_lower.at[new_leaf].set(
+                    sel(lo_p, leaf_lower[new_leaf]))
+                leaf_upper = leaf_upper.at[new_leaf].set(
+                    sel(up_p, leaf_upper[new_leaf]))
+                rng_lo, rng_hi = adv
+                rng_lo, rng_hi, box_l, box_r = _adv_child_boxes(
+                    rng_lo, rng_hi, sel, leaf, new_leaf, info)
+                adv = (rng_lo, rng_hi)
+                monov = meta.monotone[None, :]                  # (1, F)
+                inc = monov > 0
+                dec = monov < 0
+                valid_b = sel(jnp.bool_(True), jnp.bool_(False))
+                for (c_rlo, c_rhi), out in ((box_l, info.left_output),
+                                            (box_r, info.right_output)):
+                    ov_exc = _adv_overlap_except(rng_lo, rng_hi, c_rlo, c_rhi)
+                    below = rng_hi <= c_rlo[None, :]            # wholly below C
+                    above = rng_lo >= c_rhi[None, :]            # wholly above C
+                    hi_m = jnp.any(ov_exc & ((inc & below) | (dec & above)),
+                                   axis=1) & valid_b            # (L,)
+                    lo_m = jnp.any(ov_exc & ((inc & above) | (dec & below)),
+                                   axis=1) & valid_b
+                    leaf_upper = jnp.where(hi_m, jnp.minimum(leaf_upper, out),
+                                           leaf_upper)
+                    leaf_lower = jnp.where(lo_m, jnp.maximum(leaf_lower, out),
+                                           leaf_lower)
+            elif hp.has_monotone:
+                # basic bounds both children by the split midpoint (reference:
+                # monotone_constraints.hpp:327 BasicLeafConstraints)
+                mono = meta.monotone[info.feature]
+                bl = br = (info.left_output + info.right_output) * 0.5
+                lo_l, up_l = leaf_lower[leaf], leaf_upper[leaf]
+                new_up_l = jnp.where(mono > 0, jnp.minimum(up_l, bl), up_l)
+                new_lo_r = jnp.where(mono > 0, jnp.maximum(lo_l, br), lo_l)
+                new_lo_l = jnp.where(mono < 0, jnp.maximum(lo_l, bl), lo_l)
+                new_up_r = jnp.where(mono < 0, jnp.minimum(up_l, br), up_l)
+                leaf_lower = leaf_lower.at[leaf].set(sel(new_lo_l, lo_l)) \
+                    .at[new_leaf].set(sel(new_lo_r, leaf_lower[new_leaf]))
+                leaf_upper = leaf_upper.at[leaf].set(sel(new_up_l, up_l)) \
+                    .at[new_leaf].set(sel(new_up_r, leaf_upper[new_leaf]))
 
-        # ---- histograms: the smaller child gets a fresh pass over its
-        # contiguous segment; the larger child is parent - smaller ----
-        parent_hist = hist_pool[leaf].reshape(num_grp, bm, 3)
-        pair = jnp.stack([leaf, new_leaf])
+            # ---- histograms: the smaller child gets a fresh pass over its
+            # contiguous segment; the larger child is parent - smaller ----
+            parent_hist = hist_pool[leaf].reshape(num_grp, bm, 3)
+            pair = jnp.stack([leaf, new_leaf])
         if one_kernel:
             # ONE launch: partition + smaller-child histogram + both-child
             # split scan. Inputs match what the oracle's hist_of +
             # node_best_pair would see (bounds/outputs already updated
             # above); outputs are bit-identical by construction.
             if resident:
-                work = write_route_plane(work, bins_res, parity, start, cnt,
-                                         split_col, ch=part_chunk)
+                with trace_phase("lgbtpu/one_kernel_split"):
+                    work = write_route_plane(work, bins_res, parity, start, cnt,
+                                             split_col, ch=part_chunk)
             with trace_phase("lgbtpu/one_kernel_split"):
                 work, lt, hist_left, hist_right, infos = \
                     one_kernel_split_planes(
@@ -1399,51 +1411,56 @@ def build_tree_partitioned(
                         exact=hist_mode != "bf16", ch=part_chunk,
                         hist_chunk=hist_chunk, lo_w=hist_lo,
                         resident_planes=bins_res if resident else None)
-            leaf_start, leaf_cnt, leaf_parity = seg_update(
-                lt, leaf_start, leaf_cnt, leaf_parity)
+            with trace_phase("lgbtpu/tree_state"):
+                leaf_start, leaf_cnt, leaf_parity = seg_update(
+                    lt, leaf_start, leaf_cnt, leaf_parity)
         else:
-            small_start = jnp.where(left_smaller, start, start + lt)
-            small_cnt = jnp.where(left_smaller, lt, cnt - lt)
+            with trace_phase("lgbtpu/tree_state"):
+                small_start = jnp.where(left_smaller, start, start + lt)
+                small_cnt = jnp.where(left_smaller, lt, cnt - lt)
             with trace_phase("lgbtpu/histogram"):
                 hist_small, work = hist_of(work, new_parity, small_start,
                                            small_cnt)
-            hist_large = parent_hist - hist_small
-            hist_left = jnp.where(left_smaller, hist_small, hist_large)
-            hist_right = jnp.where(left_smaller, hist_large, hist_small)
-        if n_forced:
-            old_right = hist_pool[new_leaf].reshape(num_grp, bm, 3)
-            pool_val = jnp.stack([sel(hist_left, parent_hist),
-                                  sel(hist_right, old_right)])
-        else:
-            pool_val = jnp.stack([hist_left, hist_right])
-        hist_pool = hist_pool.at[pair].set(pool_val.reshape(2, -1))
-        # local (g,h,cnt) totals per child (voting mode votes with these;
-        # any group's bins partition the rows, so group 0 sums the leaf)
-        loc_parent = leaf_sum_loc[leaf]
-        loc_left = jnp.sum(hist_left[0], axis=0)
-        loc_right = loc_parent - loc_left
-        leaf_sum_loc = leaf_sum_loc.at[leaf].set(sel(loc_left, loc_parent)) \
-            .at[new_leaf].set(sel(loc_right, leaf_sum_loc[new_leaf]))
+            with trace_phase("lgbtpu/tree_state"):
+                hist_large = parent_hist - hist_small
+                hist_left = jnp.where(left_smaller, hist_small, hist_large)
+                hist_right = jnp.where(left_smaller, hist_large, hist_small)
+        with trace_phase("lgbtpu/tree_state"):
+            if n_forced:
+                old_right = hist_pool[new_leaf].reshape(num_grp, bm, 3)
+                pool_val = jnp.stack([sel(hist_left, parent_hist),
+                                      sel(hist_right, old_right)])
+            else:
+                pool_val = jnp.stack([hist_left, hist_right])
+            hist_pool = hist_pool.at[pair].set(pool_val.reshape(2, -1))
+            # local (g,h,cnt) totals per child (voting mode votes with these;
+            # any group's bins partition the rows, so group 0 sums the leaf)
+            loc_parent = leaf_sum_loc[leaf]
+            loc_left = jnp.sum(hist_left[0], axis=0)
+            loc_right = loc_parent - loc_left
+            leaf_sum_loc = leaf_sum_loc.at[leaf].set(sel(loc_left, loc_parent)) \
+                .at[new_leaf].set(sel(loc_right, leaf_sum_loc[new_leaf]))
 
-        # ---- refresh best splits for the two children ----
-        used_new = leaf_used[leaf].at[info.feature].set(True)
-        leaf_used = leaf_used.at[leaf].set(sel(used_new, leaf_used[leaf])) \
-            .at[new_leaf].set(sel(used_new, leaf_used[new_leaf]))
-        tree_used = tree_used.at[info.feature].set(
-            sel(jnp.bool_(True), tree_used[info.feature]))
+            # ---- refresh best splits for the two children ----
+            used_new = leaf_used[leaf].at[info.feature].set(True)
+            leaf_used = leaf_used.at[leaf].set(sel(used_new, leaf_used[leaf])) \
+                .at[new_leaf].set(sel(used_new, leaf_used[new_leaf]))
+            tree_used = tree_used.at[info.feature].set(
+                sel(jnp.bool_(True), tree_used[info.feature]))
 
         # one vmapped search over both children: the scan ops are tiny at
         # (F, B), so two separate calls pay the per-op dispatch cost twice
         # (one-kernel rounds already scanned inside the fused launch)
         if not one_kernel:
-            extra_pair = ()
-            if hp.mono_advanced:
-                adv = _adv_commit(adv, meta, sel, leaf, new_leaf, info,
-                                  num_bin)
-                ab_l = _adv_bounds_of(adv, leaf)
-                ab_r = _adv_bounds_of(adv, new_leaf)
-                extra_pair = (jax.tree.map(lambda a, b: jnp.stack([a, b]),
-                                           ab_l, ab_r),)
+            with trace_phase("lgbtpu/tree_state"):
+                extra_pair = ()
+                if hp.mono_advanced:
+                    adv = _adv_commit(adv, meta, sel, leaf, new_leaf, info,
+                                      num_bin)
+                    ab_l = _adv_bounds_of(adv, leaf)
+                    ab_r = _adv_bounds_of(adv, new_leaf)
+                    extra_pair = (jax.tree.map(lambda a, b: jnp.stack([a, b]),
+                                               ab_l, ab_r),)
             with trace_phase("lgbtpu/split_scan"):
                 infos = node_best_pair(
                     r, pair, jnp.stack([hist_left, hist_right]),
@@ -1451,14 +1468,15 @@ def build_tree_partitioned(
                     jnp.stack([loc_left, loc_right]), leaf_out[pair],
                     leaf_lower[pair], leaf_upper[pair], used_new, tree_used,
                     d, *extra_pair)
-        gates = jnp.stack([depth_ok(leaf_depth[leaf]),
-                           depth_ok(leaf_depth[new_leaf])]) & valid
-        infos = infos._replace(gain=jnp.where(gates, infos.gain, -jnp.inf))
-        if n_forced:
-            olds = jax.tree.map(lambda a: a[pair], best)
-            infos = jax.tree.map(
-                lambda a, b: jnp.where(valid, a, b), infos, olds)
-        best = jax.tree.map(lambda b, v: b.at[pair].set(v), best, infos)
+        with trace_phase("lgbtpu/tree_state"):
+            gates = jnp.stack([depth_ok(leaf_depth[leaf]),
+                               depth_ok(leaf_depth[new_leaf])]) & valid
+            infos = infos._replace(gain=jnp.where(gates, infos.gain, -jnp.inf))
+            if n_forced:
+                olds = jax.tree.map(lambda a: a[pair], best)
+                infos = jax.tree.map(
+                    lambda a, b: jnp.where(valid, a, b), infos, olds)
+            best = jax.tree.map(lambda b, v: b.at[pair].set(v), best, infos)
 
         return (r + 1, work, leaf_start, leaf_cnt, leaf_parity, hist_pool,
                 leaf_sum, leaf_sum_loc, leaf_out, leaf_depth, leaf_lower,
@@ -1496,6 +1514,13 @@ def assign_leaves(bins: jax.Array, log: TreeLog,
     table; when the dataset has no categorical features (static
     ``has_categorical=False``) that path is skipped entirely.
     """
+    # the one site of the phase: it serves the end of every tree build, the
+    # eager loop and valid-set routing, and no caller sits under another
+    with trace_phase("lgbtpu/route"):
+        return _route_rows(bins, log, has_categorical, bundle, bins_t)
+
+
+def _route_rows(bins, log, has_categorical, bundle, bins_t):
     n = bins.shape[0]
     max_splits = log.split_leaf.shape[0]
     # fast path: numerical(-or-bundled) trees route in ONE streaming Pallas

@@ -18,6 +18,7 @@ import numpy as np
 
 from .config import Config, OBJECTIVE_ALIASES
 from .dataset import Metadata
+from .obs import trace_phase
 from .utils.log import Log
 
 
@@ -100,9 +101,22 @@ class ObjectiveFunction:
     # objectives that draw per-iteration randomness take a traced iteration
     # index in get_gradients (see RankXENDCG)
     needs_iter = False
+    # True for an objective whose get_gradients names its own device phases
+    # (obs.PHASES); every other one runs under ``lgbtpu/objective``
+    names_own_phases = False
 
     def get_gradients(self, score: jax.Array) -> Tuple[jax.Array, jax.Array]:
         raise NotImplementedError
+
+    def gradients(self, score: jax.Array, it) -> Tuple[jax.Array, jax.Array]:
+        """``get_gradients`` as the training loops call it (fused block and
+        eager jit): the iteration index where the objective takes one, and
+        the device phase the time is booked to."""
+        args = (score, it) if self.needs_iter else (score,)
+        if self.names_own_phases:
+            return self.get_gradients(*args)
+        with trace_phase("lgbtpu/objective"):
+            return self.get_gradients(*args)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         """Initial raw score (reference: BoostFromScore, used when
@@ -540,6 +554,7 @@ class LambdarankNDCG(ObjectiveFunction):
     the reference's per-query double loop."""
     name = "lambdarank"
     is_ranking = True
+    names_own_phases = True     # lgbtpu/rank_gather, _sort, _pairs, _scatter
     # _gains_np derives from label + label_gain (both fingerprinted); the
     # bucket tables it feeds ride as jit operands
     fp_skip_attrs = ObjectiveFunction.fp_skip_attrs | {"_gains_np"}
@@ -596,11 +611,28 @@ class LambdarankNDCG(ObjectiveFunction):
         """Per-bucket (Q_b, P_b) grad/hess via padded pairwise lambdas."""
         valid = arrs["valid"]
         safe_idx = arrs["safe_idx"]
-        s = jnp.where(valid, score[safe_idx], -jnp.inf)        # (Q, P)
-        order = jnp.argsort(-s, axis=1)                        # rank -> slot
-        s_sorted = jnp.take_along_axis(s, order, axis=1)
-        g_sorted = jnp.take_along_axis(arrs["gains"], order, axis=1)
-        valid_sorted = jnp.take_along_axis(valid, order, axis=1)
+        with trace_phase("lgbtpu/rank_gather"):
+            s = jnp.where(valid, score[safe_idx], -jnp.inf)    # (Q, P)
+        with trace_phase("lgbtpu/rank_sort"):
+            order = jnp.argsort(-s, axis=1)                    # rank -> slot
+            s_sorted = jnp.take_along_axis(s, order, axis=1)
+            g_sorted = jnp.take_along_axis(arrs["gains"], order, axis=1)
+            valid_sorted = jnp.take_along_axis(valid, order, axis=1)
+        with trace_phase("lgbtpu/rank_pairs"):
+            grad_sorted, hess_sorted = self._pair_lambdas(
+                s_sorted, g_sorted, valid_sorted, arrs["inv_max_dcg"],
+                p_b, K)
+        with trace_phase("lgbtpu/rank_sort"):
+            # unsort ranks back to slots
+            inv = jnp.argsort(order, axis=1)
+            grad_q = jnp.take_along_axis(grad_sorted, inv, axis=1)
+            hess_q = jnp.take_along_axis(hess_sorted, inv, axis=1)
+        return grad_q, hess_q
+
+    def _pair_lambdas(self, s_sorted, g_sorted, valid_sorted, inv_max_dcg,
+                      p_b: int, K: int):
+        """(Q, P) scores, gains and validity in rank order -> (Q, P)
+        grad/hess in rank order, through the (Q, K, P) pairwise tensors."""
         # pairs: i in top-K ranks x j in all ranks; j > i counted once
         si = s_sorted[:, :K]                                   # (Q, K)
         gi = g_sorted[:, :K]
@@ -614,7 +646,7 @@ class LambdarankNDCG(ObjectiveFunction):
         # |delta NDCG| of swapping ranks i<->j
         dd = jnp.abs(di[None, :, None] - disc[None, None, :])
         dgain = jnp.abs(gi[:, :, None] - g_sorted[:, None, :])
-        delta_ndcg = dd * dgain * arrs["inv_max_dcg"][:, None, None]
+        delta_ndcg = dd * dgain * inv_max_dcg[:, None, None]
         # orient each pair so "hi" is the better-labelled doc
         sgn = jnp.where(worse, 1.0, -1.0)
         d = sgn * delta_s                                      # s_hi - s_lo
@@ -643,11 +675,7 @@ class LambdarankNDCG(ObjectiveFunction):
                               1.0)
             grad_sorted = grad_sorted * scale
             hess_sorted = hess_sorted * scale
-        # unsort ranks back to slots
-        inv = jnp.argsort(order, axis=1)
-        grad_q = jnp.take_along_axis(grad_sorted, inv, axis=1)
-        hess_q = jnp.take_along_axis(hess_sorted, inv, axis=1)
-        return grad_q, hess_q
+        return grad_sorted, hess_sorted
 
     def get_gradients(self, score):
         """(N,) score -> (N,) grad/hess; one padded pairwise-lambda kernel
@@ -657,18 +685,20 @@ class LambdarankNDCG(ObjectiveFunction):
         for (q_b, p_b, k_b), arrs in zip(self.bucket_shapes,
                                          self.bucket_arrays):
             grad_q, hess_q = self._bucket_lambdas(score, arrs, p_b, k_b)
-            vm = arrs["valid"].reshape(-1)
-            idx_parts.append(arrs["safe_idx"].reshape(-1))
-            g_parts.append(jnp.where(vm, grad_q.reshape(-1), 0.0))
-            h_parts.append(jnp.where(vm, hess_q.reshape(-1), 0.0))
-        flat_idx = jnp.concatenate(idx_parts)
-        grad = jnp.zeros((n,), jnp.float32).at[flat_idx].add(
-            jnp.concatenate(g_parts))
-        hess = jnp.zeros((n,), jnp.float32).at[flat_idx].add(
-            jnp.concatenate(h_parts))
-        hess = jnp.maximum(hess, 1e-20)
-        if self.weight is not None:
-            grad, hess = grad * self.weight, hess * self.weight
+            with trace_phase("lgbtpu/rank_scatter"):
+                vm = arrs["valid"].reshape(-1)
+                idx_parts.append(arrs["safe_idx"].reshape(-1))
+                g_parts.append(jnp.where(vm, grad_q.reshape(-1), 0.0))
+                h_parts.append(jnp.where(vm, hess_q.reshape(-1), 0.0))
+        with trace_phase("lgbtpu/rank_scatter"):
+            flat_idx = jnp.concatenate(idx_parts)
+            grad = jnp.zeros((n,), jnp.float32).at[flat_idx].add(
+                jnp.concatenate(g_parts))
+            hess = jnp.zeros((n,), jnp.float32).at[flat_idx].add(
+                jnp.concatenate(h_parts))
+            hess = jnp.maximum(hess, 1e-20)
+            if self.weight is not None:
+                grad, hess = grad * self.weight, hess * self.weight
         return grad, hess
 
 

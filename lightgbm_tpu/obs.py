@@ -18,19 +18,33 @@ device, and ROADMAP S2 re-tests them on a directly attached v5e
 Three layers:
 
 1. **Trusted timing** — :func:`sync`, :func:`wall`, :func:`timed_sync`,
-   :func:`ab_interleaved`. ``bench.py`` and the ``scripts/*_bisect.py`` /
-   ``scripts/profile_wall.py`` harnesses build on these.
-2. **Phase tracing** — :func:`trace_phase` wraps a region in
-   ``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` so profiler
-   timelines and HLO dumps carry the learner's phase names (pack,
-   histogram, split_scan, partition, score_update, fused dispatch/flush).
-   Both are trace/metadata-only: they never change the computed values.
+   :func:`ab_interleaved`. The ``scripts/*_bisect.py`` /
+   ``scripts/profile_wall.py`` harnesses build on these; the yardstick
+   (``benchmark/``) reads the clock itself and takes from here only what
+   the program recorded (layer 3).
+2. **Phase tracing** — :func:`trace_phase`, the one primitive every named
+   region goes through; :data:`PHASES` lists every ``lgbtpu/<phase>`` name
+   a site may use. One ``with`` feeds up to four readers:
+
+   - the compiled program's ``op_name`` metadata (``jax.named_scope``),
+     when the region is traced into a jit: ``benchmark/trace_reduce.py``
+     books each device op to its OUTERMOST ``lgbtpu/<phase>``;
+   - the profiler's host timeline (``jax.profiler.TraceAnnotation``), when
+     the region runs on the host: on the device trace's clock, so idle
+     device time is attributed to the span that held it;
+   - a **timer** (``timer=`` name): accumulated seconds and call count in
+     :data:`telemetry` and in ``utils.timer.global_timer`` (D9 retires the
+     latter), read as window deltas by the benchmark's ``timer_delta``;
+   - the **flight recorder** (``obs_trace.tracer``) when ``trace_spans=on``.
+
+   Scope and annotation are metadata: they never change computed values.
 3. **Structured run counters** — the process-global :data:`telemetry`
    registry (counters / gauges / timers / record lists) instrumenting the
-   dataset device caches, the fused pipeline, per-tree growth stats and
-   every ``auto`` knob resolution. ``Booster.telemetry()``,
-   ``CallbackEnv.telemetry``, ``cli --dump-telemetry`` and the bench JSON
-   all read :meth:`Telemetry.snapshot`.
+   dataset device caches, the fused pipeline, per-tree growth stats, every
+   ``auto`` knob resolution, and one ``job_start`` (:class:`JobStart`) and
+   one ``dataset_construct`` record per job. ``Booster.telemetry()``,
+   ``CallbackEnv.telemetry``, ``cli --dump-telemetry`` and the benchmark's
+   readers all read :meth:`Telemetry.snapshot` / :meth:`Telemetry.records`.
 
 All counter updates run on HOST, outside traced code, and never add a
 device sync: telemetry keeps bit-parity with an uninstrumented run.
@@ -44,6 +58,8 @@ import time
 from bisect import bisect_left
 from collections import defaultdict
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+
+from .utils.timer import global_timer
 
 
 # ---------------------------------------------------------------------------
@@ -146,16 +162,75 @@ def ab_interleaved(fns: Sequence[Tuple[str, Callable[[int], Callable[[], Any]]]]
 # Phase tracing
 # ---------------------------------------------------------------------------
 
+# Every ``lgbtpu/<phase>`` a trace_phase site may name: (kind, layer, timer).
+# ``device`` phases are traced into jitted programs and must be SIBLINGS:
+# the benchmark books a device op to the outermost phase of its op_name, so
+# one wrapped around another would swallow it. ``host`` phases run on the
+# host around dispatches and waits; ``timer`` is the telemetry timer the
+# site accumulates into. The layer is PERF.md's (section 3). tests/test_obs.py
+# holds every site and every compiled block program to this table.
+PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
+    # -- device: one iteration of the block program, in program order
+    "lgbtpu/block_setup": ("device", "booster", None),
+    "lgbtpu/objective": ("device", "kernels", None),
+    "lgbtpu/rank_gather": ("device", "kernels", None),
+    "lgbtpu/rank_sort": ("device", "kernels", None),
+    "lgbtpu/rank_pairs": ("device", "kernels", None),
+    "lgbtpu/rank_scatter": ("device", "kernels", None),
+    "lgbtpu/sample": ("device", "booster", None),
+    "lgbtpu/pack": ("device", "tree learner", None),
+    "lgbtpu/root_hist": ("device", "tree learner", None),
+    "lgbtpu/tree_state": ("device", "tree learner", None),
+    "lgbtpu/split_scan": ("device", "tree learner", None),
+    "lgbtpu/partition": ("device", "kernels", None),
+    "lgbtpu/histogram": ("device", "kernels", None),
+    "lgbtpu/one_kernel_split": ("device", "tree learner", None),
+    "lgbtpu/route": ("device", "kernels", None),
+    "lgbtpu/score_update": ("device", "tree learner", None),
+    "lgbtpu/tree_log": ("device", "booster", None),
+    # nested labels of single ops (lgbtpu/ops/<kernel>), never outermost
+    "lgbtpu/ops": ("device", "kernels", None),
+    # -- host: before the loop
+    "lgbtpu/construct": ("host", "host data", "construct/total"),
+    "lgbtpu/construct_copy": ("host", "host data", "construct/copy"),
+    "lgbtpu/construct_find_bins": ("host", "host data", "construct/find_bins"),
+    "lgbtpu/construct_bin_rows": ("host", "host data", "construct/bin_rows"),
+    "lgbtpu/train": ("host", "booster", "train/total"),
+    "lgbtpu/booster_init": ("host", "booster", "train/booster_init"),
+    "lgbtpu/objective_init": ("host", "booster", "train/objective_init"),
+    "lgbtpu/learner_init": ("host", "booster", "train/learner_init"),
+    # -- host: the loop
+    "lgbtpu/train_block": ("host", "booster", "train/block"),
+    "lgbtpu/train_iter": ("host", "booster", "train/iter"),
+    "lgbtpu/metric_eval": ("host", "booster", "train/metric_eval"),
+    "lgbtpu/fused_block_fn": ("host", "booster", "fused/block_fn"),
+    "lgbtpu/fused_dispatch": ("host", "booster", "fused/dispatch"),
+    "lgbtpu/fused_device_wait": ("host", "booster", "fused/device_wait"),
+    "lgbtpu/fused_flush": ("host", "booster", "fused/logs_transfer"),
+    "lgbtpu/fused_host_trees": ("host", "booster", "fused/host_trees"),
+    "lgbtpu/fused_commit": ("host", "booster", "fused/commit"),
+}
+
+
+def host_phase(name: str):
+    """``trace_phase`` of a host phase of :data:`PHASES`, with its timer."""
+    return trace_phase(name, timer=PHASES[name][2])
+
+
 @contextlib.contextmanager
-def trace_phase(name: str) -> Iterator[None]:
-    """Name a hot-phase region for profiler traces, HLO dumps and — when
-    span tracing is on — the host-side flight recorder.
+def trace_phase(name: str, timer: Optional[str] = None) -> Iterator[None]:
+    """Name a region for the compiled program, the profiler, a timer and —
+    when span tracing is on — the host-side flight recorder.
 
     Inside a jit trace, ``jax.named_scope`` stamps the phase name onto the
     emitted HLO ops; on host, ``jax.profiler.TraceAnnotation`` marks the
     span on the profiler timeline. Both are metadata-only — no runtime
     effect on the computed values, so phase-traced trees stay bit-identical
     (tests/test_obs.py rides the existing parity shapes).
+
+    ``timer`` (host regions only: inside a jit trace it would time the
+    trace) adds the region's wall seconds and one call to that telemetry
+    timer, and to ``global_timer`` under the same name.
 
     With ``trace_spans=on`` (obs_trace.tracer), host-side executions of
     the region additionally record a span into the flight recorder.
@@ -170,10 +245,15 @@ def trace_phase(name: str) -> Iterator[None]:
         ann = jax.profiler.TraceAnnotation(name)
     except Exception:  # pragma: no cover - profiler backend unavailable
         ann = contextlib.nullcontext()
+    t0 = time.perf_counter()
     try:
         with jax.named_scope(name), ann:
             yield
     finally:
+        if timer is not None:
+            seconds = time.perf_counter() - t0
+            telemetry.add_time(timer, seconds)
+            global_timer.add(timer, seconds)
         if sp is not None:
             obs_trace.tracer.end(sp)
 
@@ -201,9 +281,10 @@ _suppress = threading.local()
 
 @contextlib.contextmanager
 def suppress_backend_compiles() -> Iterator[None]:
-    """Mute ``jit/backend_compiles`` for compiles issued by the current
-    thread inside the block (used by obs_device.on_compile around its AOT
-    re-compile). The duration still lands in ``device_cost/capture_s``,
+    """Mute ``jit/backend_compiles`` and the ``jit/*_s`` timers for work
+    issued by the current thread inside the block (used by
+    obs_device.on_compile around its AOT re-lowering and re-compile). The
+    duration still lands in ``device_cost/capture_s``,
     so the capture cost stays visible — just not conflated with the
     training path's compile count."""
     prev = getattr(_suppress, "on", False)
@@ -220,11 +301,37 @@ def suppress_backend_compiles() -> Iterator[None]:
 _LISTENER_SENTINEL = "_lightgbm_tpu_compile_listener"
 
 
-def install_compile_listener() -> None:
-    """Count every XLA backend compile into ``jit/backend_compiles``.
+def _exclusive_trace_s(duration: float) -> float:
+    """Seconds of one jaxpr-trace event that no earlier event already holds.
 
-    Uses jax.monitoring's duration listener (fires once per
-    ``backend_compile`` event, including jits we did not wrap). Idempotent
+    A jitted callee traced inside its caller's trace fires its own event
+    first, and the caller's duration includes it; summing both would count
+    the callee twice. Events arrive in order of their ends, so the ones a
+    new event encloses are the newest on this thread's list, which holds
+    the finished children of the traces still open."""
+    from jax.core import trace_ctx
+    start = time.perf_counter() - duration
+    seen = getattr(_suppress, "traces", None)
+    if seen is None:
+        seen = _suppress.traces = []
+    inner = 0.0
+    while seen and seen[-1][0] >= start:
+        inner += seen.pop()[1]
+    if trace_ctx.is_top_level():
+        seen.clear()              # an outermost trace: nothing can hold it
+    else:
+        seen.append((start, duration))
+    return max(duration - inner, 0.0)
+
+
+def install_compile_listener() -> None:
+    """Count every XLA backend compile into ``jit/backend_compiles`` and its
+    seconds into ``jit/backend_compile_s`` (compile, or load from the
+    persistent cache); the seconds of jaxpr tracing into ``jit/trace_s`` and
+    of lowering to MLIR into ``jit/lower_s``.
+
+    Uses jax.monitoring's duration listener (fires once per event,
+    including jits we did not wrap). Idempotent
     across repeated calls, repeated Boosters, and module re-imports (the
     installed marker is a sentinel attribute on ``jax.monitoring``, not
     only a module global — see tests/test_obs.py)."""
@@ -237,11 +344,15 @@ def install_compile_listener() -> None:
         return
 
     def _on_event(event: str, duration: float, **kw) -> None:
+        if getattr(_suppress, "on", False):
+            return
         if "backend_compile" in event:
-            if getattr(_suppress, "on", False):
-                return
             telemetry.count(_BACKEND_COMPILES)
             telemetry.add_time("jit/backend_compile_s", duration)
+        elif event.endswith("/jaxpr_trace_duration"):
+            telemetry.add_time("jit/trace_s", _exclusive_trace_s(duration))
+        elif event.endswith("/jaxpr_to_mlir_module_duration"):
+            telemetry.add_time("jit/lower_s", duration)
 
     monitoring.register_event_duration_secs_listener(_on_event)
     setattr(monitoring, _LISTENER_SENTINEL, _on_event)
@@ -271,6 +382,18 @@ class _TrackedJit:
 
     def __call__(self, *args, **kwargs):
         out = self._fn(*args, **kwargs)
+        self.after_call(args, kwargs)
+        return out
+
+    def dispatch(self, *args, **kwargs):
+        """The wrapped call alone; the caller owes :meth:`after_call` with
+        the same arguments (``FusedTrainer.run`` reads the job's clock
+        between the two, so the cost capture is not booked to job start)."""
+        return self._fn(*args, **kwargs)
+
+    def after_call(self, args, kwargs) -> None:
+        """Count a (re)trace that the call just made and hand its signature
+        to the device-cost capture."""
         size = self._size()
         if size is not None:
             if size > self._seen:
@@ -288,7 +411,6 @@ class _TrackedJit:
                 except Exception:  # pragma: no cover - capture is best-effort
                     telemetry.count("device_cost/capture_errors")
             self._seen = size  # shrink = cache cleared; re-arm
-        return out
 
     def __getattr__(self, name: str):
         return getattr(self._fn, name)
@@ -421,9 +543,9 @@ class Telemetry:
     Thread-safe (the mesh learners and user callbacks may touch it from
     worker threads) and cheap: every mutation is a dict update under one
     lock, on host, never inside traced code. ``snapshot()`` returns a
-    plain JSON-serializable dict and folds in ``utils.timer.global_timer``
-    so the long-standing phase timers (fused/block_fn, fused/dispatch,
-    fused/logs_transfer, ...) appear without double bookkeeping.
+    plain JSON-serializable dict and folds in what only
+    ``utils.timer.global_timer`` holds (the phase timers that
+    :func:`trace_phase` feeds are in both, the registry's reading wins).
     """
 
     def __init__(self) -> None:
@@ -561,6 +683,95 @@ class Telemetry:
 
 
 telemetry = Telemetry()
+
+
+class TimerMark:
+    """The readings of some telemetry timers at one moment; :meth:`grown`
+    gives what each accumulated since, under the caller's own names
+    (``{part: timer name}``). How a record gets its parts from the timers
+    that :func:`trace_phase` sites already feed."""
+
+    def __init__(self, parts: Dict[str, str]) -> None:
+        self._parts = dict(parts)
+        self._then = self._read()
+
+    def _read(self) -> Dict[str, float]:
+        with telemetry._lock:
+            return {t: telemetry._timers.get(t, 0.0)
+                    for t in self._parts.values()}
+
+    def grown(self) -> Dict[str, float]:
+        now = self._read()
+        return {part: now[t] - self._then[t]
+                for part, t in self._parts.items()}
+
+
+class JobStart:
+    """What one ``lgb.train`` call spends before its first dispatch, written
+    by the program as ONE ``job_start`` record (and one ``Log.info`` line
+    unless the job asked for silence).
+
+    ``engine.train`` makes it at entry and calls :meth:`init_done` when the
+    booster exists; the first dispatch — the first block program called in
+    ``FusedTrainer.run`` (``path="fused"``), else the first
+    ``train_one_iter`` returned (``"eager"``) — calls :meth:`dispatched`.
+    No device sync: dispatch is asynchronous and the clock is read after it
+    returns. The parts are growths of process-global timers since entry, so
+    a second job training on another thread at the same time blurs them.
+
+    ``entry_to_first_dispatch_s`` is the whole; ``booster_init_s`` (seconds
+    of ``lgbtpu/booster_init`` less the jit and capture seconds inside it),
+    ``block_fn_s``, ``trace_s``, ``lower_s``, ``compile_or_load_s`` (all
+    jit work since entry), ``cost_capture_s`` (``obs_device.on_compile``;
+    0 on the fused path, whose capture runs behind the dispatch) and
+    ``other_s`` are disjoint and sum to it. ``objective_init_s`` and
+    ``learner_init_s`` are spans inside the booster's init, jit included.
+    """
+
+    # work that may also run inside the booster's init
+    _NESTED = {"trace_s": "jit/trace_s", "lower_s": "jit/lower_s",
+            "compile_or_load_s": "jit/backend_compile_s",
+            "cost_capture_s": "device_cost/capture_s"}
+    _HOST = {"booster_init_s": "train/booster_init",
+             "objective_init_s": "train/objective_init",
+             "learner_init_s": "train/learner_init",
+             "block_fn_s": "fused/block_fn"}
+
+    def __init__(self) -> None:
+        install_compile_listener()
+        self._t0 = time.perf_counter()
+        self._mark = TimerMark({**self._NESTED, **self._HOST})
+        self._nested_in_init = 0.0
+        self._done = False
+        self.verbose = True     # engine.train: the job's verbosity > 0
+
+    def init_done(self) -> None:
+        grown = self._mark.grown()
+        self._nested_in_init = sum(grown[p] for p in self._NESTED)
+
+    def dispatched(self, path: str) -> None:
+        if self._done:
+            return
+        self._done = True
+        whole = time.perf_counter() - self._t0
+        rec = self._mark.grown()
+        rec["booster_init_s"] -= self._nested_in_init
+        rec["other_s"] = whole - sum(
+            v for p, v in rec.items()
+            if p not in ("objective_init_s", "learner_init_s"))
+        telemetry.record("job_start", entry_to_first_dispatch_s=whole,
+                         path=path, **rec)
+        if not self.verbose:
+            return
+        from .utils.log import Log
+        Log.info("job start (%s): %.3f s to the first dispatch = init %.3f "
+                 "(objective %.3f, learner %.3f) + block_fn %.3f + trace "
+                 "%.3f + lower %.3f + compile or load %.3f + cost capture "
+                 "%.3f + other %.3f", path, whole, rec["booster_init_s"],
+                 rec["objective_init_s"], rec["learner_init_s"],
+                 rec["block_fn_s"], rec["trace_s"], rec["lower_s"],
+                 rec["compile_or_load_s"], rec["cost_capture_s"],
+                 rec["other_s"])
 
 
 def safe_metric_part(part: str, max_len: int = 48) -> str:

@@ -23,13 +23,25 @@ allocation, ``span()`` returns a shared no-op context manager, and
 Spans are HOST-side: inside a jit trace ``phase_begin`` refuses to
 record (via ``jax.core.trace_ctx.is_top_level``), so ``trace_phase`` sites
 that live in traced code cost nothing at runtime and do not pollute the
-recorder with trace-time measurements.  Device-side attribution stays
-with ``jax.named_scope`` / the XLA profiler — but the fused finalize
-path splits its spans so device time is visible from host spans alone:
-``lgbtpu/fused_device_wait`` (an ``obs.sync`` completion barrier, pure
-device-execution wait) precedes ``lgbtpu/fused_flush`` (the actual
-result transfer), the host-span mirror of the ``device_s``/
-``transfer_s`` bench breakdown (PERF.md, ISSUE 10).
+recorder with trace-time measurements.
+
+Who feeds what. This module is the serving chain's flight recorder: the
+http / batcher / session / online / fleet spans (and the linear-tree fit)
+come straight through ``tracer.span`` / ``tracer.record`` and reach nothing
+else (plus the ``span_ms/<name>`` histogram in the registry on every span
+end). The boosting loop does not call this module: its named regions go
+through
+``obs.trace_phase`` (table: ``obs.PHASES``), which feeds, in one ``with``,
+the device program's ``op_name`` scope, the profiler's host annotation,
+a telemetry timer where the site names one, and — only with
+``trace_spans=on`` — a span here, through ``phase_begin``. So a training
+span in a dump (``lgbtpu/train_block`` > ``lgbtpu/fused_dispatch``,
+``fused_device_wait``, ``fused_flush``, ``fused_host_trees``,
+``fused_commit``) is the same region the benchmark reads from the
+profiler's clock, and the timers (``fused/*``, ``train/*``) hold the same
+seconds with tracing off. ``lgbtpu/fused_device_wait`` (an ``obs.sync``
+completion barrier: device execution as the host waits for it) precedes
+``lgbtpu/fused_flush`` (the result transfer).
 
 Import-time this module is pure stdlib; jax is resolved lazily when
 tracing is first switched on.

@@ -33,7 +33,14 @@ def enable_compile_cache() -> str:
     (git-ignored) — a fixed path, because the path is part of the cache key.
     JAX's own floor (programs that took >= 1 s to compile) decides what is
     written.
+
+    The cache key holds the programs' metadata: JAX leaves it out by
+    default, and an executable loaded under another source's key carries
+    that source's ``op_name``s — the ``lgbtpu/<phase>`` scopes a device
+    trace is read by (``obs.PHASES``) would be those of whatever commit
+    filled the cache first.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -98,7 +98,7 @@ def test_phase_timers(rng):
               lgb.Dataset(X, label=y), num_boost_round=2,
               valid_sets=[lgb.Dataset(X[:100], label=y[:100])])
     rep = global_timer.report()
-    assert "boosting iteration" in rep and "dataset construction" in rep
+    assert "train/iter" in rep and "train/booster_init" in rep
 
 
 def test_native_parser_matches_python(tmp_path, rng):

@@ -355,17 +355,247 @@ def test_callback_env_carries_telemetry():
     assert env.telemetry is None
 
 
-def test_telemetry_is_bit_parity_neutral():
-    """Counters/tracing must not perturb training: two identical trains
-    (one snapshotted mid-flight via a callback, one not) produce
-    bit-identical predictions."""
-    X, y = _data(n=300, seed=4)
-    p1 = lgb.train(dict(PARAMS), lgb.Dataset(X, label=y),
-                   num_boost_round=5).predict(X)
+def _rank_data(n=600, f=6, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.rand(n, f)
+    y = np.floor(X[:, 0] * 4).astype(np.float64)
+    return X, y, [20] * (n // 20)
+
+
+@pytest.mark.parametrize("objective", ["binary", "lambdarank"])
+def test_telemetry_is_bit_parity_neutral(objective, monkeypatch):
+    """Counters/tracing must not perturb training: a train with every
+    phase scope in place and one with ``jax.named_scope`` taken out (and
+    the registry reset mid-way) give byte-identical models."""
+    import contextlib
+    import jax
+    from lightgbm_tpu import fused
+    if objective == "binary":
+        X, y = _data(n=300, seed=4)
+        kw = {"label": y}
+    else:
+        X, y, group = _rank_data(seed=4)
+        kw = {"label": y, "group": group}
+    params = dict(PARAMS, objective=objective)
+    b1 = lgb.train(dict(params), lgb.Dataset(X, **kw), num_boost_round=5)
     telemetry.reset()
-    p2 = lgb.train(dict(PARAMS), lgb.Dataset(X, label=y),
-                   num_boost_round=5).predict(X)
-    np.testing.assert_array_equal(p1, p2)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    fused._BLOCK_CACHE.clear()        # trace the block again, unscoped
+    b2 = lgb.train(dict(params), lgb.Dataset(X, **kw), num_boost_round=5)
+    fused._BLOCK_CACHE.clear()        # and keep the unscoped one to itself
+    assert b1.model_to_string() == b2.model_to_string()
+    np.testing.assert_array_equal(b1.predict(X), b2.predict(X))
+
+
+# ------------------------------------------------- phases, spans, records
+
+_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = (?:\([^=]*\)|\S+) ([\w\-]+)\(')
+_NOT_WORK = ("parameter", "tuple", "get-tuple-element", "constant")
+
+
+def _block_text(params, ds, k=2):
+    """Compiled text of fused/run_block for a tiny job."""
+    import jax.numpy as jnp
+    from lightgbm_tpu import fused
+    g = lgb.Booster(dict(params, verbosity=-1), ds).inner
+    ft = fused.FusedTrainer(g)
+    args = (g.train_score.score, ft._used_dev(), g._key, jnp.int32(0),
+            g.learner.bins, g.learner.meta,
+            fused._obj_array_state(g.objective))
+    return ft._block_fn(k).lower(*args).compile().as_text()
+
+
+def _scope_cases():
+    X, y = _data(n=600)
+    Xr, yr, group = _rank_data()
+    return {
+        "binary": ({"objective": "binary"}, X, {"label": y}),
+        "regression": ({"objective": "regression"}, X,
+                       {"label": X[:, 0] * 2 + X[:, 1]}),
+        "multiclass": ({"objective": "multiclass", "num_class": 3}, X,
+                       {"label": np.floor(X[:, 0] * 3)}),
+        "lambdarank": ({"objective": "lambdarank"}, Xr,
+                       {"label": yr, "group": group}),
+    }
+
+
+@pytest.mark.parametrize("objective", ["binary", "lambdarank", "regression",
+                                       "multiclass"])
+def test_block_program_ops_lie_under_a_phase_of_the_table(objective):
+    """Every op the program puts into the block lies under one outermost
+    ``lgbtpu/<phase>`` of ``obs.PHASES``, read from the compiled module's
+    op_name as the benchmark reads it (``benchmark/trace_reduce.py``).
+
+    What is left without one, and cannot be reached by a scope: what the
+    compiler makes itself (no op_name: layout copies, rewritten cumsums),
+    constants it hoists out of the tree build (their op_name ends at
+    ``closed_call``) and the scan's own stacking of its outputs. On the CPU
+    backend that is 9 % of the instructions that carry an op_name and 22 %
+    of all; before the phases of PR 26 it was most of them."""
+    import sys
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import trace_reduce
+    params, X, kw = _scope_cases()[objective]
+    text = _block_text(dict(params, num_leaves=7, min_data_in_leaf=5),
+                       lgb.Dataset(X, **kw))
+    scope_of = trace_reduce.scope_map(text)
+    found, work, named, bare, bare_named = set(), 0, 0, 0, 0
+    for line in text.splitlines():
+        m = _OP.match(line)
+        if not m or m.group(2) in _NOT_WORK:
+            continue
+        has_name = 'op_name="' in line
+        scope = scope_of.get(m.group(1), "")
+        work += 1
+        named += has_name
+        if scope:
+            found.add(scope)
+        else:
+            bare += 1
+            bare_named += has_name
+    assert found <= set(obs.PHASES), found - set(obs.PHASES)
+    assert all(obs.PHASES[s][0] == "device" for s in found)
+    assert bare_named <= 0.12 * named, (bare_named, named)
+    assert bare <= 0.25 * work, (bare, work)
+    rank = {"lgbtpu/rank_gather", "lgbtpu/rank_sort", "lgbtpu/rank_pairs",
+            "lgbtpu/rank_scatter"}
+    if objective == "lambdarank":
+        assert rank <= found and "lgbtpu/objective" not in found
+    else:
+        assert "lgbtpu/objective" in found and not rank & found
+    assert {"lgbtpu/route", "lgbtpu/tree_state", "lgbtpu/tree_log",
+            "lgbtpu/block_setup", "lgbtpu/sample"} <= found
+
+
+def test_every_phase_site_names_a_phase_of_the_table():
+    """No site invents a name: every literal ``lgbtpu/...`` handed to
+    trace_phase / host_phase in the package is a key of obs.PHASES, host
+    phases have a timer, and every phase of the table has a site."""
+    site = re.compile(r'(trace_phase|host_phase)\(\s*"(lgbtpu/[\w\-]+)')
+    used = {}
+    pkg = os.path.join(REPO, "lightgbm_tpu")
+    for root, _, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    for fn, name in site.findall(fh.read()):
+                        used.setdefault(name, set()).add(fn)
+    assert set(used) <= set(obs.PHASES), set(used) - set(obs.PHASES)
+    assert set(obs.PHASES) <= set(used), set(obs.PHASES) - set(used)
+    for name, (kind, layer, timer) in obs.PHASES.items():
+        assert kind in ("device", "host") and layer
+        assert (timer is not None) == (kind == "host"), name
+        if kind == "host":
+            assert used[name] == {"host_phase"}, name
+
+
+_JOB_PARTS = ("booster_init_s", "block_fn_s", "trace_s", "lower_s",
+              "compile_or_load_s", "cost_capture_s", "other_s")
+
+
+@pytest.mark.parametrize("path", ["fused", "eager"])
+def test_one_job_start_record_per_train(path):
+    from lightgbm_tpu import fused
+    X, y = _data(seed=5)
+    ds = lgb.Dataset(X, label=y)
+    ds.construct(dict(PARAMS))
+    kw = {"valid_sets": [ds]} if path == "eager" else {}
+    fused._BLOCK_CACHE.clear()
+    telemetry.reset()
+    lgb.train(dict(PARAMS), ds, num_boost_round=3, **kw)
+    first, = telemetry.records("job_start")
+    assert first["path"] == path
+    for part in _JOB_PARTS + ("objective_init_s", "learner_init_s"):
+        assert first[part] >= 0.0, (part, first)
+    assert sum(first[p] for p in _JOB_PARTS) == pytest.approx(
+        first["entry_to_first_dispatch_s"], abs=1e-6)
+    assert first["objective_init_s"] + first["learner_init_s"] <= \
+        telemetry.snapshot()["timers"]["train/booster_init"]
+    assert first["trace_s"] > 0 and first["lower_s"] > 0
+    assert first["compile_or_load_s"] > 0
+    if path == "eager":
+        return
+    # a second identical job: the block comes from the process-wide cache
+    compiles = telemetry.counter("jit/compiles/fused/run_block")
+    assert compiles == 1
+    lgb.train(dict(PARAMS), ds, num_boost_round=3)
+    _, second = telemetry.records("job_start")
+    assert telemetry.counter("jit/compiles/fused/run_block") == compiles
+    assert second["trace_s"] < 0.1 * first["trace_s"]
+    assert second["entry_to_first_dispatch_s"] < \
+        first["entry_to_first_dispatch_s"]
+
+
+def test_one_dataset_construct_record_per_real_construction():
+    X, y = _data(seed=6)
+    telemetry.reset()
+    ds = lgb.Dataset(X, label=y)
+    ds.construct(dict(PARAMS))
+    rec, = telemetry.records("dataset_construct")
+    assert (rec["rows"], rec["features"]) == X.shape
+    parts = ("copy_s", "find_bins_s", "bin_rows_s", "other_s")
+    assert all(rec[p] >= 0.0 for p in parts), rec
+    assert sum(rec[p] for p in parts) == pytest.approx(rec["total_s"],
+                                                       abs=1e-6)
+    assert rec["find_bins_s"] > 0 and rec["bin_rows_s"] > 0
+    ds.construct(dict(PARAMS))                  # cached: no new record
+    lgb.train(dict(PARAMS), ds, num_boost_round=2)
+    assert len(telemetry.records("dataset_construct")) == 1
+    # a validation set binned with the training set's mappers is a
+    # construction of its own, with nothing to find
+    ds.create_valid(X[:100], label=y[:100]).construct(dict(PARAMS))
+    _, valid = telemetry.records("dataset_construct")
+    assert valid["rows"] == 100 and valid["find_bins_s"] == 0.0
+
+
+def test_iters_finalized_follows_iter():
+    """dispatched - finalized = the iterations in flight."""
+    X, y = _data(seed=7)
+    ds = lgb.Dataset(X, label=y)
+    telemetry.reset()
+    bst = lgb.train(dict(PARAMS, tpu_iter_block=2), ds, num_boost_round=5)
+    assert telemetry.counter("fused/iters_finalized") == bst.inner.iter_ == 5
+    assert telemetry.counter("fused/iters_dispatched") == 5
+    telemetry.reset()
+    bst2 = lgb.Booster(dict(PARAMS), ds)
+    bst2.inner.train_block(4)                   # dispatched, in flight
+    assert telemetry.counter("fused/iters_dispatched") == 4
+    assert telemetry.counter("fused/iters_finalized") == 0
+    bst2.predict(X[:10])                        # a read API flushes
+    assert telemetry.counter("fused/iters_finalized") == bst2.inner.iter_ == 4
+
+
+def test_compile_listener_times_trace_and_lowering_once():
+    """jit/trace_s counts a nested jit's trace once (the caller's event
+    holds the callee's), and the capture's re-lowering not at all."""
+    import jax
+    import jax.numpy as jnp
+
+    obs.install_compile_listener()
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2.0
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x + 1.0)
+
+    telemetry.reset()
+    t0 = obs.monotonic()
+    outer(jnp.arange(7.0)).block_until_ready()
+    wall = obs.monotonic() - t0
+    t = telemetry.snapshot(include_global_timer=False)["timers"]
+    assert 0 < t["jit/trace_s"] and 0 < t["jit/lower_s"]
+    assert t["jit/trace_s"] + t["jit/lower_s"] \
+        + t["jit/backend_compile_s"] <= wall
+    before = dict(t)
+    with obs.suppress_backend_compiles():
+        outer.lower(jnp.arange(9.0)).compile()
+    t = telemetry.snapshot(include_global_timer=False)["timers"]
+    assert {k: t[k] for k in before if k.startswith("jit/")} == \
+        {k: v for k, v in before.items() if k.startswith("jit/")}
 
 
 # ------------------------------------------------------------- surfaces

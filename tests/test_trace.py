@@ -164,6 +164,38 @@ def test_off_path_starts_zero_spans_during_train():
     assert tracer.events() == []
 
 
+def test_block_tail_spans_nest_inside_train_block():
+    """With trace_spans=on the flight recorder holds the host's work on a
+    finished block (host trees, commit) inside the engine's train_block
+    span, each with the timer of its phase in the registry."""
+    obs.telemetry.reset()
+    X, y = _data(seed=3)
+    lgb.train(dict(PARAMS, trace_spans="on", tpu_iter_block=2),
+              lgb.Dataset(X, label=y), num_boost_round=6)
+    spans = tracer.events()
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp.name, []).append(sp)
+    blocks = by_name["lgbtpu/train_block"]
+    assert len(blocks) == 3
+    for name in ("lgbtpu/fused_host_trees", "lgbtpu/fused_commit",
+                 "lgbtpu/fused_block_fn", "lgbtpu/fused_dispatch"):
+        assert by_name.get(name), name
+    # blocks 0 and 1 are finalised inside calls 1 and 2, the last one by
+    # the flush at the end of lgb.train, outside every train_block
+    for name in ("lgbtpu/fused_host_trees", "lgbtpu/fused_commit"):
+        inside = [sp for sp in by_name[name]
+                  if any(b.tid == sp.tid and b.t0 <= sp.t0
+                         and sp.t0 + sp.dur <= b.t0 + b.dur for b in blocks)]
+        assert len(by_name[name]) == 3 and len(inside) == 2, name
+    # lgbtpu/train and lgbtpu/booster_init open before the booster's
+    # params switch the recorder on: timers only, on a first traced job
+    assert "lgbtpu/train" not in by_name
+    calls = obs.telemetry.snapshot()["timer_calls"]
+    assert calls["fused/host_trees"] == calls["fused/commit"] == 3
+    assert calls["train/block"] == 3 and calls["train/total"] == 1
+
+
 def test_trace_phase_refuses_inside_jit_trace():
     """trace_phase sites living in traced code (learner/boosting) must
     not record trace-time spans — only eager host executions count."""
