@@ -981,25 +981,47 @@ def partition_segment_fused(
 #
 # - HBM chunk reads use 128-lane-aligned windows derived as (x//128)*128
 #   at every use site (the lane twin of the rows kernel's (x//32)*32);
-# - in-chunk compaction runs per SB-column sub-block as ONE perm matmul
-#   (SB, LCAP) that does placement AND the circular wrap arithmetically:
-#   dest = (cursor + rank) mod LCAP, so frontier rows land at absolute
-#   circular stage slots and the stage update is a full-stage ADD — no
-#   dynamic window, no roll;
-# - the circular stages are (W, LCAP=2*SB) f32; a flush converts one
-#   STATIC half to u8 and pure-writes it to an aligned HBM window, then
-#   zeroes the half (future adds land on zeros);
+# - in-chunk compaction runs per SB-lane sub-block in two halves. The
+#   CURSOR-FREE half, for all sub-blocks of a chunk before any cursor is
+#   read: one rank matmul a chunk ((2*NSUB, SB) flags @ triu), then per
+#   sub-block ONE (SB, SB) one-hot shared by both sides — lefts packed up
+#   from lane 0 at their rank, rights packed down from lane SB-1 — built
+#   transposed (dst on sublanes, src on the lanes the ranks already sit
+#   on: one compare, no lane-to-sublane relayout) and ONE (W, SB) payload
+#   matmul contracting its lane dim. The CURSOR WALK then, per sub-block,
+#   rotates that product twice on the lanes (pltpu.roll by the traced
+#   cursor mod SB) and adds each side to its stage under (1, SB) lane
+#   masks: the lanes of the side's run, split between the stage's two
+#   static halves where the run wraps past lane SB-1;
+# - the circular stages are (W, LCAP=2*SB) f32: logical left lane q at
+#   slot q % LCAP, right descending index q at slot LCAP-1-(q % LCAP); a
+#   flush converts one STATIC half to u8 and pure-writes it to an aligned
+#   HBM window, then zeroes the half (future adds land on zeros);
 # - leftovers drain as up to 2 serial RMW tiles per side, left fully
 #   before right (their windows can overlap in the middle of the segment).
+#
+# Row placement is a pure function of row order and the cursors — lefts
+# ascending from start in row order, rights descending from start+cnt —
+# whatever SB is and whatever shape the one-hot has, so the work buffer is
+# byte-identical across such choices (tests/test_work_layout.py holds it
+# to that order, at SB 128 and 256).
 #
 # dst-plane state (edge prefills, drain RMW reads) is read through
 # work_ref — the ALIASED OUTPUT — which is the same HBM buffer on device
 # and keeps the kernel bit-faithful under the pallas interpreter, so the
-# CPU suite validates it end-to-end (tests/test_work_layout.py). Per-row
-# cost vs the rows kernel at W=64: DMA bytes ~2x lower, VPU converts ~2-3x
-# lower, perm-matmul MACs comparable (2*W*LCAP vs 2*(SB+8)*128) — the
-# expected win is the DMA/VPU term (PERF.md layout row; on-TPU A/B via
-# scripts/layout_bisect.py).
+# CPU suite validates it end-to-end (tests/test_work_layout.py).
+#
+# Cost, measured on a v5e with the kernel alone (PR 27; 4M / 1.6M-row
+# segments, chunk 1024, SB 256): 1.32 ns per row visit at W=64 planes and
+# 2.39 at W=160, i.e. 0.61 ns a row whatever the width + 0.011 ns a plane;
+# the bytes would take 0.16 / 0.39 ns at HBM speed. Why the shape above
+# (same runs; PERF.md section 6): a sub-block's cost is mostly its serial
+# latency, not its one-hot's area — SB 128 costs 1.5-1.7x a row, SB 512 no
+# less than 256 at W=64, more at W=160; a one-hot with dest on sublanes
+# needs a lane-to-sublane relayout that costs more than the one-hot
+# (+1.0 ns a row); and a one-hot that depends on a cursor keeps every
+# sub-block's matmuls behind the previous sub-block's flush (+0.15 to
+# +0.7 ns a row), which is what the shared, rank-only one-hot avoids.
 
 
 def _partition_planes_kernel(sref, work_in, work_ref, lt_ref,
@@ -1032,7 +1054,7 @@ def _partition_planes_kernel(sref, work_in, work_ref, lt_ref,
     nchunks = (tot + ch - 1) // ch
 
     # strict upper-triangular ones: ranks[j] = sum_{i<j} flags[i], flags
-    # along the LANE dim (flags (2, SB) @ triu (SB, SB) -> (2, SB))
+    # along the LANE dim (flags (2*NSUB, SB) @ triu (SB, SB), once a chunk)
     row_i = jax.lax.broadcasted_iota(jnp.int32, (sb, sb), 0)
     col_i = jax.lax.broadcasted_iota(jnp.int32, (sb, sb), 1)
     triu[:] = jnp.clip(col_i - row_i, 0, 1).astype(f32).astype(jnp.bfloat16)
@@ -1041,6 +1063,7 @@ def _partition_planes_kernel(sref, work_in, work_ref, lt_ref,
     sub_w = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
     lane_128 = jax.lax.broadcasted_iota(jnp.int32, (W, PLANE_ALIGN), 1)
     lane_sb_w = jax.lax.broadcasted_iota(jnp.int32, (W, sb), 1)
+    lane_sb = jax.lax.broadcasted_iota(jnp.int32, (1, sb), 1)
 
     # ---- prefills: neighbor lanes of the aligned edge tiles ----
     pl_in = pltpu.make_async_copy(
@@ -1105,6 +1128,27 @@ def _partition_planes_kernel(sref, work_in, work_ref, lt_ref,
             fb.at[slot], work_ref.at[dst_plane, :, pl.ds(at, sb)],
             sem.at[sem_base + slot]).start()
 
+    def place(stage, y, p, n, left):
+        """Add one side's run of y (W, SB) — n rows, packed from lane 0 up
+        (left) or from lane SB-1 down (right) — to its circular stage at
+        the side's cursor p. Positions count lanes upward on the left and
+        downward on the right (the right stage mirrors its slots): after
+        the rotate the run sits at positions (p % SB + k) % SB, k < n, in
+        the cursor's half of the stage and, past the wrap, in the other.
+        Each lane goes to ONE of the two STATIC halves under a (1, SB)
+        mask; payload bytes times 1.0 or 0.0 are exact."""
+        pos = lane_sb if left else sb - 1 - lane_sb
+        a = jax.lax.rem(p, sb)
+        h = jax.lax.rem(p // sb, 2)
+        run = pos - a
+        run = jnp.where(run < 0, run + sb, run)
+        half = jnp.where(pos >= a, h, 1 - h)
+        if not left:
+            half = 1 - half
+        y = pltpu.roll(y, a if left else jax.lax.rem(sb - a, sb), 1)
+        stage[:, 0:sb] += y * ((run < n) & (half == 0)).astype(f32)
+        stage[:, sb:lcap] += y * ((run < n) & (half == 1)).astype(f32)
+
     def body(i, carry):
         p_l, p_r, fl_l, fl_r = carry
         slot = jax.lax.rem(i, 2)
@@ -1132,35 +1176,46 @@ def _partition_planes_kernel(sref, work_in, work_ref, lt_ref,
         pos = lane_c + i * ch
         valid = (pos >= head_l) & (pos < tot)                 # (1, CH)
 
+        # ---- the cursor-free half of every sub-block, all sub-blocks of
+        # the chunk AHEAD of the cursor walk: nothing here waits for a
+        # cursor, so the rank matmul runs once a chunk and the one-hots and
+        # payload matmuls of the sub-blocks overlap ----
+        gl_c = go & valid
+        gr_c = (~go) & valid
+        flags = []
         for s in range(nsub):
-            sub = cf[:, s * sb:(s + 1) * sb]                  # (W, SB)
-            gl = go[:, s * sb:(s + 1) * sb] & valid[:, s * sb:(s + 1) * sb]
-            gr = (~go[:, s * sb:(s + 1) * sb]) & valid[:, s * sb:(s + 1) * sb]
-            flags = jnp.concatenate(
-                [gl.astype(jnp.bfloat16), gr.astype(jnp.bfloat16)], axis=0)
-            ranks = jax.lax.dot(flags, triu[:],
-                                preferred_element_type=f32)   # (2, SB)
-            nl = jnp.sum(gl.astype(jnp.int32))
-            nr = jnp.sum(gr.astype(jnp.int32))
-            lrank = ranks[0:1, :].astype(jnp.int32)
-            rrank = ranks[1:2, :].astype(jnp.int32)
-            # absolute circular stage slots: the perm matmul does placement
-            # AND the wrap; unrouted columns get -1 (all-zero perm column)
-            dest_l = jnp.where(gl, jax.lax.rem(p_l + lrank, lcap), -1)
-            dest_r = jnp.where(gr, lcap - 1 - jax.lax.rem(p_r + rrank, lcap),
-                               -1)
-            j_i = jax.lax.broadcasted_iota(jnp.int32, (sb, lcap), 1)
-            perm_l = (1 - jnp.clip(jnp.abs(j_i - dest_l.reshape(sb, 1)),
-                                   0, 1)).astype(f32).astype(jnp.bfloat16)
-            perm_r = (1 - jnp.clip(jnp.abs(j_i - dest_r.reshape(sb, 1)),
-                                   0, 1)).astype(f32).astype(jnp.bfloat16)
+            sl = slice(s * sb, (s + 1) * sb)
+            flags += [gl_c[:, sl], gr_c[:, sl]]
+        ranks = jax.lax.dot(
+            jnp.concatenate([g.astype(jnp.bfloat16) for g in flags], axis=0),
+            triu[:], preferred_element_type=f32).astype(jnp.int32)
+        ahead = []
+        for s in range(nsub):
+            gl, gr = flags[2 * s], flags[2 * s + 1]
+            # ONE sub-block-local permutation for both sides: lefts packed
+            # up from lane 0, rights packed down from lane SB-1 (they never
+            # collide: nl + nr <= SB); unrouted rows get -1 (no lane).
+            # Built TRANSPOSED, (SB dst, SB src), so that dest stays on the
+            # lanes it was computed on — one compare against a sublane iota,
+            # no lane-to-sublane relayout — and contracted on its lane dim.
+            dest = jnp.where(gl, ranks[2 * s:2 * s + 1],
+                             jnp.where(gr, sb - 1 - ranks[2 * s + 1:2 * s + 2],
+                                       -1))                   # (1, SB)
+            perm_t = jnp.where(row_i == dest, 1.0, 0.0).astype(jnp.bfloat16)
             # u8 payload bytes are integers <= 255: exact under a 0/1 bf16
             # permutation matmul with f32 accumulation
-            sub_bf = sub.astype(jnp.bfloat16)
-            out_l = jax.lax.dot(sub_bf, perm_l, preferred_element_type=f32)
-            out_r = jax.lax.dot(sub_bf, perm_r, preferred_element_type=f32)
-            lstage[...] += out_l
-            rstage[...] += out_r
+            y = jax.lax.dot_general(
+                cf[:, s * sb:(s + 1) * sb].astype(jnp.bfloat16), perm_t,
+                (((1,), (1,)), ((), ())), preferred_element_type=f32)
+            ahead.append((jnp.sum(gl.astype(jnp.int32)),
+                          jnp.sum(gr.astype(jnp.int32)), y))  # y: (W, SB)
+
+        # ---- the cursor walk: rotate each side to its stage slots. Left
+        # row k goes to logical lane p_l + k, slot (p_l + k) % LCAP; right
+        # row k to descending index p_r + k, slot LCAP-1-((p_r + k) % LCAP)
+        for nl, nr, y in ahead:
+            place(lstage, y, p_l, nl, True)
+            place(rstage, y, p_r, nr, False)
             p_l = p_l + nl
             p_r = p_r + nr
 
@@ -1251,9 +1306,9 @@ def partition_segment_planes_fused(
     ch: int = DEFAULT_CH,
     sb: int = 256,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Pallas form of :func:`partition_segment_planes` (same contract,
-    except row order WITHIN each side is unspecified — histograms are
-    order-free and sub-splits re-partition).
+    """Pallas form of :func:`partition_segment_planes` (same contract and
+    same left child; the right child holds the same rows in fully REVERSED
+    row order, where the XLA form reverses chunk by chunk).
 
     Requires whole-tile dims: Npad % 128 == 0 (lane DMA windows), plane
     count a multiple of 32 (u8 sublane tiles), ch a multiple of 128 and of

@@ -118,38 +118,103 @@ def test_pack_planes_fold_root_matches_segment_hist(rng):
     assert np.array_equal(got, w_r.T)
 
 
-@pytest.mark.parametrize("start,cnt,ch", [(137, 700, 256), (0, 1500, 256),
-                                          (513, 100, 256), (333, 1400, 512)])
-def test_planes_pallas_kernel_interpret(rng, start, cnt, ch, monkeypatch):
-    """The fused planes kernel, run under the pallas interpreter, must match
-    the XLA planes path: left child bit-exact in order, right child the same
-    row set, neighbors outside the segment untouched."""
+def _sub_block_runs(go, head, sb):
+    """(p_l % sb, nl) per compaction sub-block, as the kernel walks them:
+    sub-blocks tile the lanes from the segment's 128-aligned base, and the
+    left cursor starts at ``head`` (the segment's offset from that base)."""
+    flags = np.concatenate([np.zeros(head, bool), go])
+    runs, p_l = [], head
+    for i in range(0, len(flags), sb):
+        nl = int(flags[i:i + sb].sum())
+        runs.append((p_l % sb, nl))
+        p_l += nl
+    return runs
+
+
+# (start, cnt, ch, f, left share, sb, what the case must cross)
+_PLANES_KERNEL_CASES = [
+    (137, 700, 256, 20, 0.45, None, None),
+    (0, 1500, 256, 20, 0.45, None, None),
+    (513, 100, 256, 20, 0.45, None, None),
+    (333, 1400, 512, 20, 0.45, None, None),
+    # a left run that wraps a half boundary (p_l % SB + nl > SB), per SB
+    (137, 700, 256, 20, 0.6, 256, "wrap"),
+    (137, 700, 256, 20, 0.6, 128, "wrap"),
+    (333, 1400, 512, 20, 0.45, 128, "wrap"),
+    # every row right / every row left: nl == 0 and nl == SB sub-blocks
+    (137, 900, 256, 20, 0.0, 256, "nl0"),
+    (137, 900, 256, 20, 0.0, 128, "nl0"),
+    (50, 1100, 256, 20, 1.0, 256, "nlsb"),
+    (50, 1100, 256, 20, 1.0, 128, "nlsb"),
+    # a segment shorter than one sub-block, inside one and across two
+    (513, 37, 256, 20, 0.45, 128, "short"),
+    (600, 37, 256, 20, 0.45, 256, "short"),
+    (250, 90, 256, 20, 0.5, 128, "short"),
+    # W = 160 planes (F = 137 + 12, padded to whole u8 sublane tiles)
+    (137, 700, 256, 137, 0.45, None, None),
+    (333, 1400, 512, 137, 0.55, 128, "wrap"),
+    (333, 1400, 512, 137, 0.55, 256, "wrap"),
+]
+
+
+@pytest.mark.parametrize("start,cnt,ch,f,share,sb,crosses",
+                         _PLANES_KERNEL_CASES)
+def test_planes_pallas_kernel_interpret(rng, start, cnt, ch, f, share, sb,
+                                        crosses, monkeypatch):
+    """The fused planes kernel, run under the pallas interpreter, must place
+    rows exactly where the kernel's contract says — left child in row order
+    ascending from ``start``, right child in REVERSED row order below
+    ``start + cnt`` — whatever the sub-block size, so that the work buffer
+    (and with it the histograms' summation order and the model) is
+    byte-identical across sub-block sizes; neighbors outside the segment and
+    the source plane untouched. Against the XLA planes path: same left
+    child, same right row set (its right child is chunk-reversed)."""
     monkeypatch.setattr(P, "_INTERPRET", True)
-    n, f, num_bin = 1500, 20, 32
+    n, num_bin, feat = 1500, 32, 3
     guard = ch + 2 * P.PLANE_ALIGN
     npad = ((n + 2 * guard + 127) // 128) * 128
-    bins = np.zeros((npad, 20), np.uint8)
-    bins[guard:guard + n, :f] = rng.randint(0, num_bin, (n, f))
+    bins = np.zeros((npad, f), np.uint8)
+    bins[guard:guard + n] = rng.randint(0, num_bin, (n, f))
     ghc = np.zeros((npad, 3), np.float32)
     ghc[guard:guard + n] = rng.randn(n, 3)
     ghc[guard:guard + n, 2] = 1.0
     w0 = np.asarray(P.pack_planes(jnp.asarray(bins), jnp.asarray(ghc)))
+    width = P.work_spec(f, False, "pallas", ch, ch, layout="planes")[1]
+    w0 = np.pad(w0, ((0, width - w0.shape[0]), (0, 0)))
+    assert width == {20: 32, 137: 160}[f]
     sib = rng.randint(0, 256, w0.shape).astype(np.uint8)  # junk dst plane
     work = jnp.stack([jnp.asarray(w0), jnp.asarray(sib)])
-    table = rng.rand(num_bin) < 0.45
+    table = rng.rand(num_bin) < share
     args = (jnp.int32(0), jnp.int32(guard + start), jnp.int32(cnt),
-            jnp.int32(3), jnp.asarray(table))
+            jnp.int32(feat), jnp.asarray(table))
+    kw = {} if sb is None else {"sb": sb}
     out_x, lt_x = P.partition_segment_planes(work, *args, ch=ch)
-    out_p, lt_p = P.partition_segment_planes_fused(work, *args, ch=ch)
+    out_p, lt_p = P.partition_segment_planes_fused(work, *args, ch=ch, **kw)
     out_x, out_p = np.asarray(out_x), np.asarray(out_p)
     lt = int(lt_p)
     assert lt == int(lt_x)
     s0, s1 = guard + start, guard + start + cnt
+
+    seg = w0[:, s0:s1]
+    go = table[seg[feat]]
+    assert lt == int(go.sum())
+    if crosses is not None:
+        runs = _sub_block_runs(go, s0 % P.PLANE_ALIGN, sb)
+        assert {
+            "wrap": any(a + nl > sb for a, nl in runs),
+            "nl0": all(nl == 0 for _, nl in runs),
+            "nlsb": any(nl == sb for _, nl in runs),
+            "short": cnt < sb and len(runs) <= 2,
+        }[crosses], runs
+    want = sib.copy()
+    want[:, s0:s0 + lt] = seg[:, go]
+    want[:, s0 + lt:s1] = seg[:, ~go][:, ::-1]
+    assert np.array_equal(out_p[1], want)
+    assert np.array_equal(out_p[0], w0)
+
     assert np.array_equal(out_p[1, :, s0:s0 + lt], out_x[1, :, s0:s0 + lt])
     assert sorted(map(bytes, out_p[1, :, s0 + lt:s1].T)) == \
         sorted(map(bytes, out_x[1, :, s0 + lt:s1].T))
-    assert np.array_equal(out_p[1, :, :s0], sib[:, :s0])
-    assert np.array_equal(out_p[1, :, s1:], sib[:, s1:])
 
 
 def _train_tree(layout, n, f, leaves, seed=0, part_chunk=CH, hist_chunk=CH):
