@@ -22,6 +22,7 @@ from .utils.log import Log, LightGBMError
 # part of the dataset_construct record -> the timer of its host phase
 _CONSTRUCT_PARTS = {"total_s": "construct/total", "copy_s": "construct/copy",
                     "find_bins_s": "construct/find_bins",
+                    "bundle_s": "construct/bundle",
                     "bin_rows_s": "construct/bin_rows"}
 
 
@@ -208,9 +209,14 @@ class Dataset:
                 reference=ref_binned)
         parts = mark.grown()
         total = parts.pop("total_s")
+        ds = self._constructed
         telemetry.record("dataset_construct", total_s=total,
                          other_s=total - sum(parts.values()),
-                         rows=X.shape[0], features=X.shape[1], **parts)
+                         rows=X.shape[0], features=X.shape[1],
+                         groups=ds.num_groups,
+                         bundled_features=ds.bundled_features,
+                         sample_conflicts=ds.efb_sample_conflicts,
+                         conflict_rows=ds.efb_conflict_rows, **parts)
         self._used_params = merged
         if self.free_raw_data:
             self.data = None

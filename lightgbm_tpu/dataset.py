@@ -162,6 +162,11 @@ class BinnedDataset:
         # distributed loading: (rank, world, global_rows) when this object
         # holds only one host's row shard (io.load_dataset_sharded)
         self.shard_info: Optional[tuple] = None
+        # EFB's guarantee, counted (see _make_groups / _extract_binned):
+        # sampled rows on which bundle-mates were both set when the bundles
+        # were chosen, and rows of the WHOLE table on which they are
+        self.efb_sample_conflicts: int = 0
+        self.efb_conflict_rows: int = 0
 
     # -- accessors used by the learners --
     def bump_version(self) -> None:
@@ -241,6 +246,12 @@ class BinnedDataset:
     @property
     def has_bundles(self) -> bool:
         return any(len(g.feature_indices) > 1 for g in self.groups)
+
+    @property
+    def bundled_features(self) -> int:
+        """Features that share their device column with others."""
+        return sum(len(g.feature_indices) for g in self.groups
+                   if len(g.feature_indices) > 1)
 
     def group_num_bins(self) -> np.ndarray:
         return np.array([g.num_bins for g in self.groups], dtype=np.int32)
@@ -370,6 +381,7 @@ def construct_dataset(
         with host_phase("lgbtpu/construct_bin_rows"):
             ds.binned = _extract_binned(X, ds,
                                         nthreads=int(config.num_threads))
+        _report_bundles(ds)
         ds.metadata = Metadata(num_data, label, weight, group, init_score)
         if config.linear_tree:
             ds.raw_numeric = _raw_numeric(X, ds)
@@ -447,33 +459,42 @@ def construct_dataset(
         ds.bin_mappers = mappers
         ds.used_feature_indices = used
 
-        # ---- EFB bundling decision (reference: dataset.cpp:239 FastFeatureBundling) ----
-        ds.groups, ds.feature_to_group, ds.feature_group_offset = _make_groups(
+    # ---- EFB bundling decision (reference: dataset.cpp:239 FastFeatureBundling) ----
+    with host_phase("lgbtpu/construct_bundle"):
+        # bundles are capped at 256 bins so the matrix stays uint8; with
+        # max_bin > 256 single features already need uint16 — skip bundling
+        if config.enable_bundle and config.max_bin > 256 \
+                and any(m.sparse_rate >= 0.8 for m in mappers):
+            Log.warning("max_bin=%d > 256: exclusive feature bundling is "
+                        "skipped (a bundle is one uint8 column), every "
+                        "feature takes a device column of its own",
+                        config.max_bin)
+        (ds.groups, ds.feature_to_group, ds.feature_group_offset,
+         ds.efb_sample_conflicts) = _make_groups(
             sample_nz_mask, sample_cnt, used, mappers,
-            # bundles are capped at 256 bins so the matrix stays uint8; with
-            # max_bin > 256 single features already need uint16 — skip bundling
             enable_bundle=config.enable_bundle and config.max_bin <= 256,
             max_conflict_rate=float(getattr(config, "max_conflict_rate", 0.0)),
         )
         ds.max_bins_per_feature = max((g.num_bins for g in ds.groups), default=1)
 
-        # monotone constraints / feature penalties mapped to used features
-        if config.monotone_constraints:
-            mc = np.zeros(len(used), dtype=np.int8)
-            for i, f in enumerate(used):
-                if f < len(config.monotone_constraints):
-                    mc[i] = np.sign(config.monotone_constraints[f])
-            if np.any(mc != 0):
-                ds.monotone_constraints = mc
-        if config.feature_contri:
-            fp = np.ones(len(used), dtype=np.float32)
-            for i, f in enumerate(used):
-                if f < len(config.feature_contri):
-                    fp[i] = config.feature_contri[f]
-            ds.feature_penalty = fp
+    # monotone constraints / feature penalties mapped to used features
+    if config.monotone_constraints:
+        mc = np.zeros(len(used), dtype=np.int8)
+        for i, f in enumerate(used):
+            if f < len(config.monotone_constraints):
+                mc[i] = np.sign(config.monotone_constraints[f])
+        if np.any(mc != 0):
+            ds.monotone_constraints = mc
+    if config.feature_contri:
+        fp = np.ones(len(used), dtype=np.float32)
+        for i, f in enumerate(used):
+            if f < len(config.feature_contri):
+                fp[i] = config.feature_contri[f]
+        ds.feature_penalty = fp
 
     with host_phase("lgbtpu/construct_bin_rows"):
         ds.binned = _extract_binned(X, ds, nthreads=int(config.num_threads))
+    _report_bundles(ds)
     ds.metadata = Metadata(num_data, label, weight, group, init_score)
     if config.linear_tree:
         ds.raw_numeric = _raw_numeric(X, ds)
@@ -491,10 +512,18 @@ def _make_groups(
 ) -> tuple:
     """Greedy exclusive-feature bundling (reference: Dataset::FindGroups,
     src/io/dataset.cpp:100 — greedy graph coloring by conflict count).
+    Returns (groups, feature -> group, feature -> bin offset, conflicts
+    admitted on the sample).
 
     Only sufficiently sparse features are bundling candidates; dense features
-    get their own group. Conflicts are counted on the sample: two features
-    conflict on a row if both are away from their most-frequent (default) bin.
+    get their own group. Conflicts are counted on the SAMPLE: two features
+    conflict on a row if both are nonzero there. The conflict budget of a
+    bundle: the reference admits ``total_sample_cnt / 10000`` conflicting
+    sample rows (FindGroups; one in 1e4); here it is ``max_conflict_rate`` x
+    sample rows, 0 by default — no conflict on the sample at all. Neither
+    looks at the rows outside the sample, so the full table can still hold
+    rows on which bundle-mates are both set: ``_extract_binned`` says which
+    of the two such a row keeps, and counts them.
     A bundle's total bin count is capped at 256 so the training matrix stays
     uint8 (the partitioned learner's packed-row layout).
     """
@@ -504,7 +533,7 @@ def _make_groups(
     if not any(sparse_ok):
         # dense data: every feature is its own group, skip the conflict scan
         groups = [FeatureGroupInfo([i], [0], mappers[i].num_bins) for i in range(n)]
-        return (groups, np.arange(n, dtype=np.int32), np.zeros(n, dtype=np.int32))
+        return (groups, np.arange(n, dtype=np.int32), np.zeros(n, dtype=np.int32), 0)
     groups: List[FeatureGroupInfo] = []
     feature_to_group = np.zeros(n, dtype=np.int32)
     feature_offset = np.zeros(n, dtype=np.int32)
@@ -514,6 +543,7 @@ def _make_groups(
     bundle_masks: List[np.ndarray] = []
     bundle_bins: List[int] = []
     max_conflicts = int(max_conflict_rate * sample_cnt)
+    admitted = 0
     for i in range(n):
         if not sparse_ok[i]:
             continue
@@ -525,6 +555,7 @@ def _make_groups(
                 continue
             conflicts = int(np.count_nonzero(mask & nz))
             if conflicts <= max_conflicts:
+                admitted += conflicts
                 bundles[b].append(i)
                 bundle_masks[b] = mask | nz
                 bundle_bins[b] += nb
@@ -560,7 +591,7 @@ def _make_groups(
         feature_to_group[i] = gid
         feature_offset[i] = 0
         gid += 1
-    return groups, feature_to_group, feature_offset
+    return groups, feature_to_group, feature_offset, admitted
 
 
 def _bundle_bin(m: BinMapper, bins: np.ndarray, offset: int) -> np.ndarray:
@@ -584,8 +615,21 @@ def _extract_binned(X, ds: BinnedDataset,
     bundles share the column with per-sub-feature bin offsets, so histogram
     and partition cost scale with the BUNDLED column count. Accepts dense
     numpy or scipy sparse input; sparse stays O(nnz).
+
+    The guarantee on a CONFLICT row (two or more sub-features of one bundle
+    away from their default bins; the bundles were chosen on a sample, so
+    the full table may hold such rows): the row keeps the sub-feature placed
+    LATER in the bundle — the higher bin offset, hence the largest of its
+    candidate bundle bins — and the others read as their default bins. It
+    is part of the model's meaning and does not depend on threads or on the
+    order of a row's entries: a bundle's sub-features are written in
+    placement order, each over the ones before. (The reference's dense bin
+    keeps whichever was pushed last, the higher column index of the row.)
+    Such rows are counted here at O(nnz) into ``ds.efb_conflict_rows``
+    (a row counts once however many bundles or sub-features clash on it).
     """
     num_data = X.shape[0]
+    clashes: List[np.ndarray] = []
     max_bins = max((g.num_bins for g in ds.groups), default=1)
     dtype = np.uint8 if max_bins <= 256 else np.uint16
     out = np.zeros((num_data, len(ds.groups)), dtype=dtype)
@@ -596,36 +640,54 @@ def _extract_binned(X, ds: BinnedDataset,
     else:
         Xv = np.asarray(X, dtype=np.float64)
 
+    def away_rows(j: int, off: int):
+        """Rows on which sub-feature j of a bundle is away from its default
+        bin, their bundle bins, and the bundle bin of the value 0."""
+        m = ds.bin_mappers[j]
+        real = ds.used_feature_indices[j]
+        if sparse:
+            col = Xc.getcol(real)
+            bb = _bundle_bin(m, m.value_to_bin(
+                np.asarray(col.data, dtype=np.float64)), off)
+            base = int(_bundle_bin(m, m.value_to_bin(np.zeros(1)), off)[0])
+            nz = bb != base
+            return col.indices[nz], bb[nz], base
+        bb = _bundle_bin(m, m.value_to_bin(Xv[:, real]), off)
+        nz = bb != 0
+        return np.flatnonzero(nz), bb[nz], 0
+
     def fill_group(gid: int) -> None:
         grp = ds.groups[gid]
-        multi = len(grp.feature_indices) > 1
-        for j, off in zip(grp.feature_indices, grp.bin_offsets):
-            m = ds.bin_mappers[j]
-            real = ds.used_feature_indices[j]
-            if sparse:
-                col = Xc.getcol(real)
-                rows = col.indices
-                vals = np.asarray(col.data, dtype=np.float64)
-                zero_bin = int(m.value_to_bin(np.zeros(1))[0])
-                b_nz = m.value_to_bin(vals)
-                if multi:
-                    bb = _bundle_bin(m, b_nz, off)
-                    base = int(_bundle_bin(m, np.asarray([zero_bin]), off)[0])
-                    if base != 0:
-                        out[:, gid] = base
-                    nz = bb != base
-                    out[rows[nz], gid] = bb[nz].astype(dtype)
-                else:
-                    out[:, gid] = zero_bin
-                    out[rows, gid] = b_nz.astype(dtype)
-            else:
-                b = m.value_to_bin(Xv[:, real])
-                if multi:
-                    bb = _bundle_bin(m, b, off)
-                    nz = bb != 0
-                    out[nz, gid] = bb[nz].astype(dtype)
-                else:
-                    out[:, gid] = b.astype(dtype)
+        subs = list(zip(grp.feature_indices, grp.bin_offsets))
+        if len(subs) > 1:
+            # placement order: a later sub-feature writes over an earlier one
+            written, plain = 0, True
+            for j, off in subs:
+                at, bins, base = away_rows(j, off)
+                if base != 0:
+                    out[:, gid] = base
+                    plain = False
+                out[at, gid] = bins.astype(dtype)
+                written += at.size
+            # every write is nonzero, so fewer nonzeros than writes means
+            # rows written twice; only then are they looked for (a second
+            # pass over the bundle, O(nnz) + one byte a row)
+            if not plain or written != np.count_nonzero(out[:, gid]):
+                held = np.zeros(num_data, dtype=np.uint8)
+                for j, off in subs:
+                    held[away_rows(j, off)[0]] += 1
+                clashes.append(np.flatnonzero(held > 1))
+            return
+        j = grp.feature_indices[0]
+        m = ds.bin_mappers[j]
+        real = ds.used_feature_indices[j]
+        if sparse:
+            col = Xc.getcol(real)
+            out[:, gid] = int(m.value_to_bin(np.zeros(1))[0])
+            out[col.indices, gid] = m.value_to_bin(
+                np.asarray(col.data, dtype=np.float64)).astype(dtype)
+        else:
+            out[:, gid] = m.value_to_bin(Xv[:, real]).astype(dtype)
 
     # Dense single-feature numerical groups bin through the native threaded
     # applier (native/binning.cpp — the reference's OpenMP PushData analog,
@@ -650,7 +712,32 @@ def _extract_binned(X, ds: BinnedDataset,
     for gid in range(len(ds.groups)):
         if gid not in done:
             fill_group(gid)
+    ds.efb_conflict_rows = int(np.unique(np.concatenate(clashes)).size) \
+        if clashes else 0
     return out
+
+
+# the reference's own budget: FindGroups admits one conflicting row in 1e4
+EFB_CONFLICT_SHARE_WARN = 1e-4
+
+
+def _report_bundles(ds: BinnedDataset) -> None:
+    """Gauges ``efb/groups`` and ``efb/features`` (features that share a
+    column), counter ``efb/conflict_rows``, and one warning when more of the
+    table conflicts than the reference would have admitted on its sample."""
+    from .obs import telemetry
+    if not ds.has_bundles:
+        return
+    telemetry.gauge("efb/groups", len(ds.groups))
+    telemetry.gauge("efb/features", ds.bundled_features)
+    telemetry.count("efb/conflict_rows", ds.efb_conflict_rows)
+    if ds.efb_conflict_rows > EFB_CONFLICT_SHARE_WARN * max(ds.num_data, 1):
+        Log.warning("EFB: %d of %d rows (%.2e) set two features of one bundle; "
+                    "each keeps the feature placed later in its bundle and "
+                    "reads the other as its default bin (the bundles were "
+                    "conflict-free on the bin_construct_sample_cnt sampled "
+                    "rows only)", ds.efb_conflict_rows, ds.num_data,
+                    ds.efb_conflict_rows / max(ds.num_data, 1))
 
 
 def _load_forced_bins(filename: str, num_features: int) -> Dict[int, list]:

@@ -382,7 +382,8 @@ def build_tree(
                         jax.tree.map(lambda a, b: jnp.where(ok, a, b), fi, info),
                         ok)
 
-            use_forced = force_live & (r < n_forced)
+            with trace_phase("lgbtpu/tree_state"):
+                use_forced = force_live & (r < n_forced)
             leaf, info, force_live = jax.lax.cond(
                 use_forced, pick_forced,
                 lambda _: (leaf, info, jnp.bool_(False)), operand=None)
@@ -818,7 +819,8 @@ def build_tree_partitioned(
         if quantized:
             bad.append("int8 histograms unsupported")
         if bundle is not None or bm != num_bin:
-            bad.append("EFB feature bundling unsupported")
+            bad.append("EFB feature bundling unsupported by the one-kernel "
+                       "split (it scans bundle columns as features)")
         if comm.axis is not None:
             bad.append("multi-device comm unsupported")
         if hp.use_cegb:
@@ -982,34 +984,33 @@ def build_tree_partitioned(
         return comm.hist(h), work                         # (G, Bm, 3)
 
     def feat_view(hg, total_sum):
-        """Bundled (G, Bm, 3) histogram -> per-feature (F, B, 3) view.
-
-        Each sub-feature's own bundle slots are gathered; its shared default
-        bin is recovered as total - sum(own slots) — the reference's
-        FixHistogram contract (include/LightGBM/dataset.h:503).
-        """
         if bundle is None:
             return hg
-        flat = hg.reshape(num_grp * bm, 3)
-        fh = jnp.take(flat, bundle["proj"].reshape(-1), axis=0) \
-            .reshape(num_feat, num_bin, 3)
-        fh = fh * bundle["valid"][:, :, None]
-        rest = total_sum[None, :] - jnp.sum(fh, axis=1)          # (F, 3)
-        dpos_oh = (jnp.arange(num_bin, dtype=jnp.int32)[None, :]
-                   == bundle["dpos"][:, None])                    # (F, B)
-        put = dpos_oh[:, :, None] & bundle["has_rest"][:, None, None]
-        return jnp.where(put, rest[:, None, :], fh)
+        return bundle_feature_view(hg, total_sum, bundle)
+
+    def feat_views(hists, tot_g, tot_l):
+        """The per-feature views of K nodes' bundled histograms, for
+        ``node_best_pair``: a phase of its own beside ``split_scan`` (the
+        benchmark books an op to its outermost phase). Voting searches
+        LOCAL histograms, so their default bins come from the local totals.
+        No op where nothing is bundled."""
+        if bundle is None:
+            return hists
+        with trace_phase("lgbtpu/efb_view"):
+            return jax.vmap(feat_view)(hists, tot_l if voting else tot_g)
 
     def route_table(info):
         """Feature-space (B,) routing table -> bundle-column (Bm,) table
         (alien sub-features' slots and the shared zero follow the feature's
-        default-bin direction)."""
+        default-bin direction). Called beside ``lgbtpu/partition``, not
+        under it."""
         if bundle is None:
             return info.go_left
-        row = bundle["map_fb"][info.feature]                      # (Bm,)
-        oh = row[:, None] == jnp.arange(num_bin, dtype=jnp.int32)[None, :]
-        return (oh.astype(jnp.float32)
-                @ info.go_left.astype(jnp.float32)) > 0.5
+        with trace_phase("lgbtpu/efb_view"):
+            row = bundle["map_fb"][info.feature]                  # (Bm,)
+            oh = row[:, None] == jnp.arange(num_bin, dtype=jnp.int32)[None, :]
+            return (oh.astype(jnp.float32)
+                    @ info.go_left.astype(jnp.float32)) > 0.5
 
     fmask_search = feature_mask
     owned = comm.owned_mask(num_feat)
@@ -1046,20 +1047,21 @@ def build_tree_partitioned(
             hp.cegb_penalty_split * tot_g[2]
             + meta.cegb_coupled * (~tree_used).astype(jnp.float32))
 
-    def node_best(r, leaf, hg, tot_g, tot_l, parent_out, lower, upper,
+    def node_best(r, leaf, fv, tot_g, tot_l, parent_out, lower, upper,
                   used_row, tree_used, depth, adv_b=None):
-        """Best split for a node under the active comm strategy. ``hg`` is
-        the (bundled) histogram — global for serial/data/feature, LOCAL for
-        voting; ``tot_g``/``tot_l`` the node's global/local (g,h,cnt)."""
+        """Best split for a node under the active comm strategy. ``fv`` is
+        the node's per-feature histogram view (``feat_views``) — global for
+        serial/data/feature, LOCAL for voting; ``tot_g``/``tot_l`` the
+        node's global/local (g,h,cnt)."""
         delta = cegb_penalty(tot_g, tree_used)
         if not voting:
-            info = best_raw(r, leaf, feat_view(hg, tot_g), tot_g, parent_out,
+            info = best_raw(r, leaf, fv, tot_g, parent_out,
                             lower, upper, used_row, cegb_delta=delta,
                             node_depth=depth, adv_bounds=adv_b)
             return comm.sync_split(info)
         # ---- voting parallel (reference: GlobalVoting,
         # voting_parallel_tree_learner.cpp:151,322) ----
-        fv_loc = feat_view(hg, tot_l)
+        fv_loc = fv
         fg = best_raw(r, leaf, fv_loc, tot_l, parent_out, lower, upper,
                       used_row, want_feature_gains=True, use_hp=hp_loc,
                       node_depth=depth)
@@ -1136,9 +1138,10 @@ def build_tree_partitioned(
         # variant of the whole reduce-window/select pipeline
         root_ix = jnp.array([0], jnp.int32)
         best = _empty_best(num_leaves, num_bin)
+    root_view = feat_views(root_hist[None], root_sum[None], root_sum_loc[None])
     with trace_phase("lgbtpu/split_scan"):
         root_info = node_best_pair(
-            0, root_ix, root_hist[None], root_sum[None], root_sum_loc[None],
+            0, root_ix, root_view, root_sum[None], root_sum_loc[None],
             leaf_out[:1], leaf_lower[:1], leaf_upper[:1], leaf_used[0],
             tree_used0, jnp.int32(0),
             *((jax.tree.map(lambda a: a[None],
@@ -1189,12 +1192,14 @@ def build_tree_partitioned(
         with trace_phase("lgbtpu/tree_state"):
             leaf = jnp.argmax(best.gain).astype(jnp.int32)
             info: SplitInfo = jax.tree.map(lambda a: a[leaf], best)
-            if n_forced:
-                # forced splits (reference: serial_tree_learner.cpp:450
-                # ForceSplits) — same protocol as build_tree
-                f_leaf, f_feat, f_bin = forced
+        if n_forced:
+            # forced splits (reference: serial_tree_learner.cpp:450
+            # ForceSplits) — same protocol as build_tree; its view and its
+            # search sit under the phases of the ordinary ones
+            f_leaf, f_feat, f_bin = forced
 
-                def pick_forced(_):
+            def pick_forced(_):
+                with trace_phase("lgbtpu/tree_state"):
                     ri = jnp.minimum(r, n_forced - 1)
                     fl = f_leaf[ri]
                     # voting keeps hist_pool LOCAL; a forced split must still be
@@ -1204,9 +1209,14 @@ def build_tree_partitioned(
                     hg_forced = comm.psum(hist_pool[fl]) \
                         if (voting or comm.hist_scatter) else hist_pool[fl]
                     hg_forced = hg_forced.reshape(num_grp, bm, 3)
+                if bundle is None:
+                    fv_forced = hg_forced
+                else:
+                    with trace_phase("lgbtpu/efb_view"):
+                        fv_forced = feat_view(hg_forced, leaf_sum[fl])
+                with trace_phase("lgbtpu/split_scan"):
                     fi = find_best_split(
-                        feat_view(hg_forced, leaf_sum[fl]),
-                        leaf_sum[fl], meta,
+                        fv_forced, leaf_sum[fl], meta,
                         jnp.arange(num_feat) == f_feat[ri], hp,
                         parent_output=leaf_out[fl], leaf_lower=leaf_lower[fl],
                         leaf_upper=leaf_upper[fl],
@@ -1214,15 +1224,18 @@ def build_tree_partitioned(
                         node_depth=leaf_depth[fl],
                         adv_bounds=(_adv_bounds_of(adv, fl)
                                     if hp.mono_advanced else None))
+                with trace_phase("lgbtpu/tree_state"):
                     ok = fi.gain > -jnp.inf
                     return (jnp.where(ok, fl, leaf),
                             jax.tree.map(lambda a, b: jnp.where(ok, a, b), fi, info),
                             ok)
 
+            with trace_phase("lgbtpu/tree_state"):
                 use_forced = force_live & (r < n_forced)
-                leaf, info, force_live = jax.lax.cond(
-                    use_forced, pick_forced,
-                    lambda _: (leaf, info, jnp.bool_(False)), operand=None)
+            leaf, info, force_live = jax.lax.cond(
+                use_forced, pick_forced,
+                lambda _: (leaf, info, jnp.bool_(False)), operand=None)
+        with trace_phase("lgbtpu/tree_state"):
             s = log.num_splits
             new_leaf = s + 1
 
@@ -1284,9 +1297,10 @@ def build_tree_partitioned(
             # inside the same launch
             left_smaller = info.left_sum[2] <= info.right_sum[2]
         if not one_kernel:
+            go_left_cols = route_table(info)
             with trace_phase("lgbtpu/partition"):
                 work, lt = part_fn(work, parity, start, cnt, split_col,
-                                   route_table(info), ch=part_chunk)
+                                   go_left_cols, ch=part_chunk)
         with trace_phase("lgbtpu/tree_state"):
             new_parity = 1 - parity
 
@@ -1461,11 +1475,13 @@ def build_tree_partitioned(
                     ab_r = _adv_bounds_of(adv, new_leaf)
                     extra_pair = (jax.tree.map(lambda a, b: jnp.stack([a, b]),
                                                ab_l, ab_r),)
+            pair_g = jnp.stack([info.left_sum, info.right_sum])
+            pair_l = jnp.stack([loc_left, loc_right])
+            pair_view = feat_views(jnp.stack([hist_left, hist_right]),
+                                   pair_g, pair_l)
             with trace_phase("lgbtpu/split_scan"):
                 infos = node_best_pair(
-                    r, pair, jnp.stack([hist_left, hist_right]),
-                    jnp.stack([info.left_sum, info.right_sum]),
-                    jnp.stack([loc_left, loc_right]), leaf_out[pair],
+                    r, pair, pair_view, pair_g, pair_l, leaf_out[pair],
                     leaf_lower[pair], leaf_upper[pair], used_new, tree_used,
                     d, *extra_pair)
         with trace_phase("lgbtpu/tree_state"):
@@ -1493,6 +1509,27 @@ def build_tree_partitioned(
     if return_work:
         return log, work_fin
     return log
+
+
+def bundle_feature_view(hg: jax.Array, total_sum: jax.Array,
+                        bundle: dict) -> jax.Array:
+    """Bundled (G, Bm, 3) histogram -> per-feature (F, B, 3) view, by the
+    maps of ``BinnedDataset.bundle_maps``.
+
+    Each sub-feature's own bundle slots are gathered; its shared default
+    bin is recovered as total - sum(own slots) — the reference's
+    FixHistogram contract (include/LightGBM/dataset.h:503).
+    """
+    num_feat, num_bin = bundle["proj"].shape
+    flat = hg.reshape(-1, 3)
+    fh = jnp.take(flat, bundle["proj"].reshape(-1), axis=0) \
+        .reshape(num_feat, num_bin, 3)
+    fh = fh * bundle["valid"][:, :, None]
+    rest = total_sum[None, :] - jnp.sum(fh, axis=1)              # (F, 3)
+    dpos_oh = (jnp.arange(num_bin, dtype=jnp.int32)[None, :]
+               == bundle["dpos"][:, None])                        # (F, B)
+    put = dpos_oh[:, :, None] & bundle["has_rest"][:, None, None]
+    return jnp.where(put, rest[:, None, :], fh)
 
 
 @partial(jax.jit, static_argnames=("has_categorical",))
@@ -1993,7 +2030,9 @@ class SerialTreeLearner:
                     bad.append("int8 histograms unsupported")
                 if self.bundle is not None \
                         or self.num_bin_hist != self.num_bin:
-                    bad.append("EFB feature bundling unsupported")
+                    bad.append("EFB feature bundling unsupported by the "
+                               "one-kernel split (it scans bundle columns "
+                               "as features)")
                 if self.comm.axis is not None:
                     bad.append("multi-device comm unsupported")
                 if self.hp.use_cegb:

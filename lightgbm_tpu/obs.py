@@ -181,6 +181,9 @@ PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
     "lgbtpu/pack": ("device", "tree learner", None),
     "lgbtpu/root_hist": ("device", "tree learner", None),
     "lgbtpu/tree_state": ("device", "tree learner", None),
+    # bundled histogram -> per-feature view, feature-bin routing table ->
+    # bundle-bin one; holds no op where nothing is bundled
+    "lgbtpu/efb_view": ("device", "tree learner", None),
     "lgbtpu/split_scan": ("device", "tree learner", None),
     "lgbtpu/partition": ("device", "kernels", None),
     "lgbtpu/histogram": ("device", "kernels", None),
@@ -194,6 +197,7 @@ PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
     "lgbtpu/construct": ("host", "host data", "construct/total"),
     "lgbtpu/construct_copy": ("host", "host data", "construct/copy"),
     "lgbtpu/construct_find_bins": ("host", "host data", "construct/find_bins"),
+    "lgbtpu/construct_bundle": ("host", "host data", "construct/bundle"),
     "lgbtpu/construct_bin_rows": ("host", "host data", "construct/bin_rows"),
     "lgbtpu/train": ("host", "booster", "train/total"),
     "lgbtpu/booster_init": ("host", "booster", "train/booster_init"),

@@ -13,6 +13,10 @@ line, so a repair flips the case visibly. All of them are ``auto -> off``.
 
 libtpu takes ``/tmp/libtpu_lockfile``: one such process at a time.
 """
+import importlib.util
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -74,6 +78,25 @@ def ds_rank():
     return ds
 
 
+@pytest.fixture(scope="module")
+def ds_onehot():
+    """The expo.train cell's table at 200K rows: 700 one-hot CSR columns
+    that EFB bundles into 10 device columns, so W = 32 planes."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "expo-binary-255.json")) as f:
+        cfg = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "bench_datagen_onehot_csr",
+        os.path.join(bench, "datagen", cfg["datagen"]["kind"] + ".py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    data = gen.make(dict(cfg["shape"], rows=N_BIN), cfg["datagen"]["args"], 11)
+    ds = lgb.Dataset(data["X"], label=data["label"], params=cfg["params"])
+    ds.construct()
+    return ds
+
+
 def _abstract(tree, sharding):
     return jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype,
@@ -109,6 +132,25 @@ def test_default_binary_block_compiles(topo, ds_binary):
 def test_default_lambdarank_block_compiles(topo, ds_rank):
     kw, _ = compile_block(topo, ds_rank, {"objective": "lambdarank"})
     assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+
+
+def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
+    """The narrowest width the planes partition kernel meets: G = 10 device
+    columns + 12 payload bytes pad to W = 32 (Higgs 64, MSLR 160), with the
+    bundle maps as arguments and the view under its own phase."""
+    binned = ds_onehot.construct()
+    assert binned.has_bundles and len(binned.used_feature_indices) == 700
+    kw, c = compile_block(topo, ds_onehot, {
+        "objective": "binary", "min_data_in_leaf": 0,
+        "min_sum_hessian_in_leaf": 100})
+    assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+    assert kw["bundle"] is not None and kw["num_bin_hist"] == 256
+    _, width = partition.work_spec(binned.num_groups, False, kw["part_kernel"],
+                                   kw["part_chunk"], kw["hist_chunk"],
+                                   layout=kw["work_layout"])
+    assert (binned.num_groups, width) == (10, 32)
+    text = c.as_text()
+    assert "lgbtpu/efb_view" in text and "partition_segment_planes_fused" in text
 
 
 def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,

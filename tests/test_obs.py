@@ -417,11 +417,21 @@ def _scope_cases():
                        {"label": np.floor(X[:, 0] * 3)}),
         "lambdarank": ({"objective": "lambdarank"}, Xr,
                        {"label": yr, "group": group}),
+        "binary_bundled": ({"objective": "binary"}, _onehot(600), {"label": y}),
     }
 
 
+def _onehot(n, blocks=4, card=9):
+    """A scipy CSR one-hot table: EFB bundles each block into one column."""
+    import scipy.sparse as sp
+    rng = np.random.RandomState(3)
+    return sp.hstack([sp.csr_matrix(
+        (np.ones(n), (np.arange(n), rng.randint(0, card, n))), shape=(n, card))
+        for _ in range(blocks)]).tocsr()
+
+
 @pytest.mark.parametrize("objective", ["binary", "lambdarank", "regression",
-                                       "multiclass"])
+                                       "multiclass", "binary_bundled"])
 def test_block_program_ops_lie_under_a_phase_of_the_table(objective):
     """Every op the program puts into the block lies under one outermost
     ``lgbtpu/<phase>`` of ``obs.PHASES``, read from the compiled module's
@@ -466,6 +476,10 @@ def test_block_program_ops_lie_under_a_phase_of_the_table(objective):
         assert "lgbtpu/objective" in found and not rank & found
     assert {"lgbtpu/route", "lgbtpu/tree_state", "lgbtpu/tree_log",
             "lgbtpu/block_setup", "lgbtpu/sample"} <= found
+    # the bundle view and the routing-table translation are a phase of their
+    # own beside split_scan and partition, and hold no op without bundles
+    assert ("lgbtpu/efb_view" in found) == (objective == "binary_bundled")
+    assert "lgbtpu/split_scan" in found
 
 
 def test_every_phase_site_names_a_phase_of_the_table():
@@ -534,11 +548,15 @@ def test_one_dataset_construct_record_per_real_construction():
     ds.construct(dict(PARAMS))
     rec, = telemetry.records("dataset_construct")
     assert (rec["rows"], rec["features"]) == X.shape
-    parts = ("copy_s", "find_bins_s", "bin_rows_s", "other_s")
+    parts = ("copy_s", "find_bins_s", "bundle_s", "bin_rows_s", "other_s")
     assert all(rec[p] >= 0.0 for p in parts), rec
     assert sum(rec[p] for p in parts) == pytest.approx(rec["total_s"],
                                                        abs=1e-6)
     assert rec["find_bins_s"] > 0 and rec["bin_rows_s"] > 0
+    # dense columns: one device column a feature, nothing shared or lost
+    assert rec["groups"] == X.shape[1] and rec["bundled_features"] == 0
+    assert rec["sample_conflicts"] == 0 and rec["conflict_rows"] == 0
+    assert "efb/groups" not in telemetry.snapshot()["gauges"]
     ds.construct(dict(PARAMS))                  # cached: no new record
     lgb.train(dict(PARAMS), ds, num_boost_round=2)
     assert len(telemetry.records("dataset_construct")) == 1
