@@ -673,7 +673,8 @@ def build_tree_partitioned(
     constraint_sets: Optional[jax.Array] = None,   # (S, F) bool
     forced: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     part_kernel: str = "xla",  # xla | pallas (fused DMA kernel, TPU only)
-    hist_kernel: str = "xla",  # xla (einsum) | pallas (in-VMEM, TPU only)
+    hist_kernel: str = "xla",  # xla (einsum) | pallas (in-VMEM, TPU only:
+    # several features an MXU pass on planes, one a pass on rows)
     split_kernel: str = "off",  # off (three launches: partition, child
     # histogram, split scan) | on (ONE pallas_call per split running all
     # three phases; planes family + serial training only — bit-identical
@@ -1090,8 +1091,11 @@ def build_tree_partitioned(
             else root_sum_in
         root_sum = comm.root(root_sum_loc)
     if planes:
-        # folded into the pack pass above (bit-identical accumulation to
-        # hist_of over the root segment: same chunking, same einsum order)
+        # folded into the pack pass above: bit-identical accumulation to
+        # the XLA segment histogram over the root segment (same chunking,
+        # same einsum order). The Pallas hist_of adds the same exact
+        # products in another order: equal counts, sums within 1e-6 of a
+        # cell's sum of |terms| (tests/test_resident_state.py)
         root_hist = comm.hist(root_hist_loc)
     else:
         with trace_phase("lgbtpu/root_hist"):
@@ -1918,25 +1922,12 @@ class SerialTreeLearner:
             if auto_hist and "tpu_hist_kernel" in pre:
                 hist_kernel = _pre("tpu_hist_kernel")
                 auto_hist = False
-            elif auto_hist:
-                # auto = xla: the in-VMEM pallas kernel is bit-identical
-                # and faster standalone, but was slower in situ (alternating
-                # with the partition kernel inside the tree while-loop) when
-                # last measured; re-test queued (ROADMAP S2).
-                hist_kernel = "xla"
             elif hist_kernel == "pallas" and (part_kernel != "pallas"
                                               or mode == "int8"):
                 Log.warning("tpu_hist_kernel=pallas needs the pallas "
                             "partition layout and a non-quantized mode; "
                             "using the XLA einsum")
                 hist_kernel = "xla"
-            if hist_kernel == "pallas" and hist_chunk % 32:
-                # the kernel re-derives DMA offsets as (x // 32) * 32; a
-                # misaligned chunk would double-count the rows between the
-                # aligned offset and the true chunk start — silently wrong
-                # histograms (ADVICE: refuse loudly, like part_chunk % 32)
-                Log.fatal("tpu_hist_chunk must be a multiple of 32 with "
-                          "the pallas histogram kernel (got %d)", hist_chunk)
             layout = config.tpu_work_layout
             auto_layout = layout == "auto"
             layout_why = ""
@@ -1987,6 +1978,44 @@ class SerialTreeLearner:
             # 0.63 (10.5M x 28) and 1.17 against 0.66 (2.27M x 137) with
             # byte-identical models (PERF.md, PR 21). Selectable with
             # tpu_resident_state=on until queue 3 repairs or deletes it.
+            hist_why = ""
+            if auto_hist:
+                # auto follows backend and layout: the planes kernel builds
+                # its one-hots in VMEM and serves several features an MXU
+                # pass; in situ on a v5e it took
+                # higgs.train (F = 28) from 636.6 to 458.1 ms an iteration,
+                # mslr.train (F = 137) from 1395.1 to 940.3, expo.train
+                # (F = 10) from 654.8 to 610.1 (PERF.md, PR 29; the
+                # one-feature-a-pass kernel it replaces read 517 / 1002 /
+                # 606 in 13-s windows where the XLA loop read 639 / 1399 /
+                # 637: "slower in situ" does not hold on this machine).
+                # The rows twin and the CPU / mesh paths keep the einsum.
+                if tpu and layout == "planes" and part_kernel == "pallas" \
+                        and self.comm.axis is None:
+                    hist_kernel = "pallas"
+                    hist_why = ("planes layout + pallas partition on %s: "
+                                "one-hots built in VMEM, several features "
+                                "an MXU pass" % backend)
+                else:
+                    # the mesh learners keep the XLA einsum until a
+                    # four-chip run has timed the kernel under shard_map
+                    hist_kernel = "xla"
+                    hist_why = ("layout %s, partition %s, comm axis %s on "
+                                "%s: XLA einsum" % (layout, part_kernel,
+                                                    self.comm.axis, backend))
+            if auto_hist_chunk and hist_kernel == "pallas" \
+                    and layout == "planes":
+                # the planes kernel owns its VMEM: longer DMAs than the XLA
+                # loop's chunk (which spills at F > 64)
+                from .ops.histogram import planes_kernel_chunk
+                hist_chunk = planes_kernel_chunk(self.bins.shape[1])
+            if hist_kernel == "pallas" and hist_chunk % 32:
+                # the kernel re-derives DMA offsets as (x // 32) * 32; a
+                # misaligned chunk would double-count the rows between the
+                # aligned offset and the true chunk start — silently wrong
+                # histograms (ADVICE: refuse loudly, like part_chunk % 32)
+                Log.fatal("tpu_hist_chunk must be a multiple of 32 with "
+                          "the pallas histogram kernel (got %d)", hist_chunk)
             if layout == "resident" and hist_kernel == "pallas":
                 Log.warning("tpu_hist_kernel=pallas has no resident gather "
                             "path; using the XLA gather einsum")
@@ -2167,9 +2196,7 @@ class SerialTreeLearner:
             if auto_kernel:
                 _rec("tpu_partition_kernel", part_kernel, part_why)
             if auto_hist:
-                _rec("tpu_hist_kernel", hist_kernel,
-                     "pallas histogram slower in situ when last "
-                     "measured; re-test queued")
+                _rec("tpu_hist_kernel", hist_kernel, hist_why)
             if auto_layout:
                 _rec("tpu_work_layout", layout if layout != "resident"
                      else "planes", layout_why)

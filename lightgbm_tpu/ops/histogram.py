@@ -45,9 +45,11 @@ def _hist_chunk(bins_c: jax.Array, ghc_c: jax.Array, num_bins: int,
     Contraction order is (C, chunk) @ (chunk, F*B): the wide F*B axis sits on
     the MXU's 128-lane output dimension; the tiny channel axis pads only the
     sublane side. On TPU (``mxu_bf16``) the one-hot materializes in bfloat16
-    (exact 0/1, half the HBM traffic — this pass is bandwidth-bound) and the
-    f32 channels split hi+lo so two bf16 MXU passes keep f32 accuracy; on CPU
-    everything stays exact f32 for the test reference.
+    (exact 0/1, half the bytes of the temporary) and the f32 channels split
+    hi+lo so two bf16 MXU passes keep f32 accuracy; on CPU everything stays
+    exact f32 for the test reference. Not bandwidth-bound on a v5e: no
+    histogram of this file comes within two orders of magnitude of its HBM
+    roofline (PERF.md, PR 29); the one-hot's elements are the cost.
     """
     chunk, num_feat = bins_c.shape
     iota = jnp.arange(num_bins, dtype=bins_c.dtype)
@@ -134,12 +136,24 @@ build_histogram_jit = track_jit("ops/build_histogram", build_histogram_jit)
 # — a feature-batched einsum whose operands are (rows, F, B/lo_w) and
 # (rows, F, lo_w*NCH): far less materialization than the direct form.
 # The split width trades the two operands against each other AND shapes
-# the per-feature matmul (M = B/lo_w — larger M tiles the MXU better):
-# measured on v5e at B=256, full-N segments: lo_w=16 -> 8 -> 4 runs
-# 22.2 / 14.1 / 10.3 ms at (2M, F=28) and 29.3 / 11.4 / 12.5 ms at
-# (500K, F=137); lo_w=2 collapses (~105 ms). Auto choice: 4 for F <= 64,
-# 8 above. Exactness: bf16 (hi, lo) channel splits make every product
-# exactly representable; the MXU accumulates f32 — the reference's GPU
+# the per-feature matmul: XLA lowers the batched einsum to one MXU pass per
+# feature per 128 rows of the contraction, of which 64 x 20 (lo_w 4) or
+# 32 x 40 (lo_w 8) of 128 x 128 cells carry work, and writes both operands
+# through HBM temporaries every chunk. Measured in situ on a v5e (ledger,
+# PR 28: smaller-child rows x features over the traced histogram scope):
+# 0.190 ns per (row, feature) at F = 28 (lo_w 4, chunk 4096), 0.251 at
+# F = 137 (lo_w 8, chunk 1024), 0.224 at F = 10 (lo_w 4, chunk 4096),
+# whatever the width, the chunk and the factorisation: the passes and the
+# temporaries bound it, not the FLOPs (7.8 % of a pass) and not the bytes
+# (0.0016 ns). Packing g features into one einsum batch element to fill
+# the pass LOSES here (0.31 / 0.43 ns standalone at F = 28 / 137 against
+# 0.22 / 0.26: the g x larger off-diagonal product goes through HBM too;
+# my chip run, PR 29). On a TPU with the planes layout the segment
+# histogram therefore runs in the Pallas kernel below
+# (hist_pallas_segment_planes); this einsum stays as the CPU / mesh path
+# and as the tests' oracle. Auto choice here: 4 for F <= 64, 8 above.
+# Exactness: bf16 (hi, lo) channel splits make every product exactly
+# representable; the MXU accumulates f32 — the reference's GPU
 # f32-histogram precedent (docs/GPU-Performance.rst); all widths are
 # bit-identical.
 
@@ -595,32 +609,94 @@ def hist16_segment_resident(work: jax.Array, resident: jax.Array, plane,
         return _hist16_combine(acc, num_bins, exact, lo_w)
 
 
-def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, acc_s,
-                               sem, *, ch, nplanes, num_feat, sh, lo_w, nch,
-                               dt):
-    # Plane-major port of _hist_pallas_kernel: a chunk DMA is a contiguous
-    # (W, ch) lane slice — bins arrive as whole per-feature sublane rows
-    # (no strided byte columns) and f32 words re-assemble from 4 byte
-    # PLANES instead of 4 byte columns. Same aliasing contract: work_ref is
-    # never written, it only keeps the donated buffer from being copied.
+def _ceil_pow2(x: int) -> int:
+    return 1 << max(0, int(x) - 1).bit_length()
+
+
+def planes_kernel_params(num_feat: int, num_bins: int, lo_w: int = 0,
+                         chunk: int = 0):
+    """Static shape of the planes kernel, from what it can observe:
+    ``(lo_w, shp, g, chunk)``.
+
+    bin = lo_w * hi + lo. The hi one-hots of ``g`` consecutive features
+    stack to one (g * shp = 128, chunk) MXU operand (``shp`` = the hi range
+    padded to a power of two >= 16, whole bf16 sublane tiles), so one pass
+    of 128 rows serves g features, not one. lo_w 4 (g = 2 at 256 bins) at
+    every width: the lo x channel operand's rows (5 lo_w a feature) cost
+    2.5 x what the hi one-hot's (256 / lo_w) do, see the kernel. The chunk
+    (lanes a DMA) is the caller's: the work buffer's guard must cover it
+    (``learner.build_kwargs`` derives it from F, ``partition.work_spec``
+    the guard)."""
+    lo_w = lo_w or 4
+    shp = max(16, _ceil_pow2((num_bins + lo_w - 1) // lo_w))
+    if shp > 128 or (128 // shp * lo_w) % 8:
+        raise ValueError(
+            "hist_pallas_segment_planes: lo_w=%d does not tile %d bins into "
+            "128-row MXU operands" % (lo_w, num_bins))
+    return lo_w, shp, 128 // shp, chunk or planes_kernel_chunk(num_feat)
+
+
+def planes_kernel_chunk(num_feat: int) -> int:
+    """Lanes a DMA of the planes kernel, from F (v5e, my chip run, PR 29:
+    standalone 8192 / 4096 / 2048 / 1024 read 0.074 / 0.077 / 0.079 /
+    0.088 ns per (row, feature) at F = 28 and 0.057 / 0.060 / 0.062 / 0.070
+    at F = 137; in situ higgs.train 457.0 at 8192 against 467.5 at 4096,
+    mslr.train 943.2 at 4096 against 986.0 at 1024). The same number is
+    the root histogram's XLA chunk in the pack pass, which at F = 137 was
+    not tried over 4096."""
+    return 8192 if num_feat <= 64 else 4096
+
+
+def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
+                               acc_s, sem, *, ch, num_feat, shp, lo_w, g,
+                               nch, dt):
+    # One chunk DMA is a contiguous (W, ch) lane slice of the plane-major
+    # work buffer: bins arrive as whole per-feature sublane rows, the f32
+    # channels re-assemble from 4 byte PLANES each. work_ref is never
+    # written: it only keeps the donated buffer from being copied.
+    #
+    # Per chunk and per GROUP of g features one MXU contraction over the
+    # chunk's rows (lanes):
+    #   out[(c, j, l), (j', hi)] = sum_rows LoCh[(c, j, l), row]
+    #                                       * HiOH[(j', hi), row]
+    # whose g diagonal blocks (j == j') are the g features' histograms;
+    # the off-diagonal cells are cells the pass computed anyway. The whole
+    # product accumulates in VMEM; the diagonal is taken once per segment,
+    # outside (hist_pallas_segment_planes).
+    #
+    # Measured on a v5e (my chip run, PR 29), standalone on 1-2M-row
+    # segments, ns per (row, feature): 0.077 / 0.060 / 0.120 at F = 28 /
+    # 137 / 10 (lo_w 4, 4096 lanes a chunk) against the XLA loop's 0.224 /
+    # 0.264 / 0.32 and the one-feature-a-pass kernel's 0.115 / 0.064 /
+    # 0.167. A row of the lo x channel operand (the one the MXU streams)
+    # costs ~0.0013 ns a row of data, a row of the hi one-hot (the one it
+    # latches) ~0.0005: lo_w 4 (20 + 64 operand rows a feature) beats 8
+    # (40 + 32: 0.087 / 0.071) and 16 (80 + 16: 0.16 / 0.13). Building the
+    # hi one-hot as packed bf16 pairs (one compare for two rows) and
+    # assembling the channel words on the MXU changed nothing (0.075 /
+    # 0.060): the element's arithmetic is not the cost. The chunk DMA alone
+    # runs at 0.9 ns a row (W <= 64; 1.8 at W = 160), hidden behind the
+    # groups everywhere but at F = 10.
     f32 = jnp.float32
     i32 = jnp.int32
+    bf16 = jnp.bfloat16
     plane = sref[0]
     start = sref[1]
     cnt = sref[2]
     F = num_feat
+    glw = g * lo_w
+    shift = _LO_SHIFT[lo_w]
 
     astart = (start // 128) * 128
     head = start - astart
     tot = head + cnt
     nchunks = jnp.maximum((tot + ch - 1) // ch, 1)
 
-    acc_s[...] = jnp.zeros((F * sh, lo_w * nch), f32)
+    acc_s[...] = jnp.zeros(acc_s.shape, f32)
 
     def start_in(i, slot):
         # (x // 128) * 128 at the USE SITE proves the u8 lane-dim DMA
-        # offset is whole 128-lane tiles (the planes twin of the rows
-        # kernel's 32-row sublane alignment)
+        # offset is whole 128-lane tiles
         at = ((astart + i * ch) // 128) * 128
         pltpu.make_async_copy(
             work_in.at[plane, :, pl.ds(at, ch)],
@@ -629,17 +705,56 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, acc_s,
     start_in(0, 0)
 
     lane_i = jax.lax.broadcasted_iota(i32, (1, ch), 1)
-    iota_sh = jax.lax.broadcasted_iota(i32, (sh, ch), 0)
-    jl = jax.lax.broadcasted_iota(i32, (lo_w * nch, ch), 0) // nch
+    iota_hi = jax.lax.broadcasted_iota(i32, (shp, ch), 0)
+    iota_lo = jax.lax.broadcasted_iota(i32, (glw, ch), 0) & (lo_w - 1)
+    sub8 = jax.lax.broadcasted_iota(i32, (8, ch), 0) // lo_w
 
     def word(gb, o):
         # f32 plane from its 4 u8 byte planes; multiplies, not shifts
-        # (vector << by >= 16 miscompiles on this toolchain — see the rows
-        # kernel). i32 overflow of the top byte wraps to the sign bits.
+        # (vector << by >= 16 miscompiles on this toolchain). i32 overflow
+        # of the top byte wraps to the sign bits.
         return jax.lax.bitcast_convert_type(
             gb[o:o + 1] + gb[o + 1:o + 2] * 256
             + gb[o + 2:o + 3] * 65536
             + gb[o + 3:o + 4] * 16777216, f32)
+
+    def lo_rows(lo8, j0):
+        """(glw, ch): row j * lo_w + l holds feature j0 + j's lo digit."""
+        rows = [lo8[j0 + j:j0 + j + 1] for j in range(g)]
+        if lo_w % 8 == 0:
+            return jnp.concatenate(
+                [jnp.broadcast_to(r, (lo_w, ch)) for r in rows], axis=0)
+        per = 8 // lo_w                      # features a sublane tile
+        tiles = []
+        for t in range(0, g, per):
+            v = jnp.broadcast_to(rows[t], (8, ch))
+            for q in range(1, per):
+                v = jnp.where(sub8 == q,
+                              jnp.broadcast_to(rows[t + q], (8, ch)), v)
+            tiles.append(v)
+        return jnp.concatenate(tiles, axis=0)
+
+    def group(hi8, lo8, j0, grp, chs):
+        # features j0 .. j0 + g - 1 of an 8-feature block -> acc_s[grp]
+        hioh = jnp.concatenate(
+            [hi8[j0 + j:j0 + j + 1] == iota_hi for j in range(g)],
+            axis=0).astype(dt)                          # (g * shp, ch)
+        lomask = lo_rows(lo8, j0) == iota_lo            # (glw, ch)
+        zero = jnp.zeros((), f32)
+        loch = jnp.concatenate(
+            [jnp.where(lomask, jnp.broadcast_to(c, (glw, ch)), zero)
+             for c in chs], axis=0).astype(dt)          # (nch * glw, ch)
+        acc_s[grp] += jax.lax.dot_general(
+            loch, hioh, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)                 # (nch*glw, g*shp)
+
+    def digits(b8):
+        # >> and & for // lo_w and % lo_w (shifts under 16 are safe here)
+        return b8 >> shift, b8 & (lo_w - 1)
+
+    nblk = F // 8
+    per_blk = 8 // g
+    tail = -(-(F - 8 * nblk) // g)                      # groups after nblk
 
     def body(i, carry):
         slot = jax.lax.rem(i, 2)
@@ -653,34 +768,35 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, acc_s,
             start_in(i + 1, 1 - slot)
 
         cw = cin[slot].astype(i32)                      # (W, CH)
-        bi = cw[:F]
-        hi = bi // lo_w
-        lo = bi - hi * lo_w
+        bins_s[...] = cw[:bins_s.shape[0]]
         gb = cw[F:F + 12]
         pos = lane_i + i * ch
         valid = ((pos >= head) & (pos < tot)).astype(f32)
-        g = word(gb, 0) * valid
-        h = word(gb, 4) * valid
-        c = word(gb, 8) * valid
+        g_ = word(gb, 0) * valid
+        h_ = word(gb, 4) * valid
+        c_ = word(gb, 8) * valid
         if nch == 5:
-            g_hi = g.astype(jnp.bfloat16)
-            g_lo = (g - g_hi.astype(f32)).astype(jnp.bfloat16)
-            h_hi = h.astype(jnp.bfloat16)
-            h_lo = (h - h_hi.astype(f32)).astype(jnp.bfloat16)
-            chs = jnp.concatenate(
-                [g_hi, g_lo, h_hi, h_lo, c.astype(jnp.bfloat16)], axis=0)
+            # bf16 (hi, lo) pairs held as f32: every one-hot product is
+            # exact, the MXU accumulates f32
+            g_hi = g_.astype(bf16).astype(f32)
+            h_hi = h_.astype(bf16).astype(f32)
+            chs = [g_hi, (g_ - g_hi).astype(bf16).astype(f32),
+                   h_hi, (h_ - h_hi).astype(bf16).astype(f32), c_]
         else:
-            chs = jnp.concatenate([g, h, c], axis=0).astype(jnp.bfloat16)
-        tiled = jnp.concatenate([chs] * lo_w, axis=0).astype(dt)
+            chs = [x.astype(bf16).astype(f32) for x in (g_, h_, c_)]
 
-        for f in range(F):
-            hioh = (hi[f:f + 1] == iota_sh).astype(dt)  # (SH, CH)
-            logf = jnp.where(lo[f:f + 1] == jl, tiled,
-                             jnp.zeros((), dt))         # (lo_w*nch, CH)
-            ps = jax.lax.dot_general(
-                hioh, logf, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32)             # (SH, lo_w*nch)
-            acc_s[f * sh:(f + 1) * sh, :] += ps
+        def block(b, carry):
+            hi8, lo8 = digits(bins_s[pl.ds(pl.multiple_of(b * 8, 8), 8), :])
+            for q in range(per_blk):
+                group(hi8, lo8, q * g, b * per_blk + q, chs)
+            return carry
+
+        if nblk:
+            jax.lax.fori_loop(0, nblk, block, 0)
+        if tail:
+            hi8, lo8 = digits(bins_s[8 * nblk:8 * nblk + 8, :])
+            for q in range(tail):
+                group(hi8, lo8, q * g, nblk * per_blk + q, chs)
         return carry
 
     jax.lax.fori_loop(0, nchunks, body, 0)
@@ -691,23 +807,25 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, acc_s,
 
 def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
                                num_bins: int, num_feat: int,
-                               exact: bool = True, chunk: int = 4096,
+                               exact: bool = True, chunk: int = 0,
                                lo_w: int = 0):
     """Pallas twin of :func:`hist16_segment_planes` for the (2, W, Npad)
     plane-major work buffer. Requires the planes pallas work layout: W a
     multiple of 32 sublanes, lane starts 128-aligned +/- head, chunk a
     multiple of 128.
 
-    Returns ``(hist, work)`` — same aliasing contract as
-    :func:`hist_pallas_segment`. Runs under the pallas interpreter off-TPU
-    (LGBTPU_PALLAS_INTERPRET=1) with f32 operands so the parity test can
-    compare against the exact XLA path.
+    Returns ``(hist, work)`` — callers MUST continue with the returned work
+    buffer: it is byte-identical but aliased through the call, which is
+    what keeps XLA from copying the whole buffer defensively per histogram.
+    Runs under the pallas interpreter off-TPU (LGBTPU_PALLAS_INTERPRET=1)
+    with f32 operands. Same operands and f32 accumulation as the XLA path,
+    another ORDER of additions (the MXU's grouping of the contraction):
+    sums agree to rounding, counts exactly.
     """
     from .partition import _INTERPRET
 
     f = num_feat
-    lo_w = lo_w or auto_lo_w(f)
-    sh = (num_bins + lo_w - 1) // lo_w
+    lo_w, shp, g, chunk = planes_kernel_params(f, num_bins, lo_w, chunk)
     nch = 5 if exact else 3
     nplanes = work.shape[1]
     if nplanes % 32:
@@ -721,8 +839,15 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
         raise ValueError(
             "hist_pallas_segment_planes chunk must be a multiple of 128 "
             "(lane DMA tiles), got %d" % chunk)
-    kern = partial(_hist_pallas_kernel_planes, ch=chunk, nplanes=nplanes,
-                   num_feat=f, sh=sh, lo_w=lo_w, nch=nch, dt=_mxu_dtype())
+    ngrp = -(-f // g)
+    fp8 = 8 * (-(-f // 8))
+    if fp8 > nplanes:
+        raise ValueError(
+            "hist_pallas_segment_planes reads bins in blocks of 8 planes: "
+            "F=%d needs W >= %d, got %d" % (f, fp8, nplanes))
+    acc_shape = (ngrp, nch * g * lo_w, g * shp)
+    kern = partial(_hist_pallas_kernel_planes, ch=chunk, num_feat=f, shp=shp,
+                   lo_w=lo_w, g=g, nch=nch, dt=_mxu_dtype())
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
@@ -731,7 +856,8 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
                    pl.BlockSpec(memory_space=pltpu.HBM)],
         scratch_shapes=[
             pltpu.VMEM((2, nplanes, chunk), jnp.uint8),
-            pltpu.VMEM((f * sh, lo_w * nch), jnp.float32),
+            pltpu.VMEM((fp8, chunk), jnp.int32),
+            pltpu.VMEM(acc_shape, jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -742,16 +868,19 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
         name="hist_pallas_segment_planes",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(work.shape, work.dtype),
-                   jax.ShapeDtypeStruct((f * sh, lo_w * nch), jnp.float32)],
+                   jax.ShapeDtypeStruct(acc_shape, jnp.float32)],
         input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_INTERPRET,
     )(scalars, work)
-    h = _hist16_combine(acc.reshape(f, sh, lo_w * nch), num_bins, exact,
-                        lo_w)
-    return h, work_out
+    # the g diagonal blocks: (grp, c, j, l, j', hi) at j == j'
+    a6 = acc.reshape(ngrp, nch, g, lo_w, g, shp)
+    d = jnp.stack([a6[:, :, j, :, j, :] for j in range(g)], axis=1)
+    sh = (num_bins + lo_w - 1) // lo_w
+    h = d.transpose(0, 1, 4, 3, 2).reshape(ngrp * g, shp, lo_w * nch)
+    return _hist16_combine(h[:f, :sh], num_bins, exact, lo_w), work_out
 
 
 # ---------------------------------------------------------------------------

@@ -125,13 +125,15 @@ def resolved(kw):
 
 def test_default_binary_block_compiles(topo, ds_binary):
     kw, c = compile_block(topo, ds_binary, {"objective": "binary"})
-    assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+    assert resolved(kw) == ("planes", "pallas", "pallas", "off", "off")
     assert c.memory_analysis().temp_size_in_bytes > 0
+    # the block really holds the kernel auto resolved to
+    assert "hist_pallas_segment_planes" in c.as_text()
 
 
 def test_default_lambdarank_block_compiles(topo, ds_rank):
     kw, _ = compile_block(topo, ds_rank, {"objective": "lambdarank"})
-    assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+    assert resolved(kw) == ("planes", "pallas", "pallas", "off", "off")
 
 
 def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
@@ -143,7 +145,7 @@ def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
     kw, c = compile_block(topo, ds_onehot, {
         "objective": "binary", "min_data_in_leaf": 0,
         "min_sum_hessian_in_leaf": 100})
-    assert resolved(kw) == ("planes", "pallas", "xla", "off", "off")
+    assert resolved(kw) == ("planes", "pallas", "pallas", "off", "off")
     assert kw["bundle"] is not None and kw["num_bin_hist"] == 256
     _, width = partition.work_spec(binned.num_groups, False, kw["part_kernel"],
                                    kw["part_chunk"], kw["hist_chunk"],
@@ -162,7 +164,8 @@ def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
     binned = ds_binary.construct()
     lrn = DataParallelTreeLearner(
         cfg, binned, Mesh(np.asarray(cpu_mesh_devices[:4]), ("data",)))
-    assert resolved(lrn.build_kwargs())[:2] == ("planes", "pallas")
+    # the mesh learners keep the XLA histogram (auto, PR 29)
+    assert resolved(lrn.build_kwargs())[:3] == ("planes", "pallas", "xla")
     mesh = Mesh(np.asarray(topo.devices), ("data",))
     rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     n, f = lrn.padded_n, binned.num_features
@@ -187,6 +190,8 @@ def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
      {"tpu_resident_state": "on"}, ("resident", "pallas", "xla", "off", "off")),
     ("planes_pallas_hist", {"tpu_hist_kernel": "pallas"},
      ("planes", "pallas", "pallas", "off", "off")),
+    ("planes_xla_hist",                 # auto's pick until PR 29 timed it
+     {"tpu_hist_kernel": "xla"}, ("planes", "pallas", "xla", "off", "off")),
     ("rows_pallas_hist",
      {"tpu_work_layout": "rows", "tpu_hist_kernel": "pallas"},
      ("rows", "pallas", "pallas", "off", "off")),
@@ -195,7 +200,7 @@ def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
      ("rows", "pallas", "xla", "off", "on")),
     ("goss_compact",
      {"data_sample_strategy": "goss", "top_rate": 0.2, "other_rate": 0.1,
-      "tpu_goss_compact": "on"}, ("planes", "pallas", "xla", "off", "off")),
+      "tpu_goss_compact": "on"}, ("planes", "pallas", "pallas", "off", "off")),
 ])
 def test_selectable_path_compiles(topo, ds_binary, name, params, expect):
     kw, _ = compile_block(topo, ds_binary, dict(params, objective="binary"))
