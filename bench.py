@@ -114,8 +114,6 @@ def _phases(timer, wall, traffic=None):
         out["partition_bytes_per_row_split"] = \
             traffic["partition_bytes_per_row"]
         out["hist_gather_bytes_per_row"] = traffic["hist_bytes_per_row"]
-        out["split_kernel"] = traffic.get("split_kernel", "off")
-        out["launches_per_split"] = traffic.get("launches_per_split", 3)
         out["effective_rows"] = traffic.get("effective_rows", 0)
         out["goss_compact"] = traffic.get("goss_compact", "off")
     return out

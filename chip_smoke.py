@@ -43,8 +43,7 @@ TINY = dict(n_canary=4_096, n_full=16_384, n_pred=1_024,
 # what tpu_* = auto is documented to resolve to on a TPU at F=28 (packed row
 # 40 B <= 256 B): learner.build_kwargs, PERF.md "Layers"
 TPU_AUTO = dict(work_layout="planes", part_kernel="pallas",
-                hist_kernel="pallas", split_kernel="off", hist_mxu="off",
-                part_chunk=1024, hist_chunk=8192)
+                hist_kernel="pallas", part_chunk=1024, hist_chunk=8192)
 RESOLVED_KEYS = tuple(TPU_AUTO)
 # the canary's oracle: no Pallas partition, the row-major layout
 ORACLE = dict(tpu_work_layout="rows", tpu_partition_kernel="xla")

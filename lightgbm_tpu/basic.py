@@ -407,8 +407,8 @@ class Booster:
         # refresh learner hyperparameters that affect future trees,
         # PRESERVING the learner class: a Data/Feature/Voting mesh learner
         # must not silently downgrade to SerialTreeLearner mid-training
-        # under the model lock: serving threads read the learner (the
-        # tpu_forest_kernel resolution rides on it) while we swap it
+        # under the model lock: serving threads may read the learner while
+        # we swap it
         with inner._cache_lock:
             if inner.learner is not None:
                 from .parallel.mesh import _MeshTreeLearner, \

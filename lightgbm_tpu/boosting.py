@@ -329,14 +329,9 @@ class GBDT:
             # tree (planes/resident fold the root into the pack)
             spec = self.learner.traffic_spec()
             root_hists = 0 if (spec and spec["work_layout"] != "rows") else 1
-            one_kernel = bool(spec and spec.get("split_kernel") == "on")
-            # one-kernel split: the fused launch IS the partition launch;
-            # per-split histogram and split-scan launches disappear
             telemetry.count("learner/partition_launches", splits)
-            telemetry.count("learner/hist_launches",
-                            root_hists if one_kernel else splits + root_hists)
-            telemetry.count("learner/scan_launches",
-                            0 if one_kernel else splits)
+            telemetry.count("learner/hist_launches", splits + root_hists)
+            telemetry.count("learner/scan_launches", splits)
             if spec:
                 telemetry.gauge("traffic/work_layout", spec["work_layout"])
                 telemetry.gauge("traffic/partition_bytes_per_row",
@@ -345,8 +340,6 @@ class GBDT:
                                 spec["hist_bytes_per_row"])
                 telemetry.gauge("traffic/effective_rows",
                                 spec.get("effective_rows", 0))
-                telemetry.gauge("learner/launches_per_split",
-                                spec.get("launches_per_split", 3))
             if tree.num_leaves > 1:
                 any_nonconstant = True
         if self.config.obs_check_finite != "off":
@@ -714,74 +707,6 @@ class GBDT:
             hit = cache[key] = pack_splits(self.models[start * K:end * K],
                                            num_class=K)
             return hit
-
-    def _forest_knob(self) -> str:
-        """Resolved ``tpu_forest_kernel`` value for serving sessions:
-        the learner's build-time resolution when this booster trained in
-        process, else the configured value (``auto`` resolves ``off`` —
-        the kernel's Mosaic lowering is unvalidated on hardware; see
-        scripts/forest_bisect.py)."""
-        # sessions call this from serving threads while reset_parameter
-        # may swap the learner on the training thread
-        with self._cache_lock:
-            lr = getattr(self, "learner", None)
-        v = getattr(lr, "_forest_kernel", None)
-        if v in ("on", "off"):
-            return v
-        cfg = getattr(self.config, "tpu_forest_kernel", "auto")
-        return "off" if cfg == "auto" else cfg
-
-    def _forest_model(self, start: int, end: int):
-        """Device-resident BIN-space ``ForestPack`` for [start, end), or
-        ``None`` when the forest path is structurally ineligible (no
-        constructed train_set to supply bin mappers, splits on unmapped
-        features, node tables over the VMEM budget).
-
-        Cached behind the model-version token exactly like
-        ``_packed_model`` (``serve/forest_build`` / ``serve/forest_hit``
-        counters); ineligibility is cached too, so a hot predict path
-        never re-derives it."""
-        from .obs import telemetry
-        from .ops.forest import (FOREST_VMEM_BUDGET, forest_pack,
-                                 forest_table_bytes)
-
-        with self._cache_lock:
-            cache = getattr(self, "_forest_cache", None)
-            if cache is None:
-                cache = self._forest_cache = {}
-            key = (start, end, self._model_version)
-            hit = cache.get(key)
-            if hit is not None:
-                telemetry.count("serve/forest_hit")
-                return None if hit[0] == "ineligible" else hit[1]
-            if len(cache) > 16:
-                cache.clear()
-            ds = self.train_set
-            why = None
-            entry = None
-            if ds is None:
-                why = "no constructed train_set (bin mappers unavailable)"
-            else:
-                try:
-                    telemetry.count("serve/forest_build")
-                    K = self.num_tree_per_iteration
-                    fp, has_cat, has_linear = forest_pack(
-                        self.models[start * K:end * K], ds, num_class=K)
-                    tbytes = forest_table_bytes(fp)
-                    if tbytes > FOREST_VMEM_BUDGET:
-                        why = ("node tables %d B exceed the %d B VMEM "
-                               "budget" % (tbytes, FOREST_VMEM_BUDGET))
-                    else:
-                        entry = (fp, has_cat, has_linear)
-                except ValueError as exc:
-                    why = str(exc)
-            if entry is None:
-                cache[key] = ("ineligible", why)
-                telemetry.record("forest_ineligible", dedupe_key=why,
-                                 reason=why)
-                return None
-            cache[key] = ("ok", entry)
-            return entry
 
     def _predict_session(self, start: int, end: int):
         """Lazily created serving session per iteration range (the device

@@ -346,8 +346,6 @@ class Application:
                 raw_score=cfg.predict_raw_score,
                 warmup=cfg.serve_warmup,
                 dispatch_mode=cfg.serve_dispatch,
-                forest=(None if cfg.tpu_forest_kernel == "auto"
-                        else cfg.tpu_forest_kernel),
                 online=model_online)
             if fleet_replica:
                 from .fleet import ReplicaWatcher

@@ -377,23 +377,6 @@ class Config:
     #   measured 1.8-3.6x slower per iteration than plain planes on a v5e
     #   (PERF.md, PR 21); on: force (requires a planes-capable config —
     #   errors with tpu_work_layout=rows or int8 histograms).
-    tpu_split_kernel: str = "auto"   # auto|off|on: one-kernel split — ONE
-    #   pallas_call per split running partition + smaller-child histogram
-    #   + split scan as sequential phases (planes/resident layouts only),
-    #   vs the three-launch chain. Bit-identical trees; the three-launch
-    #   path stays as the parity oracle. auto: off everywhere until the
-    #   fused kernel is validated on real Mosaic (scripts/split_bisect.py);
-    #   on: force where structurally eligible (serial training, planes
-    #   family, no feature bundling / CEGB / intermediate monotone).
-    tpu_forest_kernel: str = "auto"  # auto|off|on: forest-at-once serving —
-    #   ONE pallas_call per row tile holding the (tile, trees) traversal
-    #   front in VMEM over BIN-space split-major node tables (ops/forest),
-    #   vs the per-depth-gather predict. Bit-identical scores; the
-    #   per-depth path stays the serving default and the parity oracle.
-    #   auto: off everywhere until the kernel is validated on real Mosaic
-    #   (scripts/forest_bisect.py); on: force where structurally eligible
-    #   (booster trained in-process or with a constructed train_set, node
-    #   tables within the VMEM budget).
     tpu_goss_compact: str = "auto"   # auto|off|on: GOSS row compaction —
     #   after the sampler emits the inbag mask, a device sort-by-inbag +
     #   static-shape slice packs the surviving rows into a compact work
@@ -406,15 +389,6 @@ class Config:
     #   hardware; on: force where eligible (GOSS sampling active, serial
     #   training, not int8 — the stochastic-rounding draws are
     #   row-position seeded).
-    tpu_hist_mxu: str = "auto"       # auto|off|on: one-hot MXU histogram —
-    #   a Pallas kernel (rows layout) that builds per-chunk one-hots in
-    #   VMEM and feeds the MXU via matmul, serving both the f32 hi/lo-16
-    #   path and the use_quantized_grad int8 path (int8 x int8 -> i32
-    #   accumulation) from one kernel body. The segment-histogram einsum
-    #   stays verbatim as the bit-parity oracle. auto: off everywhere
-    #   until scripts/hist_mxu_bisect.py validates the MXU lowering on
-    #   hardware; on: force where eligible (rows layout, pallas
-    #   partition widths, hist chunk % 32 == 0).
     use_quantized_grad: bool = False  # int8 stochastic gradient quantization
     #   (LightGBM 4.x quantized training analog; rows per leaf <= ~16M)
 
@@ -494,18 +468,9 @@ class Config:
         if self.tpu_resident_state not in ("auto", "off", "on"):
             Log.fatal("tpu_resident_state must be auto, off or on; got %s",
                       self.tpu_resident_state)
-        if self.tpu_split_kernel not in ("auto", "off", "on"):
-            Log.fatal("tpu_split_kernel must be auto, off or on; got %s",
-                      self.tpu_split_kernel)
-        if self.tpu_forest_kernel not in ("auto", "off", "on"):
-            Log.fatal("tpu_forest_kernel must be auto, off or on; got %s",
-                      self.tpu_forest_kernel)
         if self.tpu_goss_compact not in ("auto", "off", "on"):
             Log.fatal("tpu_goss_compact must be auto, off or on; got %s",
                       self.tpu_goss_compact)
-        if self.tpu_hist_mxu not in ("auto", "off", "on"):
-            Log.fatal("tpu_hist_mxu must be auto, off or on; got %s",
-                      self.tpu_hist_mxu)
         if self.serve_dispatch not in ("continuous", "coalesce"):
             Log.fatal("serve_dispatch must be continuous or coalesce; "
                       "got %s", self.serve_dispatch)

@@ -595,14 +595,9 @@ class FusedTrainer:
         spec = self.learner.traffic_spec()
         root_hists = 0 if (spec and spec["work_layout"] != "rows") \
             else len(trees)
-        one_kernel = bool(spec and spec.get("split_kernel") == "on")
-        # one-kernel split: the fused launch IS the partition launch; the
-        # per-split child histogram and split-scan launches disappear
-        hist_launches = root_hists if one_kernel else splits + root_hists
-        scan_launches = 0 if one_kernel else splits
         telemetry.count("learner/partition_launches", splits)
-        telemetry.count("learner/hist_launches", hist_launches)
-        telemetry.count("learner/scan_launches", scan_launches)
+        telemetry.count("learner/hist_launches", splits + root_hists)
+        telemetry.count("learner/scan_launches", splits)
         if spec:
             telemetry.gauge("traffic/work_layout", spec["work_layout"])
             telemetry.gauge("traffic/partition_bytes_per_row",
@@ -611,9 +606,6 @@ class FusedTrainer:
                             spec["hist_bytes_per_row"])
             telemetry.gauge("traffic/effective_rows",
                             spec.get("effective_rows", 0))
-            telemetry.gauge("learner/launches_per_split",
-                            spec.get("launches_per_split",
-                                     3 if not one_kernel else 1))
 
     def _host_tree(self, host: BlockLogs, pick):
         from .tree import Tree

@@ -675,10 +675,6 @@ def build_tree_partitioned(
     part_kernel: str = "xla",  # xla | pallas (fused DMA kernel, TPU only)
     hist_kernel: str = "xla",  # xla (einsum) | pallas (in-VMEM, TPU only:
     # several features an MXU pass on planes, one a pass on rows)
-    split_kernel: str = "off",  # off (three launches: partition, child
-    # histogram, split scan) | on (ONE pallas_call per split running all
-    # three phases; planes family + serial training only — bit-identical
-    # trees, the off path is the parity oracle)
     work_buf: Optional[jax.Array] = None,  # carried (2, Npad, W) u8 buffer
     return_work: bool = False,
     bins_t: Optional[jax.Array] = None,    # (F, N) transposed bins — pass a
@@ -707,9 +703,6 @@ def build_tree_partitioned(
     # DENSE ghc: XLA's row reduce uses strided accumulators, so summing
     # the compacted array would regroup the f32 additions (+/-1 ulp) —
     # histogram matmuls accumulate sequentially over rows and are immune
-    hist_mxu: str = "off",  # off | on: one-hot MXU histogram kernel
-    # (ops/histogram.py hist_mxu_segment — rows layout; serves both the
-    # f32 hi/lo and the int8 quantized path from one kernel body)
 ) -> TreeLog:
     """Grow one leaf-wise tree with a physical row partition.
 
@@ -754,8 +747,7 @@ def build_tree_partitioned(
             num_bin_hist=num_bin_hist, bundle=bundle,
             constraint_sets=constraint_sets, forced=forced,
             part_kernel=part_kernel, hist_kernel=hist_kernel,
-            split_kernel=split_kernel, work_layout=work_layout,
-            goss_compact_rows=0, hist_mxu=hist_mxu,
+            work_layout=work_layout, goss_compact_rows=0,
             return_work=return_work)
 
         def _compact(_):
@@ -784,10 +776,9 @@ def build_tree_partitioned(
 
     from .ops.histogram import (hist16_segment, hist16_segment_planes,
                                 hist16_segment_q, hist16_segment_resident,
-                                hist_mxu_segment, hist_pallas_segment,
+                                hist_pallas_segment,
                                 hist_pallas_segment_planes)
-    from .ops.partition import (one_kernel_split_planes,
-                                pack_planes_fold_root,
+    from .ops.partition import (pack_planes_fold_root,
                                 pack_resident_fold_root, pack_rows,
                                 pack_rows_quantized, partition_segment,
                                 partition_segment_fused,
@@ -807,47 +798,6 @@ def build_tree_partitioned(
     guard, buf_width = work_spec(num_grp, quantized, part_kernel,
                                  part_chunk, hist_chunk, layout=work_layout)
     bm = num_bin_hist if num_bin_hist is not None else num_bin
-    one_kernel = split_kernel == "on"
-    if one_kernel:
-        # the fused kernel inlines the scan verbatim under these premises
-        # (serial comm => hist/sync_split identity; bundle None => group ==
-        # feature and route_table identity; no CEGB / by-node RNG /
-        # extra-trees / constraint sets => best_raw reduces to a plain
-        # find_best_split over fmask_search; scalar monotone bounds only)
-        bad = []
-        if not planes or not fused_part:
-            bad.append("needs the fused pallas planes/resident layout")
-        if quantized:
-            bad.append("int8 histograms unsupported")
-        if bundle is not None or bm != num_bin:
-            bad.append("EFB feature bundling unsupported by the one-kernel "
-                       "split (it scans bundle columns as features)")
-        if comm.axis is not None:
-            bad.append("multi-device comm unsupported")
-        if hp.use_cegb:
-            bad.append("CEGB penalties unsupported")
-        if hp.has_monotone and (hp.mono_intermediate or hp.mono_advanced):
-            bad.append("intermediate/advanced monotone unsupported")
-        if feature_fraction_bynode < 1.0 or extra_trees:
-            bad.append("by-node sampling / extra-trees unsupported")
-        if constraint_sets is not None:
-            bad.append("interaction constraint sets unsupported")
-        if hist_chunk % 128:
-            bad.append("hist_chunk must be a multiple of 128")
-        if bad:
-            raise ValueError("tpu_split_kernel=on is not eligible here: "
-                             + "; ".join(bad))
-    if hist_mxu == "on":
-        bad = []
-        if planes:
-            bad.append("needs the rows work layout")
-        if not fused_part:
-            bad.append("needs part_kernel=pallas (128-lane work rows)")
-        if hist_chunk % 32:
-            bad.append("hist_chunk must be a multiple of 32")
-        if bad:
-            raise ValueError("tpu_hist_mxu=on is not eligible here: "
-                             + "; ".join(bad))
 
     # ---- packed ping-pong working buffers with guard rows ----
     # the matrix columns are EFB bundles (== features when no bundling)
@@ -953,24 +903,10 @@ def build_tree_partitioned(
                                       num_feat=num_grp,
                                       exact=hist_mode != "bf16",
                                       chunk=hist_chunk, lo_w=hist_lo)
-        elif quantized and hist_mxu == "on":
-            # int8 one-hots x int8 channels -> i32 on the MXU; integer
-            # accumulation makes parity with hist16_segment_q exact
-            h, work = hist_mxu_segment(work, plane, start, cnt,
-                                       num_bins=bm, num_feat=num_grp,
-                                       quantized=True, gscale=gscale,
-                                       hscale=hscale, chunk=hist_chunk,
-                                       lo_w=hist_lo)
         elif quantized:
             h = hist16_segment_q(work, plane, start, cnt, gscale, hscale,
                                  num_bins=bm, num_feat=num_grp,
                                  chunk=hist_chunk, lo_w=hist_lo)
-        elif hist_mxu == "on":
-            h, work = hist_mxu_segment(work, plane, start, cnt,
-                                       num_bins=bm, num_feat=num_grp,
-                                       quantized=False,
-                                       exact=hist_mode != "bf16",
-                                       chunk=hist_chunk, lo_w=hist_lo)
         elif hist_kernel == "pallas":
             # in-VMEM chunk loop + accumulator: one streamed read of the
             # segment, none of the XLA loop's per-chunk parasitic fusions
@@ -1095,7 +1031,7 @@ def build_tree_partitioned(
         # the XLA segment histogram over the root segment (same chunking,
         # same einsum order). The Pallas hist_of adds the same exact
         # products in another order: equal counts, sums within 1e-6 of a
-        # cell's sum of |terms| (tests/test_resident_state.py)
+        # cell's sum of |terms| (tests/test_histogram.py)
         root_hist = comm.hist(root_hist_loc)
     else:
         with trace_phase("lgbtpu/root_hist"):
@@ -1296,15 +1232,12 @@ def build_tree_partitioned(
             split_col = bundle["group"][info.feature] if bundle is not None \
                 else info.feature
             # smaller child by GLOBAL in-bag count, so all shards agree
-            # (serial_tree_learner.cpp:418) — known BEFORE the partition runs,
-            # which is what lets the one-kernel path histogram the right child
-            # inside the same launch
+            # (serial_tree_learner.cpp:418)
             left_smaller = info.left_sum[2] <= info.right_sum[2]
-        if not one_kernel:
-            go_left_cols = route_table(info)
-            with trace_phase("lgbtpu/partition"):
-                work, lt = part_fn(work, parity, start, cnt, split_col,
-                                   go_left_cols, ch=part_chunk)
+        go_left_cols = route_table(info)
+        with trace_phase("lgbtpu/partition"):
+            work, lt = part_fn(work, parity, start, cnt, split_col,
+                               go_left_cols, ch=part_chunk)
         with trace_phase("lgbtpu/tree_state"):
             new_parity = 1 - parity
 
@@ -1329,18 +1262,12 @@ def build_tree_partitioned(
             )
 
             # ---- segment bookkeeping ----
-            def seg_update(lt, leaf_start, leaf_cnt, leaf_parity):
-                leaf_start = leaf_start.at[new_leaf].set(
-                    sel(start + lt, leaf_start[new_leaf]))
-                leaf_cnt = leaf_cnt.at[leaf].set(sel(lt, cnt)) \
-                    .at[new_leaf].set(sel(cnt - lt, leaf_cnt[new_leaf]))
-                leaf_parity = leaf_parity.at[leaf].set(sel(new_parity, parity)) \
-                    .at[new_leaf].set(sel(new_parity, leaf_parity[new_leaf]))
-                return leaf_start, leaf_cnt, leaf_parity
-
-            if not one_kernel:
-                leaf_start, leaf_cnt, leaf_parity = seg_update(
-                    lt, leaf_start, leaf_cnt, leaf_parity)
+            leaf_start = leaf_start.at[new_leaf].set(
+                sel(start + lt, leaf_start[new_leaf]))
+            leaf_cnt = leaf_cnt.at[leaf].set(sel(lt, cnt)) \
+                .at[new_leaf].set(sel(cnt - lt, leaf_cnt[new_leaf]))
+            leaf_parity = leaf_parity.at[leaf].set(sel(new_parity, parity)) \
+                .at[new_leaf].set(sel(new_parity, leaf_parity[new_leaf]))
 
             # ---- stats bookkeeping ----
             leaf_sum = leaf_sum.at[leaf].set(sel(info.left_sum, leaf_sum[leaf])) \
@@ -1407,43 +1334,15 @@ def build_tree_partitioned(
             # contiguous segment; the larger child is parent - smaller ----
             parent_hist = hist_pool[leaf].reshape(num_grp, bm, 3)
             pair = jnp.stack([leaf, new_leaf])
-        if one_kernel:
-            # ONE launch: partition + smaller-child histogram + both-child
-            # split scan. Inputs match what the oracle's hist_of +
-            # node_best_pair would see (bounds/outputs already updated
-            # above); outputs are bit-identical by construction.
-            if resident:
-                with trace_phase("lgbtpu/one_kernel_split"):
-                    work = write_route_plane(work, bins_res, parity, start, cnt,
-                                             split_col, ch=part_chunk)
-            with trace_phase("lgbtpu/one_kernel_split"):
-                work, lt, hist_left, hist_right, infos = \
-                    one_kernel_split_planes(
-                        work, parity, start, cnt,
-                        jnp.int32(0) if resident else split_col,
-                        info.go_left, left_smaller, d, parent_hist, meta,
-                        fmask_search,
-                        jnp.stack([info.left_sum, info.right_sum]),
-                        leaf_out[pair], leaf_lower[pair], leaf_upper[pair],
-                        hp, num_bins=bm, num_feat=num_grp,
-                        exact=hist_mode != "bf16", ch=part_chunk,
-                        hist_chunk=hist_chunk, lo_w=hist_lo,
-                        resident_planes=bins_res if resident else None)
-            with trace_phase("lgbtpu/tree_state"):
-                leaf_start, leaf_cnt, leaf_parity = seg_update(
-                    lt, leaf_start, leaf_cnt, leaf_parity)
-        else:
-            with trace_phase("lgbtpu/tree_state"):
-                small_start = jnp.where(left_smaller, start, start + lt)
-                small_cnt = jnp.where(left_smaller, lt, cnt - lt)
-            with trace_phase("lgbtpu/histogram"):
-                hist_small, work = hist_of(work, new_parity, small_start,
-                                           small_cnt)
-            with trace_phase("lgbtpu/tree_state"):
-                hist_large = parent_hist - hist_small
-                hist_left = jnp.where(left_smaller, hist_small, hist_large)
-                hist_right = jnp.where(left_smaller, hist_large, hist_small)
+            small_start = jnp.where(left_smaller, start, start + lt)
+            small_cnt = jnp.where(left_smaller, lt, cnt - lt)
+        with trace_phase("lgbtpu/histogram"):
+            hist_small, work = hist_of(work, new_parity, small_start,
+                                       small_cnt)
         with trace_phase("lgbtpu/tree_state"):
+            hist_large = parent_hist - hist_small
+            hist_left = jnp.where(left_smaller, hist_small, hist_large)
+            hist_right = jnp.where(left_smaller, hist_large, hist_small)
             if n_forced:
                 old_right = hist_pool[new_leaf].reshape(num_grp, bm, 3)
                 pool_val = jnp.stack([sel(hist_left, parent_hist),
@@ -1468,26 +1367,24 @@ def build_tree_partitioned(
 
         # one vmapped search over both children: the scan ops are tiny at
         # (F, B), so two separate calls pay the per-op dispatch cost twice
-        # (one-kernel rounds already scanned inside the fused launch)
-        if not one_kernel:
-            with trace_phase("lgbtpu/tree_state"):
-                extra_pair = ()
-                if hp.mono_advanced:
-                    adv = _adv_commit(adv, meta, sel, leaf, new_leaf, info,
-                                      num_bin)
-                    ab_l = _adv_bounds_of(adv, leaf)
-                    ab_r = _adv_bounds_of(adv, new_leaf)
-                    extra_pair = (jax.tree.map(lambda a, b: jnp.stack([a, b]),
-                                               ab_l, ab_r),)
-            pair_g = jnp.stack([info.left_sum, info.right_sum])
-            pair_l = jnp.stack([loc_left, loc_right])
-            pair_view = feat_views(jnp.stack([hist_left, hist_right]),
-                                   pair_g, pair_l)
-            with trace_phase("lgbtpu/split_scan"):
-                infos = node_best_pair(
-                    r, pair, pair_view, pair_g, pair_l, leaf_out[pair],
-                    leaf_lower[pair], leaf_upper[pair], used_new, tree_used,
-                    d, *extra_pair)
+        with trace_phase("lgbtpu/tree_state"):
+            extra_pair = ()
+            if hp.mono_advanced:
+                adv = _adv_commit(adv, meta, sel, leaf, new_leaf, info,
+                                  num_bin)
+                ab_l = _adv_bounds_of(adv, leaf)
+                ab_r = _adv_bounds_of(adv, new_leaf)
+                extra_pair = (jax.tree.map(lambda a, b: jnp.stack([a, b]),
+                                           ab_l, ab_r),)
+        pair_g = jnp.stack([info.left_sum, info.right_sum])
+        pair_l = jnp.stack([loc_left, loc_right])
+        pair_view = feat_views(jnp.stack([hist_left, hist_right]),
+                               pair_g, pair_l)
+        with trace_phase("lgbtpu/split_scan"):
+            infos = node_best_pair(
+                r, pair, pair_view, pair_g, pair_l, leaf_out[pair],
+                leaf_lower[pair], leaf_upper[pair], used_new, tree_used,
+                d, *extra_pair)
         with trace_phase("lgbtpu/tree_state"):
             gates = jnp.stack([depth_ok(leaf_depth[leaf]),
                                depth_ok(leaf_depth[new_leaf])]) & valid
@@ -1842,10 +1739,7 @@ class SerialTreeLearner:
                          "tpu_hist_kernel": ("pallas", "xla"),
                          "tpu_work_layout": ("planes", "rows"),
                          "tpu_resident_state": ("resident", "off"),
-                         "tpu_split_kernel": ("on", "off"),
-                         "tpu_forest_kernel": ("on", "off"),
-                         "tpu_goss_compact": ("on", "off"),
-                         "tpu_hist_mxu": ("on", "off")}
+                         "tpu_goss_compact": ("on", "off")}
                 for k, v in raw.items():
                     if k in valid and v in valid[k]:
                         pre[k] = v
@@ -2033,76 +1927,6 @@ class SerialTreeLearner:
                 Log.fatal("planes layout needs tpu_part_chunk a multiple "
                           "of 128 and, above 256, of the 256-row "
                           "compaction sub-block (got %d)", part_chunk)
-            sk = config.tpu_split_kernel
-            auto_sk = sk == "auto"
-            sk_why = ""
-            if auto_sk and "tpu_split_kernel" in pre:
-                sk = _pre("tpu_split_kernel")
-                auto_sk = False
-            elif auto_sk:
-                # auto = off: bit-parity holds under the pallas interpreter
-                # but Mosaic refuses the kernel for a v5e (whole-ref HBM
-                # load on resident, in-kernel dynamic_slice on planes:
-                # tests/test_aot_tpu.py). Nothing to measure until that is
-                # repaired; scripts/split_bisect.py is the gate after it.
-                sk = "off"
-                sk_why = ("Mosaic refuses the one-kernel split for a v5e "
-                          "(tests/test_aot_tpu.py); repair the lowering, "
-                          "then scripts/split_bisect.py decides")
-            if sk == "on":
-                bad = []
-                if layout not in ("planes", "resident") \
-                        or part_kernel != "pallas":
-                    bad.append("needs the fused pallas planes/resident "
-                               "layout")
-                if mode == "int8":
-                    bad.append("int8 histograms unsupported")
-                if self.bundle is not None \
-                        or self.num_bin_hist != self.num_bin:
-                    bad.append("EFB feature bundling unsupported by the "
-                               "one-kernel split (it scans bundle columns "
-                               "as features)")
-                if self.comm.axis is not None:
-                    bad.append("multi-device comm unsupported")
-                if self.hp.use_cegb:
-                    bad.append("CEGB penalties unsupported")
-                if self.hp.has_monotone and (self.hp.mono_intermediate
-                                             or self.hp.mono_advanced):
-                    bad.append("intermediate/advanced monotone unsupported")
-                if float(config.feature_fraction_bynode) < 1.0 \
-                        or bool(config.extra_trees):
-                    bad.append("by-node sampling / extra-trees unsupported")
-                if kw.get("constraint_sets") is not None:
-                    bad.append("interaction constraint sets unsupported")
-                if hist_chunk % 128:
-                    bad.append("hist_chunk must be a multiple of 128")
-                if bad:
-                    Log.warning("tpu_split_kernel=on is not eligible here "
-                                "(%s); using the three-launch path",
-                                "; ".join(bad))
-                    sk = "off"
-                    if auto_sk:
-                        sk_why = "structurally ineligible: " + "; ".join(bad)
-            fk = config.tpu_forest_kernel
-            auto_fk = fk == "auto"
-            fk_why = ""
-            if auto_fk and "tpu_forest_kernel" in pre:
-                fk = _pre("tpu_forest_kernel")
-                auto_fk = False
-            elif auto_fk:
-                # auto = off: parity with the per-depth-gather predict
-                # holds under the pallas interpreter, but Mosaic refuses
-                # the kernel for a v5e (in-kernel dynamic_slice over the
-                # node tables: tests/test_aot_tpu.py). The per-depth gather
-                # compiles and is what serving runs.
-                fk = "off"
-                fk_why = ("Mosaic refuses the forest kernel for a v5e "
-                          "(tests/test_aot_tpu.py); repair the lowering, "
-                          "then scripts/forest_bisect.py decides")
-            # serve-time eligibility (train_set present, tables within the
-            # VMEM budget) is per-model state — boosting._forest_model
-            # re-checks it on every pack; only the knob resolves here
-            self._forest_kernel = fk
             from .ops.partition import goss_compact_rows as _gcr
             n_rows = int(self.bins.shape[0])
             goss_active = (config.data_sample_strategy == "goss"
@@ -2152,38 +1976,6 @@ class SerialTreeLearner:
                     gc = "off"
                     if auto_gc:
                         gc_why = "structurally ineligible: " + "; ".join(bad)
-            hm = config.tpu_hist_mxu
-            auto_hm = hm == "auto"
-            hm_why = ""
-            if auto_hm and "tpu_hist_mxu" in pre:
-                hm = _pre("tpu_hist_mxu")
-                auto_hm = False
-            elif auto_hm:
-                # auto = off: the f32 variant compiles for a v5e (86 s)
-                # and is untimed; the int8 variant is refused (102 MB of
-                # scoped VMEM against its own 100 MB limit:
-                # tests/test_aot_tpu.py). It needs the rows layout, which
-                # auto does not pick on a TPU.
-                hm = "off"
-                hm_why = ("one-hot MXU histogram: f32 compiles for a v5e "
-                          "but is untimed, int8 is refused (VMEM); "
-                          "scripts/hist_mxu_bisect.py decides")
-            if hm == "on":
-                bad = []
-                if layout in ("planes", "resident"):
-                    bad.append("needs the rows work layout")
-                if part_kernel != "pallas":
-                    bad.append("needs part_kernel=pallas (128-lane work "
-                               "rows)")
-                if hist_chunk % 32:
-                    bad.append("hist_chunk must be a multiple of 32")
-                if bad:
-                    Log.warning("tpu_hist_mxu=on is not eligible here "
-                                "(%s); using the XLA einsum path",
-                                "; ".join(bad))
-                    hm = "off"
-                    if auto_hm:
-                        hm_why = "structurally ineligible: " + "; ".join(bad)
             # auto-knob resolution records: what auto chose and why
             # (deduped, so repeated build_kwargs calls keep one record per
             # distinct resolution)
@@ -2211,14 +2003,8 @@ class SerialTreeLearner:
             if auto_hist_chunk:
                 _rec("tpu_hist_chunk", hist_chunk,
                      "packed width %d default chunk" % self.bins.shape[1])
-            if auto_sk:
-                _rec("tpu_split_kernel", sk, sk_why)
-            if auto_fk:
-                _rec("tpu_forest_kernel", fk, fk_why)
             if auto_gc:
                 _rec("tpu_goss_compact", gc, gc_why)
-            if auto_hm:
-                _rec("tpu_hist_mxu", hm, hm_why)
             kw.update(
                 hist_chunk=hist_chunk,
                 part_chunk=part_chunk,
@@ -2228,10 +2014,8 @@ class SerialTreeLearner:
                 bundle=self.bundle,
                 part_kernel=part_kernel,
                 hist_kernel=hist_kernel,
-                split_kernel=sk,
                 work_layout=layout,
                 goss_compact_rows=m_rows if gc == "on" else 0,
-                hist_mxu=hm,
             )
         else:
             kw.update(
@@ -2371,21 +2155,15 @@ class SerialTreeLearner:
             hist = w
         else:
             hist = w                    # row-major reads the packed row
-        one_kernel = kw.get("split_kernel", "off") == "on"
         n = int(self.bins.shape[0])
         m = int(kw.get("goss_compact_rows", 0))
         return {"work_layout": layout, "work_width": int(w),
                 "partition_bytes_per_row": int(part),
                 "hist_bytes_per_row": int(hist),
-                "split_kernel": kw.get("split_kernel", "off"),
-                "hist_mxu": kw.get("hist_mxu", "off"),
                 # rows every downstream pass scans per tree: the GOSS
                 # compact prefix when compaction resolved on, else N
                 "effective_rows": m if 0 < m < n else n,
-                "goss_compact": "on" if 0 < m < n else "off",
-                # device launches per split on this config: partition +
-                # child histogram + split scan, or the fused one-kernel
-                "launches_per_split": 1 if one_kernel else 3}
+                "goss_compact": "on" if 0 < m < n else "off"}
 
     def train(self, ghc: jax.Array, feature_mask: jax.Array, key: jax.Array,
               cegb_used: Optional[jax.Array] = None) -> TreeLog:

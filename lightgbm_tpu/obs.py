@@ -187,7 +187,6 @@ PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
     "lgbtpu/split_scan": ("device", "tree learner", None),
     "lgbtpu/partition": ("device", "kernels", None),
     "lgbtpu/histogram": ("device", "kernels", None),
-    "lgbtpu/one_kernel_split": ("device", "tree learner", None),
     "lgbtpu/route": ("device", "kernels", None),
     "lgbtpu/score_update": ("device", "tree learner", None),
     "lgbtpu/tree_log": ("device", "booster", None),

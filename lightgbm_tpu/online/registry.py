@@ -74,7 +74,7 @@ class ModelRegistry:
                  max_queue_rows: int = 0, overload: str = "shed",
                  tenant_quota_rows: int = 0, tenant_weights=None,
                  raw_score: bool = False, warmup: bool = False,
-                 dispatch_mode: str = "continuous", forest=None,
+                 dispatch_mode: str = "continuous",
                  online=None) -> RegistryEntry:
         """Build and register the serving stack for one model.
 
@@ -87,7 +87,7 @@ class ModelRegistry:
         model_id = str(model_id)
         if not model_id:
             raise LightGBMError("model_id must be non-empty")
-        session = PredictSession(booster, buckets=buckets, forest=forest)
+        session = PredictSession(booster, buckets=buckets)
         if warmup:
             session.warmup()
         batcher = MicroBatcher(session, max_batch_rows=max_batch_rows,

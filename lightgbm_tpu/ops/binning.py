@@ -18,9 +18,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-# guards the lazy sorted-category views: serving threads bin rows for
-# the forest path (session._bin_rows) concurrently with main-thread
-# predicts on the same mappers
+# guards the lazy sorted-category views: serving and ingest threads bin
+# rows concurrently with main-thread predicts on the same mappers
 _SORT_LOCK = threading.Lock()
 
 # Values with |x| <= kZeroThreshold fall into the zero bin

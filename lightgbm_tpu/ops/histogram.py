@@ -881,207 +881,3 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
     sh = (num_bins + lo_w - 1) // lo_w
     h = d.transpose(0, 1, 4, 3, 2).reshape(ngrp * g, shp, lo_w * nch)
     return _hist16_combine(h[:f, :sh], num_bins, exact, lo_w), work_out
-
-
-# ---------------------------------------------------------------------------
-# One-hot MXU histogram (rows layout, f32-hilo + int8 from one body)
-# ---------------------------------------------------------------------------
-#
-# The rows pallas kernel above already keeps the accumulator in VMEM, but
-# its per-feature dots carry bf16 operands only — the use_quantized_grad
-# int8 path still falls back to the XLA einsum loop (hist16_segment_q),
-# which XLA will not lower to the MXU (PERF round 3: the int8 batched
-# einsum stays on the VPU, projected ~39 -> ~15-20 ms/iter if it fed the
-# MXU). This kernel serves BOTH precisions from one body: the one-hots
-# build in VMEM per chunk and feed the MXU via jax.lax.dot_general —
-# bf16 x bf16 -> f32 for the hi/lo-16 path (identical channel math to
-# _hist_pallas_kernel) and int8 x int8 -> i32 for quantized training
-# (2x bf16 MXU peak, and integer accumulation is order-exact, so parity
-# with hist16_segment_q holds bit-for-bit at ANY chunk grouping). Unlike
-# hist_pallas_segment it runs under the pallas interpreter off-TPU with
-# f32 operands (the planes kernel's precedent), so the parity tests pin
-# both modes against the XLA oracles on CPU.
-
-
-def _hist_mxu_kernel(sref, work_in, work_ref, acc_ref, cin, acc_s, sem,
-                     *, ch, width, num_feat, sh, lo_w, nch, quantized, dt):
-    # same aliasing contract as _hist_pallas_kernel: work_ref is never
-    # written — it exists so the donated work buffer aliases through the
-    # call instead of being defensively copied per histogram
-    f32 = jnp.float32
-    i32 = jnp.int32
-    plane = sref[0]
-    start = sref[1]
-    cnt = sref[2]
-    F = num_feat
-
-    astart = (start // 32) * 32
-    head = start - astart
-    tot = head + cnt
-    nchunks = jnp.maximum((tot + ch - 1) // ch, 1)
-    acc_dt = i32 if quantized else f32
-    odt = jnp.int8 if quantized else dt
-
-    acc_s[...] = jnp.zeros((F * sh, lo_w * nch), acc_dt)
-
-    def start_in(i, slot):
-        # (x // 32) * 32 at the USE SITE proves the u8 DMA row offset
-        # 32-aligned (see _hist_pallas_kernel)
-        at = ((astart + i * ch) // 32) * 32
-        pltpu.make_async_copy(
-            work_in.at[plane, pl.ds(at, ch), :],
-            cin.at[slot], sem.at[slot]).start()
-
-    start_in(0, 0)
-
-    sub_i = jax.lax.broadcasted_iota(i32, (ch, 1), 0)
-    iota_sh = jax.lax.broadcasted_iota(i32, (ch, sh), 1)
-    jl = jax.lax.broadcasted_iota(i32, (ch, lo_w * nch), 1) // nch
-
-    def word(gb, o):
-        # f32 word from 4 u8 bytes; multiplies, not shifts (vector << by
-        # >= 16 miscompiles on this toolchain — see _hist_pallas_kernel)
-        return jax.lax.bitcast_convert_type(
-            gb[:, o:o + 1] + gb[:, o + 1:o + 2] * 256
-            + gb[:, o + 2:o + 3] * 65536
-            + gb[:, o + 3:o + 4] * 16777216, f32)
-
-    def body(i, carry):
-        slot = jax.lax.rem(i, 2)
-        at = ((astart + i * ch) // 32) * 32
-        pltpu.make_async_copy(
-            work_in.at[plane, pl.ds(at, ch), :],
-            cin.at[slot], sem.at[slot]).wait()
-
-        @pl.when(i + 1 < nchunks)
-        def _():
-            start_in(i + 1, 1 - slot)
-
-        cw = cin[slot].astype(i32)                      # (CH, W)
-        bi = cw[:, :F]
-        hi = bi // lo_w
-        lo = bi - hi * lo_w
-        pos = sub_i + i * ch
-        vb = (pos >= head) & (pos < tot)
-        if quantized:
-            # (F+3) u8 rows: bins | int8 g byte | int8 h byte | u8 cnt
-            # (pack_rows_quantized). Sign-decode from the u8-as-i32 view;
-            # the valid mask multiplies in as an exact integer 0/1.
-            gq = cw[:, F:F + 1]
-            gq = jnp.where(gq >= 128, gq - 256, gq)
-            hq = cw[:, F + 1:F + 2]
-            hq = jnp.where(hq >= 128, hq - 256, hq)
-            cq = cw[:, F + 2:F + 3]
-            v = vb.astype(i32)
-            tiled = jnp.concatenate(
-                [jnp.concatenate([gq * v, hq * v, cq * v], axis=1)
-                 .astype(jnp.int8)] * lo_w, axis=1)     # (CH, lo_w*3)
-        else:
-            gb = cw[:, F:F + 12]
-            valid = vb.astype(f32)
-            g = word(gb, 0) * valid
-            h = word(gb, 4) * valid
-            c = word(gb, 8) * valid
-            if nch == 5:
-                g_hi = g.astype(jnp.bfloat16)
-                g_lo = (g - g_hi.astype(f32)).astype(jnp.bfloat16)
-                h_hi = h.astype(jnp.bfloat16)
-                h_lo = (h - h_hi.astype(f32)).astype(jnp.bfloat16)
-                chs = jnp.concatenate(
-                    [g_hi, g_lo, h_hi, h_lo, c.astype(jnp.bfloat16)],
-                    axis=1)
-            else:
-                chs = jnp.concatenate([g, h, c], axis=1) \
-                    .astype(jnp.bfloat16)
-            tiled = jnp.concatenate([chs] * lo_w, axis=1).astype(dt)
-
-        for f in range(F):
-            hioh = (hi[:, f:f + 1] == iota_sh).astype(odt)
-            logf = jnp.where(lo[:, f:f + 1] == jl, tiled,
-                             jnp.zeros((), odt))
-            ps = jax.lax.dot_general(
-                hioh, logf, (((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dt)          # (SH, lo_w*nch)
-            acc_s[f * sh:(f + 1) * sh, :] += ps
-        return carry
-
-    jax.lax.fori_loop(0, nchunks, body, 0)
-    out_cp = pltpu.make_async_copy(acc_s, acc_ref, sem.at[0])
-    out_cp.start()
-    out_cp.wait()
-
-
-def hist_mxu_segment(work: jax.Array, plane, start, cnt, *,
-                     num_bins: int, num_feat: int, quantized: bool = False,
-                     gscale=None, hscale=None, exact: bool = True,
-                     chunk: int = 4096, lo_w: int = 0):
-    """One-hot MXU segment histogram -> ``(hist (F, num_bins, 3) f32, work)``.
-
-    Serves two precisions from one kernel body (``tpu_hist_mxu``):
-
-    - ``quantized=False``: f32 hi/lo-16 — same channel math and chunk
-      accumulation as :func:`hist_pallas_segment`, bit-parity oracle
-      :func:`hist16_segment`.
-    - ``quantized=True``: int8 one-hots x int8 channels -> i32 MXU
-      accumulation, dequantized with ``gscale``/``hscale`` exactly as
-      :func:`hist16_segment_q` (the oracle) — integer accumulation makes
-      the parity exact at any chunk size or segment alignment.
-
-    Requires the rows pallas work layout: width a multiple of 128, chunk a
-    multiple of 32. Same aliasing contract as :func:`hist_pallas_segment`
-    (callers must continue with the returned work buffer). Runs under the
-    pallas interpreter off-TPU (LGBTPU_PALLAS_INTERPRET=1) with f32
-    operands so parity tests compare against the exact XLA paths.
-    """
-    from .partition import _INTERPRET
-
-    f = num_feat
-    lo_w = lo_w or auto_lo_w(f)
-    sh = (num_bins + lo_w - 1) // lo_w
-    nch = 3 if quantized else (5 if exact else 3)
-    width = work.shape[2]
-    if width % 128:
-        raise ValueError("hist_mxu_segment needs 128-lane work rows")
-    if chunk % 32:
-        raise ValueError(
-            "hist_mxu_segment chunk must be a multiple of 32 "
-            "(u8 sublane DMA tiles), got %d" % chunk)
-    if quantized and (gscale is None or hscale is None):
-        raise ValueError("hist_mxu_segment quantized mode needs gscale/hscale")
-    acc_dt = jnp.int32 if quantized else jnp.float32
-    kern = partial(_hist_mxu_kernel, ch=chunk, width=width, num_feat=f,
-                   sh=sh, lo_w=lo_w, nch=nch, quantized=quantized,
-                   dt=_mxu_dtype())
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.HBM),
-                   pl.BlockSpec(memory_space=pltpu.HBM)],
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk, width), jnp.uint8),
-            pltpu.VMEM((f * sh, lo_w * nch), acc_dt),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    scalars = jnp.stack([plane.astype(jnp.int32), start.astype(jnp.int32),
-                         cnt.astype(jnp.int32)])
-    work_out, acc = pl.pallas_call(
-        kern,
-        name="hist_mxu_segment",
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(work.shape, work.dtype),
-                   jax.ShapeDtypeStruct((f * sh, lo_w * nch), acc_dt)],
-        input_output_aliases={1: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=100 * 1024 * 1024),
-        interpret=_INTERPRET,
-    )(scalars, work)
-    if quantized:
-        h = acc.reshape(f, sh, lo_w, 3).reshape(f, sh * lo_w, 3)[:, :num_bins]
-        scale = jnp.stack([1.0 / gscale, 1.0 / hscale, jnp.float32(1.0)])
-        return h.astype(jnp.float32) * scale[None, None, :], work_out
-    h = _hist16_combine(acc.reshape(f, sh, lo_w * nch), num_bins, exact,
-                        lo_w)
-    return h, work_out

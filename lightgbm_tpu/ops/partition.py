@@ -726,7 +726,7 @@ def _partition_kernel(sref, work_in, work_ref, lt_ref,
         # routing table lookup: the (B,) bool table rides the scalar
         # prefetch as 8 bit-packed i32 words (a full-array VMEM-spec input
         # here put the WHOLE device into a ~400 us/op dispatch mode —
-        # measured in scripts/spec_bisect.py — and poisoned every
+        # measured pre-round, PERF.md section 6 — and poisoned every
         # subsequent op in the process, pallas or XLA alike)
         coli = col.astype(jnp.int32)
         word = jax.lax.shift_right_logical(coli, 5)
@@ -1370,281 +1370,3 @@ def partition_segment_planes_fused(
         interpret=_INTERPRET,
     )(scalars, work)
     return work_out, lt[0]
-
-
-# ---------------------------------------------------------------------------
-# One-kernel split: partition + smaller-child histogram + split scan
-# ---------------------------------------------------------------------------
-#
-# The fused planes path pays THREE device launches per split — partition,
-# smaller-child histogram, split scan — and the histogram launch re-reads
-# the freshly routed child rows from HBM. This kernel runs all three as
-# sequential phases of ONE pallas_call (tpu_split_kernel=on):
-#
-#   A. partition — _partition_planes_kernel called as a plain function on
-#      the same refs/scratch; bytes land in work_ref (the aliased output)
-#      exactly as the standalone launch leaves them.
-#   B. smaller-child histogram — re-streams the routed child's contiguous
-#      segment from work_ref through 128-lane-aligned DMA windows of
-#      hist_chunk + 128 lanes, then slices the oracle's UNALIGNED chunk out
-#      in VMEM. The chunk grid, valid masking and _hist16_chunk_planes f32
-#      accumulation order replicate hist16_segment_planes /
-#      hist16_segment_resident byte-for-byte — bit-identity with the
-#      three-launch oracle is the contract, which is also why the child
-#      bytes are still READ once here (accumulating during routing would
-#      change the f32 chunk grouping): the launch disappears, the re-read
-#      stays (PERF.md round 12 is honest about this).
-#   C. scan tail — sibling histogram by parent-minus-child subtraction,
-#      then find_best_split vmapped over both children on the SAME inputs
-#      the learner's node_best_pair would see; SplitInfo fields write to
-#      dedicated outputs.
-#
-# Validation status: bit-parity is proven under the pallas interpreter
-# (tests/test_one_kernel.py grows bit-identical trees vs the oracle). On
-# real Mosaic the scan tail (argsort/switch in find_best_split) and the
-# resident gather do not lower yet — tpu_split_kernel=auto therefore
-# resolves to "off" everywhere and the first v5e session A/Bs it via
-# scripts/split_bisect.py. Phases A/B are written DMA-aligned so that
-# bring-up starts from a TPU-shaped kernel.
-
-
-def _one_kernel_split_kernel(sref, *refs, ch, sb, nplanes, hist_ch,
-                             num_feat, num_bins, exact, lo_w, hp, resident,
-                             npad):
-    from .split import FeatureMeta, find_best_split
-
-    f32 = jnp.float32
-    base = 2 if resident else 1
-    work_in = refs[0]
-    res_in = refs[1] if resident else None
-    (phist_in, nb_in, mm_in, mb_in, ic_in, mono_in, pen_in, cegb_in,
-     fmask_in, sums_in, outs_in, lows_in, ups_in) = refs[base:base + 13]
-    (work_ref, lt_ref, hl_ref, hr_ref, g_ref, f_ref, b_ref, k_ref, dl_ref,
-     gl_ref, lsum_ref, rsum_ref, lout_ref, rout_ref) = \
-        refs[base + 13:base + 27]
-    (triu, cin, pre, lstage, rstage, lfb, rfb, sem, hbuf) = refs[base + 27:]
-
-    # ---- phase A: partition (identical code, identical bytes) ----
-    _partition_planes_kernel(sref, work_in, work_ref, lt_ref, triu, cin,
-                             pre, lstage, rstage, lfb, rfb, sem,
-                             ch=ch, sb=sb, nplanes=nplanes)
-
-    # ---- phase B: smaller-child histogram over the routed segment ----
-    from .histogram import _hist16_chunk_planes, _hist16_combine
-
-    start = sref[1]
-    cnt = sref[2]
-    dst = 1 - sref[0]
-    lt = lt_ref[0]
-    left_smaller = sref[12] == 1
-    small_start = jnp.where(left_smaller, start, start + lt)
-    small_cnt = jnp.where(left_smaller, lt, cnt - lt)
-    sh = (num_bins + lo_w - 1) // lo_w
-    nch = 5 if exact else 3
-    nchunks = (small_cnt + hist_ch - 1) // hist_ch
-    res = res_in[...] if resident else None
-
-    def hbody(i, acc):
-        off = small_start + i * hist_ch
-        # Mosaic wants provably 128-lane-aligned HBM offsets: DMA the
-        # aligned superset window, slice the oracle's unaligned chunk out
-        # in VMEM (guard >= hist_chunk + 2*PLANE_ALIGN keeps it in bounds)
-        at = (off // PLANE_ALIGN) * PLANE_ALIGN
-        cp = pltpu.make_async_copy(
-            work_ref.at[dst, :, pl.ds(at, hist_ch + PLANE_ALIGN)],
-            hbuf, sem.at[0])
-        cp.start()
-        cp.wait()
-        cw = jax.lax.dynamic_slice(hbuf[...], (jnp.int32(0), off - at),
-                                   (nplanes, hist_ch))
-        if resident:
-            ridx = _decode_ridx(cw[RST_ROUTE:RST_GH_OFF], npad)
-            cb = jnp.take(res, ridx, axis=1)              # (F, CH)
-            cg = unpack_ghc_planes(cw, RST_GH_OFF)        # (3, CH)
-        else:
-            cb = cw[:num_feat]
-            cg = unpack_ghc_planes(cw, num_feat)
-        rows_left = small_cnt - i * hist_ch
-        valid = jnp.arange(hist_ch, dtype=jnp.int32) < rows_left
-        cgm = cg * valid[None, :].astype(f32)
-        return acc + _hist16_chunk_planes(cb, cgm, num_bins, exact, lo_w)
-
-    acc = jax.lax.fori_loop(
-        0, nchunks, hbody,
-        jnp.zeros((num_feat, sh, lo_w * nch), f32))
-    hist_small = _hist16_combine(acc, num_bins, exact, lo_w)  # (F, B, 3)
-
-    # ---- phase C: sibling by subtraction + fused split scan ----
-    parent_hist = phist_in[...]
-    hist_large = parent_hist - hist_small
-    hist_left = jnp.where(left_smaller, hist_small, hist_large)
-    hist_right = jnp.where(left_smaller, hist_large, hist_small)
-    hl_ref[...] = hist_left
-    hr_ref[...] = hist_right
-
-    meta = FeatureMeta(
-        num_bins=nb_in[...], movable_missing=mm_in[...],
-        missing_bin=mb_in[...], is_categorical=ic_in[...],
-        monotone=mono_in[...], penalty=pen_in[...],
-        cegb_coupled=cegb_in[...])
-    fmask = fmask_in[...]
-    depth = sref[13]
-
-    # the learner's node_best_pair reduces to exactly this under the
-    # one-kernel eligibility gate (serial comm, no bundling/CEGB/by-node
-    # RNG): find_best_split vmapped over the two children
-    infos = jax.vmap(
-        lambda hg, tg, po, lo, up: find_best_split(
-            hg, tg, meta, fmask, hp, parent_output=po, leaf_lower=lo,
-            leaf_upper=up, node_depth=depth)
-    )(jnp.stack([hist_left, hist_right]), sums_in[...], outs_in[...],
-      lows_in[...], ups_in[...])
-    g_ref[...] = infos.gain.astype(f32)
-    f_ref[...] = infos.feature.astype(jnp.int32)
-    b_ref[...] = infos.bin.astype(jnp.int32)
-    k_ref[...] = infos.kind.astype(jnp.int32)
-    dl_ref[...] = infos.default_left.astype(jnp.bool_)
-    gl_ref[...] = infos.go_left.astype(jnp.bool_)
-    lsum_ref[...] = infos.left_sum.astype(f32)
-    rsum_ref[...] = infos.right_sum.astype(f32)
-    lout_ref[...] = infos.left_output.astype(f32)
-    rout_ref[...] = infos.right_output.astype(f32)
-
-
-def one_kernel_split_planes(
-    work: jax.Array,        # (2, W, Npad) u8 ping-pong plane pair
-    src_plane: jax.Array,
-    start: jax.Array,
-    cnt: jax.Array,
-    feat: jax.Array,        # routed plane index (0 for resident)
-    go_left: jax.Array,     # (B,) bool routing table
-    left_smaller: jax.Array,  # scalar bool: left child is the smaller one
-    depth: jax.Array,       # scalar i32 child depth (node_depth of the scan)
-    parent_hist: jax.Array,  # (F, B, 3) f32 parent histogram
-    meta,                   # FeatureMeta of (F,) arrays
-    fmask: jax.Array,       # (F,) bool search mask
-    sums2: jax.Array,       # (2, 3) f32 [left_sum, right_sum]
-    outs2: jax.Array,       # (2,) f32 child outputs
-    lows2: jax.Array,       # (2,) f32 child lower bounds
-    ups2: jax.Array,        # (2,) f32 child upper bounds
-    hp,                     # SplitHyper (static python scalars)
-    *,
-    num_bins: int,
-    num_feat: int,
-    exact: bool = True,
-    ch: int = DEFAULT_CH,
-    sb: int = 256,
-    hist_chunk: int = 2048,
-    lo_w: int = 0,
-    resident_planes: jax.Array = None,  # (F, Npad) u8 resident bin planes
-):
-    """ONE pallas launch per split: partition + smaller-child histogram +
-    split scan (see the module comment above). Same partition contract as
-    :func:`partition_segment_planes_fused`; histogram and SplitInfo values
-    are bit-identical to the three-launch chain it replaces.
-
-    Returns ``(work, lt, hist_left, hist_right, infos)`` where ``infos`` is
-    a batch-2 SplitInfo (left child then right child).
-    """
-    from .histogram import auto_lo_w
-    from .split import SplitInfo
-
-    _, nplanes, npad = work.shape
-    if npad % 128:
-        raise ValueError(
-            "one-kernel split needs whole 128-lane tiles in the lane dim, "
-            "got Npad=%d" % npad)
-    if nplanes % 32:
-        raise ValueError(
-            "one-kernel split needs whole 32-sublane u8 tiles, got W=%d "
-            "planes" % nplanes)
-    sb = min(sb, ch)
-    if ch % sb or ch % 128:
-        raise ValueError(
-            "one-kernel split chunk %d must be a multiple of 128 and of "
-            "the sub-block %d" % (ch, sb))
-    if hist_chunk % 128:
-        # the in-kernel histogram DMA re-derives lane offsets as
-        # (x // 128) * 128; a misaligned chunk would shift the VMEM slice
-        raise ValueError(
-            "one-kernel split hist_chunk must be a multiple of 128, got %d"
-            % hist_chunk)
-    resident = resident_planes is not None
-    lo_w = lo_w or auto_lo_w(num_feat)
-    sh = (num_bins + lo_w - 1) // lo_w
-    nch = 5 if exact else 3
-    f32 = jnp.float32
-    i32 = jnp.int32
-
-    scalars = jnp.concatenate([
-        jnp.stack([src_plane.astype(i32), start.astype(i32),
-                   cnt.astype(i32), feat.astype(i32)]),
-        pack_table_bits(go_left),
-        jnp.stack([left_smaller.astype(i32), depth.astype(i32)])])
-
-    kern = partial(_one_kernel_split_kernel, ch=ch, sb=sb, nplanes=nplanes,
-                   hist_ch=hist_chunk, num_feat=num_feat, num_bins=num_bins,
-                   exact=exact, lo_w=lo_w, hp=hp, resident=resident,
-                   npad=npad)
-    extra_in = [resident_planes] if resident else []
-    extra_in += [parent_hist, meta.num_bins, meta.movable_missing,
-                 meta.missing_bin, meta.is_categorical, meta.monotone,
-                 meta.penalty, meta.cegb_coupled, fmask, sums2, outs2,
-                 lows2, ups2]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * (1 + len(extra_in)),
-        out_specs=[pl.BlockSpec(memory_space=pltpu.HBM),
-                   pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pltpu.HBM)] * 12,
-        scratch_shapes=[
-            pltpu.VMEM((sb, sb), jnp.bfloat16),                # triu
-            pltpu.VMEM((2, nplanes, ch), jnp.uint8),           # cin x2
-            pltpu.VMEM((2, nplanes, PLANE_ALIGN), jnp.uint8),  # prefills
-            pltpu.VMEM((nplanes, 2 * sb), f32),                # lstage
-            pltpu.VMEM((nplanes, 2 * sb), f32),                # rstage
-            pltpu.VMEM((2, nplanes, sb), jnp.uint8),           # lfb x2
-            pltpu.VMEM((2, nplanes, sb), jnp.uint8),           # rfb x2
-            pltpu.SemaphoreType.DMA((8,)),
-            pltpu.VMEM((nplanes, hist_chunk + PLANE_ALIGN),
-                       jnp.uint8),                             # hist window
-        ],
-    )
-    del sh, nch  # shapes below are post-combine; acc lives in the kernel
-    B = num_bins
-    F = num_feat
-    outs = pl.pallas_call(
-        kern,
-        name="one_kernel_split_planes",
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(work.shape, work.dtype),
-            jax.ShapeDtypeStruct((1,), i32),
-            jax.ShapeDtypeStruct((F, B, 3), f32),   # hist_left
-            jax.ShapeDtypeStruct((F, B, 3), f32),   # hist_right
-            jax.ShapeDtypeStruct((2,), f32),        # gain
-            jax.ShapeDtypeStruct((2,), i32),        # feature
-            jax.ShapeDtypeStruct((2,), i32),        # bin
-            jax.ShapeDtypeStruct((2,), i32),        # kind
-            jax.ShapeDtypeStruct((2,), jnp.bool_),  # default_left
-            jax.ShapeDtypeStruct((2, B), jnp.bool_),  # go_left
-            jax.ShapeDtypeStruct((2, 3), f32),      # left_sum
-            jax.ShapeDtypeStruct((2, 3), f32),      # right_sum
-            jax.ShapeDtypeStruct((2,), f32),        # left_output
-            jax.ShapeDtypeStruct((2,), f32),        # right_output
-        ],
-        input_output_aliases={1: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=100 * 1024 * 1024),
-        interpret=_INTERPRET,
-    )(scalars, work, *extra_in)
-    (work_out, lt, hist_left, hist_right, gain, feature, bin_, kind,
-     default_left, go_left_out, left_sum, right_sum, left_output,
-     right_output) = outs
-    infos = SplitInfo(gain=gain, feature=feature, bin=bin_, kind=kind,
-                      default_left=default_left, go_left=go_left_out,
-                      left_sum=left_sum, right_sum=right_sum,
-                      left_output=left_output, right_output=right_output)
-    return work_out, lt[0], hist_left, hist_right, infos

@@ -200,9 +200,8 @@ predict_raw = track_jit("ops/predict_raw", jax.jit(
 
 def split_bin_table(a, dataset):
     """Per-split BIN-space routing quantities for one tree's
-    ``to_split_arrays`` dict: the single conversion shared by
-    ``tree_to_bin_log`` (go_left tables for ``assign_leaves``) and the
-    forest repack (``ops/forest.py`` split-major node tables).
+    ``to_split_arrays`` dict, from which ``tree_to_bin_log`` builds the
+    go_left tables for ``assign_leaves``.
 
     Returns a dict of per-split arrays — ``feature`` (inner index),
     ``tbin`` (threshold bin: go left iff ``bin <= tbin``), ``miss_bin``/

@@ -102,7 +102,7 @@ class PredictServer:
                  request_timeout_s: float = 30.0,
                  max_queue_rows: int = 0, overload: str = "shed",
                  tenant_quota_rows: int = 0, tenant_weights=None,
-                 dispatch_mode: str = "continuous", forest=None,
+                 dispatch_mode: str = "continuous",
                  online=None) -> None:
         from ..online.registry import ModelRegistry
 
@@ -119,7 +119,7 @@ class PredictServer:
                               tenant_quota_rows=tenant_quota_rows,
                               tenant_weights=tenant_weights,
                               raw_score=raw_score,
-                              dispatch_mode=dispatch_mode, forest=forest,
+                              dispatch_mode=dispatch_mode,
                               warmup=warmup, online=online)
         elif model is not None or online is not None:
             raise LightGBMError(

@@ -28,10 +28,8 @@ import numpy as np
 
 from ..obs import telemetry, track_jit
 from ..obs_trace import tracer
-from ..ops import partition
-from ..ops.forest import forest_predict_impl
 from ..ops.predict import predict_raw_impl
-from ..utils.log import LightGBMError, Log
+from ..utils.log import LightGBMError
 
 #: Default bucket ladder. Rungs are ~4x apart: at most ~25% of a dispatch
 #: is padding in the worst case, and a full warmup compiles 5 programs.
@@ -45,15 +43,6 @@ _predict_bucket = track_jit("serve/predict_bucket", jax.jit(
     predict_raw_impl,
     static_argnames=("num_class", "has_cat", "has_linear", "tree_batch")))
 
-# forest-at-once path (ops/forest.py): same process-wide sharing and the
-# same bucket contract — one compile per (rung, model shape), zero on
-# repeat dispatches. The per-depth-gather _predict_bucket above stays the
-# default and the bit-parity oracle (tpu_forest_kernel discipline).
-_forest_bucket = track_jit("serve/forest_bucket", jax.jit(
-    forest_predict_impl,
-    static_argnames=("num_class", "has_cat", "has_linear", "tree_batch",
-                     "tile", "interpret")))
-
 
 class PredictSession:
     """Serving handle over a trained booster (``lgb.Booster`` or inner
@@ -66,15 +55,10 @@ class PredictSession:
 
     def __init__(self, model, *, start_iteration: int = 0,
                  num_iteration: int = -1,
-                 buckets: Optional[Sequence[int]] = None,
-                 forest: Optional[str] = None) -> None:
+                 buckets: Optional[Sequence[int]] = None) -> None:
         self._gbdt = getattr(model, "inner", model)
         if start_iteration < 0:
             raise LightGBMError("start_iteration must be >= 0")
-        if forest not in (None, "on", "off"):
-            raise LightGBMError(
-                "forest must be None (follow tpu_forest_kernel), 'on' or "
-                "'off', got %r" % (forest,))
         self._start = int(start_iteration)
         self._num = int(num_iteration)
         rungs = tuple(sorted({int(b) for b in (buckets or DEFAULT_BUCKETS)}))
@@ -89,15 +73,6 @@ class PredictSession:
         self._version = -1
         self._range = (0, 0)
         self._warm: set = set()
-        # forest-at-once state: explicit override (None = follow the
-        # booster's resolved tpu_forest_kernel knob), version-keyed entry,
-        # inner->total column map for host binning, warn-once latch
-        self._forest_cfg = forest
-        self._fentry = None
-        self._fver = -1
-        self._frange = (0, 0)
-        self._f_cols: Optional[np.ndarray] = None
-        self._forest_warned = False
 
     # ------------------------------------------------------------ resolution
     def num_features(self) -> int:
@@ -151,53 +126,6 @@ class PredictSession:
                 self._warm.clear()
             return self._pack, self._has_cat, self._has_linear
 
-    def _forest_mode(self) -> str:
-        """Effective forest-kernel mode for this session: the explicit
-        constructor override when given, else the booster's resolved
-        ``tpu_forest_kernel`` knob (ledger preresolution included)."""
-        if self._forest_cfg is not None:
-            return self._forest_cfg
-        return self._gbdt._forest_knob()
-
-    def _ensure_forest(self):
-        """Version-keyed forest entry (``(ForestPack, has_cat,
-        has_linear)``) via the booster's ``_forest_model`` cache, or
-        ``None`` when the model is structurally ineligible (no train_set,
-        unmapped splits, node tables past the VMEM budget). Same snapshot
-        discipline as :meth:`_ensure_pack`."""
-        g = self._gbdt
-        with self._lock, g._cache_lock:
-            ver = g.model_version
-            rng = self._resolve_range()
-            if self._fver != ver or self._frange != rng:
-                self._fentry = g._forest_model(*rng)
-                self._fver, self._frange = ver, rng
-                self._f_cols = None
-                if g.train_set is not None:
-                    self._f_cols = np.asarray(
-                        g.train_set.used_feature_indices, np.int64)
-                # forest tables changed -> compiled rungs are stale
-                self._warm.clear()
-            return self._fentry
-
-    def _bin_rows(self, X: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        """Host-side binning for the forest path: (n, F_total) float32
-        raw rows -> ((n, Fi) int32 BIN matrix, (n, Fi) float32 raw
-        values), both in INNER feature order (the order the forest tables
-        were packed in). Pure numpy — no device work, no sync."""
-        ds = self._gbdt.train_set
-        with self._lock:
-            cols = self._f_cols
-        if cols is not None and len(cols) and X.shape[1] <= int(cols.max()):
-            raise LightGBMError(
-                "predict rows have %d features but the model was trained "
-                "on %d" % (X.shape[1], int(cols.max()) + 1))
-        Xr = np.ascontiguousarray(X[:, cols]) if cols is not None else X
-        bins = np.empty(Xr.shape, np.int32)
-        for j in range(Xr.shape[1]):
-            bins[:, j] = ds.bin_mappers[j].value_to_bin(Xr[:, j])
-        return bins, Xr
-
     def version(self) -> int:
         """Model-version token of the currently-resident pack (-1 before
         the first dispatch). The online promotion gate's observable: a
@@ -230,20 +158,7 @@ class PredictSession:
         MicroBatcher) pull results when delivering them. N beyond the top
         rung is chunked; each chunk pads up to its covering bucket.
         """
-        forest = None
-        if self._forest_mode() == "on":
-            forest = self._ensure_forest()
-            if forest is None:
-                with self._lock:
-                    warn = not self._forest_warned
-                    self._forest_warned = True
-                if warn:
-                    Log.warning(
-                        "tpu_forest_kernel=on but this model is ineligible "
-                        "for the forest path; serving stays on the "
-                        "per-depth-gather oracle")
-        if forest is None:
-            pack, has_cat, has_linear = self._ensure_pack()
+        pack, has_cat, has_linear = self._ensure_pack()
         X = np.ascontiguousarray(np.asarray(X), dtype=np.float32)
         if X.ndim == 1:
             X = X[None, :]
@@ -254,11 +169,6 @@ class PredictSession:
         pieces: List[Tuple[jax.Array, int]] = []
         if n == 0:
             return pieces
-        if forest is not None:
-            # bin the whole request once (host numpy); the kernel routes
-            # in BIN space and gathers raw values only for linear leaves
-            X, Xraw = self._bin_rows(X)
-            telemetry.count("serve/forest_dispatches")
         nf = X.shape[1]
         top = self.buckets[-1]
         telemetry.count("serve/dispatches")
@@ -278,24 +188,9 @@ class PredictSession:
                     telemetry.count("serve/pad_rows", b - rows)
                     chunk = np.concatenate(
                         [chunk, np.zeros((b - rows, nf), chunk.dtype)])
-                if forest is not None:
-                    fp, f_cat, f_lin = forest
-                    xchunk = Xraw[lo:lo + top]
-                    if b > rows:
-                        xchunk = np.concatenate(
-                            [xchunk,
-                             np.zeros((b - rows, nf), np.float32)])
-                    # the interpret flag is read per call so it keys the
-                    # jit cache (tests flip it; nothing else may)
-                    score = _forest_bucket(
-                        jnp.asarray(chunk), jnp.asarray(xchunk), fp,
-                        num_class=self._K, has_cat=f_cat,
-                        has_linear=f_lin, interpret=partition._INTERPRET)
-                else:
-                    score = _predict_bucket(jnp.asarray(chunk), pack,
-                                            num_class=self._K,
-                                            has_cat=has_cat,
-                                            has_linear=has_linear)
+                score = _predict_bucket(jnp.asarray(chunk), pack,
+                                        num_class=self._K, has_cat=has_cat,
+                                        has_linear=has_linear)
                 pieces.append((score, rows))
         return pieces
 

@@ -2,10 +2,9 @@
 # Pre-commit gate, layered by cost:
 #
 #   check.sh            lint (full repo) + lint tests + the fast
-#                       serve/online/obs/one-kernel/forest-kernel
-#                       tier-1 subset (a few min CPU; the one-kernel
-#                       and forest-kernel parity trains run under the
-#                       pallas interpreter)
+#                       serve/online/obs/chip-path tier-1 subset (a few
+#                       min CPU; the chip-path parity trains run under
+#                       the pallas interpreter)
 #   check.sh --fast     lint only files changed vs git + lint tests
 #
 # Every mode (including --fast) fails on baseline drift: lint.py exits
@@ -55,13 +54,12 @@ echo "== lint tests =="
 JAX_PLATFORMS=cpu python -m pytest tests/test_lint.py -q -m 'not slow'
 
 if [ "$RUN_SUBSET" = 1 ]; then
-    echo "== serve/online/obs/linear/one-kernel/forest/goss-mxu fast tests =="
+    echo "== serve/online/obs/linear/chip-path/goss fast tests =="
     JAX_PLATFORMS=cpu python -m pytest -q -m 'not slow' \
         tests/test_serve.py tests/test_online.py \
         tests/test_obs.py tests/test_trace.py \
-        tests/test_linear_device.py tests/test_one_kernel.py \
-        tests/test_forest_kernel.py tests/test_goss_compact.py \
-        tests/test_hist_mxu.py
+        tests/test_linear_device.py tests/test_chip_path.py \
+        tests/test_goss_compact.py
 fi
 
 if [ "$RUN_FLEET" = 1 ]; then

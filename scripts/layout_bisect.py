@@ -1,5 +1,9 @@
 """Interleaved A/B: rows (2, Npad, W) vs planes (2, W, Npad) work layout.
 
+The hardware harness behind the ``tpu_work_layout`` and
+``tpu_partition_kernel`` auto knobs: it times the XLA and the Pallas
+partition in both layouts side by side.
+
 Measures the three hot paths the layout change touches — partition,
 segment histogram, and pack(+root fold) — under measurement discipline v2
 (PERF.md):

@@ -6,10 +6,8 @@ replaced so every ``auto`` knob resolves as it does on a TPU, the programs
 are lowered against ``ShapeDtypeStruct``s placed on a ``v5e:2x2`` topology
 device, and ``.compile()`` runs the real TPU compiler. Compile-only: this
 says nothing about results, hangs or speed (``chip_smoke.py`` does, on the
-chip). Interpreter parity never predicted these verdicts.
-
-The refused programs are ``xfail(strict=True)`` carrying the compiler's first
-line, so a repair flips the case visibly. All of them are ``auto -> off``.
+chip). Interpreter parity never predicted these verdicts: the three
+programs Mosaic refused (PR 21) passed it, and left the tree in PR 30.
 
 libtpu takes ``/tmp/libtpu_lockfile``: one such process at a time.
 """
@@ -117,15 +115,14 @@ def compile_block(topo, ds, params, k=5):
 
 
 def resolved(kw):
-    return (kw["work_layout"], kw["part_kernel"], kw["hist_kernel"],
-            kw["split_kernel"], kw["hist_mxu"])
+    return kw["work_layout"], kw["part_kernel"], kw["hist_kernel"]
 
 
 # ------------------------------------------------- what auto picks on a TPU
 
 def test_default_binary_block_compiles(topo, ds_binary):
     kw, c = compile_block(topo, ds_binary, {"objective": "binary"})
-    assert resolved(kw) == ("planes", "pallas", "pallas", "off", "off")
+    assert resolved(kw) == ("planes", "pallas", "pallas")
     assert c.memory_analysis().temp_size_in_bytes > 0
     # the block really holds the kernel auto resolved to
     assert "hist_pallas_segment_planes" in c.as_text()
@@ -133,7 +130,7 @@ def test_default_binary_block_compiles(topo, ds_binary):
 
 def test_default_lambdarank_block_compiles(topo, ds_rank):
     kw, _ = compile_block(topo, ds_rank, {"objective": "lambdarank"})
-    assert resolved(kw) == ("planes", "pallas", "pallas", "off", "off")
+    assert resolved(kw) == ("planes", "pallas", "pallas")
 
 
 def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
@@ -145,7 +142,7 @@ def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
     kw, c = compile_block(topo, ds_onehot, {
         "objective": "binary", "min_data_in_leaf": 0,
         "min_sum_hessian_in_leaf": 100})
-    assert resolved(kw) == ("planes", "pallas", "pallas", "off", "off")
+    assert resolved(kw) == ("planes", "pallas", "pallas")
     assert kw["bundle"] is not None and kw["num_bin_hist"] == 256
     _, width = partition.work_spec(binned.num_groups, False, kw["part_kernel"],
                                    kw["part_chunk"], kw["hist_chunk"],
@@ -165,7 +162,7 @@ def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
     lrn = DataParallelTreeLearner(
         cfg, binned, Mesh(np.asarray(cpu_mesh_devices[:4]), ("data",)))
     # the mesh learners keep the XLA histogram (auto, PR 29)
-    assert resolved(lrn.build_kwargs())[:3] == ("planes", "pallas", "xla")
+    assert resolved(lrn.build_kwargs()) == ("planes", "pallas", "xla")
     mesh = Mesh(np.asarray(topo.devices), ("data",))
     rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     n, f = lrn.padded_n, binned.num_features
@@ -185,22 +182,19 @@ def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
 
 @pytest.mark.parametrize("name,params,expect", [
     ("rows_fused_partition",            # the pre-round program
-     {"tpu_work_layout": "rows"}, ("rows", "pallas", "xla", "off", "off")),
+     {"tpu_work_layout": "rows"}, ("rows", "pallas", "xla")),
     ("resident",                        # auto's pick until PR 21 timed it
-     {"tpu_resident_state": "on"}, ("resident", "pallas", "xla", "off", "off")),
+     {"tpu_resident_state": "on"}, ("resident", "pallas", "xla")),
     ("planes_pallas_hist", {"tpu_hist_kernel": "pallas"},
-     ("planes", "pallas", "pallas", "off", "off")),
+     ("planes", "pallas", "pallas")),
     ("planes_xla_hist",                 # auto's pick until PR 29 timed it
-     {"tpu_hist_kernel": "xla"}, ("planes", "pallas", "xla", "off", "off")),
+     {"tpu_hist_kernel": "xla"}, ("planes", "pallas", "xla")),
     ("rows_pallas_hist",
      {"tpu_work_layout": "rows", "tpu_hist_kernel": "pallas"},
-     ("rows", "pallas", "pallas", "off", "off")),
-    ("rows_hist_mxu_f32",
-     {"tpu_work_layout": "rows", "tpu_hist_mxu": "on"},
-     ("rows", "pallas", "xla", "off", "on")),
+     ("rows", "pallas", "pallas")),
     ("goss_compact",
      {"data_sample_strategy": "goss", "top_rate": 0.2, "other_rate": 0.1,
-      "tpu_goss_compact": "on"}, ("planes", "pallas", "pallas", "off", "off")),
+      "tpu_goss_compact": "on"}, ("planes", "pallas", "pallas")),
 ])
 def test_selectable_path_compiles(topo, ds_binary, name, params, expect):
     kw, _ = compile_block(topo, ds_binary, dict(params, objective="binary"))
@@ -228,55 +222,3 @@ def test_serving_predict_compiles(topo, small_model):
         jax.ShapeDtypeStruct((65_536, X.shape[1]), jnp.float32, sharding=sh),
         _abstract(pack, sh), num_class=1, has_cat=has_cat,
         has_linear=has_linear).compile()
-
-
-# ----------------------------------------- what the compiler refuses today
-
-@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
-    "Mosaic: 'Loads are only allowed on VMEM and SMEM references.' — "
-    "ops/partition.py one_kernel_split loads the whole (F, Npad) HBM ref"))
-def test_one_kernel_split_resident_compiles(topo, ds_binary):
-    kw, _ = compile_block(topo, ds_binary, {
-        "objective": "binary", "tpu_split_kernel": "on",
-        "tpu_resident_state": "on"})
-    assert resolved(kw)[:4] == ("resident", "pallas", "xla", "on")
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=(
-    "Mosaic: 'Unimplemented primitive in Pallas TPU lowering for "
-    "KernelType.TC: dynamic_slice.' — the one-kernel split's in-kernel "
-    "histogram window"))
-def test_one_kernel_split_planes_compiles(topo, ds_binary):
-    kw, _ = compile_block(topo, ds_binary, {"objective": "binary",
-                                            "tpu_split_kernel": "on"})
-    assert resolved(kw)[:4] == ("planes", "pallas", "xla", "on")
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=(
-    "Mosaic: 'Unimplemented primitive in Pallas TPU lowering for "
-    "KernelType.TC: dynamic_slice.' — ops/forest.py indexes its node tables "
-    "by round in-kernel (with u8 bins it stops earlier, at 'Unsupported "
-    "cast: uint8 -> float32')"))
-def test_forest_kernel_compiles(topo, small_model):
-    from lightgbm_tpu.serve import session
-    bst, X = small_model
-    fp, has_cat, has_linear = bst.inner._forest_model(0, 8)
-    sh = SingleDeviceSharding(topo.devices[0])
-    n, f = 4_096, X.shape[1]
-    session._forest_bucket.lower(
-        jax.ShapeDtypeStruct((n, f), jnp.int32, sharding=sh),   # _bin_rows
-        jax.ShapeDtypeStruct((n, f), jnp.float32, sharding=sh),
-        _abstract(fp, sh), num_class=1, has_cat=has_cat,
-        has_linear=has_linear, interpret=False).compile()
-
-
-@pytest.mark.xfail(strict=True, raises=jax.errors.JaxRuntimeError, reason=(
-    "XLA: 'RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... "
-    "Scoped allocation with size 102.41M and limit 100.00M' — "
-    "ops/histogram.py hist_mxu_segment in int8 mode against its own "
-    "vmem_limit_bytes"))
-def test_hist_mxu_quantized_compiles(topo, ds_binary):
-    kw, _ = compile_block(topo, ds_binary, {
-        "objective": "binary", "tpu_hist_mxu": "on",
-        "use_quantized_grad": True})
-    assert kw["hist_mxu"] == "on" and kw["hist_mode"] == "int8"

@@ -147,17 +147,16 @@ def test_train_parity_categorical(rng):
 
 
 @pytest.mark.slow
-def test_train_parity_planes_split_kernel(rng, monkeypatch):
-    """Satellite 2: compaction composes with the planes pallas partition
-    stream AND the one-kernel split — GOSS rides tpu_split_kernel through
-    the compacted recursion, byte for byte."""
+def test_train_parity_planes_pallas_partition(rng, monkeypatch):
+    """Compaction composes with the planes layout and the Pallas partition
+    (the path auto takes on a TPU): the compacted recursion grows the
+    dense-mask build's trees, byte for byte."""
     monkeypatch.setattr(P, "_INTERPRET", True)
     n = 700
     X = rng.randn(n, 8)
     y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
     extra = {"tpu_work_layout": "planes", "tpu_partition_kernel": "pallas",
-             "tpu_part_chunk": 256, "tpu_hist_chunk": 256,
-             "tpu_split_kernel": "on", "max_bin": 31}
+             "tpu_part_chunk": 256, "tpu_hist_chunk": 256, "max_bin": 31}
     on, off = _ab_models(extra, X, y, rounds=4)
     assert on == off
 

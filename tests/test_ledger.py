@@ -113,11 +113,11 @@ def test_second_train_preresolves_all_tpu_auto_knobs(tmp_path):
     assert entries[-1]["resolved_knobs"] == first
 
 
-def test_goss_and_mxu_knobs_preresolve(tmp_path):
+def test_goss_knob_preresolves(tmp_path):
     """ISSUE 17 pin: on a GOSS config tpu_goss_compact resolves through
-    the bisect-gated path (not the structural no-GOSS branch) and, with
-    tpu_hist_mxu, preresolves from the ledger on run 2 — zero NEW
-    auto_resolution records for either knob."""
+    the bisect-gated path (not the structural no-GOSS branch) and
+    preresolves from the ledger on run 2 — zero NEW auto_resolution
+    records."""
     path = str(tmp_path / "ledger.jsonl")
     X, y = _data(n=640, f=11, seed=4)   # keep the shared block cache cold
     p = _params(path, boosting="goss", top_rate=0.3, other_rate=0.2)
@@ -127,8 +127,6 @@ def test_goss_and_mxu_knobs_preresolve(tmp_path):
     first = {r["knob"]: r for r in obs.telemetry.records("auto_resolution")}
     assert first["tpu_goss_compact"]["value"] == "off"
     assert "goss_bisect" in first["tpu_goss_compact"]["reason"]
-    assert first["tpu_hist_mxu"]["value"] == "off"
-    assert "hist_mxu_bisect" in first["tpu_hist_mxu"]["reason"]
 
     obs.telemetry.reset()
     lgb.train(dict(p), lgb.Dataset(X, label=y), num_boost_round=5)
@@ -137,7 +135,7 @@ def test_goss_and_mxu_knobs_preresolve(tmp_path):
     pre = {r["knob"]: r["value"]
            for r in obs.telemetry.records("ledger_preresolution")}
     assert pre == {k: r["value"] for k, r in first.items()}
-    assert {"tpu_goss_compact", "tpu_hist_mxu"} <= set(pre)
+    assert "tpu_goss_compact" in pre
 
 
 @pytest.mark.slow  # two fresh-resolution trainings; the preresolve hit

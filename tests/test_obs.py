@@ -283,15 +283,13 @@ def test_train_telemetry_counters_and_auto_records():
     assert snap["timers"].get("fused/dispatch", 0) > 0
     assert snap["timers"].get("fused/logs_transfer", 0) > 0
     # one auto-resolution record per auto knob (ISSUE 10 added the
-    # chunk knobs so the run ledger can preresolve the full set; ISSUE 16
-    # added the forest-serving kernel knob; ISSUE 17 the GOSS compaction
-    # and MXU histogram knobs)
+    # chunk knobs so the run ledger can preresolve the full set; ISSUE 17
+    # the GOSS compaction knob)
     knobs = {r["knob"]: r for r in snap["records"]["auto_resolution"]}
     assert set(knobs) == {"tpu_partition_kernel", "tpu_hist_kernel",
                           "tpu_work_layout", "tpu_resident_state",
                           "tpu_part_chunk", "tpu_hist_chunk",
-                          "tpu_split_kernel", "tpu_forest_kernel",
-                          "tpu_goss_compact", "tpu_hist_mxu"}
+                          "tpu_goss_compact"}
     for r in knobs.values():
         assert r["configured"] == "auto" and r["value"] and r["reason"]
     assert "traffic/work_layout" in snap["gauges"]

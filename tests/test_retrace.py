@@ -159,29 +159,6 @@ def test_second_same_shape_linear_predict_zero_compiles():
     assert obs.telemetry.counter("serve/bucket_hit") == 2
 
 
-def test_forest_kernel_same_bucket_zero_compiles(monkeypatch):
-    """ISSUE 16: the forest-at-once path rides the same bucket contract —
-    after the first dispatch warms a rung, repeat forest predicts pay
-    ZERO tracked compiles, ZERO backend compiles, and ZERO node-table
-    rebuilds (the serve/forest_build counter)."""
-    from lightgbm_tpu.ops import partition
-    from lightgbm_tpu.serve import PredictSession
-    monkeypatch.setattr(partition, "_INTERPRET", True)   # no Mosaic on CPU
-    X, y = _data()
-    ds = lgb.Dataset(X, label=y)
-    bst = lgb.train(dict(PARAMS), ds, num_boost_round=5)
-    sess = PredictSession(bst, buckets=(256,), forest="on")
-    sess.predict(X[:200], raw_score=True)    # warm: table build + compile
-    obs.telemetry.reset()
-    sess.predict(X[:200], raw_score=True)    # same bucket, same N
-    sess.predict(X[:256], raw_score=True)    # same bucket, different N
-    jc = obs.telemetry.snapshot()["jit_compiles"]
-    assert jc["total"] == 0, jc
-    assert jc["backend_compiles"] == 0, jc
-    assert obs.telemetry.counter("serve/forest_build") == 0
-    assert obs.telemetry.counter("serve/forest_dispatches") == 2
-
-
 def test_warmup_ladder_compile_budget():
     """warmup() pre-compiles the ladder: at most one predict compile per
     rung, and a second warmup compiles nothing new."""
