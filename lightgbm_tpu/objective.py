@@ -18,7 +18,7 @@ import numpy as np
 
 from .config import Config, OBJECTIVE_ALIASES
 from .dataset import Metadata
-from .obs import trace_phase
+from .obs import telemetry, trace_phase
 from .utils.log import Log
 
 
@@ -521,8 +521,20 @@ def _pad_queries(qb: np.ndarray) -> Tuple[np.ndarray, int]:
 # lambda kernel per distinct rung. Padding every query to the GLOBAL max
 # (the round-3 design) wasted ~1.9x tensor volume at MSLR-like length
 # spreads; the ladder caps waste at ~25% for a handful of compilations.
+# The slots of all buckets, concatenated in ladder order, are the layout
+# the gradient is computed in: a row's score goes to its slot by one gather
+# (``safe_idx``) and its gradient comes back by one (``slot_of_row``).
 _BUCKET_LADDER = (8, 16, 24, 32, 48, 64, 96, 128, 160, 192, 224, 256,
                   320, 384, 448, 512, 640, 768, 1024)
+
+# a bucket of padded length p_b ranks its documents by COUNTING (two fused
+# (Q, P, P) passes: P compares and P selects a slot) up to this length and
+# by sorting above it: counting is quadratic in the query, the sorted path is
+# not. The choice is a function of the static p_b alone. On a v5e 262,144
+# slots cost the sorted path 15.2 ms at every length and the counted one
+# 3.2 ms at 1,280 documents, 8.6 at 8,192, 16.4 at 16,384: level at 15.3K
+# (PERF.md section 6, PR 31).
+_COUNT_MAX_P = 15360
 
 
 def _bucket_queries(qb: np.ndarray):
@@ -546,12 +558,38 @@ def _bucket_queries(qb: np.ndarray):
     return out
 
 
+def _rank_by_counting(s):
+    """(Q, P) scores -> (Q, P) int32 rank of every slot among its query's,
+    highest score first, ties to the lower slot: the position a stable
+    ``argsort(-s)`` gives it, as a count of the slots ahead of it. The
+    (Q, P, P) compare fuses into its reduction and is never stored."""
+    p = s.shape[1]
+    slot = jnp.arange(p, dtype=jnp.int32)
+    sk, sj = s[:, :, None], s[:, None, :]                  # k ahead of j?
+    ahead = (sk > sj) | ((sk == sj) & (slot[:, None] < slot[None, :]))
+    return jnp.sum(ahead, axis=1, dtype=jnp.int32)
+
+
+def _table_at_rank(table, rank):
+    """``table[rank]`` for a (P,) table and (Q, P) ranks below P, as a
+    one-hot select fused into its sum like the count above: exact (one
+    non-zero term), and no gather of every slot. The chip's own
+    ``1 / log2(rank + 2)`` is 600 ulp off the float64-made table."""
+    r = jnp.arange(table.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(rank[:, None, :] == r[None, :, None],
+                             table[None, :, None], 0.0), axis=1)
+
+
 class LambdarankNDCG(ObjectiveFunction):
     """LambdaRank with NDCG lambda gradients (reference: rank_objective.hpp:100
     LambdarankNDCG): per-query pairwise lambdas weighted by |ΔNDCG|,
     truncation_level caps the high-ranked side of each pair, optional
     lambdarank_norm. Vectorized as (query-chunk, trunc, P) tensors instead of
-    the reference's per-query double loop."""
+    the reference's per-query double loop, and computed in SLOT order (the
+    padded (Q, P) layout of a length bucket): a document's rank is a count,
+    the top-``trunc`` rows are picked by it, and nothing is sorted or moved
+    by a computed index but the score on its way in and the gradient on its
+    way out. Buckets longer than ``_COUNT_MAX_P`` sort instead."""
     name = "lambdarank"
     is_ranking = True
     names_own_phases = True     # lgbtpu/rank_gather, _sort, _pairs, _scatter
@@ -583,6 +621,8 @@ class LambdarankNDCG(ObjectiveFunction):
         disc_np = 1.0 / np.log2(np.arange(p_max) + 2.0)
         self.bucket_shapes = []   # python-static (Q_b, P_b, K_b)
         self.bucket_arrays = []   # device tables, passed as jit operands
+        slot_of_row = np.zeros(self.num_data, np.int32)
+        slots = counted = 0
         for p_b, qsel in buckets:
             q_b = len(qsel)
             idx = np.full((q_b, p_b), -1, dtype=np.int32)
@@ -591,6 +631,9 @@ class LambdarankNDCG(ObjectiveFunction):
                                                  dtype=np.int32)
             valid = idx >= 0
             safe = np.maximum(idx, 0)
+            slot_of_row[idx[valid]] = slots + np.flatnonzero(valid)
+            slots += q_b * p_b
+            counted += q_b * p_b * (p_b <= _COUNT_MAX_P)
             gains = np.where(valid, self._gains_np[safe], 0.0)
             g_sorted = -np.sort(-gains, axis=1)
             max_dcg = (g_sorted * disc_np[None, :p_b]).sum(axis=1)
@@ -603,25 +646,67 @@ class LambdarankNDCG(ObjectiveFunction):
                 "gains": jnp.asarray(gains, jnp.float32),
                 "inv_max_dcg": jnp.asarray(inv, jnp.float32),
             })
+        # every row owns exactly one slot of the concatenated buckets
+        self.slot_of_row = jnp.asarray(slot_of_row)
         self.discount = jnp.asarray(disc_np, jnp.float32)
         self.sigmoid_ = float(cfg.sigmoid)
         self.norm = bool(cfg.lambdarank_norm)
+        telemetry.gauge("rank/buckets", len(buckets))
+        telemetry.gauge("rank/slots", slots)
+        telemetry.gauge("rank/slots_counted_share",
+                        counted / slots if slots else 1.0)
 
-    def _bucket_lambdas(self, score, arrs, p_b: int, K: int):
-        """Per-bucket (Q_b, P_b) grad/hess via padded pairwise lambdas."""
-        valid = arrs["valid"]
-        safe_idx = arrs["safe_idx"]
-        with trace_phase("lgbtpu/rank_gather"):
-            s = jnp.where(valid, score[safe_idx], -jnp.inf)    # (Q, P)
+    def _bucket_lambdas(self, s, arrs, K: int):
+        """Per-bucket (Q_b, P_b) scores (-inf on padding slots) -> grad/hess,
+        all in slot order, via padded pairwise lambdas."""
+        if s.shape[1] <= _COUNT_MAX_P:
+            return self._counted_lambdas(s, arrs, K)
+        return self._sorted_lambdas(s, arrs, K)
+
+    def _counted_lambdas(self, s, arrs, K: int):
+        """Slot order throughout: each slot's rank by counting, the top-K
+        rows by a one-hot select on the rank, their row sums back to their
+        slots by the same one-hot."""
+        valid, gains = arrs["valid"], arrs["gains"]
+        with trace_phase("lgbtpu/rank_sort"):
+            rank = _rank_by_counting(s)                        # (Q, P)
+            disc = _table_at_rank(self.discount[:s.shape[1]], rank)
+        with trace_phase("lgbtpu/rank_pairs"):
+            # exactly one slot of a query holds rank r, so each sum has one
+            # non-zero term and is exact (an invalid slot's score is -inf)
+            top = rank[:, None, :] == jnp.arange(K)[None, :, None]
+            si = jnp.sum(jnp.where(top, s[:, None, :], 0.0), axis=2)
+            gi = jnp.sum(jnp.where(top, gains[:, None, :], 0.0), axis=2)
+            vi = jnp.any(top & valid[:, None, :], axis=2)
+            # the rows' values stay (Q, K) buffers: left free, XLA:TPU turns
+            # each reduce-then-broadcast into a (Q, K, P) reduce-window and
+            # keeps that tensor in HBM (126 MB at the cell's 96 rung)
+            si, gi, vi = jax.lax.optimization_barrier((si, gi, vi))
+            lam_i, hess_i, grad, hess = self._pair_lambdas(
+                si, gi, vi, s, gains, valid, rank, disc, arrs["inv_max_dcg"])
+            lam_i, hess_i = jax.lax.optimization_barrier((lam_i, hess_i))
+            grad = grad + jnp.sum(
+                jnp.where(top, lam_i[:, :, None], 0.0), axis=1)
+            hess = hess + jnp.sum(
+                jnp.where(top, hess_i[:, :, None], 0.0), axis=1)
+            return self._normalized(grad, hess)
+
+    def _sorted_lambdas(self, s, arrs, K: int):
+        """Rank order between two sorts: for buckets too long to count."""
+        p_b = s.shape[1]
         with trace_phase("lgbtpu/rank_sort"):
             order = jnp.argsort(-s, axis=1)                    # rank -> slot
             s_sorted = jnp.take_along_axis(s, order, axis=1)
             g_sorted = jnp.take_along_axis(arrs["gains"], order, axis=1)
-            valid_sorted = jnp.take_along_axis(valid, order, axis=1)
+            valid_sorted = jnp.take_along_axis(arrs["valid"], order, axis=1)
         with trace_phase("lgbtpu/rank_pairs"):
-            grad_sorted, hess_sorted = self._pair_lambdas(
-                s_sorted, g_sorted, valid_sorted, arrs["inv_max_dcg"],
-                p_b, K)
+            lam_i, hess_i, grad, hess = self._pair_lambdas(
+                s_sorted[:, :K], g_sorted[:, :K], valid_sorted[:, :K],
+                s_sorted, g_sorted, valid_sorted,
+                jnp.arange(p_b, dtype=jnp.int32)[None, :],
+                self.discount[None, :p_b], arrs["inv_max_dcg"])
+            grad_sorted, hess_sorted = self._normalized(
+                grad.at[:, :K].add(lam_i), hess.at[:, :K].add(hess_i))
         with trace_phase("lgbtpu/rank_sort"):
             # unsort ranks back to slots
             inv = jnp.argsort(order, axis=1)
@@ -629,77 +714,72 @@ class LambdarankNDCG(ObjectiveFunction):
             hess_q = jnp.take_along_axis(hess_sorted, inv, axis=1)
         return grad_q, hess_q
 
-    def _pair_lambdas(self, s_sorted, g_sorted, valid_sorted, inv_max_dcg,
-                      p_b: int, K: int):
-        """(Q, P) scores, gains and validity in rank order -> (Q, P)
-        grad/hess in rank order, through the (Q, K, P) pairwise tensors."""
-        # pairs: i in top-K ranks x j in all ranks; j > i counted once
-        si = s_sorted[:, :K]                                   # (Q, K)
-        gi = g_sorted[:, :K]
-        vi = valid_sorted[:, :K]
+    def _pair_lambdas(self, si, gi, vi, s, g, valid, rank, disc,
+                      inv_max_dcg):
+        """The (Q, K, P) pairwise tensors: i the document of rank r < K
+        (``si``, ``gi``, ``vi``: (Q, K) score, gain, validity) x j every
+        document, in whatever order ``s``, ``g``, ``valid``, ``rank``,
+        ``disc`` (its rank's discount) list them. Returns the rows' sums
+        ``lam_i``, ``hess_i`` (Q, K) and the columns' ``lam_j``, ``hess_j``
+        (Q, P)."""
+        K = si.shape[1]
         di = self.discount[:K]
-        disc = self.discount[:p_b]
-        delta_s = si[:, :, None] - s_sorted[:, None, :]        # (Q, K, P)
-        worse = (gi[:, :, None] > g_sorted[:, None, :])
-        better = (gi[:, :, None] < g_sorted[:, None, :])
-        pair_mask = (worse | better) & vi[:, :, None] & valid_sorted[:, None, :]
+        delta_s = si[:, :, None] - s[:, None, :]               # (Q, K, P)
+        worse = (gi[:, :, None] > g[:, None, :])
+        better = (gi[:, :, None] < g[:, None, :])
+        # pairs: i in top-K ranks x j in all ranks; j > i counted once
+        once = rank[:, None, :] > jnp.arange(K)[None, :, None]
+        pair_mask = (worse | better) & vi[:, :, None] & valid[:, None, :] \
+            & once
         # |delta NDCG| of swapping ranks i<->j
-        dd = jnp.abs(di[None, :, None] - disc[None, None, :])
-        dgain = jnp.abs(gi[:, :, None] - g_sorted[:, None, :])
+        dd = jnp.abs(di[None, :, None] - disc[:, None, :])
+        dgain = jnp.abs(gi[:, :, None] - g[:, None, :])
         delta_ndcg = dd * dgain * inv_max_dcg[:, None, None]
         # orient each pair so "hi" is the better-labelled doc
         sgn = jnp.where(worse, 1.0, -1.0)
         d = sgn * delta_s                                      # s_hi - s_lo
         sig = self.sigmoid_
         p = 1.0 / (1.0 + jnp.exp(sig * d))                     # misorder prob
-        lam = -sig * p * delta_ndcg
-        hess = sig * sig * p * (1.0 - p) * delta_ndcg
-        lam = jnp.where(pair_mask, lam, 0.0)
-        hess = jnp.where(pair_mask, hess, 0.0)
-        jr = jnp.arange(p_b)[None, None, :]
-        ir = jnp.arange(K)[None, :, None]
-        once = jr > ir
-        lam = jnp.where(once, lam, 0.0)
-        hess = jnp.where(once, hess, 0.0)
+        lam = jnp.where(pair_mask, -sig * p * delta_ndcg, 0.0)
+        hess = jnp.where(pair_mask, sig * sig * p * (1.0 - p) * delta_ndcg,
+                         0.0)
         lam_i = jnp.sum(lam * sgn, axis=2)                     # (Q, K)
-        lam_j = -lam * sgn                                     # (Q, K, P)
         hess_i = jnp.sum(hess, axis=2)
-        grad_sorted = jnp.zeros_like(s_sorted).at[:, :K].add(lam_i) \
-            + jnp.sum(lam_j, axis=1)
-        hess_sorted = jnp.zeros_like(s_sorted).at[:, :K].add(hess_i) \
-            + jnp.sum(hess, axis=1)
-        if self.norm:
-            norm = jnp.sum(jnp.abs(grad_sorted), axis=1, keepdims=True)
-            scale = jnp.where(norm > 0,
-                              jnp.log2(1 + norm) / jnp.maximum(norm, 1e-20),
-                              1.0)
-            grad_sorted = grad_sorted * scale
-            hess_sorted = hess_sorted * scale
-        return grad_sorted, hess_sorted
+        return lam_i, hess_i, jnp.sum(-lam * sgn, axis=1), \
+            jnp.sum(hess, axis=1)
+
+    def _normalized(self, grad, hess):
+        if not self.norm:
+            return grad, hess
+        norm = jnp.sum(jnp.abs(grad), axis=1, keepdims=True)
+        scale = jnp.where(norm > 0,
+                          jnp.log2(1 + norm) / jnp.maximum(norm, 1e-20), 1.0)
+        return grad * scale, hess * scale
 
     def get_gradients(self, score):
-        """(N,) score -> (N,) grad/hess; one padded pairwise-lambda kernel
-        per length bucket, scattered back in a single disjoint update."""
-        n = score.shape[0]
-        idx_parts, g_parts, h_parts = [], [], []
-        for (q_b, p_b, k_b), arrs in zip(self.bucket_shapes,
-                                         self.bucket_arrays):
-            grad_q, hess_q = self._bucket_lambdas(score, arrs, p_b, k_b)
+        """(N,) score -> (N,) grad/hess. Every slot of a bucket reads its
+        row's score (one gather a bucket; a padding slot reads row 0 and is
+        masked); one padded pairwise-lambda kernel per length bucket leaves
+        its (Q_b, P_b) slots in place; every row reads its own slot of the
+        concatenated buckets back (a permutation: one gather, no add).
+        Both ways move PAIRS of floats: XLA's gather of (n, 2) rows runs at
+        5 ns an index on a v5e, its gather of scalars at 7-15 (PERF.md
+        section 6, PR 31)."""
+        with trace_phase("lgbtpu/rank_gather"):
+            score2 = jnp.stack([score, score], axis=1)
+        parts = []
+        for (_, _, k_b), arrs in zip(self.bucket_shapes, self.bucket_arrays):
+            with trace_phase("lgbtpu/rank_gather"):
+                s = jnp.where(arrs["valid"], score2[arrs["safe_idx"]][..., 0],
+                              -jnp.inf)                        # (Q, P)
+            grad_q, hess_q = self._bucket_lambdas(s, arrs, k_b)
             with trace_phase("lgbtpu/rank_scatter"):
-                vm = arrs["valid"].reshape(-1)
-                idx_parts.append(arrs["safe_idx"].reshape(-1))
-                g_parts.append(jnp.where(vm, grad_q.reshape(-1), 0.0))
-                h_parts.append(jnp.where(vm, hess_q.reshape(-1), 0.0))
+                parts.append(
+                    jnp.stack([grad_q, hess_q], axis=-1).reshape(-1, 2))
         with trace_phase("lgbtpu/rank_scatter"):
-            flat_idx = jnp.concatenate(idx_parts)
-            grad = jnp.zeros((n,), jnp.float32).at[flat_idx].add(
-                jnp.concatenate(g_parts))
-            hess = jnp.zeros((n,), jnp.float32).at[flat_idx].add(
-                jnp.concatenate(h_parts))
-            hess = jnp.maximum(hess, 1e-20)
-            if self.weight is not None:
-                grad, hess = grad * self.weight, hess * self.weight
-        return grad, hess
+            gh = jnp.concatenate(parts)[self.slot_of_row]
+            return _weighted(gh[:, 0], jnp.maximum(gh[:, 1], 1e-20),
+                             self.weight)
 
 
 class RankXENDCG(ObjectiveFunction):
