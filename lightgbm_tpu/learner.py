@@ -1807,10 +1807,8 @@ class SerialTreeLearner:
                 hist_chunk = _pre("tpu_hist_chunk")
                 auto_hist_chunk = False
             elif auto_hist_chunk:
-                # measured on v5e (lo_w-tuned einsum): 4096-row chunks win
-                # at F<=64; wide matrices spill VMEM — 1024 is ~8% faster
-                # than 2048 at F=137
-                hist_chunk = 4096 if self.bins.shape[1] <= 64 else 1024
+                from .ops.histogram import einsum_chunk
+                hist_chunk = einsum_chunk(self.bins.shape[1])
             hist_kernel = config.tpu_hist_kernel
             auto_hist = hist_kernel == "auto"
             if auto_hist and "tpu_hist_kernel" in pre:
@@ -2017,6 +2015,22 @@ class SerialTreeLearner:
                 work_layout=layout,
                 goss_compact_rows=m_rows if gc == "on" else 0,
             )
+            # which side of each width gate this job took and what its two
+            # largest device buffers hold: one record per distinct resolution
+            # (build_kwargs runs several times a job)
+            from .ops.route import route_form
+            path = dict(
+                packed_row_bytes=row_w, work_layout=layout,
+                part_kernel=part_kernel, hist_kernel=hist_kernel,
+                route_kernel="pallas_" + route_form(self.bins.shape[1])
+                if tpu and not self.hp.has_categorical else "xla",
+                part_chunk=part_chunk, hist_chunk=hist_chunk,
+                hist_pool_gb=self.num_leaves * self.bins.shape[1]
+                * self.num_bin_hist * 12 / 1e9,
+                work_buffer_gb=float(np.prod(
+                    self._work_buf_shape(kw), dtype=np.float64)) / 1e9)
+            telemetry.record("learner_path",
+                             dedupe_key=tuple(path.values()), **path)
         else:
             kw.update(
                 hist_chunk=min(int(config.tpu_rows_per_chunk), 8192),
@@ -2095,8 +2109,10 @@ class SerialTreeLearner:
         of paying a fresh 2x(N,W) alloc+zero per tree)."""
         if not self.use_partition():
             return None
+        return self._work_buf_shape(self.build_kwargs()), jnp.uint8
+
+    def _work_buf_shape(self, kw) -> tuple:
         from .ops.partition import planes_npad, work_spec
-        kw = self.build_kwargs()
         guard, w = work_spec(self.bins.shape[1],
                              kw["hist_mode"] == "int8", kw["part_kernel"],
                              kw["part_chunk"], kw["hist_chunk"],
@@ -2109,9 +2125,8 @@ class SerialTreeLearner:
             # N-sized buffers in-graph)
             n = m
         if kw["work_layout"] in ("planes", "resident"):
-            return ((2, w, planes_npad(n, guard, kw["part_kernel"])),
-                    jnp.uint8)
-        return ((2, n + 2 * guard, w), jnp.uint8)
+            return (2, w, planes_npad(n, guard, kw["part_kernel"]))
+        return (2, n + 2 * guard, w)
 
     def resident_spec(self):
         """(guard, npad) of the resident bin-plane buffer, or None when the

@@ -246,15 +246,23 @@ def find_bin(
         budget = max(1, n_avail - n_zero_bin)
         n_neg_bins = int(round(budget * (len(neg) / total_for_density))) if len(neg) else 0
         n_pos_bins = budget - n_neg_bins
+        # the sample's distinct values with their counts, ascending: the
+        # negative ones, those in the zero band, the positive ones
+        distinct = []
         if len(neg):
             dv, cnts = np.unique(neg, return_counts=True)
+            distinct.append((dv, cnts))
             b = _greedy_find_bin(dv, cnts, len(neg), max(1, n_neg_bins), min_data_in_bin)
             bounds_list.extend(x for x in b if x < np.inf)
             bounds_list.append(-K_ZERO_THRESHOLD)  # close the negative region
+        if len(nonzero) < len(vals):
+            distinct.append(np.unique(vals[np.abs(vals) <= K_ZERO_THRESHOLD],
+                                      return_counts=True))
         if n_zero_bin and len(pos):
             bounds_list.append(K_ZERO_THRESHOLD)   # zero bin (−kzt, +kzt]
         if len(pos):
             dv, cnts = np.unique(pos, return_counts=True)
+            distinct.append((dv, cnts))
             b = _greedy_find_bin(dv, cnts, len(pos), max(1, n_pos_bins), min_data_in_bin)
             bounds_list.extend(x for x in b if x < np.inf)
         bounds = sorted(set(bounds_list))
@@ -289,10 +297,23 @@ def find_bin(
         ok = np.any((csum[:-1] >= min_split_data) & (csum[-1] - csum[:-1] >= min_split_data))
         m.is_trivial = not bool(ok)
 
-    # most frequent bin on the sample
-    bins_sample = m.value_to_bin(np.concatenate([vals, np.full(implicit_zero, 0.0)]))
-    if len(bins_sample):
-        m.most_freq_bin = int(np.bincount(bins_sample, minlength=m.num_bins).argmax())
+    # most frequent bin on the sample. Bin k holds the values in
+    # (upper_bounds[k-1], upper_bounds[k]] (value_to_bin), so its count is a
+    # difference of "sampled values <= bound", read off the sorted distinct
+    # values: 255 searches a column where binning the sample again took as
+    # long as finding the bounds (18 of 38 ms a column at a 200k-row sample)
+    if distinct or implicit_zero:
+        counts = np.zeros(m.num_bins, dtype=np.int64)
+        if distinct:
+            dv = np.concatenate([d[0] for d in distinct])
+            upto = np.concatenate([[0], np.cumsum(np.concatenate(
+                [d[1] for d in distinct]))])
+            counts[:num_value_bins] = np.diff(
+                upto[np.searchsorted(dv, m.upper_bounds, side="right")],
+                prepend=0)
+        counts[int(np.searchsorted(m.upper_bounds, 0.0, side="left"))] \
+            += implicit_zero
+        m.most_freq_bin = int(counts.argmax())
     m.sparse_rate = zero_cnt / max(1, total_sample_cnt)
     return m
 

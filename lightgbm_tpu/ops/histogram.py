@@ -647,6 +647,30 @@ def planes_kernel_chunk(num_feat: int) -> int:
     return 8192 if num_feat <= 64 else 4096
 
 
+# the XLA einsum loop's two one-hot operands of one chunk, (chunk, F, SH)
+# and (chunk, F, 5 lo_w) bf16, stay in VMEM (128 MiB on a v5e) while they
+# are this small, and go through HBM past it
+EINSUM_OPERAND_BYTES = 80 << 20
+
+
+def einsum_chunk(num_feat: int) -> int:
+    """Rows a pass of the XLA segment loops (``hist16_segment`` and its
+    planes twin), from F alone. Measured on v5e (lo_w-tuned einsum):
+    4096-row chunks win at F <= 64; wider matrices spill VMEM, 1024 is ~8%
+    faster than 2048 at F = 137. Past that the chunk halves until the
+    operands fit again: at F = 2,000 standalone 1024 / 512 / 256 / 128 / 64
+    read 0.522 / 0.369 / 0.172 / 0.195 / 0.473 ns per (row, feature), 295 /
+    147 / 74 / 37 / 18 MB of operands (my chip run, PR 32)."""
+    if num_feat <= 64:
+        return 4096
+    lo_w = auto_lo_w(num_feat)
+    row = num_feat * (256 // lo_w + 5 * lo_w) * 2       # bf16, 256 bins
+    chunk = 1024
+    while chunk > 128 and chunk * row > EINSUM_OPERAND_BYTES:
+        chunk //= 2
+    return chunk
+
+
 def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
                                acc_s, sem, *, ch, num_feat, shp, lo_w, g,
                                nch, dt):

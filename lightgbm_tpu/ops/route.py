@@ -8,6 +8,12 @@ HBM traffic drops to one read of the transposed binned matrix plus one
 write of the leaf vector, and the per-round work is a handful of VPU ops on
 a resident (rows/128, 128) tile (~5 ms/tree).
 
+That streaming form holds a block of EVERY column in VMEM, so it stops at
+a few hundred columns; a wider table takes the wide form (``route_form``):
+one grid step a (row tile, round), handed the one column its round splits
+on, so a tree reads its own ``num_leaves - 1`` columns and never the
+table's width.
+
 Scope: numerical splits, with or without EFB bundles (all per-round
 quantities reduce to SMEM scalars). Categorical splits need a per-row
 (B,)-table lookup — those trees fall back to the XLA router.
@@ -42,41 +48,80 @@ TBL_W = 10
 ROUTE_BLOCK_ROWS = 16384  # rows per grid block (shared with assign_leaves)
 
 
-def _route_kernel(sref, binst_ref, out_ref, *, rounds, csub, num_feat):
+# Mosaic gives a kernel 16 MiB of scoped VMEM on a v5e, and the streaming
+# form holds one block of EVERY matrix column twice (the pipeline's two
+# buffers): F x 32 KB, which the ``v5e:2x2`` compiler accepts to F = 500 and
+# refuses from 504 (PERF.md, PR 32). Tables whose pair of blocks passes this
+# budget take the wide form, which holds one column a step.
+ROUTE_VMEM_BUDGET = 12 << 20
+
+
+def route_form(num_feat: int, rows_per_block: int = ROUTE_BLOCK_ROWS,
+               itemsize: int = 1) -> str:
+    """``stream`` while a block of all ``num_feat`` columns fits VMEM twice
+    (the pipeline's two buffers), ``wide`` past that: the static shape
+    alone decides."""
+    fits = 2 * num_feat * rows_per_block * itemsize <= ROUTE_VMEM_BUDGET
+    return "stream" if fits else "wide"
+
+
+def _route_round(sref, r, num_splits, read_col, state):
+    """One round of the split log on a resident (csub, 128) tile;
+    ``read_col(column index)`` -> that tile's bins of the round's column."""
+    i32 = jnp.int32
+    base = 1 + r * TBL_W
+    col_idx = sref[base + 0]
+    leaf = sref[base + 1]
+    tbin = sref[base + 2]
+    miss = sref[base + 3]
+    dl = sref[base + 4]
+    plain = sref[base + 5]
+    off = sref[base + 6]
+    dpos = sref[base + 7]
+    nbm1 = sref[base + 8]
+    rest = sref[base + 9]
+    col = read_col(col_idx).astype(i32)            # (csub, 128)
+    # bundle slot -> feature bin (identity when plain): slots above the
+    # shared default position shift down by one. All routing flags stay
+    # in i32 0/1 form — Mosaic cannot truncate i8 vectors to i1 data.
+    rank = col - off
+    fb = rank + jnp.clip(rank - dpos + 1, 0, 1)    # +1 when rank >= dpos
+    in_r = jnp.clip(col - off + 1, 0, 1) \
+        * jnp.clip(off + nbm1 - col, 0, 1)         # 1 when in range
+    eff = jnp.where(plain == 1, col, fb)
+    go = jnp.clip(tbin - eff + 1, 0, 1)            # 1 when eff <= tbin
+    is_miss = 1 - jnp.clip(jnp.abs(eff - miss), 0, 1)
+    go = jnp.where((miss >= 0) & (is_miss == 1), dl, go)
+    go = jnp.where((plain == 1) | (in_r == 1), go, rest)
+    upd = jnp.where((state == leaf) & (go == 0), r + 1, state)
+    return jnp.where(r < num_splits, upd, state)
+
+
+def _route_kernel(sref, binst_ref, out_ref, *, rounds, csub):
     i32 = jnp.int32
     num_splits = sref[0]
     state = jnp.zeros((csub, 128), i32)
 
     def body(r, state):
-        base = 1 + r * TBL_W
-        col_idx = sref[base + 0]
-        leaf = sref[base + 1]
-        tbin = sref[base + 2]
-        miss = sref[base + 3]
-        dl = sref[base + 4]
-        plain = sref[base + 5]
-        off = sref[base + 6]
-        dpos = sref[base + 7]
-        nbm1 = sref[base + 8]
-        rest = sref[base + 9]
-        col = binst_ref[col_idx].astype(i32)           # (csub, 128)
-        # bundle slot -> feature bin (identity when plain): slots above the
-        # shared default position shift down by one. All routing flags stay
-        # in i32 0/1 form — Mosaic cannot truncate i8 vectors to i1 data.
-        rank = col - off
-        fb = rank + jnp.clip(rank - dpos + 1, 0, 1)    # +1 when rank >= dpos
-        in_r = jnp.clip(col - off + 1, 0, 1) \
-            * jnp.clip(off + nbm1 - col, 0, 1)         # 1 when in range
-        eff = jnp.where(plain == 1, col, fb)
-        go = jnp.clip(tbin - eff + 1, 0, 1)            # 1 when eff <= tbin
-        is_miss = 1 - jnp.clip(jnp.abs(eff - miss), 0, 1)
-        go = jnp.where((miss >= 0) & (is_miss == 1), dl, go)
-        go = jnp.where((plain == 1) | (in_r == 1), go, rest)
-        upd = jnp.where((state == leaf) & (go == 0), r + 1, state)
-        return jnp.where(r < num_splits, upd, state)
+        return _route_round(sref, r, num_splits,
+                            lambda col_idx: binst_ref[col_idx], state)
 
     state = jax.lax.fori_loop(0, rounds, body, state)
     out_ref[:, :] = state
+
+
+def _route_kernel_wide(sref, col_ref, out_ref):
+    """One grid step a (row block, round): the leaf tile stays in its output
+    block across the rounds, and the pipeline fetches the next round's
+    column (its index comes from the prefetched table) behind this one."""
+    r = pl.program_id(1)
+
+    @pl.when(r == 0)
+    def _():
+        out_ref[:, :] = jnp.zeros(out_ref.shape, jnp.int32)
+
+    out_ref[:, :] = _route_round(sref, r, sref[0], lambda _: col_ref[0],
+                                 out_ref[:, :])
 
 
 def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
@@ -87,6 +132,13 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
     ``bins_t`` must be the transposed binned matrix reshaped to
     (F, Npad/128, 128) with Npad a multiple of rows_per_block; padding rows
     route harmlessly (callers slice [:n]).
+
+    Two forms, chosen by the static shape alone. While a block of all F
+    columns fits VMEM twice (``ROUTE_VMEM_BUDGET``), each row block is
+    streamed through once and the rounds run over it in registers. Past
+    that, the grid gains a round axis and each step is handed the ONE column
+    its round splits on, straight from HBM: a tree reads at most
+    ``num_leaves - 1`` columns, never the table's width.
     """
     num_feat, nsub, _ = bins_t.shape
     rounds = (table.shape[0]) // TBL_W
@@ -95,15 +147,32 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
     grid = nsub // csub
     scalars = jnp.concatenate([num_splits.reshape(1).astype(jnp.int32),
                                table.astype(jnp.int32)])
-    kern = partial(_route_kernel, rounds=rounds, csub=csub,
-                   num_feat=num_feat)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((num_feat, csub, 128),
-                               lambda i, s: (0, i, 0))],
-        out_specs=pl.BlockSpec((csub, 128), lambda i, s: (i, 0)),
-    )
+    if route_form(num_feat, rows_per_block,
+                  bins_t.dtype.itemsize) == "stream":
+        kern = partial(_route_kernel, rounds=rounds, csub=csub)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((num_feat, csub, 128),
+                                   lambda i, s: (0, i, 0))],
+            out_specs=pl.BlockSpec((csub, 128), lambda i, s: (i, 0)),
+        )
+        semantics = ("arbitrary",)
+    else:
+        def column(i, r, s):
+            # rounds past the tree's last split re-name its column, which
+            # the pipeline does not fetch again
+            live = jnp.minimum(r, jnp.maximum(s[0] - 1, 0))
+            return jnp.clip(s[1 + live * TBL_W], 0, num_feat - 1), i, 0
+
+        kern = _route_kernel_wide
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(grid, rounds),
+            in_specs=[pl.BlockSpec((1, csub, 128), column)],
+            out_specs=pl.BlockSpec((csub, 128), lambda i, r, s: (i, 0)),
+        )
+        semantics = ("arbitrary", "arbitrary")
     from .partition import _INTERPRET
     out = pl.pallas_call(
         kern,
@@ -112,7 +181,7 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
         out_shape=jax.ShapeDtypeStruct((nsub, 128), jnp.int32),
         interpret=_INTERPRET,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=semantics),
     )(scalars, bins_t)
     return out.reshape(-1)
 

@@ -152,6 +152,40 @@ def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
     assert "lgbtpu/efb_view" in text and "partition_segment_planes_fused" in text
 
 
+# columns -> (layout, partition, histogram, router) of a dense table: both
+# sides of the two width gates (a packed row of 256 B, of 512 B) and of the
+# router's VMEM budget, and epsilon.train's own width
+WIDE = {300: ("rows", "pallas", "xla", "pallas_stream"),
+        600: ("rows", "xla", "xla", "pallas_wide"),
+        2000: ("rows", "xla", "xla", "pallas_wide")}
+
+
+@pytest.mark.parametrize("f", sorted(WIDE))
+def test_wide_table_block_compiles(topo, f):
+    """The parent's router held a block of EVERY column in VMEM twice and
+    was refused from 504 columns ("Scoped allocation with size 16.05M and
+    limit 16.00M exceeded scoped vmem limit"; 62.50M at 2,000), so no table
+    wider than that trained on a TPU (PR 32). The compile follows the width,
+    not the rows (17 s at 300 columns, 105 s at 2,000, on 8 cores), so
+    20,000 rows stand for epsilon.train's 400,000."""
+    from lightgbm_tpu.obs import telemetry
+    rng = np.random.RandomState(f)
+    X = rng.randn(20_000, f).astype(np.float32)
+    ds = lgb.Dataset(X, label=(X[:, :8].sum(axis=1) > 0).astype(np.float32))
+    telemetry.reset()
+    kw, c = compile_block(topo, ds, {
+        "objective": "binary", "min_data_in_leaf": 1,
+        "min_sum_hessian_in_leaf": 100})
+    rec = telemetry.records("learner_path")[-1]
+    assert resolved(kw) + (rec["route_kernel"],) == WIDE[f]
+    assert rec["packed_row_bytes"] == f + 12
+    assert rec["hist_pool_gb"] == pytest.approx(
+        255 * f * kw["num_bin_hist"] * 12 / 1e9)     # 255 bins a column
+    text = c.as_text()
+    assert "lgbtpu/route/route_rows" in text
+    assert ("partition_segment_fused" in text) == (WIDE[f][1] == "pallas")
+
+
 def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,
                                                       cpu_mesh_devices):
     from lightgbm_tpu.config import Config

@@ -302,7 +302,8 @@ def _learner(f, params, mesh_devices=None, n=300):
 
 
 # name, on a TPU?, F, params, (layout, partition, histogram, partition
-# chunk, histogram chunk), warning expected
+# chunk, histogram chunk), warning expected. The router's side (ROUTER below)
+# follows the backend and the width alone.
 RESOLUTION = [
     # the three cells' device widths: packed rows of 40, 149 and 22 B
     ("higgs_f28", True, 28, {}, ("planes", "pallas", "pallas", 1024, 8192),
@@ -314,6 +315,10 @@ RESOLUTION = [
     ("row_over_256B", True, 250, {}, ("rows", "pallas", "xla", 1024, 1024),
      None),
     ("row_over_512B", True, 510, {}, ("rows", "xla", "xla", 2048, 1024),
+     None),
+    # epsilon.train's width: past both gates and past the router's VMEM
+    # (the einsum's chunk halves until its one-hot operands fit VMEM)
+    ("epsilon_f2000", True, 2000, {}, ("rows", "xla", "xla", 2048, 256),
      None),
     ("quantized_grad", True, 28, {"use_quantized_grad": True},
      ("rows", "pallas", "xla", 1024, 4096), None),
@@ -342,6 +347,12 @@ RESOLUTION = [
 ]
 
 
+# the router's form where it is not the streaming kernel of a TPU / the XLA
+# loop of every other backend
+ROUTER = {"row_over_512B": "pallas_wide", "epsilon_f2000": "pallas_wide",
+          "explicit_pallas_partition_over_512B": "pallas_wide"}
+
+
 @pytest.mark.parametrize("name,tpu,f,params,expect,warning", RESOLUTION,
                          ids=[r[0] for r in RESOLUTION])
 def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
@@ -357,9 +368,24 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
         with pytest.raises(LightGBMError, match=warning):
             _learner(f, params)
         return
-    kw = _learner(f, params, mesh).build_kwargs()
+    obs.telemetry.reset()
+    lrn = _learner(f, params, mesh)
+    kw = lrn.build_kwargs()
     assert (kw["work_layout"], kw["part_kernel"], kw["hist_kernel"],
             kw["part_chunk"], kw["hist_chunk"]) == expect
+    # the job's record says what was decided, and what the two largest
+    # device buffers hold: it cannot drift from the decision
+    (rec,) = obs.telemetry.records("learner_path")
+    assert (rec["work_layout"], rec["part_kernel"], rec["hist_kernel"],
+            rec["part_chunk"], rec["hist_chunk"]) == expect
+    assert rec["route_kernel"] == ROUTER.get(
+        name, "pallas_stream" if tpu else "xla")
+    assert rec["packed_row_bytes"] == f + (
+        P.GH_BYTES_Q if kw["hist_mode"] == "int8" else P.GH_BYTES)
+    assert rec["hist_pool_gb"] == pytest.approx(
+        4 * f * lrn.num_bin_hist * 12 / 1e9)
+    assert rec["work_buffer_gb"] == pytest.approx(
+        np.prod(lrn.work_buf_spec()[0], dtype=np.float64) / 1e9)
     assert kw["hist_mode"] == (
         "int8" if name in ("quantized_grad", "planes_int8") else "hilo")
     hits = [m for m in warnings_log if warning and warning in m]
