@@ -364,8 +364,9 @@ class Config:
     #   buffer layout. rows = (2, Npad, W) row-major; planes = transposed
     #   (2, W, Npad) feature-major planes — each 128-lane tile carries 128
     #   rows of ONE byte column (no dead lanes) and the root histogram is
-    #   folded into the pack pass. auto: planes on TPU at row widths
-    #   <= 256 B, rows elsewhere. Both layouts grow bit-identical trees.
+    #   folded into the pack pass. auto: planes on TPU at every width
+    #   whose chunk the planes kernels' VMEM holds (F <= 8,734; int8
+    #   excepted), rows elsewhere. Both layouts grow bit-identical trees.
     tpu_resident_state: str = "auto"  # auto|off|on: resident permuted
     #   training state (planes layout only). The bin planes live ONCE in a
     #   (F, Npad) resident buffer in original row order; the per-split

@@ -842,10 +842,12 @@ def build_tree_partitioned(
                 return base_part(work, plane, start, cnt, jnp.int32(0),
                                  table, ch=ch)
         else:
+            from .ops.histogram import root_einsum_chunk
             with trace_phase("lgbtpu/pack"):
                 work, root_hist_loc = pack_planes_fold_root(
                     work, bins, ghc, guard, num_bins=bm,
-                    exact=hist_mode != "bf16", chunk=hist_chunk,
+                    exact=hist_mode != "bf16",
+                    chunk=root_einsum_chunk(num_grp, hist_chunk),
                     lo_w=hist_lo)
             part_fn = base_part
     else:
@@ -1771,20 +1773,64 @@ class SerialTreeLearner:
                             % backend if part_kernel == "pallas" else
                             "backend %s has no Mosaic: portable XLA pipeline"
                             % backend)
-            from .ops.partition import GH_BYTES, GH_BYTES_Q
-            row_w = self.bins.shape[1] + (GH_BYTES_Q if mode == "int8"
-                                          else GH_BYTES)
-            if part_kernel == "pallas" and row_w > 512:
-                # 512 bytes = 4 DMA lane-tiles; beyond that the permutation
-                # matmul and VMEM scratch stop paying for themselves
+            from .ops.histogram import planes_kernel_chunk
+            from .ops.partition import (GH_BYTES, GH_BYTES_Q,
+                                        planes_part_chunk)
+            n_col = int(self.bins.shape[1])
+            row_w = n_col + (GH_BYTES_Q if mode == "int8" else GH_BYTES)
+            # both planes kernels hold every plane of a chunk in VMEM; the
+            # widest table whose smallest chunk still fits (the histogram's
+            # accumulator is 10 KB a column: planes_kernel_chunk)
+            planes_fit = planes_kernel_chunk(n_col) > 0
+            layout = config.tpu_work_layout
+            auto_layout = layout == "auto"
+            layout_why = ""
+            if auto_layout and "tpu_work_layout" in pre:
+                layout = _pre("tpu_work_layout")
+                auto_layout = False
+            elif auto_layout:
+                # planes at every width the kernels' VMEM holds: the two
+                # planes kernels are the chip's fast path (PRs 27, 29) and
+                # neither unrolls over the planes, so a wide row pays them
+                # per plane what a narrow one does (PERF.md, PR 33: at
+                # W = 2,016 17.1 ns a row visit and 0.048-0.059 ns a (row,
+                # feature) alone, where the rows layout's XLA loops paid
+                # 136 and 0.29; at W = 320 and 512 3.9 and 5.9 ns against
+                # the rows kernel's 5.9 and 6.8, 0.047 against the einsum's
+                # 0.24 and 0.22). int8 keeps rows (no quantized planes pack
+                # pass yet)
+                layout = "planes" if (
+                    tpu and planes_fit and mode != "int8") else "rows"
+                if layout == "planes":
+                    layout_why = ("packed row %d B on %s: both planes "
+                                  "kernels hold a chunk of it in VMEM"
+                                  % (row_w, backend))
+                elif not tpu:
+                    layout_why = "backend %s: row-major default" % backend
+                elif mode == "int8":
+                    layout_why = "int8 mode has no quantized planes pack"
+                else:
+                    layout_why = ("packed row %d B: the planes histogram's "
+                                  "accumulator does not fit VMEM" % row_w)
+            elif layout == "planes" and mode == "int8":
+                Log.warning("tpu_work_layout=planes does not support int8 "
+                            "quantized training; using rows")
+                layout = "rows"
+            if part_kernel == "pallas" and (
+                    (row_w > 512 and layout == "rows")
+                    or (not planes_fit and layout != "rows")):
+                # the ROWS kernel's DMA window is 4 lane-tiles of 128 B
+                # (int8 and an explicit rows layout are what still reach
+                # it); the planes kernel stops where its VMEM does
                 if not auto_kernel:
                     Log.warning(
                         "tpu_partition_kernel=pallas needs packed rows "
-                        "<= 512 bytes (got %d); using the XLA kernel",
-                        row_w)
+                        "<= 512 bytes in the rows layout and a width the "
+                        "planes kernels' VMEM holds (got %d, layout %s); "
+                        "using the XLA kernel", row_w, layout)
                 part_kernel = "xla"
-                part_why = ("packed row %d B exceeds the 512 B pallas DMA "
-                            "window" % row_w)
+                part_why = ("packed row %d B in the %s layout is past the "
+                            "pallas kernel's window" % (row_w, layout))
             part_chunk = int(config.tpu_part_chunk)
             auto_part_chunk = part_chunk <= 0
             if auto_part_chunk and "tpu_part_chunk" in pre:
@@ -1792,9 +1838,15 @@ class SerialTreeLearner:
                 auto_part_chunk = False
             elif auto_part_chunk:
                 # measured on v5e: the XLA path optimum is 2048 (per-op
-                # overhead vs O(ch^2) compaction matmul); the pallas kernel
-                # has no per-op overhead, so 1024 halves the matmul work
-                part_chunk = 1024 if part_kernel == "pallas" else 2048
+                # overhead vs O(ch^2) compaction matmul); the pallas kernels
+                # have no per-op overhead, so 1024 halves the matmul work,
+                # and the planes kernel's chunk follows its plane count
+                if part_kernel != "pallas":
+                    part_chunk = 2048
+                elif layout == "rows":
+                    part_chunk = 1024
+                else:
+                    part_chunk = planes_part_chunk(row_w)
             if part_kernel == "pallas" and (
                     part_chunk % 32
                     or (part_chunk > 256 and part_chunk % 256)):
@@ -1820,34 +1872,6 @@ class SerialTreeLearner:
                             "partition layout and a non-quantized mode; "
                             "using the XLA einsum")
                 hist_kernel = "xla"
-            layout = config.tpu_work_layout
-            auto_layout = layout == "auto"
-            layout_why = ""
-            if auto_layout and "tpu_work_layout" in pre:
-                layout = _pre("tpu_work_layout")
-                auto_layout = False
-            elif auto_layout:
-                # planes pay off when a packed row wastes most of a
-                # 128-lane DMA tile; at > 256 B row-major tiles are already
-                # >= 2-tile efficient. int8 keeps rows (no quantized planes
-                # pack pass yet)
-                layout = "planes" if (
-                    tpu and row_w <= 256 and mode != "int8") else "rows"
-                if layout == "planes":
-                    layout_why = ("packed row %d B <= 256 B on %s: plane "
-                                  "tiles waste fewer DMA lanes" % (row_w,
-                                                                   backend))
-                elif not tpu:
-                    layout_why = "backend %s: row-major default" % backend
-                elif mode == "int8":
-                    layout_why = "int8 mode has no quantized planes pack"
-                else:
-                    layout_why = ("packed row %d B > 256 B: row tiles "
-                                  "already >= 2-tile efficient" % row_w)
-            elif layout == "planes" and mode == "int8":
-                Log.warning("tpu_work_layout=planes does not support int8 "
-                            "quantized training; using rows")
-                layout = "rows"
             rs = config.tpu_resident_state
             auto_rs = rs == "auto"
             if rs == "on":
@@ -1899,8 +1923,7 @@ class SerialTreeLearner:
                     and layout == "planes":
                 # the planes kernel owns its VMEM: longer DMAs than the XLA
                 # loop's chunk (which spills at F > 64)
-                from .ops.histogram import planes_kernel_chunk
-                hist_chunk = planes_kernel_chunk(self.bins.shape[1])
+                hist_chunk = planes_kernel_chunk(n_col)
             if hist_kernel == "pallas" and hist_chunk % 32:
                 # the kernel re-derives DMA offsets as (x // 32) * 32; a
                 # misaligned chunk would double-count the rows between the
@@ -2015,9 +2038,9 @@ class SerialTreeLearner:
                 work_layout=layout,
                 goss_compact_rows=m_rows if gc == "on" else 0,
             )
-            # which side of each width gate this job took and what its two
-            # largest device buffers hold: one record per distinct resolution
-            # (build_kwargs runs several times a job)
+            # which layout, kernels and chunks this job's width took and what
+            # its two largest device buffers hold: one record per distinct
+            # resolution (build_kwargs runs several times a job)
             from .ops.route import route_form
             path = dict(
                 packed_row_bytes=row_w, work_layout=layout,

@@ -636,21 +636,57 @@ def planes_kernel_params(num_feat: int, num_bins: int, lo_w: int = 0,
     return lo_w, shp, 128 // shp, chunk or planes_kernel_chunk(num_feat)
 
 
+# what the planes histogram kernel may take of a v5e's 128 MiB of VMEM, and
+# the room its chunk-sized buffers get of it
+PLANES_HIST_VMEM = 100 << 20
+PLANES_HIST_CHUNK_BYTES = 24 << 20
+
+
 def planes_kernel_chunk(num_feat: int) -> int:
-    """Lanes a DMA of the planes kernel, from F (v5e, my chip run, PR 29:
-    standalone 8192 / 4096 / 2048 / 1024 read 0.074 / 0.077 / 0.079 /
-    0.088 ns per (row, feature) at F = 28 and 0.057 / 0.060 / 0.062 / 0.070
-    at F = 137; in situ higgs.train 457.0 at 8192 against 467.5 at 4096,
-    mslr.train 943.2 at 4096 against 986.0 at 1024). The same number is
-    the root histogram's XLA chunk in the pack pass, which at F = 137 was
-    not tried over 4096."""
-    return 8192 if num_feat <= 64 else 4096
+    """Lanes a DMA of the planes kernel, from F; 0 where no chunk fits VMEM
+    (``learner.build_kwargs`` then keeps the rows layout).
+
+    v5e, my chip run, PR 29: standalone 8192 / 4096 / 2048 / 1024 read
+    0.074 / 0.077 / 0.079 / 0.088 ns per (row, feature) at F = 28 and 0.057
+    / 0.060 / 0.062 / 0.070 at F = 137; in situ higgs.train 457.0 at 8192
+    against 467.5 at 4096, mslr.train 943.2 at 4096 against 986.0 at 1024.
+
+    A chunk holds 10 B a plane-lane in VMEM (``cin`` twice, ``bins_s`` and
+    the i32 chunk value) beside the (F / 2, 40, 128) f32 accumulator, 10 KB
+    a feature. The chunk halves until those buffers are under 24 MiB (4096
+    to W = 608, 2048 to 1,216, 1024 to 2,432, ...) and, with the
+    accumulator, under the kernel's limit less 4 MiB. PR 33, standalone at
+    F = 2,000 on segments of 399K / 25K / 1.5K rows: 4096 reads 38.4 / 3.60
+    / 1.32 ms a call, 2048 41.2 / 3.61 / 1.14, 1024 47.4 / 3.91 / 1.17, 512
+    59.1 / 4.58 / 1.23: a tree of 400,000 rows makes 254 segments, three
+    quarters of them under 6K rows, and a segment pays whole chunks. F =
+    300 and 500 keep 4096 (0.047 against 2048's 0.051 on long segments).
+    The widest table that fits is F = 8,734."""
+    from .partition import work_spec
+    if num_feat <= 64:
+        return 8192
+    w = work_spec(num_feat, False, "pallas", 0, 0, layout="planes")[1]
+    acc = -(-num_feat // 2) * 40 * 128 * 4
+    chunk = 4096
+    while chunk >= 128 and (
+            10 * w * chunk > PLANES_HIST_CHUNK_BYTES
+            or acc + 10 * w * chunk > PLANES_HIST_VMEM - (4 << 20)):
+        chunk //= 2
+    return chunk if chunk >= 128 else 0
 
 
 # the XLA einsum loop's two one-hot operands of one chunk, (chunk, F, SH)
 # and (chunk, F, 5 lo_w) bf16, stay in VMEM (128 MiB on a v5e) while they
 # are this small, and go through HBM past it
 EINSUM_OPERAND_BYTES = 80 << 20
+
+
+def _fit_einsum_operands(chunk: int, num_feat: int) -> int:
+    lo_w = auto_lo_w(num_feat)
+    row = num_feat * (256 // lo_w + 5 * lo_w) * 2       # bf16, 256 bins
+    while chunk > 128 and chunk * row > EINSUM_OPERAND_BYTES:
+        chunk //= 2
+    return chunk
 
 
 def einsum_chunk(num_feat: int) -> int:
@@ -663,12 +699,18 @@ def einsum_chunk(num_feat: int) -> int:
     147 / 74 / 37 / 18 MB of operands (my chip run, PR 32)."""
     if num_feat <= 64:
         return 4096
-    lo_w = auto_lo_w(num_feat)
-    row = num_feat * (256 // lo_w + 5 * lo_w) * 2       # bf16, 256 bins
-    chunk = 1024
-    while chunk > 128 and chunk * row > EINSUM_OPERAND_BYTES:
-        chunk //= 2
-    return chunk
+    return _fit_einsum_operands(1024, num_feat)
+
+
+def root_einsum_chunk(num_feat: int, hist_chunk: int) -> int:
+    """Rows a pass of the planes pack, whose root histogram is the XLA
+    einsum: the segment histogram's own chunk while the einsum's operands
+    fit (8192 / 4096 / 8192 at F = 28 / 137 / 10: PR 29 measured the pack
+    falling as it grew), halved until they do past that (256 at F = 2,000:
+    the pack reads 155 ms there, its root einsum 136 of them; with the
+    transposed write kept at 1024 or 4096 lanes around 256-row einsum
+    passes 252 and 256; my chip run, PR 33)."""
+    return _fit_einsum_operands(hist_chunk, num_feat)
 
 
 def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
@@ -896,7 +938,7 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
         input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=100 * 1024 * 1024),
+            vmem_limit_bytes=PLANES_HIST_VMEM),
         interpret=_INTERPRET,
     )(scalars, work)
     # the g diagonal blocks: (grp, c, j, l, j', hi) at j == j'

@@ -585,6 +585,29 @@ def compact_rows_by_inbag(bins: jax.Array, ghc: jax.Array, m: int):
             jnp.sum(inbag.astype(jnp.int32)))
 
 
+def planes_part_chunk(row_w: int) -> int:
+    """Lanes a chunk of the fused planes partition, from the packed row's
+    bytes or its padded plane count W (the steps are multiples of 32, so
+    both give the same answer). The sub-block stays 256 at every width.
+
+    The kernel holds a chunk of every plane as one (W, chunk) f32 value.
+    (1024, 256) is what the chip chose at W = 64 and W = 160 (PR 27) and
+    what W = 320 and 512 were timed at (3.86 and 5.87 ns a row visit, PR
+    33). At W = 2,016 (my chip run, PR 33, alone on 399K-row segments; a
+    1,500-row segment in brackets, us a call), (chunk, SB): (2048, 256)
+    23.4 ns a row visit [72.0], (1024, 512) 26.6 [91.6], (1024, 256) 21.9
+    [60.9], (512, 256) 19.2 [53.5], (1024, 128) 19.6 [52.2], (512, 128)
+    18.3 [47.1], (256, 256) 17.1 [46.6], (256, 128) 17.7 [44.1], (128, 128)
+    19.1 [44.3]: the chunk value is 8 MB at 1024 lanes and the smaller one
+    wins, down to the sub-block. So the chunk halves while W x chunk is
+    over 512K elements (a 2 MiB value): 1024 to W = 512, 512 to 1,024, 256
+    past it."""
+    ch = 1024
+    while ch > 256 and row_w * ch > (512 << 10):
+        ch //= 2
+    return ch
+
+
 def planes_npad(n: int, guard: int, part_kernel: str = "xla") -> int:
     """Lane count of the planes work buffer: segment lanes + guards, padded
     to whole 128-lane tiles when the pallas kernel DMAs it."""

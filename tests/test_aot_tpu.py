@@ -153,21 +153,22 @@ def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
 
 
 # columns -> (layout, partition, histogram, router) of a dense table: both
-# sides of the two width gates (a packed row of 256 B, of 512 B) and of the
-# router's VMEM budget, and epsilon.train's own width
-WIDE = {300: ("rows", "pallas", "xla", "pallas_stream"),
-        600: ("rows", "xla", "xla", "pallas_wide"),
-        2000: ("rows", "xla", "xla", "pallas_wide")}
+# sides of the two width gates PR 33 took out (a packed row of 256 B, of
+# 512 B) and of the router's VMEM budget, and epsilon.train's own width
+WIDE = {300: ("planes", "pallas", "pallas", "pallas_stream"),
+        600: ("planes", "pallas", "pallas", "pallas_wide"),
+        2000: ("planes", "pallas", "pallas", "pallas_wide")}
 
 
 @pytest.mark.parametrize("f", sorted(WIDE))
 def test_wide_table_block_compiles(topo, f):
-    """The parent's router held a block of EVERY column in VMEM twice and
+    """Until PR 32 the router held a block of EVERY column in VMEM twice and
     was refused from 504 columns ("Scoped allocation with size 16.05M and
     limit 16.00M exceeded scoped vmem limit"; 62.50M at 2,000), so no table
-    wider than that trained on a TPU (PR 32). The compile follows the width,
-    not the rows (17 s at 300 columns, 105 s at 2,000, on 8 cores), so
-    20,000 rows stand for epsilon.train's 400,000."""
+    wider than that trained on a TPU. Since PR 33 a wide table takes the
+    planes layout and both planes kernels, as a narrow one does. The
+    compile follows the width, not the rows, so 20,000 rows stand for
+    epsilon.train's 400,000."""
     from lightgbm_tpu.obs import telemetry
     rng = np.random.RandomState(f)
     X = rng.randn(20_000, f).astype(np.float32)
@@ -183,7 +184,37 @@ def test_wide_table_block_compiles(topo, f):
         255 * f * kw["num_bin_hist"] * 12 / 1e9)     # 255 bins a column
     text = c.as_text()
     assert "lgbtpu/route/route_rows" in text
-    assert ("partition_segment_fused" in text) == (WIDE[f][1] == "pallas")
+    assert "partition_segment_planes_fused" in text
+    assert "hist_pallas_segment_planes" in text
+
+
+def test_planes_kernels_compile_alone_at_2016_planes(topo):
+    """The two planes kernels at epsilon.train's W = 2,016 and the chunks
+    the width rules give there, on the cell's own (2, 2016, ~402K) buffer:
+    a second or two each where the block takes minutes. And the histogram
+    at the widest table the rule lets through."""
+    from lightgbm_tpu.ops import histogram
+    sh = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    i32 = sds((), jnp.int32)
+    for f, n in ((2000, 400_000), (8734, 20_000)):
+        chunk = histogram.planes_kernel_chunk(f)
+        ch = partition.planes_part_chunk(f + 12)
+        assert (chunk, ch) == {2000: (1024, 256), 8734: (128, 256)}[f]
+        guard, w = partition.work_spec(f, False, "pallas", ch, chunk,
+                                       layout="planes")
+        work = sds((2, w, partition.planes_npad(n, guard, "pallas")),
+                   jnp.uint8)
+        jax.jit(lambda wk, p, s, c: histogram.hist_pallas_segment_planes(
+            wk, p, s, c, num_bins=256, num_feat=f, exact=True,
+            chunk=chunk)).lower(work, i32, i32, i32).compile()
+        jax.jit(lambda wk, p, s, c, ft, tb:
+                partition.partition_segment_planes_fused(
+                    wk, p, s, c, ft, tb, ch=ch)).lower(
+            work, i32, i32, i32, i32, sds((256,), jnp.bool_)).compile()
+    assert histogram.planes_kernel_chunk(8735) == 0
 
 
 def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,

@@ -201,6 +201,26 @@ def fused_block_vs_eager(rng, tmp_path):
                     "job_start")[-1]["path"] == "fused")
 
 
+def _wide(f):
+    def make(rng, tmp_path):
+        # epsilon.train's mechanism at a W the suite meets nowhere else
+        # (320 and 640 planes): a few strong columns, so no two candidates
+        # tie, and few bins, so the interpreter stays quick
+        n = 2600
+        X = rng.randn(n, f)
+        w = np.zeros(f)
+        w[rng.choice(f, 10, replace=False)] = rng.randn(10) * 1.5
+        y = (X @ w + 0.3 * rng.randn(n) > 0).astype(np.float64)
+        return Case(X, y, {"max_bin": 15, "num_leaves": 6}, rounds=1,
+                    active=lambda kw, bst: (
+                        bst.inner.learner.bins.shape[1] == f))
+    make.__name__ = "wide_f%d" % f
+    return make
+
+
+wide_f300, wide_f600 = _wide(300), _wide(600)
+
+
 # (case, histogram kernel on the chip side); the three cells' mechanisms
 # run the kernel the chip runs there
 CASES = [
@@ -211,7 +231,8 @@ CASES = [
     (monotone_advanced, "xla"), (forced_splits, "xla"),
     (bynode_extra_trees, "xla"), (cegb, "xla"),
     (interaction_constraints, "xla"), (hist_bf16, "pallas"),
-    (fused_block_vs_eager, "pallas"),
+    (fused_block_vs_eager, "pallas"), (wide_f300, "pallas"),
+    (wide_f600, "pallas"),
 ]
 
 
@@ -312,14 +333,19 @@ RESOLUTION = [
      None),
     ("expo_f10", True, 10, {}, ("planes", "pallas", "pallas", 1024, 8192),
      None),
-    ("row_over_256B", True, 250, {}, ("rows", "pallas", "xla", 1024, 1024),
+    # past the two width gates PR 33 took out: the chunks follow W
+    ("row_over_256B", True, 250, {}, ("planes", "pallas", "pallas", 1024, 4096),
      None),
-    ("row_over_512B", True, 510, {}, ("rows", "xla", "xla", 2048, 1024),
+    ("row_over_512B", True, 510, {}, ("planes", "pallas", "pallas", 512, 4096),
      None),
-    # epsilon.train's width: past both gates and past the router's VMEM
-    # (the einsum's chunk halves until its one-hot operands fit VMEM)
-    ("epsilon_f2000", True, 2000, {}, ("rows", "xla", "xla", 2048, 256),
+    # epsilon.train's width: W = 2,016 planes, past the router's VMEM
+    ("epsilon_f2000", True, 2000, {}, ("planes", "pallas", "pallas", 256, 1024),
      None),
+    # past what the planes histogram's accumulator leaves of VMEM
+    ("wider_than_vmem", True, 9000, {}, ("rows", "xla", "xla", 2048, 128),
+     None),
+    ("explicit_planes_wider_than_vmem", True, 9000,
+     {"tpu_work_layout": "planes"}, ("planes", "xla", "xla", 2048, 128), None),
     ("quantized_grad", True, 28, {"use_quantized_grad": True},
      ("rows", "pallas", "xla", 1024, 4096), None),
     ("mesh_axis", True, 28, {"tree_learner": "data"},
@@ -329,7 +355,15 @@ RESOLUTION = [
     ("tpu_explicit_xla_hist", True, 137, {"tpu_hist_kernel": "xla"},
      ("planes", "pallas", "xla", 1024, 1024), None),
     ("explicit_pallas_partition_over_512B", True, 510,
-     {"tpu_partition_kernel": "pallas"}, ("rows", "xla", "xla", 2048, 1024),
+     {"tpu_partition_kernel": "pallas"},
+     ("planes", "pallas", "pallas", 512, 4096), None),
+    # the ROWS Pallas partition keeps its 512 B window: int8 and an
+    # explicit rows layout are what still reach it
+    ("quantized_over_512B", True, 510, {"use_quantized_grad": True},
+     ("rows", "xla", "xla", 2048, 1024), None),
+    ("explicit_rows_pallas_over_512B", True, 510,
+     {"tpu_work_layout": "rows", "tpu_partition_kernel": "pallas"},
+     ("rows", "xla", "xla", 2048, 1024),
      "tpu_partition_kernel=pallas needs packed rows <= 512 bytes"),
     ("cpu", False, 28, {}, ("rows", "xla", "xla", 2048, 4096), None),
     ("cpu_explicit_planes", False, 28, {"tpu_work_layout": "planes"},
@@ -349,8 +383,11 @@ RESOLUTION = [
 
 # the router's form where it is not the streaming kernel of a TPU / the XLA
 # loop of every other backend
-ROUTER = {"row_over_512B": "pallas_wide", "epsilon_f2000": "pallas_wide",
-          "explicit_pallas_partition_over_512B": "pallas_wide"}
+ROUTER = dict.fromkeys(
+    ("row_over_512B", "epsilon_f2000", "wider_than_vmem",
+     "explicit_planes_wider_than_vmem",
+     "explicit_pallas_partition_over_512B", "quantized_over_512B",
+     "explicit_rows_pallas_over_512B"), "pallas_wide")
 
 
 @pytest.mark.parametrize("name,tpu,f,params,expect,warning", RESOLUTION,
@@ -387,9 +424,36 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
     assert rec["work_buffer_gb"] == pytest.approx(
         np.prod(lrn.work_buf_spec()[0], dtype=np.float64) / 1e9)
     assert kw["hist_mode"] == (
-        "int8" if name in ("quantized_grad", "planes_int8") else "hilo")
+        "int8" if name in ("quantized_grad", "planes_int8",
+                           "quantized_over_512B") else "hilo")
     hits = [m for m in warnings_log if warning and warning in m]
     assert bool(hits) == bool(warning), warnings_log
+
+
+@pytest.mark.parametrize("f,kernel_chunk,root_chunk,part", [
+    # the three older cells: today's numbers, which PR 29 and PR 27 measured
+    (28, 8192, 8192, 1024), (137, 4096, 4096, 1024), (10, 8192, 8192, 1024),
+    # the band the chip timed at the narrow cells' values (PR 33)
+    (300, 4096, 1024, 1024), (500, 4096, 1024, 1024),
+    # epsilon.train: the chip chose at W = 2,016 (PR 33)
+    (2000, 1024, 256, 256),
+    # the widest table whose accumulator leaves VMEM a chunk, and past it
+    (8734, 128, 128, 256), (8735, 0, 128, 256),
+])
+def test_chunks_follow_the_width(f, kernel_chunk, root_chunk, part):
+    """The three static rules of the planes path: the histogram kernel's
+    chunk, the pack's (its root histogram is the XLA einsum, so it follows
+    the einsum's operand bytes) and the partition kernel's."""
+    from lightgbm_tpu.ops import histogram as H
+    assert H.planes_kernel_chunk(f) == kernel_chunk
+    assert H.root_einsum_chunk(
+        f, kernel_chunk or H.einsum_chunk(f)) == root_chunk
+    w = P.work_spec(f, False, "pallas", 0, 0, layout="planes")[1]
+    assert P.planes_part_chunk(w) == P.planes_part_chunk(f + 12) == part
+    if kernel_chunk:
+        guard, _ = P.work_spec(f, False, "pallas", part, kernel_chunk,
+                               layout="planes")
+        assert guard == max(part, kernel_chunk) + 2 * P.PLANE_ALIGN
 
 
 @pytest.mark.parametrize("knob", ["tpu_split_kernel", "tpu_forest_kernel",

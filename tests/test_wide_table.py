@@ -1,17 +1,19 @@
-"""Tables wider than the chip path's width gates, trained on the CPU.
+"""Tables wider than the narrow cells', trained on the CPU.
 
-``build_kwargs`` sends a packed row of more than 256 B to the rows layout
-and the XLA einsum histogram, one of more than 512 B to the XLA partition
-too, and ``ops/route.py`` gives a table whose columns do not fit VMEM
-together the router's wide form (``epsilon.train``, 2,000 columns, is past
-all three; ``tests/test_aot_tpu.py`` compiles its block for a v5e). Here a
-300- and a 600-column table train through ``lgb.train`` as they resolve on a
-TPU, ``runtime.on_tpu`` replaced as in ``tests/test_chip_path.py`` and the
-Pallas kernels under the interpreter, and are held to the benchmark's plain
-numpy references by the cell's own checks: the root's split, both of its
-children's, the routed leaf counts, ``predict``.
+Until PR 33 ``build_kwargs`` sent a packed row of more than 256 B to the
+rows layout and the XLA einsum histogram, and one of more than 512 B to the
+XLA partition too; now such a table takes the planes layout and both planes
+kernels with chunks that follow its width, and ``ops/route.py`` gives a
+table whose columns do not fit VMEM together the router's wide form
+(``epsilon.train``, 2,000 columns; ``tests/test_aot_tpu.py`` compiles its
+block for a v5e). Here a 300- and a 600-column table train through
+``lgb.train`` as they resolve on a TPU, ``runtime.on_tpu`` replaced as in
+``tests/test_chip_path.py`` and the Pallas kernels under the interpreter,
+and are held to the benchmark's plain numpy references by the cell's own
+checks: the root's split, both of its children's, the routed leaf counts,
+``predict``.
 
-One thing does not follow the patch: the einsum's operands stay float32
+One thing does not follow the patch: the kernels' operands stay float32
 (``histogram._mxu_dtype``), because XLA:CPU accumulates a bf16 dot in bf16.
 """
 import importlib.util
@@ -92,8 +94,8 @@ def _train(X, y, params):
 
 
 # columns, (layout, partition, histogram, router) as on a TPU
-SIDES = [(300, ("rows", "pallas", "xla", "pallas_stream")),
-         (600, ("rows", "xla", "xla", "pallas_wide"))]
+SIDES = [(300, ("planes", "pallas", "pallas", "pallas_stream")),
+         (600, ("planes", "pallas", "pallas", "pallas_wide"))]
 
 
 @pytest.mark.parametrize("f,path", SIDES, ids=["f300", "f600"])
