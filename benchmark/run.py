@@ -180,19 +180,21 @@ def main():
          "label": data["label"], "group": data["group"], "booster": job["booster"],
          "binned": binned, "header": header, "trees": trees}
     correct = True
+    compared = {}   # check -> its verdict and, in its detail, each number compared beside its limit
     for chk in cfg["checks"]:
         t = time.perf_counter()
         ok, detail = load_module("checks", chk["kind"]).run(chk, c)
         say("CHECK", {"check": chk["kind"], "ok": bool(ok), "detail": detail,
                       "seconds": time.perf_counter() - t})
+        compared[chk["kind"]] = {"ok": bool(ok), "detail": detail}
         correct = correct and bool(ok)
-    window_trees = trees[iters_done:iters_done + iters]
-    # an iteration failed if it left no tree, or one that split nothing
-    failed = iters - sum(t["num_leaves"] > 1 for t in window_trees)
+    window_trees, failed = model_text.window(header, trees, iters_done, iters)
     counters = delta(sc, so, "counters")
-    if counters.get("jit/backend_compiles", 0):
-        say("CHECK", {"check": "no_compile_in_window", "ok": False,
-                      "detail": "%d backend compiles inside the window" % counters["jit/backend_compiles"]})
+    compiles = counters.get("jit/backend_compiles", 0)
+    compared["no_compile_in_window"] = {
+        "ok": not compiles, "detail": "%d backend compiles inside the window (at most 0)" % compiles}
+    if compiles:
+        say("CHECK", dict(compared["no_compile_in_window"], check="no_compile_in_window"))
         correct = False
     check_s = time.perf_counter() - t_check
 
@@ -272,6 +274,12 @@ def main():
             "total_s": time.perf_counter() - T0}
     if breakdown:
         line["breakdown"] = breakdown
+    # what decided `correct`: last in the line and last on standard error,
+    # which is what the driver's record keeps of a run that is not correct
+    line["compared"] = compared
+    for kind, verdict in compared.items():
+        print("COMPARED %s ok=%s: %s" % (kind, verdict["ok"], verdict["detail"]),
+              file=sys.stderr, flush=True)
     if rehearse:
         say("REHEARSAL", line)
         sys.exit(4)
