@@ -216,7 +216,8 @@ class Dataset:
                          groups=ds.num_groups,
                          bundled_features=ds.bundled_features,
                          sample_conflicts=ds.efb_sample_conflicts,
-                         conflict_rows=ds.efb_conflict_rows, **parts)
+                         conflict_rows=ds.efb_conflict_rows,
+                         cat_other_bin_rows=ds.cat_other_bin_rows, **parts)
         self._used_params = merged
         if self.free_raw_data:
             self.data = None

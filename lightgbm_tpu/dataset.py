@@ -167,6 +167,11 @@ class BinnedDataset:
         # were chosen, and rows of the WHOLE table on which they are
         self.efb_sample_conflicts: int = 0
         self.efb_conflict_rows: int = 0
+        # (row, categorical column) cells of the whole table binned into the
+        # column's shared last bin: a category beyond the max_bin - 1 most
+        # frequent of the sample, unseen there, negative or NaN. Such a value
+        # goes right at every categorical node (_extract_binned counts)
+        self.cat_other_bin_rows: int = 0
 
     # -- accessors used by the learners --
     def bump_version(self) -> None:
@@ -381,7 +386,7 @@ def construct_dataset(
         with host_phase("lgbtpu/construct_bin_rows"):
             ds.binned = _extract_binned(X, ds,
                                         nthreads=int(config.num_threads))
-        _report_bundles(ds)
+        _report_columns(ds)
         ds.metadata = Metadata(num_data, label, weight, group, init_score)
         if config.linear_tree:
             ds.raw_numeric = _raw_numeric(X, ds)
@@ -494,7 +499,7 @@ def construct_dataset(
 
     with host_phase("lgbtpu/construct_bin_rows"):
         ds.binned = _extract_binned(X, ds, nthreads=int(config.num_threads))
-    _report_bundles(ds)
+    _report_columns(ds)
     ds.metadata = Metadata(num_data, label, weight, group, init_score)
     if config.linear_tree:
         ds.raw_numeric = _raw_numeric(X, ds)
@@ -627,9 +632,14 @@ def _extract_binned(X, ds: BinnedDataset,
     keeps whichever was pushed last, the higher column index of the row.)
     Such rows are counted here at O(nnz) into ``ds.efb_conflict_rows``
     (a row counts once however many bundles or sub-features clash on it).
+
+    A categorical column's last bin is shared by every value the bin finder
+    gave no bin of its own; the cells that land there are counted into
+    ``ds.cat_other_bin_rows``, one pass over the column's byte a row.
     """
     num_data = X.shape[0]
     clashes: List[np.ndarray] = []
+    others: List[int] = []
     max_bins = max((g.num_bins for g in ds.groups), default=1)
     dtype = np.uint8 if max_bins <= 256 else np.uint16
     out = np.zeros((num_data, len(ds.groups)), dtype=dtype)
@@ -688,6 +698,8 @@ def _extract_binned(X, ds: BinnedDataset,
                 np.asarray(col.data, dtype=np.float64)).astype(dtype)
         else:
             out[:, gid] = m.value_to_bin(Xv[:, real]).astype(dtype)
+        if m.bin_type == BIN_CATEGORICAL:
+            others.append(int(np.count_nonzero(out[:, gid] == m.missing_bin)))
 
     # Dense single-feature numerical groups bin through the native threaded
     # applier (native/binning.cpp — the reference's OpenMP PushData analog,
@@ -714,6 +726,7 @@ def _extract_binned(X, ds: BinnedDataset,
             fill_group(gid)
     ds.efb_conflict_rows = int(np.unique(np.concatenate(clashes)).size) \
         if clashes else 0
+    ds.cat_other_bin_rows = sum(others)
     return out
 
 
@@ -721,11 +734,20 @@ def _extract_binned(X, ds: BinnedDataset,
 EFB_CONFLICT_SHARE_WARN = 1e-4
 
 
-def _report_bundles(ds: BinnedDataset) -> None:
+def _report_columns(ds: BinnedDataset) -> None:
     """Gauges ``efb/groups`` and ``efb/features`` (features that share a
     column), counter ``efb/conflict_rows``, and one warning when more of the
-    table conflicts than the reference would have admitted on its sample."""
+    table conflicts than the reference would have admitted on its sample.
+    For a table with categorical columns: gauges ``cat/features`` and
+    ``cat/max_bins`` (the widest such column's bins, the shared last one
+    included) and counter ``cat/other_bin_rows``."""
     from .obs import telemetry
+    cat_bins = [m.num_bins for m in ds.bin_mappers
+                if m.bin_type == BIN_CATEGORICAL]
+    if cat_bins:
+        telemetry.gauge("cat/features", len(cat_bins))
+        telemetry.gauge("cat/max_bins", max(cat_bins))
+        telemetry.count("cat/other_bin_rows", ds.cat_other_bin_rows)
     if not ds.has_bundles:
         return
     telemetry.gauge("efb/groups", len(ds.groups))

@@ -376,10 +376,12 @@ class FusedTrainer:
                     # per-tree pad/reshape copy rides inside the scan body.
                     bins_t = None
                     if wspec is not None:
-                        from .ops.route import ROUTE_BLOCK_ROWS
+                        from .ops.route import (ROUTE_BLOCK_ROWS,
+                                                pallas_routes)
                         bins_t = bins.T
-                        if not learner.hp.has_categorical \
-                                and runtime.on_tpu():
+                        if runtime.on_tpu() and pallas_routes(
+                                learner.hp.has_categorical,
+                                learner.num_bin):
                             n_ = bins.shape[0]
                             npad = ((n_ + ROUTE_BLOCK_ROWS - 1)
                                     // ROUTE_BLOCK_ROWS) * ROUTE_BLOCK_ROWS
@@ -586,11 +588,14 @@ class FusedTrainer:
         follow the builder's contract — one partition pass and one
         smaller-child histogram per split, plus one root histogram per
         tree on the rows layout (planes/resident fold the root histogram
-        into the pack pass)."""
+        into the pack pass). ``tree/splits_categorical`` counts the splits
+        whose left side is a set of categories (``Tree.num_cat``)."""
         splits = sum(t.num_leaves - 1 for t in trees)
         leaves = sum(t.num_leaves for t in trees)
         telemetry.count("tree/trees", len(trees))
         telemetry.count("tree/splits", splits)
+        telemetry.count("tree/splits_categorical",
+                        sum(t.num_cat for t in trees))
         telemetry.count("tree/leaves", leaves)
         spec = self.learner.traffic_spec()
         root_hists = 0 if (spec and spec["work_layout"] != "rows") \

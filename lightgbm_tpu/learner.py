@@ -41,6 +41,7 @@ from .ops.split import (
     SplitInfo,
     calc_leaf_output,
     find_best_split,
+    scan_phase,
 )
 from .tree import Tree
 from .utils.log import Log
@@ -249,10 +250,11 @@ def _make_best_for(meta: FeatureMeta, hp: SplitHyper, key, feature_mask,
                  used_row, extra_mask=None, want_feature_gains=False,
                  use_hp=None, cegb_delta=None, node_depth=None,
                  adv_bounds=None):
-        fmask, rand_thr = node_inputs(r, leaf)
-        fmask = fmask & allowed_mask(used_row)
-        if extra_mask is not None:
-            fmask = fmask & extra_mask
+        with scan_phase(use_hp if use_hp is not None else hp, inside=True):
+            fmask, rand_thr = node_inputs(r, leaf)
+            fmask = fmask & allowed_mask(used_row)
+            if extra_mask is not None:
+                fmask = fmask & extra_mask
         return find_best_split(
             hist, parent_sum, meta, fmask, use_hp if use_hp is not None else hp,
             parent_output=parent_out, leaf_lower=lower, leaf_upper=upper,
@@ -992,7 +994,8 @@ def build_tree_partitioned(
         the node's per-feature histogram view (``feat_views``) — global for
         serial/data/feature, LOCAL for voting; ``tot_g``/``tot_l`` the
         node's global/local (g,h,cnt)."""
-        delta = cegb_penalty(tot_g, tree_used)
+        with scan_phase(hp, inside=True):
+            delta = cegb_penalty(tot_g, tree_used)
         if not voting:
             info = best_raw(r, leaf, fv, tot_g, parent_out,
                             lower, upper, used_row, cegb_delta=delta,
@@ -1081,7 +1084,7 @@ def build_tree_partitioned(
         root_ix = jnp.array([0], jnp.int32)
         best = _empty_best(num_leaves, num_bin)
     root_view = feat_views(root_hist[None], root_sum[None], root_sum_loc[None])
-    with trace_phase("lgbtpu/split_scan"):
+    with scan_phase(hp):
         root_info = node_best_pair(
             0, root_ix, root_view, root_sum[None], root_sum_loc[None],
             leaf_out[:1], leaf_lower[:1], leaf_upper[:1], leaf_used[0],
@@ -1156,7 +1159,7 @@ def build_tree_partitioned(
                 else:
                     with trace_phase("lgbtpu/efb_view"):
                         fv_forced = feat_view(hg_forced, leaf_sum[fl])
-                with trace_phase("lgbtpu/split_scan"):
+                with scan_phase(hp):
                     fi = find_best_split(
                         fv_forced, leaf_sum[fl], meta,
                         jnp.arange(num_feat) == f_feat[ri], hp,
@@ -1382,7 +1385,7 @@ def build_tree_partitioned(
         pair_l = jnp.stack([loc_left, loc_right])
         pair_view = feat_views(jnp.stack([hist_left, hist_right]),
                                pair_g, pair_l)
-        with trace_phase("lgbtpu/split_scan"):
+        with scan_phase(hp):
             infos = node_best_pair(
                 r, pair, pair_view, pair_g, pair_l, leaf_out[pair],
                 leaf_lower[pair], leaf_upper[pair], used_new, tree_used,
@@ -1463,24 +1466,27 @@ def assign_leaves(bins: jax.Array, log: TreeLog,
 def _route_rows(bins, log, has_categorical, bundle, bins_t):
     n = bins.shape[0]
     max_splits = log.split_leaf.shape[0]
-    # fast path: numerical(-or-bundled) trees route in ONE streaming Pallas
-    # pass (ops/route.py) — the fori form below re-reads the matrix and the
-    # leaf vector once per round (~30 ms/tree at 2M x 28 vs ~5 ms)
-    if not has_categorical:
-        from .ops.route import (ROUTE_BLOCK_ROWS, build_route_table,
-                                route_rows)
-        if runtime.on_tpu():
-            if bins_t is not None and bins_t.ndim == 3:
-                btr = bins_t   # pre-padded (F, npad/128, 128) block form
-            else:
-                bt = bins_t if bins_t is not None else bins.T
-                rb = ROUTE_BLOCK_ROWS
-                npad = ((n + rb - 1) // rb) * rb
-                if npad != n:
-                    bt = jnp.pad(bt, ((0, 0), (0, npad - n)))
-                btr = bt.reshape(bins.shape[1], npad // 128, 128)
-            table = build_route_table(log, None, bundle)
-            return route_rows(btr, table, log.num_splits, n)[:n]
+    # fast path on a TPU: every tree, categorical rounds too, routes in ONE
+    # streaming Pallas pass (ops/route.py) — the fori form below re-reads
+    # the matrix and the leaf vector once per round (~30 ms/tree at 2M x 28
+    # vs ~5 ms), and a categorical round of it is an (N, B) one-hot. It
+    # stays as the CPU path and the tests' oracle
+    from .ops.route import (ROUTE_BLOCK_ROWS, build_route_table,
+                            pallas_routes, route_rows)
+    if runtime.on_tpu() and pallas_routes(has_categorical,
+                                          log.go_left.shape[1]):
+        if bins_t is not None and bins_t.ndim == 3:
+            btr = bins_t   # pre-padded (F, npad/128, 128) block form
+        else:
+            bt = bins_t if bins_t is not None else bins.T
+            rb = ROUTE_BLOCK_ROWS
+            npad = ((n + rb - 1) // rb) * rb
+            if npad != n:
+                bt = jnp.pad(bt, ((0, 0), (0, npad - n)))
+            btr = bt.reshape(bins.shape[1], npad // 128, 128)
+        table = build_route_table(log, None, bundle, has_categorical)
+        return route_rows(btr, table, log.num_splits, n,
+                          categorical=has_categorical)[:n]
     # the routing state is pure HBM traffic (a full-N read-modify-write per
     # round): u8 leaf ids cut it 4x whenever they fit (num_leaves <= 256 —
     # always true for the partitioned builder's default shapes)
@@ -2041,12 +2047,13 @@ class SerialTreeLearner:
             # which layout, kernels and chunks this job's width took and what
             # its two largest device buffers hold: one record per distinct
             # resolution (build_kwargs runs several times a job)
-            from .ops.route import route_form
+            from .ops.route import pallas_routes, route_form
             path = dict(
                 packed_row_bytes=row_w, work_layout=layout,
                 part_kernel=part_kernel, hist_kernel=hist_kernel,
                 route_kernel="pallas_" + route_form(self.bins.shape[1])
-                if tpu and not self.hp.has_categorical else "xla",
+                if tpu and pallas_routes(self.hp.has_categorical,
+                                         self.num_bin) else "xla",
                 part_chunk=part_chunk, hist_chunk=hist_chunk,
                 hist_pool_gb=self.num_leaves * self.bins.shape[1]
                 * self.num_bin_hist * 12 / 1e9,

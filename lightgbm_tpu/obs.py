@@ -185,6 +185,11 @@ PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
     # bundle-bin one; holds no op where nothing is bundled
     "lgbtpu/efb_view": ("device", "tree learner", None),
     "lgbtpu/split_scan": ("device", "tree learner", None),
+    # the categorical half of a node search (one-vs-rest gains, sort keys,
+    # the bins' order by count or by sort, the sorted histogram, prefix sums,
+    # the winner's table), named inside ops/split.py beside split_scan; holds
+    # no op without categorical columns
+    "lgbtpu/cat_scan": ("device", "tree learner", None),
     "lgbtpu/partition": ("device", "kernels", None),
     "lgbtpu/histogram": ("device", "kernels", None),
     "lgbtpu/route": ("device", "kernels", None),

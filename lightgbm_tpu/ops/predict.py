@@ -133,8 +133,11 @@ def _route_tree(X, tp, has_cat: bool):
         go = jnp.where((mt == 1) & (jnp.abs(v) <= K_ZERO),
                        tp.default_left[r], go)
         if has_cat:
+            # upstream's CategoricalDecision: NaN right, truncated toward
+            # zero, negative right (-2.7 truncates to the padding's -2)
             iv = jnp.where(jnp.isfinite(col), col, -1.0).astype(jnp.int32)
-            in_set = jnp.any(iv[:, None] == tp.cat_values[r][None, :], axis=1)
+            in_set = jnp.any(iv[:, None] == tp.cat_values[r][None, :], axis=1) \
+                & (iv >= 0)
             go = jnp.where(tp.kind[r] > 0, in_set, go)
         upd = jnp.where((row_slot == tp.slot[r]) & ~go, r + 1, row_slot)
         return jnp.where(active, upd, row_slot)

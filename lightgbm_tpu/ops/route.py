@@ -15,8 +15,13 @@ on, so a tree reads its own ``num_leaves - 1`` columns and never the
 table's width.
 
 Scope: numerical splits, with or without EFB bundles (all per-round
-quantities reduce to SMEM scalars). Categorical splits need a per-row
-(B,)-table lookup — those trees fall back to the XLA router.
+quantities reduce to SMEM scalars), and categorical splits: a job with
+categorical columns carries each round's kind and its (B <= 256,) go-left
+table as ``TABLE_WORDS`` bit-packed SMEM words (the partition kernels' form,
+``ops/partition.py`` ``pack_table_bits``), and a categorical round replaces
+the threshold comparison by bit ``eff`` of those words. ``categorical`` is a
+STATIC argument: a job without such columns compiles the ten-column table
+and the numerical round alone.
 
 Reference analog: Tree::PredictLeafIndex over pre-binned data
 (src/io/tree.cpp), used for score updates via the data partition
@@ -32,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .partition import TABLE_WORDS, pack_table_bits
+
 
 # SMEM table layout: per round r the columns are
 #   0 col      matrix column to read (bundle group or feature)
@@ -44,6 +51,9 @@ from jax.experimental.pallas import tpu as pltpu
 #   7 dpos     bundle: shared default-bin slot position
 #   8 nbm1     bundle: sub-feature slots (num_bins - 1)
 #   9 rest     bundle: direction of out-of-range slots
+# and, in a job with categorical columns only (``table_width``):
+#   10 cat     1 = categorical round
+#   11.. words the round's go-left table, bit b of word w = bin 32*w + b
 TBL_W = 10
 ROUTE_BLOCK_ROWS = 16384  # rows per grid block (shared with assign_leaves)
 
@@ -65,11 +75,22 @@ def route_form(num_feat: int, rows_per_block: int = ROUTE_BLOCK_ROWS,
     return "stream" if fits else "wide"
 
 
-def _route_round(sref, r, num_splits, read_col, state):
+def table_width(categorical: bool) -> int:
+    """Scalars a round holds in the SMEM table."""
+    return TBL_W + 1 + TABLE_WORDS if categorical else TBL_W
+
+
+def pallas_routes(has_categorical: bool, num_bin: int) -> bool:
+    """Whether ``route_rows`` can take a job's trees: always, but for
+    categorical columns of more bins than the bit words hold."""
+    return not has_categorical or num_bin <= 32 * TABLE_WORDS
+
+
+def _route_round(sref, r, num_splits, read_col, state, categorical):
     """One round of the split log on a resident (csub, 128) tile;
     ``read_col(column index)`` -> that tile's bins of the round's column."""
     i32 = jnp.int32
-    base = 1 + r * TBL_W
+    base = 1 + r * table_width(categorical)
     col_idx = sref[base + 0]
     leaf = sref[base + 1]
     tbin = sref[base + 2]
@@ -89,28 +110,48 @@ def _route_round(sref, r, num_splits, read_col, state):
     in_r = jnp.clip(col - off + 1, 0, 1) \
         * jnp.clip(off + nbm1 - col, 0, 1)         # 1 when in range
     eff = jnp.where(plain == 1, col, fb)
-    go = jnp.clip(tbin - eff + 1, 0, 1)            # 1 when eff <= tbin
-    is_miss = 1 - jnp.clip(jnp.abs(eff - miss), 0, 1)
-    go = jnp.where((miss >= 0) & (is_miss == 1), dl, go)
+
+    def go_numerical():
+        go = jnp.clip(tbin - eff + 1, 0, 1)        # 1 when eff <= tbin
+        is_miss = 1 - jnp.clip(jnp.abs(eff - miss), 0, 1)
+        return jnp.where((miss >= 0) & (is_miss == 1), dl, go)
+
+    def go_categorical():
+        # bit ``eff`` of the round's table: the word by eight selects on
+        # scalars (as the partition kernels read theirs), then shift + mask
+        word = jax.lax.shift_right_logical(eff, 5)
+        wvals = jnp.zeros_like(eff)
+        for w in range(TABLE_WORDS):
+            wvals = jnp.where(word == w, sref[base + TBL_W + 1 + w], wvals)
+        return jnp.bitwise_and(jax.lax.shift_right_logical(
+            wvals, jnp.bitwise_and(eff, 31)), 1)
+
+    if categorical:
+        # the round's kind is a scalar: only its own comparison runs
+        go = jax.lax.cond(sref[base + TBL_W] > 0, go_categorical,
+                          go_numerical)
+    else:
+        go = go_numerical()
     go = jnp.where((plain == 1) | (in_r == 1), go, rest)
     upd = jnp.where((state == leaf) & (go == 0), r + 1, state)
     return jnp.where(r < num_splits, upd, state)
 
 
-def _route_kernel(sref, binst_ref, out_ref, *, rounds, csub):
+def _route_kernel(sref, binst_ref, out_ref, *, rounds, csub, categorical):
     i32 = jnp.int32
     num_splits = sref[0]
     state = jnp.zeros((csub, 128), i32)
 
     def body(r, state):
         return _route_round(sref, r, num_splits,
-                            lambda col_idx: binst_ref[col_idx], state)
+                            lambda col_idx: binst_ref[col_idx], state,
+                            categorical)
 
     state = jax.lax.fori_loop(0, rounds, body, state)
     out_ref[:, :] = state
 
 
-def _route_kernel_wide(sref, col_ref, out_ref):
+def _route_kernel_wide(sref, col_ref, out_ref, *, categorical):
     """One grid step a (row block, round): the leaf tile stays in its output
     block across the rounds, and the pipeline fetches the next round's
     column (its index comes from the prefetched table) behind this one."""
@@ -121,13 +162,14 @@ def _route_kernel_wide(sref, col_ref, out_ref):
         out_ref[:, :] = jnp.zeros(out_ref.shape, jnp.int32)
 
     out_ref[:, :] = _route_round(sref, r, sref[0], lambda _: col_ref[0],
-                                 out_ref[:, :])
+                                 out_ref[:, :], categorical)
 
 
 def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
-               n: int, *, rows_per_block: int = ROUTE_BLOCK_ROWS
-               ) -> jax.Array:
-    """(F, Npad/128, 128) u8 tiles + (R*TBL_W,) i32 table -> (Npad,) i32.
+               n: int, *, rows_per_block: int = ROUTE_BLOCK_ROWS,
+               categorical: bool = False) -> jax.Array:
+    """(F, Npad/128, 128) u8 tiles + (R*width,) i32 table -> (Npad,) i32;
+    ``table`` is ``build_route_table``'s for the same ``categorical``.
 
     ``bins_t`` must be the transposed binned matrix reshaped to
     (F, Npad/128, 128) with Npad a multiple of rows_per_block; padding rows
@@ -141,7 +183,8 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
     ``num_leaves - 1`` columns, never the table's width.
     """
     num_feat, nsub, _ = bins_t.shape
-    rounds = (table.shape[0]) // TBL_W
+    width = table_width(categorical)
+    rounds = (table.shape[0]) // width
     csub = rows_per_block // 128
     assert nsub % csub == 0, (nsub, csub)
     grid = nsub // csub
@@ -149,7 +192,8 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
                                table.astype(jnp.int32)])
     if route_form(num_feat, rows_per_block,
                   bins_t.dtype.itemsize) == "stream":
-        kern = partial(_route_kernel, rounds=rounds, csub=csub)
+        kern = partial(_route_kernel, rounds=rounds, csub=csub,
+                       categorical=categorical)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(grid,),
@@ -163,9 +207,9 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
             # rounds past the tree's last split re-name its column, which
             # the pipeline does not fetch again
             live = jnp.minimum(r, jnp.maximum(s[0] - 1, 0))
-            return jnp.clip(s[1 + live * TBL_W], 0, num_feat - 1), i, 0
+            return jnp.clip(s[1 + live * width], 0, num_feat - 1), i, 0
 
-        kern = _route_kernel_wide
+        kern = partial(_route_kernel_wide, categorical=categorical)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(grid, rounds),
@@ -186,9 +230,11 @@ def route_rows(bins_t: jax.Array, table: jax.Array, num_splits: jax.Array,
     return out.reshape(-1)
 
 
-def build_route_table(log, meta, bundle: Optional[dict]) -> jax.Array:
+def build_route_table(log, meta, bundle: Optional[dict],
+                      categorical: bool = False) -> jax.Array:
     """Assemble the per-round SMEM scalar table from a TreeLog (in-graph;
-    all gathers are over (R,)-sized arrays)."""
+    all gathers are over (R,)-sized arrays). ``categorical`` adds each
+    round's kind and its bit-packed go-left table."""
     r_iota = jnp.arange(log.split_leaf.shape[0], dtype=jnp.int32)
     feat = log.feature
     if bundle is not None:
@@ -211,5 +257,9 @@ def build_route_table(log, meta, bundle: Optional[dict]) -> jax.Array:
             log.default_left.astype(jnp.int32), plain.astype(jnp.int32),
             off, dpos, nbm1, rest.astype(jnp.int32)]
     del r_iota, meta
-    return jnp.stack([c.astype(jnp.int32) for c in cols],
-                     axis=1).reshape(-1)
+    table = jnp.stack([c.astype(jnp.int32) for c in cols], axis=1)
+    if categorical:
+        table = jnp.concatenate(
+            [table, (log.kind > 0).astype(jnp.int32)[:, None],
+             jax.vmap(pack_table_bits)(log.go_left)], axis=1)
+    return table.reshape(-1)

@@ -18,10 +18,13 @@ hessian-derived cnt_factor trick, feature_histogram.hpp:316).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..obs import trace_phase
 
 NEG_INF = -jnp.inf
 K_EPSILON = 1e-15
@@ -79,6 +82,21 @@ class SplitHyper(NamedTuple):
     cegb_tradeoff: float = 1.0
     cegb_penalty_split: float = 0.0
     use_cegb: bool = False
+
+
+def scan_phase(hp: SplitHyper, inside: bool = False):
+    """The device scope of a node search. A job without categorical columns
+    is named whole by its caller, ``lgbtpu/split_scan`` around the call, and
+    nothing in here names anything (the ``inside`` sites are no-ops). A job
+    WITH them names its phases in here instead, as ``objective.py``'s
+    ``names_own_phases`` does for ``rank_*``: ``lgbtpu/cat_scan`` must be a
+    SIBLING of ``lgbtpu/split_scan`` (the benchmark books an op to the
+    outermost scope of its name), so the caller's wrap stays off and the
+    numerical scan, the combine and the winner's sums take ``split_scan``
+    from the inside."""
+    if hp.has_categorical == inside:
+        return trace_phase("lgbtpu/split_scan")
+    return contextlib.nullcontext()
 
 
 class SplitInfo(NamedTuple):
@@ -169,6 +187,29 @@ def _split_gain_pair(gl, hl, cl, gr, hr, cr, hp: SplitHyper, *,
     return gain, wl, wr, ok
 
 
+# A bin's place in the sorted order of its column is a COUNT while the
+# (F, B, B) compare is small: how many bins sort before it, ties by bin index,
+# which is the stable sort's order. On a v5e the two argsorts of a node
+# search, the gather of the histogram by their order and the scatter of the
+# winner's table took 35 of expo_cat.train's 253 ms an iteration (chip run,
+# PR 35; an XLA gather costs 5-15 ns an index there); the compare and a
+# one-hot select are elementwise. Past the limit (F > 64 at 256 bins) XLA on
+# a CPU would hold the select in memory (it does not fuse it into its sum,
+# PR 31: 24 B a cell), and the sorts stay. Both forms give the same bits.
+_COUNT_MAX_CELLS = 1 << 22
+
+
+def _stable_rank(keys: jax.Array, b_iota: jax.Array) -> jax.Array:
+    """(..., B) sort keys -> (..., B) i32: each bin's position in the stable
+    ascending sort. A NaN key (0 / 0: a group without hessian under
+    ``cat_smooth=0``) ranks with the unused bins, as +inf."""
+    keys = jnp.where(jnp.isnan(keys), jnp.inf, keys)
+    mine, other = keys[..., :, None], keys[..., None, :]
+    before = (other < mine) | ((other == mine)
+                               & (b_iota[None, :] < b_iota[:, None]))
+    return jnp.sum(before, axis=-1, dtype=jnp.int32)
+
+
 def find_best_split(
     hist: jax.Array,          # (F, B, 3) f32
     parent_sum: jax.Array,    # (3,)
@@ -192,198 +233,227 @@ def find_best_split(
     With ``want_feature_gains`` (static), returns only the per-feature max
     gains (F,) — the voting-parallel learner's local vote input (reference:
     voting_parallel_tree_learner.cpp:322 local top-k votes)."""
-    num_feat, num_bin, _ = hist.shape
-    b_iota = jnp.arange(num_bin, dtype=jnp.int32)
-    bin_valid = b_iota[None, :] < meta.num_bins[:, None]            # (F, B)
-    hist = jnp.where(bin_valid[:, :, None], hist, 0.0)
-    parent_gain = leaf_objective_value(parent_sum[0], parent_sum[1], hp)
+    with scan_phase(hp, inside=True):
+        num_feat, num_bin, _ = hist.shape
+        b_iota = jnp.arange(num_bin, dtype=jnp.int32)
+        bin_valid = b_iota[None, :] < meta.num_bins[:, None]            # (F, B)
+        hist = jnp.where(bin_valid[:, :, None], hist, 0.0)
+        parent_gain = leaf_objective_value(parent_sum[0], parent_sum[1], hp)
 
-    # ---------- numerical thresholds ----------
-    is_missing_bin = meta.movable_missing[:, None] & (b_iota[None, :] == meta.missing_bin[:, None])
-    miss = jnp.sum(jnp.where(is_missing_bin[:, :, None], hist, 0.0), axis=1)   # (F, 3)
-    hist_nm = jnp.where(is_missing_bin[:, :, None], 0.0, hist)
-    cum = jnp.cumsum(hist_nm, axis=1)                                # (F, B, 3)
-    total = parent_sum[None, None, :]
+        # ---------- numerical thresholds ----------
+        is_missing_bin = meta.movable_missing[:, None] & (b_iota[None, :] == meta.missing_bin[:, None])
+        miss = jnp.sum(jnp.where(is_missing_bin[:, :, None], hist, 0.0), axis=1)   # (F, 3)
+        hist_nm = jnp.where(is_missing_bin[:, :, None], 0.0, hist)
+        cum = jnp.cumsum(hist_nm, axis=1)                                # (F, B, 3)
+        total = parent_sum[None, None, :]
 
-    def eval_dir(left):
-        right = total - left
-        gl, hl, cl = left[..., 0], left[..., 1], left[..., 2]
-        gr, hr, cr = right[..., 0], right[..., 1], right[..., 2]
-        gain, _, _, ok = _split_gain_pair(
-            gl, hl, cl, gr, hr, cr, hp,
-            parent_output=parent_output, lower=leaf_lower, upper=leaf_upper,
-            monotone=meta.monotone[:, None] if hp.has_monotone else None,
-            child_bounds=adv_bounds)
-        ok = ok & (cl >= hp.min_data_in_leaf) & (cr >= hp.min_data_in_leaf) \
-            & (hl >= hp.min_sum_hessian_in_leaf) & (hr >= hp.min_sum_hessian_in_leaf)
-        return jnp.where(ok, gain - parent_gain, NEG_INF)
+        def eval_dir(left):
+            right = total - left
+            gl, hl, cl = left[..., 0], left[..., 1], left[..., 2]
+            gr, hr, cr = right[..., 0], right[..., 1], right[..., 2]
+            gain, _, _, ok = _split_gain_pair(
+                gl, hl, cl, gr, hr, cr, hp,
+                parent_output=parent_output, lower=leaf_lower, upper=leaf_upper,
+                monotone=meta.monotone[:, None] if hp.has_monotone else None,
+                child_bounds=adv_bounds)
+            ok = ok & (cl >= hp.min_data_in_leaf) & (cr >= hp.min_data_in_leaf) \
+                & (hl >= hp.min_sum_hessian_in_leaf) & (hr >= hp.min_sum_hessian_in_leaf)
+            return jnp.where(ok, gain - parent_gain, NEG_INF)
 
-    # threshold t means bins <= t go left; missing assigned per direction.
-    # Both directions ride ONE stacked (2, F, B) eval — _split_gain_pair
-    # broadcasts over leading axes, so this halves the per-round op chain
-    # the 254-round scan dispatches (split-scan diet).
-    t_valid = (b_iota[None, :] < meta.num_bins[:, None] - 1) & ~meta.is_categorical[:, None]
-    if rand_threshold is not None:
-        # extra-trees: only one random threshold per feature is considered
-        # (reference: USE_RAND_SPLIT in FindBestThresholdSequentially)
-        t_valid = t_valid & (b_iota[None, :] == rand_threshold[:, None])
-    gains2 = eval_dir(jnp.stack([cum, cum + miss[:, None, :]], axis=0))
-    # nothing to gain from dl when there is no missing mass; keep dr on ties
-    gains2 = jnp.where(
-        jnp.stack([t_valid, t_valid & meta.movable_missing[:, None]], axis=0),
-        gains2, NEG_INF)
-    gain_dr, gain_dl = gains2[0], gains2[1]
-    num_gain = jnp.maximum(gain_dr, gain_dl)                 # (F, B)
-    num_dl = gain_dl > gain_dr
+        # threshold t means bins <= t go left; missing assigned per direction.
+        # Both directions ride ONE stacked (2, F, B) eval — _split_gain_pair
+        # broadcasts over leading axes, so this halves the per-round op chain
+        # the 254-round scan dispatches (split-scan diet).
+        t_valid = (b_iota[None, :] < meta.num_bins[:, None] - 1) & ~meta.is_categorical[:, None]
+        if rand_threshold is not None:
+            # extra-trees: only one random threshold per feature is considered
+            # (reference: USE_RAND_SPLIT in FindBestThresholdSequentially)
+            t_valid = t_valid & (b_iota[None, :] == rand_threshold[:, None])
+        gains2 = eval_dir(jnp.stack([cum, cum + miss[:, None, :]], axis=0))
+        # nothing to gain from dl when there is no missing mass; keep dr on ties
+        gains2 = jnp.where(
+            jnp.stack([t_valid, t_valid & meta.movable_missing[:, None]], axis=0),
+            gains2, NEG_INF)
+        gain_dr, gain_dl = gains2[0], gains2[1]
+        num_gain = jnp.maximum(gain_dr, gain_dl)                 # (F, B)
+        num_dl = gain_dl > gain_dr
 
     # ---------- categorical ----------
     if hp.has_categorical:
-        extra_l2 = hp.cat_l2
-        # candidate categories exclude the trailing other/missing bin
-        cat_bin_ok = meta.is_categorical[:, None] & (b_iota[None, :] < meta.num_bins[:, None] - 1)
-        g_b, h_b, c_b = hist[..., 0], hist[..., 1], hist[..., 2]
+        with trace_phase("lgbtpu/cat_scan"):
+            extra_l2 = hp.cat_l2
+            # candidate categories exclude the trailing other/missing bin
+            cat_bin_ok = meta.is_categorical[:, None] & (b_iota[None, :] < meta.num_bins[:, None] - 1)
+            g_b, h_b, c_b = hist[..., 0], hist[..., 1], hist[..., 2]
 
-        # one-vs-rest (reference: one-hot when #cats <= max_cat_to_onehot)
-        num_cats = meta.num_bins - 1
-        use_onehot = meta.is_categorical & (num_cats <= hp.max_cat_to_onehot)
-        left = hist
-        right = total - left
-        oh_gain, _, _, _ = _split_gain_pair(
-            left[..., 0], left[..., 1], left[..., 2],
-            right[..., 0], right[..., 1], right[..., 2], hp,
-            extra_l2=extra_l2, parent_output=parent_output)
-        oh_ok = (left[..., 2] >= hp.min_data_in_leaf) & (right[..., 2] >= hp.min_data_in_leaf) \
-            & (left[..., 1] >= hp.min_sum_hessian_in_leaf) \
-            & (right[..., 1] >= hp.min_sum_hessian_in_leaf) \
-            & cat_bin_ok & use_onehot[:, None] & (c_b > 0)
-        oh_gain = jnp.where(oh_ok, oh_gain - parent_gain, NEG_INF)
-
-        # many-vs-many: sort categories by g/(h+cat_smooth), scan prefixes
-        # (reference: FindBestThresholdCategoricalInner sorted scan)
-        group_ok = cat_bin_ok & (c_b >= hp.min_data_per_group) & ~use_onehot[:, None]
-        key = jnp.where(group_ok, g_b / (h_b + hp.cat_smooth), jnp.inf)
-        order_asc = jnp.argsort(key, axis=1)
-        key_desc = jnp.where(group_ok, g_b / (h_b + hp.cat_smooth), -jnp.inf)
-        order_desc = jnp.argsort(-key_desc, axis=1)
-        n_groups = jnp.sum(group_ok, axis=1)                         # (F,)
-
-        def mvm_gains(order2):
-            # both sort directions in ONE stacked (2, F, B) eval, same
-            # collapse as the numerical missing-direction pair above
-            h_sorted = jnp.take_along_axis(hist[None], order2[..., None],
-                                           axis=2)
-            csum = jnp.cumsum(h_sorted, axis=2)                      # prefix of k+1
-            k1 = b_iota[None, :] + 1.0                               # prefix size
-            left = csum
+            # one-vs-rest (reference: one-hot when #cats <= max_cat_to_onehot)
+            num_cats = meta.num_bins - 1
+            use_onehot = meta.is_categorical & (num_cats <= hp.max_cat_to_onehot)
+            left = hist
             right = total - left
-            gain, _, _, _ = _split_gain_pair(
+            oh_gain, _, _, _ = _split_gain_pair(
                 left[..., 0], left[..., 1], left[..., 2],
                 right[..., 0], right[..., 1], right[..., 2], hp,
                 extra_l2=extra_l2, parent_output=parent_output)
-            ok = (k1 <= hp.max_cat_threshold) & (k1 < n_groups[:, None]) \
-                & (left[..., 2] >= hp.min_data_in_leaf) & (right[..., 2] >= hp.min_data_in_leaf) \
+            oh_ok = (left[..., 2] >= hp.min_data_in_leaf) & (right[..., 2] >= hp.min_data_in_leaf) \
                 & (left[..., 1] >= hp.min_sum_hessian_in_leaf) \
-                & (right[..., 1] >= hp.min_sum_hessian_in_leaf)
-            return jnp.where(ok, gain - parent_gain, NEG_INF)
+                & (right[..., 1] >= hp.min_sum_hessian_in_leaf) \
+                & cat_bin_ok & use_onehot[:, None] & (c_b > 0)
+            oh_gain = jnp.where(oh_ok, oh_gain - parent_gain, NEG_INF)
 
-        mvm_asc, mvm_desc = mvm_gains(jnp.stack([order_asc, order_desc],
-                                                axis=0))
-        num_gain = jnp.where(meta.is_categorical[:, None], NEG_INF, num_gain)
+            # many-vs-many: sort categories by g/(h+cat_smooth), scan prefixes
+            # (reference: FindBestThresholdCategoricalInner sorted scan)
+            group_ok = cat_bin_ok & (c_b >= hp.min_data_per_group) & ~use_onehot[:, None]
+            # the two sort keys of a column: ascending and, negated, descending;
+            # a bin that is no group sorts last either way
+            ratio = g_b / (h_b + hp.cat_smooth)
+            keys2 = jnp.stack([jnp.where(group_ok, ratio, jnp.inf),
+                               jnp.where(group_ok, -ratio, jnp.inf)], axis=0)
+            by_count = num_feat * num_bin * num_bin <= _COUNT_MAX_CELLS
+            if by_count:
+                rank2 = _stable_rank(keys2, b_iota)                      # (2, F, B)
+            else:
+                order2 = jnp.argsort(keys2, axis=2)
+            n_groups = jnp.sum(group_ok, axis=1)                         # (F,)
+
+            def mvm_gains():
+                # both sort directions in ONE stacked (2, F, B) eval, same
+                # collapse as the numerical missing-direction pair above
+                if by_count:
+                    # the bin of rank k, by a one-hot select: one term a sum
+                    at = rank2[:, :, None, :] == b_iota[None, None, :, None]
+                    h_sorted = jnp.sum(jnp.where(
+                        at[..., None], hist[None, :, None, :, :], 0.0), axis=3)
+                else:
+                    h_sorted = jnp.take_along_axis(hist[None], order2[..., None],
+                                                   axis=2)
+                # prefix of k+1 bins: a product with a triangle of ones (the MXU
+                # in f32, the same op for both forms) where a cumsum over 256
+                # becomes a two-level reduce-window that XLA gives no op_name
+                # and that costs more: 8.6 of expo_cat.train's 228 ms an
+                # iteration outside every scope, 1.3 as this product (chip
+                # runs, PR 35; the gains sit as close to the float64
+                # reference either way)
+                csum = jnp.einsum(
+                    "kj,dfjc->dfkc", (b_iota[:, None] >= b_iota[None, :])
+                    .astype(jnp.float32), h_sorted,
+                    precision=jax.lax.Precision.HIGHEST)
+                k1 = b_iota[None, :] + 1.0                               # prefix size
+                left = csum
+                right = total - left
+                gain, _, _, _ = _split_gain_pair(
+                    left[..., 0], left[..., 1], left[..., 2],
+                    right[..., 0], right[..., 1], right[..., 2], hp,
+                    extra_l2=extra_l2, parent_output=parent_output)
+                ok = (k1 <= hp.max_cat_threshold) & (k1 < n_groups[:, None]) \
+                    & (left[..., 2] >= hp.min_data_in_leaf) & (right[..., 2] >= hp.min_data_in_leaf) \
+                    & (left[..., 1] >= hp.min_sum_hessian_in_leaf) \
+                    & (right[..., 1] >= hp.min_sum_hessian_in_leaf)
+                return jnp.where(ok, gain - parent_gain, NEG_INF)
+
+            mvm_asc, mvm_desc = mvm_gains()
+            num_gain = jnp.where(meta.is_categorical[:, None], NEG_INF, num_gain)
     else:
         oh_gain = jnp.full_like(num_gain, NEG_INF)
         mvm_asc = jnp.full_like(num_gain, NEG_INF)
         mvm_desc = jnp.full_like(num_gain, NEG_INF)
-        order_asc = order_desc = None
         num_gain = jnp.where(meta.is_categorical[:, None], NEG_INF, num_gain)
 
-    # ---------- combine ----------
-    # One live-lane mask and ONE final select instead of a chain of
-    # per-adjustment wheres over the full (4, F, B) plane: every adjustment
-    # runs unguarded on the adjusted values (keeping the reference op order
-    # gain*penalty, *mono_pen, -cegb — bit-identical on live lanes) and
-    # dead lanes are forced to -inf once at the end.
-    stacked = jnp.stack([num_gain, oh_gain, mvm_asc, mvm_desc], axis=0)  # (4, F, B)
-    live = (stacked > NEG_INF) & feature_mask[None, :, None]
-    adj = stacked * meta.penalty[None, :, None]
-    if hp.has_monotone and hp.monotone_penalty > 0 and node_depth is not None:
-        # reference: monotone_constraints.hpp:355 — splits on monotone
-        # features at shallow depths are discounted (and forbidden while
-        # penalization >= depth + 1)
-        p = jnp.float32(hp.monotone_penalty)
-        d = node_depth.astype(jnp.float32)
-        eps = jnp.float32(K_EPSILON)
-        pen = jnp.where(p >= d + 1.0, eps,
-                        jnp.where(p <= 1.0, 1.0 - p / (2.0 ** d) + eps,
-                                  1.0 - 2.0 ** (p - 1.0 - d) + eps))
-        mono_f = meta.monotone != 0
-        adj = jnp.where(mono_f[None, :, None], adj * pen, adj)
-    if hp.use_cegb and cegb_delta is not None:
-        adj = adj - cegb_delta[None, :, None]
-    stacked = jnp.where(live, adj, NEG_INF)
-    if want_feature_gains:
-        return jnp.max(stacked, axis=(0, 2))                 # (F,)
-    flat = stacked.reshape(-1)
-    best_idx = jnp.argmax(flat)
-    best_gain = flat[best_idx]
-    kind = (best_idx // (num_feat * num_bin)).astype(jnp.int32)
-    rem = best_idx % (num_feat * num_bin)
-    feat = (rem // num_bin).astype(jnp.int32)
-    tbin = (rem % num_bin).astype(jnp.int32)
+    with scan_phase(hp, inside=True):
+        # ---------- combine ----------
+        # One live-lane mask and ONE final select instead of a chain of
+        # per-adjustment wheres over the full (4, F, B) plane: every adjustment
+        # runs unguarded on the adjusted values (keeping the reference op order
+        # gain*penalty, *mono_pen, -cegb — bit-identical on live lanes) and
+        # dead lanes are forced to -inf once at the end.
+        stacked = jnp.stack([num_gain, oh_gain, mvm_asc, mvm_desc], axis=0)  # (4, F, B)
+        live = (stacked > NEG_INF) & feature_mask[None, :, None]
+        adj = stacked * meta.penalty[None, :, None]
+        if hp.has_monotone and hp.monotone_penalty > 0 and node_depth is not None:
+            # reference: monotone_constraints.hpp:355 — splits on monotone
+            # features at shallow depths are discounted (and forbidden while
+            # penalization >= depth + 1)
+            p = jnp.float32(hp.monotone_penalty)
+            d = node_depth.astype(jnp.float32)
+            eps = jnp.float32(K_EPSILON)
+            pen = jnp.where(p >= d + 1.0, eps,
+                            jnp.where(p <= 1.0, 1.0 - p / (2.0 ** d) + eps,
+                                      1.0 - 2.0 ** (p - 1.0 - d) + eps))
+            mono_f = meta.monotone != 0
+            adj = jnp.where(mono_f[None, :, None], adj * pen, adj)
+        if hp.use_cegb and cegb_delta is not None:
+            adj = adj - cegb_delta[None, :, None]
+        stacked = jnp.where(live, adj, NEG_INF)
+        if want_feature_gains:
+            return jnp.max(stacked, axis=(0, 2))                 # (F,)
+        flat = stacked.reshape(-1)
+        best_idx = jnp.argmax(flat)
+        best_gain = flat[best_idx]
+        kind = (best_idx // (num_feat * num_bin)).astype(jnp.int32)
+        rem = best_idx % (num_feat * num_bin)
+        feat = (rem // num_bin).astype(jnp.int32)
+        tbin = (rem % num_bin).astype(jnp.int32)
 
-    # ---------- routing table for the winner ----------
-    def tbl_numerical():
-        base = b_iota <= tbin
-        dl = num_dl[feat, tbin]
-        base = jnp.where(meta.movable_missing[feat] & (b_iota == meta.missing_bin[feat]),
-                         dl, base)
-        return base, dl
+        # ---------- routing table for the winner ----------
+        def tbl_numerical():
+            base = b_iota <= tbin
+            dl = num_dl[feat, tbin]
+            base = jnp.where(meta.movable_missing[feat] & (b_iota == meta.missing_bin[feat]),
+                             dl, base)
+            return base, dl
 
-    def tbl_onehot():
-        return b_iota == tbin, jnp.bool_(False)
+        def tbl_onehot():
+            return b_iota == tbin, jnp.bool_(False)
 
-    def tbl_mvm(order):
-        row = order[feat]
-        prefix = b_iota <= tbin                      # first (tbin+1) sorted bins
-        tbl = jnp.zeros((num_bin,), bool).at[row].set(prefix)
-        return tbl, jnp.bool_(False)
+        def tbl_mvm(direction):
+            # the first (tbin + 1) bins of the winner's sorted order go left
+            if by_count:
+                return rank2[direction, feat] <= tbin, jnp.bool_(False)
+            row = order2[direction, feat]
+            tbl = jnp.zeros((num_bin,), bool).at[row].set(b_iota <= tbin)
+            return tbl, jnp.bool_(False)
 
     if hp.has_categorical:
-        go_left, default_left = jax.lax.switch(
-            kind,
-            [lambda: tbl_numerical(), lambda: tbl_onehot(),
-             lambda: tbl_mvm(order_asc), lambda: tbl_mvm(order_desc)],
-        )
+        # the winner's table: an mvm winner scatters its prefix by the sort
+        # order, so the whole pick belongs to the categorical search
+          with trace_phase("lgbtpu/cat_scan"):
+            go_left, default_left = jax.lax.switch(
+                kind,
+                [lambda: tbl_numerical(), lambda: tbl_onehot(),
+                 lambda: tbl_mvm(0), lambda: tbl_mvm(1)],
+            )
     else:
         go_left, default_left = tbl_numerical()
 
-    left_sum = jnp.sum(jnp.where(go_left[None, :, None], hist[feat][None], 0.0), axis=(0, 1))
-    right_sum = parent_sum - left_sum
-    is_cat_win = kind > 0
-    extra = jnp.where(is_cat_win, hp.cat_l2, 0.0)
-    wl = _smoothed(calc_leaf_output(left_sum[0], left_sum[1], hp, extra),
-                   left_sum[2], parent_output, hp)
-    wr = _smoothed(calc_leaf_output(right_sum[0], right_sum[1], hp, extra),
-                   right_sum[2], parent_output, hp)
-    if hp.has_monotone:
-        if adv_bounds is not None:
-            lo_l, up_l, lo_r, up_r = adv_bounds
-            wl = jnp.clip(wl, lo_l[feat, tbin], up_l[feat, tbin])
-            wr = jnp.clip(wr, lo_r[feat, tbin], up_r[feat, tbin])
-        else:
-            wl = jnp.clip(wl, leaf_lower, leaf_upper)
-            wr = jnp.clip(wr, leaf_lower, leaf_upper)
+    with scan_phase(hp, inside=True):
+        left_sum = jnp.sum(jnp.where(go_left[None, :, None], hist[feat][None], 0.0), axis=(0, 1))
+        right_sum = parent_sum - left_sum
+        is_cat_win = kind > 0
+        extra = jnp.where(is_cat_win, hp.cat_l2, 0.0)
+        wl = _smoothed(calc_leaf_output(left_sum[0], left_sum[1], hp, extra),
+                       left_sum[2], parent_output, hp)
+        wr = _smoothed(calc_leaf_output(right_sum[0], right_sum[1], hp, extra),
+                       right_sum[2], parent_output, hp)
+        if hp.has_monotone:
+            if adv_bounds is not None:
+                lo_l, up_l, lo_r, up_r = adv_bounds
+                wl = jnp.clip(wl, lo_l[feat, tbin], up_l[feat, tbin])
+                wr = jnp.clip(wr, lo_r[feat, tbin], up_r[feat, tbin])
+            else:
+                wl = jnp.clip(wl, leaf_lower, leaf_upper)
+                wr = jnp.clip(wr, leaf_lower, leaf_upper)
 
-    valid = best_gain > jnp.float32(hp.min_gain_to_split)
-    best_gain = jnp.where(valid, best_gain, NEG_INF)
-    return SplitInfo(
-        gain=best_gain.astype(jnp.float32),
-        feature=feat,
-        bin=tbin,
-        kind=kind,
-        default_left=default_left,
-        go_left=go_left,
-        left_sum=left_sum,
-        right_sum=right_sum,
-        left_output=wl.astype(jnp.float32),
-        right_output=wr.astype(jnp.float32),
-    )
+        valid = best_gain > jnp.float32(hp.min_gain_to_split)
+        best_gain = jnp.where(valid, best_gain, NEG_INF)
+        return SplitInfo(
+            gain=best_gain.astype(jnp.float32),
+            feature=feat,
+            bin=tbin,
+            kind=kind,
+            default_left=default_left,
+            go_left=go_left,
+            left_sum=left_sum,
+            right_sum=right_sum,
+            left_output=wl.astype(jnp.float32),
+            right_output=wr.astype(jnp.float32),
+        )

@@ -80,16 +80,32 @@ def ds_rank():
 def ds_onehot():
     """The expo.train cell's table at 200K rows: 700 one-hot CSR columns
     that EFB bundles into 10 device columns, so W = 32 planes."""
+    data, cfg = _bench_table("expo-binary-255", N_BIN)
+    ds = lgb.Dataset(data["X"], label=data["label"], params=cfg["params"])
+    ds.construct()
+    return ds
+
+
+def _bench_table(config, rows, seed=11):
+    """A benchmark configuration's own table at ``rows`` rows -> (data, cfg)."""
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "configs", "expo-binary-255.json")) as f:
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
         cfg = json.load(f)
     spec = importlib.util.spec_from_file_location(
-        "bench_datagen_onehot_csr",
+        "bench_datagen_" + cfg["datagen"]["kind"],
         os.path.join(bench, "datagen", cfg["datagen"]["kind"] + ".py"))
     gen = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gen)
-    data = gen.make(dict(cfg["shape"], rows=N_BIN), cfg["datagen"]["args"], 11)
+    return gen.make(dict(cfg["shape"], rows=rows), cfg["datagen"]["args"],
+                    seed), cfg
+
+
+@pytest.fixture(scope="module")
+def ds_coded():
+    """The expo_cat.train cell's table at 200K rows: expo's eight source
+    columns as dense codes, six of them ``categorical_feature``."""
+    data, cfg = _bench_table("expo-categorical-255", N_BIN)
     ds = lgb.Dataset(data["X"], label=data["label"], params=cfg["params"])
     ds.construct()
     return ds
@@ -150,6 +166,33 @@ def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
     assert (binned.num_groups, width) == (10, 32)
     text = c.as_text()
     assert "lgbtpu/efb_view" in text and "partition_segment_planes_fused" in text
+
+
+def test_categorical_block_compiles_with_the_pallas_router(topo, ds_coded):
+    """Until PR 35 ``has_categorical`` sent EVERY tree of such a job to the
+    XLA ``fori_loop`` router (254 full-N rounds a tree, a categorical one an
+    (N, 256) one-hot). Now the round's go-left table rides the SMEM table as
+    eight bit-packed words and the job compiles the ``route_rows`` kernel:
+    the block holds its custom call and no ``while`` under ``lgbtpu/route``.
+    The categorical search is a phase of its own beside the numerical."""
+    from lightgbm_tpu.obs import telemetry
+    binned = ds_coded.construct()
+    assert [m.num_bins for m in binned.bin_mappers] == \
+        [13, 32, 8, 23, 255, 255, 240, 200]
+    assert not binned.has_bundles
+    telemetry.reset()
+    kw, c = compile_block(topo, ds_coded, {
+        "objective": "binary", "min_data_in_leaf": 0,
+        "min_sum_hessian_in_leaf": 100})
+    assert kw["hp"].has_categorical
+    assert resolved(kw) == ("planes", "pallas", "pallas")
+    assert telemetry.records("learner_path")[-1]["route_kernel"] == \
+        "pallas_stream"
+    text = c.as_text()
+    assert "lgbtpu/route/route_rows" in text
+    routed = [ln for ln in text.splitlines() if "lgbtpu/route" in ln]
+    assert routed and not [ln for ln in routed if " while(" in ln]
+    assert "lgbtpu/cat_scan" in text and "lgbtpu/split_scan" in text
 
 
 # columns -> (layout, partition, histogram, router) of a dense table: both

@@ -365,6 +365,10 @@ RESOLUTION = [
      {"tpu_work_layout": "rows", "tpu_partition_kernel": "pallas"},
      ("rows", "xla", "xla", 2048, 1024),
      "tpu_partition_kernel=pallas needs packed rows <= 512 bytes"),
+    # expo_cat.train's width: six of its columns categorical. Until PR 35 a
+    # categorical column sent every tree of the job to the XLA router
+    ("categorical_f8", True, 8, {"categorical_feature": "0,1,2,3,4,5"},
+     ("planes", "pallas", "pallas", 1024, 8192), None),
     ("cpu", False, 28, {}, ("rows", "xla", "xla", 2048, 4096), None),
     ("cpu_explicit_planes", False, 28, {"tpu_work_layout": "planes"},
      ("planes", "xla", "xla", 2048, 4096), None),
@@ -417,6 +421,7 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
             rec["part_chunk"], rec["hist_chunk"]) == expect
     assert rec["route_kernel"] == ROUTER.get(
         name, "pallas_stream" if tpu else "xla")
+    assert lrn.hp.has_categorical == (name == "categorical_f8")
     assert rec["packed_row_bytes"] == f + (
         P.GH_BYTES_Q if kw["hist_mode"] == "int8" else P.GH_BYTES)
     assert rec["hist_pool_gb"] == pytest.approx(

@@ -416,7 +416,19 @@ def _scope_cases():
         "lambdarank": ({"objective": "lambdarank"}, Xr,
                        {"label": yr, "group": group}),
         "binary_bundled": ({"objective": "binary"}, _onehot(600), {"label": y}),
+        "binary_categorical": ({"objective": "binary", "min_data_per_group": 5},
+                               _coded(X), {"label": y,
+                                           "categorical_feature": [0, 1]}),
     }
+
+
+def _coded(X):
+    """Column 0 three categories (one against the rest), column 1 twelve
+    (many against many); the others stay numerical."""
+    rng = np.random.RandomState(4)
+    X = X.copy()
+    X[:, 0], X[:, 1] = rng.randint(0, 3, len(X)), rng.randint(0, 12, len(X))
+    return X
 
 
 def _onehot(n, blocks=4, card=9):
@@ -429,7 +441,8 @@ def _onehot(n, blocks=4, card=9):
 
 
 @pytest.mark.parametrize("objective", ["binary", "lambdarank", "regression",
-                                       "multiclass", "binary_bundled"])
+                                       "multiclass", "binary_bundled",
+                                       "binary_categorical"])
 def test_block_program_ops_lie_under_a_phase_of_the_table(objective):
     """Every op the program puts into the block lies under one outermost
     ``lgbtpu/<phase>`` of ``obs.PHASES``, read from the compiled module's
@@ -478,6 +491,14 @@ def test_block_program_ops_lie_under_a_phase_of_the_table(objective):
     # own beside split_scan and partition, and hold no op without bundles
     assert ("lgbtpu/efb_view" in found) == (objective == "binary_bundled")
     assert "lgbtpu/split_scan" in found
+    # the categorical half of the search is a SIBLING of split_scan (named
+    # inside ops/split.py, where the caller's wrap stays off), and a job
+    # without categorical columns holds no op under it
+    assert ("lgbtpu/cat_scan" in found) == (objective == "binary_categorical")
+    assert "lgbtpu/cat_scan" in obs.PHASES
+    nested = [ln for ln in text.splitlines()
+              if "lgbtpu/split_scan" in ln and "lgbtpu/cat_scan" in ln]
+    assert not nested, nested[:2]
 
 
 def test_every_phase_site_names_a_phase_of_the_table():

@@ -1,6 +1,7 @@
 """Best-split scan vs exhaustive naive search."""
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from lightgbm_tpu.ops.split import (
     FeatureMeta, SplitHyper, find_best_split, leaf_objective_value)
@@ -164,3 +165,97 @@ def test_monotone_constraint_blocks():
     info = find_best_split(jnp.asarray(hist), jnp.asarray(parent), meta,
                            jnp.ones(1, bool), hp)
     assert float(info.gain) == -np.inf
+
+
+# ---- the categorical order by counting (PR 35) against the sorts it replaced
+
+def _cat_hist(rng, num_bins, ties):
+    """Per-bin (g, h, count) of a few categorical columns and a numerical one;
+    with ``ties`` several bins of a column share one g / h, so that only the
+    stable order (ties by bin index) tells them apart."""
+    f, b = len(num_bins), max(num_bins)
+    hist = np.zeros((f, b, 3), np.float32)
+    for i, nb in enumerate(num_bins):
+        cnt = rng.randint(0, 400, nb).astype(np.float32)   # some under min_data_per_group
+        hist[i, :nb, 2] = cnt
+        hist[i, :nb, 1] = 0.25 * cnt
+        ratio = rng.randn(nb)
+        if ties:
+            ratio = np.round(ratio)                         # -2 .. 2: many equal keys
+        hist[i, :nb, 0] = ratio * (hist[i, :nb, 1] + 10.0) if ties \
+            else ratio * hist[i, :nb, 1]
+    parent = hist[0].sum(axis=0)
+    for i in range(1, f):       # one parent for all columns: the rest in bin 0
+        hist[i, 0] += parent - hist[i].sum(axis=0)
+    return hist, parent
+
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+def test_rank_by_count_is_the_stable_sorts_order(rng, ties):
+    from lightgbm_tpu.ops import split
+    keys = rng.randn(2, 5, 64).astype(np.float32)
+    if ties:
+        keys = np.round(keys)
+    keys[:, :, 50:] = np.inf                  # bins that are no group sort last
+    keys[0, 0, 3] = np.nan                    # ranks with them, as +inf
+    want = np.argsort(np.argsort(np.where(np.isnan(keys), np.inf, keys),
+                                 axis=-1, kind="stable"), axis=-1, kind="stable")
+    got = split._stable_rank(jnp.asarray(keys), jnp.arange(64, dtype=jnp.int32))
+    assert np.array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+def test_count_form_gives_the_sorted_forms_split_bit_for_bit(rng, ties, monkeypatch):
+    """The same SplitInfo, every field and every bit, whether a bin's place
+    in its column's order is counted or sorted: the count is the stable
+    sort's order, the one-hot select picks what the gather picked, and the
+    winner's table is a compare where the sorted form scatters."""
+    from lightgbm_tpu.ops import split
+    num_bins = [40, 64, 13, 30, 64]
+    meta = _meta(num_bins, is_cat=[True, True, True, True, False])
+    hp = SplitHyper(min_data_in_leaf=1, min_sum_hessian_in_leaf=1e-3,
+                    has_categorical=True, min_data_per_group=50.0)
+    found = set()
+    for _ in range(6):
+        hist, parent = _cat_hist(rng, num_bins, ties)
+        mask = jnp.asarray(rng.rand(5) < 0.8)
+        infos = []
+        for limit in (split._COUNT_MAX_CELLS, 0):
+            monkeypatch.setattr(split, "_COUNT_MAX_CELLS", limit)
+            infos.append(find_best_split(jnp.asarray(hist), jnp.asarray(parent),
+                                         meta, mask, hp))
+        for a, b in zip(*infos):
+            assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
+        found.add(int(infos[0].kind))
+    assert found & {2, 3}, found          # many-against-many winners among them
+
+
+def test_count_form_trains_the_sorted_forms_trees(rng, monkeypatch):
+    """Through ``lgb.train``: the model text is the same, byte for byte."""
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import fused
+    from lightgbm_tpu.ops import split
+    n = 4000
+    X = rng.randn(n, 4)
+    X[:, 0] = rng.randint(0, 40, n)
+    X[:, 1] = np.minimum(rng.zipf(1.4, n), 90) - 1
+    w0, w1 = rng.randn(40), rng.randn(90)
+    y = (w0[X[:, 0].astype(int)] + w1[X[:, 1].astype(int)] + X[:, 2]
+         + 0.3 * rng.randn(n) > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
+              "min_data_in_leaf": 5, "min_data_per_group": 20}
+    texts = []
+    for limit in (split._COUNT_MAX_CELLS, 0):
+        monkeypatch.setattr(split, "_COUNT_MAX_CELLS", limit)
+        jax.clear_caches()
+        fused._BLOCK_CACHE.clear()
+        ds = lgb.Dataset(X, label=y, params=dict(params),
+                         categorical_feature=[0, 1])
+        texts.append(lgb.train(dict(params), ds,
+                               num_boost_round=5).model_to_string())
+    jax.clear_caches()
+    fused._BLOCK_CACHE.clear()
+    assert texts[0] == texts[1]
+    assert "cat_threshold=" in texts[0]
