@@ -365,7 +365,7 @@ class Config:
     #   (2, W, Npad) feature-major planes — each 128-lane tile carries 128
     #   rows of ONE byte column (no dead lanes) and the root histogram is
     #   folded into the pack pass. auto: planes on TPU at every width
-    #   whose chunk the planes kernels' VMEM holds (F <= 8,734; int8
+    #   whose chunk the planes kernels' VMEM holds (F <= 6,896; int8
     #   excepted), rows elsewhere. Both layouts grow bit-identical trees.
     tpu_resident_state: str = "auto"  # auto|off|on: resident permuted
     #   training state (planes layout only). The bin planes live ONCE in a

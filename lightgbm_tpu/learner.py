@@ -34,13 +34,14 @@ from . import runtime
 from .config import Config
 from .dataset import BinnedDataset
 from .obs import trace_phase, track_jit
-from .ops.histogram import build_histogram
+from .ops.histogram import build_histogram, hist_bins
 from .ops.split import (
     FeatureMeta,
     SplitHyper,
     SplitInfo,
     calc_leaf_output,
     find_best_split,
+    find_best_split_planes,
     scan_phase,
 )
 from .tree import Tree
@@ -94,24 +95,24 @@ class Comm:
         return -(-g // d) * d
 
     def hist(self, h):
-        """Leaf-histogram reduction: reduce-scatter by group blocks for
-        data-parallel (each shard owns [idx*blk, (idx+1)*blk) re-embedded
-        into the full shape, zeros elsewhere); identity when rows are
-        replicated (feature) or hists stay local (voting)."""
+        """Leaf-histogram reduction of a channel-major (3, G, Bp) histogram:
+        reduce-scatter by group blocks (axis 1) for data-parallel (each
+        shard owns [idx*blk, (idx+1)*blk) re-embedded into the full shape,
+        zeros elsewhere); identity when rows are replicated (feature) or
+        hists stay local (voting)."""
         if self.axis is None or self.mode in ("feature", "voting"):
             return h
         if self.hist_scatter:
-            g = h.shape[0]
+            g = h.shape[1]
             gpad = self._gpad(g)
             blk = gpad // self.num_machines
-            hp = jnp.pad(h, ((0, gpad - g),) + ((0, 0),) * (h.ndim - 1))
-            sc = jax.lax.psum_scatter(hp, self.axis, scatter_dimension=0,
+            hp = jnp.pad(h, ((0, 0), (0, gpad - g), (0, 0)))
+            sc = jax.lax.psum_scatter(hp, self.axis, scatter_dimension=1,
                                       tiled=True)
             idx = jax.lax.axis_index(self.axis)
             out = jax.lax.dynamic_update_slice(
-                jnp.zeros_like(hp), sc,
-                (idx * blk,) + (0,) * (h.ndim - 1))
-            return out[:g]
+                jnp.zeros_like(hp), sc, (0, idx * blk, 0))
+            return out[:, :g]
         return jax.lax.psum(h, self.axis)
 
     def owned_group_mask(self, feat_group, num_groups: int):
@@ -210,12 +211,43 @@ def _set_best(best: SplitInfo, idx, info: SplitInfo) -> SplitInfo:
 
 
 
+# The histogram pool of the partitioned builder: (num_leaves, 3, G, Bp) f32,
+# a leaf's row IS its channel-major histogram (ops/histogram.py hist_bins).
+# Rows are read and written by dynamic slices on the leaf axis alone: every
+# op between the histogram kernel, the pool and the scan is elementwise on
+# lane-dense (3, G, Bp) arrays, and no tile is ever reshaped: on a v5e at
+# F = 2,000 the row's read, the subtraction, the select and both writes
+# cost 52 us a split together (tree_state 13.2 ms an iteration of 254
+# splits; chip run, PR 36). Until PR 36 the pool held k-minor flat rows of
+# (G, B, 3) and each of the six ops a split that carried 6.1 MB in or out
+# of it cost 0.35-0.56 ms (tiles padded 3 -> 128): 822 of epsilon.train's
+# 1,340 ms an iteration (PERF.md section 6).
+
+
+def pool_read(pool: jax.Array, leaf) -> jax.Array:
+    """A leaf's (3, G, Bp) histogram, as an array of its own: left to
+    itself XLA fuses this slice into BOTH children's writes, the second of
+    which then reads the pool as it was before the first, and to keep that
+    it copies the whole pool (1.57 GB at F = 2,000) twice a split (read in
+    the block compiled for v5e:2x2, PR 36)."""
+    return jax.lax.optimization_barrier(
+        jax.lax.dynamic_index_in_dim(pool, leaf, 0, keepdims=False))
+
+
+def pool_write(pool: jax.Array, leaf, h: jax.Array) -> jax.Array:
+    """The pool with ``leaf``'s row replaced, in place inside the loop."""
+    return jax.lax.dynamic_update_index_in_dim(pool, h, leaf, 0)
+
+
 def _make_best_for(meta: FeatureMeta, hp: SplitHyper, key, feature_mask,
                    num_feat: int, feature_fraction_bynode: float,
-                   extra_trees: bool, constraint_sets, extra_seed: int = 6):
+                   extra_trees: bool, constraint_sets, extra_seed: int = 6,
+                   search=find_best_split):
     """Shared per-node split evaluation: by-node column sampling,
     extra-trees random thresholds, interaction constraints, then the
-    vectorized (F, B) best-split scan."""
+    vectorized (F, B) best-split scan: ``search`` is ``find_best_split`` over
+    the dense builder's (F, B, 3) histograms, ``find_best_split_planes``
+    over the partitioned builder's channel-major (3, F, B) ones."""
 
     def allowed_mask(used_row):
         """Interaction constraints (reference: col_sampler.hpp:94 GetByNode):
@@ -255,7 +287,7 @@ def _make_best_for(meta: FeatureMeta, hp: SplitHyper, key, feature_mask,
             fmask = fmask & allowed_mask(used_row)
             if extra_mask is not None:
                 fmask = fmask & extra_mask
-        return find_best_split(
+        return search(
             hist, parent_sum, meta, fmask, use_hp if use_hp is not None else hp,
             parent_output=parent_out, leaf_lower=lower, leaf_upper=upper,
             rand_threshold=rand_thr, want_feature_gains=want_feature_gains,
@@ -800,6 +832,10 @@ def build_tree_partitioned(
     guard, buf_width = work_spec(num_grp, quantized, part_kernel,
                                  part_chunk, hist_chunk, layout=work_layout)
     bm = num_bin_hist if num_bin_hist is not None else num_bin
+    # a leaf's histogram in this loop: (3, num_grp, bp), channel-major with
+    # the bins on the lanes (ops/histogram.py hist_bins); every hist_of
+    # branch, the packs' root histogram, the pool and the scan hold it
+    bp = hist_bins(bm)
 
     # ---- packed ping-pong working buffers with guard rows ----
     # the matrix columns are EFB bundles (== features when no bundling)
@@ -884,9 +920,11 @@ def build_tree_partitioned(
         part_fn = partition_segment_fused if fused_part else partition_segment
 
     def hist_of(work, plane, start, cnt):
-        """-> ((G, Bm, 3) reduced histogram, work). Callers must continue
-        with the RETURNED work: the pallas kernel aliases the buffer
-        through the call (identical bytes) so XLA never copies it."""
+        """-> ((3, G, Bp) reduced histogram, work): the g, h and count
+        planes of the G device columns, bins padded to whole lane tiles
+        (zeros). Callers must continue with the RETURNED work: the pallas
+        kernel aliases the buffer through the call (identical bytes) so XLA
+        never copies it."""
         if resident:
             # unit-stride gather over the resident bin planes through the
             # permuted row-index plane; same chunking and f32 accumulation
@@ -922,21 +960,24 @@ def build_tree_partitioned(
             h = hist16_segment(work, plane, start, cnt, num_bins=bm,
                                num_feat=num_grp, exact=hist_mode != "bf16",
                                chunk=hist_chunk, lo_w=hist_lo)
-        return comm.hist(h), work                         # (G, Bm, 3)
+        return comm.hist(h), work                         # (3, G, Bp)
 
     def feat_view(hg, total_sum):
+        """(3, G, Bp) device-column histogram -> the (3, F, B) planes the
+        scan reads; without bundles the columns ARE the features and the
+        pad bins are cut off."""
         if bundle is None:
-            return hg
-        return bundle_feature_view(hg, total_sum, bundle)
+            return hg[..., :num_bin]
+        return bundle_feature_view(hg, total_sum, bundle, bm)
 
     def feat_views(hists, tot_g, tot_l):
-        """The per-feature views of K nodes' bundled histograms, for
-        ``node_best_pair``: a phase of its own beside ``split_scan`` (the
-        benchmark books an op to its outermost phase). Voting searches
-        LOCAL histograms, so their default bins come from the local totals.
-        No op where nothing is bundled."""
+        """The per-feature views of K nodes' histograms, (K, 3, F, B), for
+        ``node_best_pair``: with bundles a phase of its own beside
+        ``split_scan`` (the benchmark books an op to its outermost phase).
+        Voting searches LOCAL histograms, so their default bins come from
+        the local totals."""
         if bundle is None:
-            return hists
+            return feat_view(hists, None)
         with trace_phase("lgbtpu/efb_view"):
             return jax.vmap(feat_view)(hists, tot_l if voting else tot_g)
 
@@ -967,7 +1008,8 @@ def build_tree_partitioned(
         fmask_search = fmask_search & owned_g
     best_raw = _make_best_for(meta, hp, key, fmask_search, num_feat,
                               feature_fraction_bynode, extra_trees,
-                              constraint_sets, extra_seed)
+                              constraint_sets, extra_seed,
+                              search=find_best_split_planes)
     voting = comm.mode == "voting"
     if voting:
         d = float(max(comm.num_machines, 1))
@@ -1018,9 +1060,8 @@ def build_tree_partitioned(
         selmat = (sel[:, None]
                   == jnp.arange(num_feat, dtype=jnp.int32)[None, :]) \
             .astype(jnp.float32)                               # (k2, F)
-        flat = fv_loc.reshape(num_feat, -1)
-        merged = comm.psum(selmat @ flat)                      # (k2, B*3)
-        full = (selmat.T @ merged).reshape(fv_loc.shape)       # voted rows only
+        merged = comm.psum(jnp.einsum("kf,cfb->ckb", selmat, fv_loc))
+        full = jnp.einsum("kf,ckb->cfb", selmat, merged)       # voted rows only
         selmask = jnp.any(selmat > 0.5, axis=0)
         return best_raw(r, leaf, full, tot_g, parent_out, lower, upper,
                         used_row, extra_mask=selmask, cegb_delta=delta,
@@ -1043,12 +1084,12 @@ def build_tree_partitioned(
             root_hist, work = hist_of(work, jnp.int32(0), jnp.int32(guard),
                                       jnp.int32(n))
     with trace_phase("lgbtpu/tree_state"):
-        # the pool is kept FLAT per leaf: 4-D pools make XLA's layout
-        # assignment disagree between the while carry and the gather/update
-        # consumers, inserting a full pool copy per split (measured 2x430 us at
-        # F=137); a 2-D (L, G*B*3) pool has one canonical layout
-        hist_pool = jnp.zeros((num_leaves, num_grp * bm * 3), jnp.float32)
-        hist_pool = hist_pool.at[0].set(root_hist.reshape(-1))
+        # a leaf's row of the pool IS its histogram, (3, G, Bp): read and
+        # written by dynamic slices on the leaf axis alone (pool_read,
+        # pool_write), so no tile of it is ever reshaped
+        hist_pool = pool_write(
+            jnp.zeros((num_leaves, 3, num_grp, bp), jnp.float32),
+            jnp.int32(0), root_hist)
         leaf_sum = jnp.zeros((num_leaves, 3), jnp.float32).at[0].set(root_sum)
         leaf_sum_loc = jnp.zeros((num_leaves, 3), jnp.float32).at[0].set(
             root_sum_loc)
@@ -1151,16 +1192,16 @@ def build_tree_partitioned(
                     # identical on every shard (default_left/gain derive from
                     # missing mass), so globalize the leaf histogram first. The
                     # cond predicate is replicated, so the psum is uniform.
-                    hg_forced = comm.psum(hist_pool[fl]) \
-                        if (voting or comm.hist_scatter) else hist_pool[fl]
-                    hg_forced = hg_forced.reshape(num_grp, bm, 3)
+                    hg_forced = pool_read(hist_pool, fl)
+                    if voting or comm.hist_scatter:
+                        hg_forced = comm.psum(hg_forced)
                 if bundle is None:
-                    fv_forced = hg_forced
+                    fv_forced = feat_view(hg_forced, None)
                 else:
                     with trace_phase("lgbtpu/efb_view"):
                         fv_forced = feat_view(hg_forced, leaf_sum[fl])
                 with scan_phase(hp):
-                    fi = find_best_split(
+                    fi = find_best_split_planes(
                         fv_forced, leaf_sum[fl], meta,
                         jnp.arange(num_feat) == f_feat[ri], hp,
                         parent_output=leaf_out[fl], leaf_lower=leaf_lower[fl],
@@ -1337,7 +1378,7 @@ def build_tree_partitioned(
 
             # ---- histograms: the smaller child gets a fresh pass over its
             # contiguous segment; the larger child is parent - smaller ----
-            parent_hist = hist_pool[leaf].reshape(num_grp, bm, 3)
+            parent_hist = pool_read(hist_pool, leaf)
             pair = jnp.stack([leaf, new_leaf])
             small_start = jnp.where(left_smaller, start, start + lt)
             small_cnt = jnp.where(left_smaller, lt, cnt - lt)
@@ -1349,16 +1390,18 @@ def build_tree_partitioned(
             hist_left = jnp.where(left_smaller, hist_small, hist_large)
             hist_right = jnp.where(left_smaller, hist_large, hist_small)
             if n_forced:
-                old_right = hist_pool[new_leaf].reshape(num_grp, bm, 3)
-                pool_val = jnp.stack([sel(hist_left, parent_hist),
-                                      sel(hist_right, old_right)])
+                old_right = pool_read(hist_pool, new_leaf)
+                hist_pool = pool_write(hist_pool, leaf,
+                                       sel(hist_left, parent_hist))
+                hist_pool = pool_write(hist_pool, new_leaf,
+                                       sel(hist_right, old_right))
             else:
-                pool_val = jnp.stack([hist_left, hist_right])
-            hist_pool = hist_pool.at[pair].set(pool_val.reshape(2, -1))
+                hist_pool = pool_write(hist_pool, leaf, hist_left)
+                hist_pool = pool_write(hist_pool, new_leaf, hist_right)
             # local (g,h,cnt) totals per child (voting mode votes with these;
             # any group's bins partition the rows, so group 0 sums the leaf)
             loc_parent = leaf_sum_loc[leaf]
-            loc_left = jnp.sum(hist_left[0], axis=0)
+            loc_left = jnp.sum(hist_left[:, 0], axis=1)
             loc_right = loc_parent - loc_left
             leaf_sum_loc = leaf_sum_loc.at[leaf].set(sel(loc_left, loc_parent)) \
                 .at[new_leaf].set(sel(loc_right, leaf_sum_loc[new_leaf]))
@@ -1418,24 +1461,33 @@ def build_tree_partitioned(
 
 
 def bundle_feature_view(hg: jax.Array, total_sum: jax.Array,
-                        bundle: dict) -> jax.Array:
-    """Bundled (G, Bm, 3) histogram -> per-feature (F, B, 3) view, by the
-    maps of ``BinnedDataset.bundle_maps``.
+                        bundle: dict, num_bin_hist: int) -> jax.Array:
+    """Bundled (3, G, Bp) histogram -> per-feature (3, F, B) view, both
+    channel-major, by the maps of ``BinnedDataset.bundle_maps`` (whose
+    ``proj`` counts ``num_bin_hist`` slots a device column).
 
     Each sub-feature's own bundle slots are gathered; its shared default
     bin is recovered as total - sum(own slots) — the reference's
     FixHistogram contract (include/LightGBM/dataset.h:503).
+
+    The gather moves (g, h, count) triples by slot index out of the small
+    bundled histogram (G x Bp x 12 B: 30 KB at expo.train's G = 10): a
+    gather's price on a v5e follows its index count and slice shape, not its
+    bytes (PERF.md section 6, PR 31), and one triple an index is the form
+    PR 28 measured; what it hands the scan is channel-major.
     """
     num_feat, num_bin = bundle["proj"].shape
-    flat = hg.reshape(-1, 3)
-    fh = jnp.take(flat, bundle["proj"].reshape(-1), axis=0) \
-        .reshape(num_feat, num_bin, 3)
-    fh = fh * bundle["valid"][:, :, None]
-    rest = total_sum[None, :] - jnp.sum(fh, axis=1)              # (F, 3)
+    bp = hg.shape[-1]
+    proj = bundle["proj"] // num_bin_hist * bp + bundle["proj"] % num_bin_hist
+    flat = jnp.moveaxis(hg, 0, -1).reshape(-1, 3)                # (G*Bp, 3)
+    fh = jnp.take(flat, proj.reshape(-1), axis=0).T \
+        .reshape(3, num_feat, num_bin)
+    fh = fh * bundle["valid"][None]
+    rest = total_sum[:, None] - jnp.sum(fh, axis=2)              # (3, F)
     dpos_oh = (jnp.arange(num_bin, dtype=jnp.int32)[None, :]
                == bundle["dpos"][:, None])                        # (F, B)
-    put = dpos_oh[:, :, None] & bundle["has_rest"][:, None, None]
-    return jnp.where(put, rest[:, None, :], fh)
+    put = dpos_oh & bundle["has_rest"][:, None]
+    return jnp.where(put[None], rest[:, :, None], fh)
 
 
 @partial(jax.jit, static_argnames=("has_categorical",))
@@ -2056,7 +2108,7 @@ class SerialTreeLearner:
                                          self.num_bin) else "xla",
                 part_chunk=part_chunk, hist_chunk=hist_chunk,
                 hist_pool_gb=self.num_leaves * self.bins.shape[1]
-                * self.num_bin_hist * 12 / 1e9,
+                * hist_bins(self.num_bin_hist) * 12 / 1e9,
                 work_buffer_gb=float(np.prod(
                     self._work_buf_shape(kw), dtype=np.float64)) / 1e9)
             telemetry.record("learner_path",

@@ -7,9 +7,11 @@ kernels (src/treelearner/ocl/histogram256.cl,
 src/treelearner/kernels/histogram_16_64_256.cu). Design:
 
 - The binned matrix is dense ``(rows, features)`` int8/int16 in HBM. A
-  histogram is ``(features, max_bins, 3)`` float32 of (sum_grad, sum_hess,
-  count). The count channel replaces the reference's hessian-derived
-  ``cnt_factor`` trick (feature_histogram.hpp:316) exactly.
+  histogram holds (sum_grad, sum_hess, count) float32 a (feature, bin):
+  ``(features, max_bins, 3)`` from the dense builder's ``build_histogram``,
+  channel-major ``(3, features, bins padded to 128)`` from every segment
+  histogram (``hist_bins``). The count channel replaces the reference's
+  hessian-derived ``cnt_factor`` trick (feature_histogram.hpp:316) exactly.
 - Accumulation is a one-hot × (g,h,cnt) matmul: bins one-hot encodes to
   ``(chunk, F*B)`` and a single ``(F*B, chunk) @ (chunk, 3)`` contraction
   rides the MXU. TPUs have no fast scatter-add; this keeps the hot op a
@@ -206,14 +208,50 @@ def _hist16_chunk(cb, cgm, num_bins: int, exact: bool, lo_w: int = LO_W):
                       preferred_element_type=jnp.float32)
 
 
+# ---------------------------------------------------------------------------
+# The shape a leaf's histogram has in the split loop
+# ---------------------------------------------------------------------------
+#
+# CHANNEL-MAJOR with the bins on the lanes: (3, F, Bp) f32, the planes of
+# (sum_grad, sum_hess, count), Bp = the bins padded to whole 128-lane tiles
+# (zeros past num_bins). Every segment histogram of this file, the root
+# histogram folded into the packs (ops/partition.py) and the learner's pool
+# hold it, and ops/split.py scans it plane by plane. An (F, B, 3) array,
+# channels minor, costs a v5e 0.35-0.56 ms for every op that touches 6.1 MB
+# of it (its tiles pad 3 -> 128): 822 of epsilon.train's 1,340 ms an
+# iteration before PR 36 (PERF.md section 6). The dense builder
+# (build_histogram, learner.build_tree) keeps (F, B, C).
+
+
+def hist_bins(num_bins: int) -> int:
+    """Bins of the channel-major histogram: whole 128-lane tiles."""
+    return -(-int(num_bins) // 128) * 128
+
+
+def hist_planes(h: jax.Array) -> jax.Array:
+    """(F, B, 3) -> the loop's (3, F, hist_bins(B))."""
+    b = h.shape[1]
+    return jnp.pad(jnp.moveaxis(h, -1, 0),
+                   ((0, 0), (0, 0), (0, hist_bins(b) - b)))
+
+
+def hist_fb3(h: jax.Array, num_bins: int) -> jax.Array:
+    """The loop's (3, F, Bp) -> (F, num_bins, 3): the dense builder's shape,
+    for the tests' oracles and the references."""
+    return jnp.moveaxis(h[..., :num_bins], 0, -1)
+
+
 def _hist16_combine(acc, num_bins: int, exact: bool, lo_w: int = LO_W):
+    """(F, SH, lo_w*NCH) chunk accumulator -> (3, F, hist_bins(num_bins))."""
     f, sh, _ = acc.shape
     nch = 5 if exact else 3
     h = acc.reshape(f, sh, lo_w, nch).reshape(f, sh * lo_w, nch)[:, :num_bins]
     if exact:
-        return jnp.stack([h[..., 0] + h[..., 1],
-                          h[..., 2] + h[..., 3], h[..., 4]], axis=-1)
-    return h
+        h = jnp.stack([h[..., 0] + h[..., 1],
+                       h[..., 2] + h[..., 3], h[..., 4]], axis=0)
+    else:
+        h = jnp.moveaxis(h, -1, 0)
+    return jnp.pad(h, ((0, 0), (0, 0), (0, hist_bins(num_bins) - num_bins)))
 
 
 def _hist16_chunk_int8(cb, gq, hq, cnt, valid, num_bins: int,
@@ -239,7 +277,7 @@ def _hist16_chunk_int8(cb, gq, hq, cnt, valid, num_bins: int,
 def hist16_segment_q(work: jax.Array, plane, start, cnt, gscale, hscale, *,
                      num_bins: int, num_feat: int,
                      chunk: int = 2048, lo_w: int = 0) -> jax.Array:
-    """int8-quantized segment histogram -> dequantized (F, num_bins, 3) f32.
+    """int8-quantized segment histogram -> dequantized (3, F, Bp) f32.
 
     work rows are (F + 3) u8: bins then int8 g, int8 h, u8 cnt
     (ops/partition.py pack_rows_quantized). int32 accumulation bounds rows
@@ -269,7 +307,7 @@ def hist16_segment_q(work: jax.Array, plane, start, cnt, gscale, hscale, *,
     h = acc.reshape(f, sh, lo_w, 3).reshape(f, sh * lo_w, 3)[:, :num_bins]
     scale = jnp.stack([1.0 / gscale, 1.0 / hscale,
                        jnp.float32(1.0)])
-    return h.astype(jnp.float32) * scale[None, None, :]
+    return hist_planes(h.astype(jnp.float32) * scale[None, None, :])
 
 
 # ---------------------------------------------------------------------------
@@ -453,7 +491,7 @@ def hist16_segment(work: jax.Array, plane, start, cnt, *,
                    num_bins: int, num_feat: int, exact: bool = True,
                    chunk: int = 2048, lo_w: int = 0) -> jax.Array:
     """Histogram of physical rows [start, start+cnt) of ping-pong plane
-    ``plane`` -> (F, num_bins, 3).
+    ``plane`` -> (3, F, Bp), channel-major (``hist_bins``).
 
     work: (2, Npad, F+12) u8 packed working buffers (ops/partition.py
     pack_rows): bins columns followed by (g, h, cnt) f32 bytes, already
@@ -618,15 +656,17 @@ def planes_kernel_params(num_feat: int, num_bins: int, lo_w: int = 0,
     """Static shape of the planes kernel, from what it can observe:
     ``(lo_w, shp, g, chunk)``.
 
-    bin = lo_w * hi + lo. The hi one-hots of ``g`` consecutive features
-    stack to one (g * shp = 128, chunk) MXU operand (``shp`` = the hi range
-    padded to a power of two >= 16, whole bf16 sublane tiles), so one pass
-    of 128 rows serves g features, not one. lo_w 4 (g = 2 at 256 bins) at
-    every width: the lo x channel operand's rows (5 lo_w a feature) cost
-    2.5 x what the hi one-hot's (256 / lo_w) do, see the kernel. The chunk
-    (lanes a DMA) is the caller's: the work buffer's guard must cover it
-    (``learner.build_kwargs`` derives it from F, ``partition.work_spec``
-    the guard)."""
+    bin = shp * l + m. The one-hots of the LOW digit m (``shp`` values: the
+    bins over ``lo_w``, padded to a power of two >= 16, whole bf16 sublane
+    tiles) of ``g`` consecutive features stack to one (g * shp = 128, chunk)
+    MXU operand, so one pass of 128 rows serves g features, not one, and
+    ``shp`` consecutive bins of a feature come out side by side on the
+    lanes; the high digit l (``lo_w`` values) rides the channel operand.
+    lo_w 4 (g = 2 at 256 bins) at every width: the digit x channel
+    operand's rows (5 lo_w a feature) cost 2.5 x what the one-hot's (256 /
+    lo_w) do, see the kernel. The chunk (lanes a DMA) is the caller's: the
+    work buffer's guard must cover it (``learner.build_kwargs`` derives it
+    from F, ``partition.work_spec`` the guard)."""
     lo_w = lo_w or 4
     shp = max(16, _ceil_pow2((num_bins + lo_w - 1) // lo_w))
     if shp > 128 or (128 // shp * lo_w) % 8:
@@ -653,20 +693,22 @@ def planes_kernel_chunk(num_feat: int) -> int:
 
     A chunk holds 10 B a plane-lane in VMEM (``cin`` twice, ``bins_s`` and
     the i32 chunk value) beside the (F / 2, 40, 128) f32 accumulator, 10 KB
-    a feature. The chunk halves until those buffers are under 24 MiB (4096
-    to W = 608, 2048 to 1,216, 1024 to 2,432, ...) and, with the
-    accumulator, under the kernel's limit less 4 MiB. PR 33, standalone at
+    a feature, and the (3, F, 256) planes the kernel's last step makes of
+    it, 3 KB a feature (PR 36). The chunk halves until the chunk's buffers
+    are under 24 MiB (4096 to W = 608, 2048 to 1,216, 1024 to 2,432, ...)
+    and, with the accumulator and the planes, under the kernel's limit less
+    4 MiB. PR 33, standalone at
     F = 2,000 on segments of 399K / 25K / 1.5K rows: 4096 reads 38.4 / 3.60
     / 1.32 ms a call, 2048 41.2 / 3.61 / 1.14, 1024 47.4 / 3.91 / 1.17, 512
     59.1 / 4.58 / 1.23: a tree of 400,000 rows makes 254 segments, three
     quarters of them under 6K rows, and a segment pays whole chunks. F =
     300 and 500 keep 4096 (0.047 against 2048's 0.051 on long segments).
-    The widest table that fits is F = 8,734."""
+    The widest table that fits is F = 6,896 (8,734 before the planes)."""
     from .partition import work_spec
     if num_feat <= 64:
         return 8192
     w = work_spec(num_feat, False, "pallas", 0, 0, layout="planes")[1]
-    acc = -(-num_feat // 2) * 40 * 128 * 4
+    acc = -(-num_feat // 2) * 40 * 128 * 4 + 8 * -(-num_feat // 8) * 3 * 256 * 4
     chunk = 4096
     while chunk >= 128 and (
             10 * w * chunk > PLANES_HIST_CHUNK_BYTES
@@ -713,32 +755,40 @@ def root_einsum_chunk(num_feat: int, hist_chunk: int) -> int:
     return _fit_einsum_operands(hist_chunk, num_feat)
 
 
-def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
-                               acc_s, sem, *, ch, num_feat, shp, lo_w, g,
-                               nch, dt):
+def _hist_pallas_kernel_planes(sref, work_in, work_ref, hist_ref, cin, bins_s,
+                               acc_s, out_s, sem, *, ch, num_feat, shp, lo_w,
+                               g, nch, dt):
     # One chunk DMA is a contiguous (W, ch) lane slice of the plane-major
     # work buffer: bins arrive as whole per-feature sublane rows, the f32
     # channels re-assemble from 4 byte PLANES each. work_ref is never
     # written: it only keeps the donated buffer from being copied.
     #
-    # Per chunk and per GROUP of g features one MXU contraction over the
-    # chunk's rows (lanes):
-    #   out[(c, j, l), (j', hi)] = sum_rows LoCh[(c, j, l), row]
-    #                                       * HiOH[(j', hi), row]
+    # bin = shp * l + m. Per chunk and per GROUP of g features one MXU
+    # contraction over the chunk's rows (lanes):
+    #   acc[(c, j, l), (j', m)] = sum_rows DigitCh[(c, j, l), row]
+    #                                      * OneHot[(j', m), row]
     # whose g diagonal blocks (j == j') are the g features' histograms;
     # the off-diagonal cells are cells the pass computed anyway. The whole
-    # product accumulates in VMEM; the diagonal is taken once per segment,
-    # outside (hist_pallas_segment_planes).
+    # product accumulates in VMEM. The last step (``finish``) takes the
+    # diagonal and the channel pairs' sums there too and leaves the (3, F,
+    # Bp) planes, bins on the lanes: shp consecutive bins of a feature sit
+    # side by side in a row of acc already, so a feature's row of bins is
+    # lo_w lane-shifted pieces, and no array with the channels or a digit
+    # minor ever reaches HBM (6.1 MB a call at F = 2,000 where the
+    # accumulator is 20.5; before PR 36 XLA took it apart in four ops of
+    # 0.4-0.56 ms each, PERF.md section 6).
     #
     # Measured on a v5e (my chip run, PR 29), standalone on 1-2M-row
     # segments, ns per (row, feature): 0.077 / 0.060 / 0.120 at F = 28 /
     # 137 / 10 (lo_w 4, 4096 lanes a chunk) against the XLA loop's 0.224 /
     # 0.264 / 0.32 and the one-feature-a-pass kernel's 0.115 / 0.064 /
-    # 0.167. A row of the lo x channel operand (the one the MXU streams)
-    # costs ~0.0013 ns a row of data, a row of the hi one-hot (the one it
+    # 0.167. A row of the digit x channel operand (the one the MXU streams)
+    # costs ~0.0013 ns a row of data, a row of the one-hot (the one it
     # latches) ~0.0005: lo_w 4 (20 + 64 operand rows a feature) beats 8
-    # (40 + 32: 0.087 / 0.071) and 16 (80 + 16: 0.16 / 0.13). Building the
-    # hi one-hot as packed bf16 pairs (one compare for two rows) and
+    # (40 + 32: 0.087 / 0.071) and 16 (80 + 16: 0.16 / 0.13); which digit
+    # of the bin rides which operand is free (the measurements were made
+    # with the high digit latched). Building the
+    # one-hot as packed bf16 pairs (one compare for two rows) and
     # assembling the channel words on the MXU changed nothing (0.075 /
     # 0.060): the element's arithmetic is not the cost. The chunk DMA alone
     # runs at 0.9 ns a row (W <= 64; 1.8 at W = 160), hidden behind the
@@ -751,7 +801,7 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
     cnt = sref[2]
     F = num_feat
     glw = g * lo_w
-    shift = _LO_SHIFT[lo_w]
+    shift = shp.bit_length() - 1
 
     astart = (start // 128) * 128
     head = start - astart
@@ -785,7 +835,7 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
             + gb[o + 3:o + 4] * 16777216, f32)
 
     def lo_rows(lo8, j0):
-        """(glw, ch): row j * lo_w + l holds feature j0 + j's lo digit."""
+        """(glw, ch): row j * lo_w + l holds feature j0 + j's high digit."""
         rows = [lo8[j0 + j:j0 + j + 1] for j in range(g)]
         if lo_w % 8 == 0:
             return jnp.concatenate(
@@ -815,8 +865,8 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
             preferred_element_type=f32)                 # (nch*glw, g*shp)
 
     def digits(b8):
-        # >> and & for // lo_w and % lo_w (shifts under 16 are safe here)
-        return b8 >> shift, b8 & (lo_w - 1)
+        # (m, l) of bin = shp * l + m (shifts under 16 are safe here)
+        return b8 & (shp - 1), b8 >> shift
 
     nblk = F // 8
     per_blk = 8 // g
@@ -866,7 +916,52 @@ def _hist_pallas_kernel_planes(sref, work_in, work_ref, acc_ref, cin, bins_s,
         return carry
 
     jax.lax.fori_loop(0, nchunks, body, 0)
-    out_cp = pltpu.make_async_copy(acc_s, acc_ref, sem.at[0])
+
+    # ---- the planes of the accumulator, one 8-feature block a step ----
+    bp = out_s.shape[2]
+    seg = 128 // shp                    # pieces a 128-lane tile of bins
+    # (sublane, piece) code of every cell of an (8, 128) tile
+    code = jax.lax.broadcasted_iota(i32, (8, 128), 0) * seg \
+        + jax.lax.broadcasted_iota(i32, (8, 128), 1) // shp
+
+    def finish(b, nq):
+        # features 8 b .. 8 b + g nq - 1 (groups b * per_blk ..) -> out_s
+        tiles = [[jnp.zeros((8, 128), f32) for _ in range(bp // 128)]
+                 for _ in range(3)]
+        for q in range(nq):
+            a = acc_s[b * per_blk + q]                  # (nch * glw, 128)
+            if nch == 5:
+                xs = [a[:glw] + a[glw:2 * glw],
+                      a[2 * glw:3 * glw] + a[3 * glw:4 * glw], a[4 * glw:]]
+            else:
+                xs = [a[c * glw:(c + 1) * glw] for c in range(3)]
+            for c, x in enumerate(xs):
+                # piece (j, l): row j * lo_w + l, lanes shp * j .. + shp,
+                # goes to row q * g + j, bins shp * l .. + shp: seg lane
+                # shifts serve every piece of x
+                rolled = [x] + [pltpu.roll(x, k * shp, 1)
+                                for k in range(1, seg)]
+                for j in range(g):
+                    for l in range(min(lo_w, bp // shp)):
+                        t, d = divmod(l, seg)
+                        r = j * lo_w + l
+                        row = jnp.broadcast_to(
+                            rolled[(d - j) % seg][r:r + 1], (8, 128))
+                        tiles[c][t] = jnp.where(
+                            code == (q * g + j) * seg + d, row, tiles[c][t])
+        at = b * 8 if isinstance(b, int) else pl.multiple_of(b * 8, 8)
+        for c in range(3):
+            for t in range(bp // 128):
+                out_s[c, pl.ds(at, 8), t * 128:(t + 1) * 128] = tiles[c][t]
+
+    if nblk:
+        def fin(b, carry):
+            finish(b, per_blk)
+            return carry
+        jax.lax.fori_loop(0, nblk, fin, 0)
+    if tail:
+        finish(nblk, tail)
+    out_cp = pltpu.make_async_copy(out_s, hist_ref, sem.at[0])
     out_cp.start()
     out_cp.wait()
 
@@ -880,9 +975,11 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
     multiple of 32 sublanes, lane starts 128-aligned +/- head, chunk a
     multiple of 128.
 
-    Returns ``(hist, work)`` — callers MUST continue with the returned work
-    buffer: it is byte-identical but aliased through the call, which is
-    what keeps XLA from copying the whole buffer defensively per histogram.
+    Returns ``(hist, work)``, ``hist`` the channel-major (3, F, Bp) planes
+    (``hist_bins``) as the kernel wrote them — callers MUST continue with
+    the returned work buffer: it is byte-identical but aliased through the
+    call, which is what keeps XLA from copying the whole buffer defensively
+    per histogram.
     Runs under the pallas interpreter off-TPU (LGBTPU_PALLAS_INTERPRET=1)
     with f32 operands. Same operands and f32 accumulation as the XLA path,
     another ORDER of additions (the MXU's grouping of the contraction):
@@ -912,6 +1009,7 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
             "hist_pallas_segment_planes reads bins in blocks of 8 planes: "
             "F=%d needs W >= %d, got %d" % (f, fp8, nplanes))
     acc_shape = (ngrp, nch * g * lo_w, g * shp)
+    out_shape = (3, fp8, hist_bins(num_bins))
     kern = partial(_hist_pallas_kernel_planes, ch=chunk, num_feat=f, shp=shp,
                    lo_w=lo_w, g=g, nch=nch, dt=_mxu_dtype())
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -924,26 +1022,24 @@ def hist_pallas_segment_planes(work: jax.Array, plane, start, cnt, *,
             pltpu.VMEM((2, nplanes, chunk), jnp.uint8),
             pltpu.VMEM((fp8, chunk), jnp.int32),
             pltpu.VMEM(acc_shape, jnp.float32),
+            pltpu.VMEM(out_shape, jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     scalars = jnp.stack([plane.astype(jnp.int32), start.astype(jnp.int32),
                          cnt.astype(jnp.int32)])
-    work_out, acc = pl.pallas_call(
+    work_out, h = pl.pallas_call(
         kern,
         name="hist_pallas_segment_planes",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(work.shape, work.dtype),
-                   jax.ShapeDtypeStruct(acc_shape, jnp.float32)],
+                   jax.ShapeDtypeStruct(out_shape, jnp.float32)],
         input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=PLANES_HIST_VMEM),
         interpret=_INTERPRET,
     )(scalars, work)
-    # the g diagonal blocks: (grp, c, j, l, j', hi) at j == j'
-    a6 = acc.reshape(ngrp, nch, g, lo_w, g, shp)
-    d = jnp.stack([a6[:, :, j, :, j, :] for j in range(g)], axis=1)
-    sh = (num_bins + lo_w - 1) // lo_w
-    h = d.transpose(0, 1, 4, 3, 2).reshape(ngrp * g, shp, lo_w * nch)
-    return _hist16_combine(h[:f, :sh], num_bins, exact, lo_w), work_out
+    # rows past F (the last block's) hold what the g/h/c byte planes read
+    # as bins: cut off
+    return h[:, :f], work_out

@@ -323,8 +323,9 @@ def pack_planes_fold_root(work: jax.Array, bins: jax.Array, ghc: jax.Array,
     hist16_segment(work, 0, guard, n) exactly, so the folded histogram is
     bit-identical to the rows path's root pass.
 
-    Returns (work, (F, num_bins, 3) root histogram) — LOCAL, callers
-    reduce via comm.hist like any other segment histogram.
+    Returns (work, (3, F, Bp) root histogram), channel-major like every
+    segment histogram (ops/histogram.py hist_bins) — LOCAL, callers reduce
+    via comm.hist like any other segment histogram.
     """
     from .histogram import _hist16_chunk, _hist16_combine, auto_lo_w
 

@@ -210,8 +210,18 @@ def _stable_rank(keys: jax.Array, b_iota: jax.Array) -> jax.Array:
     return jnp.sum(before, axis=-1, dtype=jnp.int32)
 
 
-def find_best_split(
-    hist: jax.Array,          # (F, B, 3) f32
+def find_best_split(hist: jax.Array, parent_sum: jax.Array,
+                    meta: FeatureMeta, feature_mask: jax.Array,
+                    hp: SplitHyper, **kw):
+    """:func:`find_best_split_planes` over the dense builder's ``(F, B, 3)``
+    histogram (``learner.build_tree``, the tests, the references): the
+    channels move to the front once, same search, same bits."""
+    return find_best_split_planes(jnp.moveaxis(hist, -1, 0), parent_sum,
+                                  meta, feature_mask, hp, **kw)
+
+
+def find_best_split_planes(
+    hist: jax.Array,          # (3, F, B) f32: the g, h, count planes
     parent_sum: jax.Array,    # (3,)
     meta: FeatureMeta,
     feature_mask: jax.Array,  # (F,) bool — col sampling / interaction constraints
@@ -228,29 +238,35 @@ def find_best_split(
     # per-candidate child bounds (reference: monotone_constraints.hpp:856
     # AdvancedLeafConstraints — per-threshold constraints in the scan)
 ) -> SplitInfo:
-    """Best split over all features for one leaf's histogram.
+    """Best split over all features for one leaf's histogram, CHANNEL-MAJOR:
+    ``hist[0]``, ``hist[1]``, ``hist[2]`` are the (F, B) planes of gradient
+    sums, hessian sums and counts, bins on the minor axis (the split loop's
+    shape, ``ops/histogram.py`` ``hist_bins``, cut to the B feature bins).
+    Nothing in here builds an array with the channels minor.
 
     With ``want_feature_gains`` (static), returns only the per-feature max
     gains (F,) — the voting-parallel learner's local vote input (reference:
     voting_parallel_tree_learner.cpp:322 local top-k votes)."""
     with scan_phase(hp, inside=True):
-        num_feat, num_bin, _ = hist.shape
+        _, num_feat, num_bin = hist.shape
         b_iota = jnp.arange(num_bin, dtype=jnp.int32)
         bin_valid = b_iota[None, :] < meta.num_bins[:, None]            # (F, B)
-        hist = jnp.where(bin_valid[:, :, None], hist, 0.0)
+        hist = jnp.where(bin_valid[None], hist, 0.0)
         parent_gain = leaf_objective_value(parent_sum[0], parent_sum[1], hp)
 
         # ---------- numerical thresholds ----------
         is_missing_bin = meta.movable_missing[:, None] & (b_iota[None, :] == meta.missing_bin[:, None])
-        miss = jnp.sum(jnp.where(is_missing_bin[:, :, None], hist, 0.0), axis=1)   # (F, 3)
-        hist_nm = jnp.where(is_missing_bin[:, :, None], 0.0, hist)
-        cum = jnp.cumsum(hist_nm, axis=1)                                # (F, B, 3)
-        total = parent_sum[None, None, :]
+        miss = jnp.sum(jnp.where(is_missing_bin[None], hist, 0.0), axis=2)     # (3, F)
+        hist_nm = jnp.where(is_missing_bin[None], 0.0, hist)
+        cum = jnp.cumsum(hist_nm, axis=2)                                # (3, F, B)
+        # (g, h, count) of the node, against planes with any leading axes
+        total = parent_sum[:, None, None, None]
 
         def eval_dir(left):
+            # left: (3, D, F, B), D the candidates stacked behind the planes
             right = total - left
-            gl, hl, cl = left[..., 0], left[..., 1], left[..., 2]
-            gr, hr, cr = right[..., 0], right[..., 1], right[..., 2]
+            gl, hl, cl = left
+            gr, hr, cr = right
             gain, _, _, ok = _split_gain_pair(
                 gl, hl, cl, gr, hr, cr, hp,
                 parent_output=parent_output, lower=leaf_lower, upper=leaf_upper,
@@ -269,7 +285,7 @@ def find_best_split(
             # extra-trees: only one random threshold per feature is considered
             # (reference: USE_RAND_SPLIT in FindBestThresholdSequentially)
             t_valid = t_valid & (b_iota[None, :] == rand_threshold[:, None])
-        gains2 = eval_dir(jnp.stack([cum, cum + miss[:, None, :]], axis=0))
+        gains2 = eval_dir(jnp.stack([cum, cum + miss[:, :, None]], axis=1))
         # nothing to gain from dl when there is no missing mass; keep dr on ties
         gains2 = jnp.where(
             jnp.stack([t_valid, t_valid & meta.movable_missing[:, None]], axis=0),
@@ -284,20 +300,19 @@ def find_best_split(
             extra_l2 = hp.cat_l2
             # candidate categories exclude the trailing other/missing bin
             cat_bin_ok = meta.is_categorical[:, None] & (b_iota[None, :] < meta.num_bins[:, None] - 1)
-            g_b, h_b, c_b = hist[..., 0], hist[..., 1], hist[..., 2]
+            g_b, h_b, c_b = hist
 
             # one-vs-rest (reference: one-hot when #cats <= max_cat_to_onehot)
             num_cats = meta.num_bins - 1
             use_onehot = meta.is_categorical & (num_cats <= hp.max_cat_to_onehot)
             left = hist
-            right = total - left
+            right = total[:, 0] - left
             oh_gain, _, _, _ = _split_gain_pair(
-                left[..., 0], left[..., 1], left[..., 2],
-                right[..., 0], right[..., 1], right[..., 2], hp,
+                left[0], left[1], left[2], right[0], right[1], right[2], hp,
                 extra_l2=extra_l2, parent_output=parent_output)
-            oh_ok = (left[..., 2] >= hp.min_data_in_leaf) & (right[..., 2] >= hp.min_data_in_leaf) \
-                & (left[..., 1] >= hp.min_sum_hessian_in_leaf) \
-                & (right[..., 1] >= hp.min_sum_hessian_in_leaf) \
+            oh_ok = (left[2] >= hp.min_data_in_leaf) & (right[2] >= hp.min_data_in_leaf) \
+                & (left[1] >= hp.min_sum_hessian_in_leaf) \
+                & (right[1] >= hp.min_sum_hessian_in_leaf) \
                 & cat_bin_ok & use_onehot[:, None] & (c_b > 0)
             oh_gain = jnp.where(oh_ok, oh_gain - parent_gain, NEG_INF)
 
@@ -323,10 +338,10 @@ def find_best_split(
                     # the bin of rank k, by a one-hot select: one term a sum
                     at = rank2[:, :, None, :] == b_iota[None, None, :, None]
                     h_sorted = jnp.sum(jnp.where(
-                        at[..., None], hist[None, :, None, :, :], 0.0), axis=3)
+                        at[None], hist[:, None, :, None, :], 0.0), axis=4)
                 else:
-                    h_sorted = jnp.take_along_axis(hist[None], order2[..., None],
-                                                   axis=2)
+                    h_sorted = jnp.take_along_axis(hist[:, None], order2[None],
+                                                   axis=3)             # (3, 2, F, B)
                 # prefix of k+1 bins: a product with a triangle of ones (the MXU
                 # in f32, the same op for both forms) where a cumsum over 256
                 # becomes a two-level reduce-window that XLA gives no op_name
@@ -335,20 +350,19 @@ def find_best_split(
                 # runs, PR 35; the gains sit as close to the float64
                 # reference either way)
                 csum = jnp.einsum(
-                    "kj,dfjc->dfkc", (b_iota[:, None] >= b_iota[None, :])
+                    "kj,cdfj->cdfk", (b_iota[:, None] >= b_iota[None, :])
                     .astype(jnp.float32), h_sorted,
                     precision=jax.lax.Precision.HIGHEST)
                 k1 = b_iota[None, :] + 1.0                               # prefix size
                 left = csum
                 right = total - left
                 gain, _, _, _ = _split_gain_pair(
-                    left[..., 0], left[..., 1], left[..., 2],
-                    right[..., 0], right[..., 1], right[..., 2], hp,
+                    left[0], left[1], left[2], right[0], right[1], right[2], hp,
                     extra_l2=extra_l2, parent_output=parent_output)
                 ok = (k1 <= hp.max_cat_threshold) & (k1 < n_groups[:, None]) \
-                    & (left[..., 2] >= hp.min_data_in_leaf) & (right[..., 2] >= hp.min_data_in_leaf) \
-                    & (left[..., 1] >= hp.min_sum_hessian_in_leaf) \
-                    & (right[..., 1] >= hp.min_sum_hessian_in_leaf)
+                    & (left[2] >= hp.min_data_in_leaf) & (right[2] >= hp.min_data_in_leaf) \
+                    & (left[1] >= hp.min_sum_hessian_in_leaf) \
+                    & (right[1] >= hp.min_sum_hessian_in_leaf)
                 return jnp.where(ok, gain - parent_gain, NEG_INF)
 
             mvm_asc, mvm_desc = mvm_gains()
@@ -426,7 +440,7 @@ def find_best_split(
         go_left, default_left = tbl_numerical()
 
     with scan_phase(hp, inside=True):
-        left_sum = jnp.sum(jnp.where(go_left[None, :, None], hist[feat][None], 0.0), axis=(0, 1))
+        left_sum = jnp.sum(jnp.where(go_left[None, :], hist[:, feat], 0.0), axis=1)
         right_sum = parent_sum - left_sum
         is_cat_win = kind > 0
         extra = jnp.where(is_cat_win, hp.cat_l2, 0.0)

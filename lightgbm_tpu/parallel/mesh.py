@@ -13,7 +13,7 @@ src/network/network.cpp). The mapping (SURVEY.md §2.3):
   * data-parallel: rows sharded, per-leaf histograms psum'd, every shard
     derives the same split (histogram ReduceScatter + best-split argmax
     sync fold into one collective, data_parallel_tree_learner.cpp:155-251).
-    Comm per split round: one (G, Bm, 3) f32 allreduce of the smaller
+    Comm per split round: one (3, G, Bp) f32 allreduce of the smaller
     child's histogram.
   * feature-parallel: rows replicated, the split SEARCH is sharded by
     feature ownership and only the winning SplitInfo is argmax-synced

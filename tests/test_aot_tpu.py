@@ -14,6 +14,7 @@ libtpu takes ``/tmp/libtpu_lockfile``: one such process at a time.
 import importlib.util
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -203,6 +204,16 @@ WIDE = {300: ("planes", "pallas", "pallas", "pallas_stream"),
         2000: ("planes", "pallas", "pallas", "pallas_wide")}
 
 
+def _compile_wide_block(topo, f):
+    """A dense table of ``f`` columns under epsilon.train's parameters."""
+    rng = np.random.RandomState(f)
+    X = rng.randn(20_000, f).astype(np.float32)
+    ds = lgb.Dataset(X, label=(X[:, :8].sum(axis=1) > 0).astype(np.float32))
+    return compile_block(topo, ds, {
+        "objective": "binary", "min_data_in_leaf": 1,
+        "min_sum_hessian_in_leaf": 100})
+
+
 @pytest.mark.parametrize("f", sorted(WIDE))
 def test_wide_table_block_compiles(topo, f):
     """Until PR 32 the router held a block of EVERY column in VMEM twice and
@@ -213,22 +224,71 @@ def test_wide_table_block_compiles(topo, f):
     compile follows the width, not the rows, so 20,000 rows stand for
     epsilon.train's 400,000."""
     from lightgbm_tpu.obs import telemetry
-    rng = np.random.RandomState(f)
-    X = rng.randn(20_000, f).astype(np.float32)
-    ds = lgb.Dataset(X, label=(X[:, :8].sum(axis=1) > 0).astype(np.float32))
     telemetry.reset()
-    kw, c = compile_block(topo, ds, {
-        "objective": "binary", "min_data_in_leaf": 1,
-        "min_sum_hessian_in_leaf": 100})
+    kw, c = _compile_wide_block(topo, f)
     rec = telemetry.records("learner_path")[-1]
     assert resolved(kw) + (rec["route_kernel"],) == WIDE[f]
     assert rec["packed_row_bytes"] == f + 12
+    assert kw["num_bin_hist"] == 255
     assert rec["hist_pool_gb"] == pytest.approx(
-        255 * f * kw["num_bin_hist"] * 12 / 1e9)     # 255 bins a column
+        255 * f * 256 * 12 / 1e9)     # 255 bins a column, padded to 256
     text = c.as_text()
     assert "lgbtpu/route/route_rows" in text
     assert "partition_segment_planes_fused" in text
     assert "hist_pallas_segment_planes" in text
+
+
+_DT_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "u8": 1,
+             "s8": 1, "pred": 1}
+
+
+def _arrays_of(line):
+    """(dtype, dims, minor_to_major) of every array in an HLO instruction's
+    result type (a fusion's may be a tuple)."""
+    m = re.match(r"^(\(.*?\)|\S+) [\w\-]+\(", line.split(" = ", 1)[1])
+    return [(dt, [int(d) for d in dims.split(",")],
+             [int(d) for d in lay.split(",")])
+            for dt, dims, lay in re.findall(
+                r"(\w+)\[([\d,]+)\]\{([\d,]+)", m.group(1) if m else "")
+            if dt in _DT_BYTES]
+
+
+def test_wide_block_keeps_bins_on_the_lanes_and_one_pool(topo):
+    """What PR 36 bought, read in the compiled text with no chip: until then
+    822 of epsilon.train's 1,340 device ms an iteration were six XLA ops a
+    split (``copy``, ``reshape``) that carried the 6.1 MB histogram between
+    the kernel, the pool and the scan through tiles padded 3 -> 128,
+    because (F, B, 3) arrays keep the channels minor. In the F = 2,000
+    block no instruction over 1 MB under ``lgbtpu/histogram`` or
+    ``lgbtpu/tree_state`` may have a layout whose minor dimension is
+    narrower than 128, and the split loop carries ONE pool that nothing
+    copies (a slice of the pool fused into both children's writes made XLA
+    copy all 1.57 GB of it twice a split). Keeps the next kernel or pool PR
+    from bringing the conversions back."""
+    f = 2000
+    text = _compile_wide_block(topo, f)[1].as_text()
+    pool = "f32[255,3,%d,256]" % f
+    seen, narrow = 0, []
+    for ln in text.splitlines():
+        scope = re.search(r'op_name="[^"]*?lgbtpu/(\w+)', ln)
+        if " = " not in ln or not scope \
+                or scope.group(1) not in ("histogram", "tree_state"):
+            continue
+        for dt, dims, lay in _arrays_of(ln):
+            if _DT_BYTES[dt] * int(np.prod(dims)) <= 1 << 20:
+                continue
+            seen += 1
+            if dims[lay[0]] < 128:
+                narrow.append(ln.strip()[:200])
+    # the kernel's planes, the parent's row, both children's writes
+    assert seen >= 4 and not narrow, narrow
+    assert "f32[3,%d,256]" % f in text and "f32[%d,255,3]" % f not in text
+    whiles = [ln for ln in text.splitlines()
+              if re.search(r" while\(", ln) and pool in ln]
+    assert whiles and all(
+        ln.split(" while(")[0].count(pool) == 1 for ln in whiles)
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= %s\S* copy\(" % re.escape(pool), ln)]
 
 
 def test_planes_kernels_compile_alone_at_2016_planes(topo):
@@ -242,10 +302,10 @@ def test_planes_kernels_compile_alone_at_2016_planes(topo):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
     i32 = sds((), jnp.int32)
-    for f, n in ((2000, 400_000), (8734, 20_000)):
+    for f, n in ((2000, 400_000), (6896, 20_000)):
         chunk = histogram.planes_kernel_chunk(f)
         ch = partition.planes_part_chunk(f + 12)
-        assert (chunk, ch) == {2000: (1024, 256), 8734: (128, 256)}[f]
+        assert (chunk, ch) == {2000: (1024, 256), 6896: (128, 256)}[f]
         guard, w = partition.work_spec(f, False, "pallas", ch, chunk,
                                        layout="planes")
         work = sds((2, w, partition.planes_npad(n, guard, "pallas")),
@@ -257,7 +317,7 @@ def test_planes_kernels_compile_alone_at_2016_planes(topo):
                 partition.partition_segment_planes_fused(
                     wk, p, s, c, ft, tb, ch=ch)).lower(
             work, i32, i32, i32, i32, sds((256,), jnp.bool_)).compile()
-    assert histogram.planes_kernel_chunk(8735) == 0
+    assert histogram.planes_kernel_chunk(6897) == 0
 
 
 def test_data_parallel_build_compiles_on_four_devices(topo, ds_binary,

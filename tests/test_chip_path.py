@@ -424,8 +424,10 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
     assert lrn.hp.has_categorical == (name == "categorical_f8")
     assert rec["packed_row_bytes"] == f + (
         P.GH_BYTES_Q if kw["hist_mode"] == "int8" else P.GH_BYTES)
+    # the pool's rows are channel-major (3, F, bins padded to 128 lanes)
+    from lightgbm_tpu.ops.histogram import hist_bins
     assert rec["hist_pool_gb"] == pytest.approx(
-        4 * f * lrn.num_bin_hist * 12 / 1e9)
+        4 * f * hist_bins(lrn.num_bin_hist) * 12 / 1e9)
     assert rec["work_buffer_gb"] == pytest.approx(
         np.prod(lrn.work_buf_spec()[0], dtype=np.float64) / 1e9)
     assert kw["hist_mode"] == (
@@ -442,8 +444,9 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
     (300, 4096, 1024, 1024), (500, 4096, 1024, 1024),
     # epsilon.train: the chip chose at W = 2,016 (PR 33)
     (2000, 1024, 256, 256),
-    # the widest table whose accumulator leaves VMEM a chunk, and past it
-    (8734, 128, 128, 256), (8735, 0, 128, 256),
+    # the widest table whose accumulator and output planes (PR 36) leave
+    # VMEM a chunk, and past it
+    (6896, 128, 128, 256), (6897, 0, 128, 256),
 ])
 def test_chunks_follow_the_width(f, kernel_chunk, root_chunk, part):
     """The three static rules of the planes path: the histogram kernel's

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.learner import bundle_feature_view
+from lightgbm_tpu.ops.histogram import hist_fb3, hist_planes
 from lightgbm_tpu.obs import telemetry
 
 pytest.importorskip("scipy.sparse")
@@ -142,8 +143,10 @@ def test_feature_view_equals_histograms_of_the_raw_columns(efb, table, clean):
                                         minlength=Bm)
     total = ghc.sum(axis=0)
     maps = {k: jnp.asarray(v) for k, v in binned.bundle_maps().items()}
-    view = np.asarray(bundle_feature_view(
-        jnp.asarray(hist, jnp.float32), jnp.asarray(total, jnp.float32), maps))
+    # the loop's channel-major (3, G, Bp) in, (3, F, B) out
+    view = np.asarray(hist_fb3(bundle_feature_view(
+        hist_planes(jnp.asarray(hist, jnp.float32)),
+        jnp.asarray(total, jnp.float32), maps, Bm), maps["proj"].shape[1]))
     raw = efb.raw_feature_histograms(efb.csc_of(table["X"]), gm, ghc)
     fixed = efb.feature_histograms(hist, total, gm)
     # the reference's two routes agree to float64 rounding

@@ -8,8 +8,8 @@ import pytest
 
 from lightgbm_tpu.ops import partition as P
 from lightgbm_tpu.ops.histogram import (
-    build_histogram_jit, build_histogram_np, hist16_segment_planes,
-    hist_pallas_segment_planes)
+    build_histogram_jit, build_histogram_np, hist16_segment_planes, hist_bins,
+    hist_fb3, hist_pallas_segment_planes)
 
 CH = 256
 
@@ -106,19 +106,20 @@ def test_hist_pallas_planes_kernel_interpret(rng, n, f, num_bin, start, cnt,
     a = (jnp.int32(0), jnp.int32(guard + start), jnp.int32(cnt))
     got, work_out = hist_pallas_segment_planes(
         work, *a, num_bins=num_bin, num_feat=f, chunk=chunk, lo_w=lo_w)
-    got = np.asarray(got)
+    assert got.shape == (3, f, hist_bins(num_bin))
+    assert not np.asarray(got)[..., num_bin:].any()
+    got = np.asarray(hist_fb3(got, num_bin))
     seg = slice(start, start + cnt)
     terms = np.asarray(ghc)[seg].astype(np.float64)
     terms[:, :2] = _hilo(np.asarray(ghc)[seg, :2])
     b = np.asarray(bins)[seg]
     want = build_histogram_np(b, terms, num_bin).astype(np.float64)
     room = build_histogram_np(b, np.abs(terms), num_bin).astype(np.float64)
-    assert got.shape == (f, num_bin, 3)
     assert np.array_equal(got[..., 2], want[..., 2])
     assert np.all(np.abs(got - want) <= 1e-6 * room + 1e-30)
     # and the XLA loop reads the same histogram to the same tolerance
-    ref = np.asarray(hist16_segment_planes(
-        work, *a, num_bins=num_bin, num_feat=f, chunk=CH))
+    ref = np.asarray(hist_fb3(hist16_segment_planes(
+        work, *a, num_bins=num_bin, num_feat=f, chunk=CH), num_bin))
     assert np.array_equal(got[..., 2], ref[..., 2])
     assert np.all(np.abs(got - ref) <= 2e-6 * room + 1e-30)
     assert np.array_equal(np.asarray(work_out), np.asarray(work))
@@ -141,7 +142,7 @@ def test_hist_pallas_planes_kernel_interpret_bf16_channels(rng, monkeypatch):
     a = (jnp.int32(0), jnp.int32(guard + start), jnp.int32(cnt))
     got, _ = hist_pallas_segment_planes(
         work, *a, num_bins=num_bin, num_feat=f, chunk=256, exact=False)
-    got = np.asarray(got)
+    got = np.asarray(hist_fb3(got, num_bin))
     seg = slice(start, start + cnt)
     terms = np.asarray(jnp.asarray(ghc)[seg].astype(jnp.bfloat16)
                        .astype(jnp.float32)).astype(np.float64)

@@ -12,7 +12,7 @@ import pytest
 
 from lightgbm_tpu.ops.partition import (
     DEFAULT_CH, guard_rows, pack_rows, partition_segment, unpack_ghc)
-from lightgbm_tpu.ops.histogram import hist16_segment
+from lightgbm_tpu.ops.histogram import hist16_segment, hist_fb3
 
 CH = 256  # small chunk so multi-chunk paths are exercised at test sizes
 G = guard_rows(CH)
@@ -73,9 +73,9 @@ def test_hist16_segment(rng, num_bin, exact):
     n, f = 900, 5
     bins, ghc, work0, work = _mk(rng, n, f=f, num_bin=num_bin)
     start, cnt = 57, 700
-    out = np.asarray(hist16_segment(
+    out = np.asarray(hist_fb3(hist16_segment(
         work, jnp.int32(0), jnp.int32(G + start), jnp.int32(cnt),
-        num_bins=num_bin, num_feat=f, exact=exact, chunk=CH))
+        num_bins=num_bin, num_feat=f, exact=exact, chunk=CH), num_bin))
     seg_b = bins[G + start:G + start + cnt]
     seg_g = ghc[G + start:G + start + cnt]
     ref = np.zeros((f, num_bin, 3), np.float64)
