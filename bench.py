@@ -77,8 +77,20 @@ def make_mslr_like(n, f=137, docs_per_query=120, seed=11):
     return X.astype(np.float64), y, np.asarray(sizes, dtype=np.int64)
 
 
-def _phases(timer, wall, traffic=None):
-    """Fused-path phase dict for one timed train + its own accounting.
+_HOST_KEYS = ("fused/block_fn", "fused/dispatch", "fused/host_trees",
+              "train/booster_init")
+_PHASE_KEYS = _HOST_KEYS + ("fused/device_wait", "fused/logs_transfer")
+
+
+def _phase_mark():
+    """The registry's phase timers now; ``.grown()`` is what a train adds."""
+    from lightgbm_tpu import obs
+    return obs.TimerMark({k: k for k in _PHASE_KEYS})
+
+
+def _phases(t, wall, traffic=None):
+    """Fused-path phase dict for one timed train + its own accounting, from
+    ``t``, the seconds each phase timer of the registry grew by over it.
 
     Device-time attribution (obs_device PR): each finalize bounds device
     execution with a forced 1-element transfer (obs.sync) BEFORE pulling
@@ -98,15 +110,11 @@ def _phases(timer, wall, traffic=None):
     accounting of the per-split hot loop (SerialTreeLearner.traffic_spec) —
     merged AFTER the wall accounting so accounted_pct stays a pure
     wall-time self-check."""
-    t = timer.times
-    host_keys = ("fused/block_fn", "fused/dispatch", "fused/host_trees",
-                 "train/booster_init")
-    keys = host_keys + ("fused/device_wait", "fused/logs_transfer")
-    out = {k.split("/")[-1]: round(t.get(k, 0.0), 3) for k in keys}
+    out = {k.split("/")[-1]: round(t.get(k, 0.0), 3) for k in _PHASE_KEYS}
     out["device_s"] = round(t.get("fused/device_wait", 0.0), 3)
     out["transfer_s"] = round(t.get("fused/logs_transfer", 0.0), 3)
-    out["host_s"] = round(sum(t.get(k, 0.0) for k in host_keys), 3)
-    acc = sum(t.get(k, 0.0) for k in keys)
+    out["host_s"] = round(sum(t.get(k, 0.0) for k in _HOST_KEYS), 3)
+    acc = sum(t.get(k, 0.0) for k in _PHASE_KEYS)
     out["other"] = round(max(wall - acc, 0.0), 3)
     out["accounted_pct"] = round(100.0 * min(acc / max(wall, 1e-9), 1.0), 1)
     if traffic:
@@ -119,7 +127,7 @@ def _phases(timer, wall, traffic=None):
     return out
 
 
-def run_higgs(lgb, n_rows, timer):
+def run_higgs(lgb, n_rows):
     from lightgbm_tpu import obs
     with obs.wall("higgs/datagen") as w:
         X, y = make_higgs_like(n_rows)
@@ -144,18 +152,19 @@ def run_higgs(lgb, n_rows, timer):
         wb = lgb.train(dict(params), ds, num_boost_round=20)
         obs.sync(wb.inner.train_score.score)
     warmup_s = w.seconds
-    timer.reset()
+    mark = _phase_mark()
     with obs.wall("higgs/train") as w:
         bst = lgb.train(dict(params), ds, num_boost_round=N_ITER)
         obs.sync(bst.inner.train_score.score)
     train_s = w.seconds
-    phases = _phases(timer, train_s, bst.inner.learner.traffic_spec())
+    phases = _phases(mark.grown(), train_s,
+                     bst.inner.learner.traffic_spec())
     (_, _, auc, _), = bst.eval_train()
     return ((n_rows * N_ITER) / train_s, auc, train_s, warmup_s, t_gen,
             t_cons, phases)
 
 
-def run_mslr(lgb, timer):
+def run_mslr(lgb):
     from lightgbm_tpu import obs
     with obs.wall("mslr/datagen") as w:
         X, y, group = make_mslr_like(RANK_ROWS)
@@ -178,12 +187,13 @@ def run_mslr(lgb, timer):
         wb = lgb.train(dict(params), ds, num_boost_round=10)
         obs.sync(wb.inner.train_score.score)
     warmup_s = w.seconds
-    timer.reset()
+    mark = _phase_mark()
     with obs.wall("mslr/train") as w:
         bst = lgb.train(dict(params), ds, num_boost_round=RANK_ITER)
         obs.sync(bst.inner.train_score.score)
     train_s = w.seconds
-    phases = _phases(timer, train_s, bst.inner.learner.traffic_spec())
+    phases = _phases(mark.grown(), train_s,
+                     bst.inner.learner.traffic_spec())
     evals = {name: v for (_, name, v, _) in bst.eval_train()}
     ndcg = evals.get("ndcg@10", next(iter(evals.values())))
     return ((RANK_ROWS * RANK_ITER) / train_s, ndcg, train_s, warmup_s,
@@ -267,13 +277,12 @@ def main():
     import lightgbm_tpu as lgb
     from lightgbm_tpu import runtime
     runtime.enable_compile_cache()
-    from lightgbm_tpu.utils.timer import global_timer
 
     if TRACE_PATH:
         from lightgbm_tpu.obs_trace import tracer
         tracer.configure("on")
     h_tp, auc, h_train, h_warm, h_gen, h_cons, h_ph = run_higgs(
-        lgb, N_ROWS, global_timer)
+        lgb, N_ROWS)
     result = {
         "metric": "higgs_like_binary_train_throughput",
         "value": round(h_tp / 1e6, 4),
@@ -288,8 +297,7 @@ def main():
     }
     if not SKIP_2M:
         try:
-            tp2, auc2, tr2, wm2, _, _, ph2 = run_higgs(lgb, N2_ROWS,
-                                                       global_timer)
+            tp2, auc2, tr2, wm2, _, _, ph2 = run_higgs(lgb, N2_ROWS)
             result["value_2m"] = round(tp2 / 1e6, 4)
             result["unit_2m"] = (
                 "M rows*iters/s (N=%d; auc=%.4f; train=%.1fs warmup=%.1fs)"
@@ -300,7 +308,7 @@ def main():
     if not SKIP_RANK:
         try:
             (r_tp, ndcg, r_train, r_warm, r_gen, r_cons,
-             r_ph) = run_mslr(lgb, global_timer)
+             r_ph) = run_mslr(lgb)
             result["rank_value"] = round(r_tp / 1e6, 4)
             result["rank_unit"] = (
                 "M rows*iters/s (MSLR-like N=%d F=137 leaves=%d bins=%d "

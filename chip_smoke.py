@@ -169,7 +169,6 @@ def main():
     from bench import make_higgs_like
     from lightgbm_tpu import io_native, obs, obs_device, runtime
     from lightgbm_tpu.ops import partition
-    from lightgbm_tpu.utils.timer import global_timer
 
     cache_dir = runtime.enable_compile_cache()
 
@@ -199,7 +198,6 @@ def main():
     def fresh():
         obs.telemetry.reset()
         obs_device.reset()
-        global_timer.reset()
 
     # host only, before the device is asked for anything
     with obs.wall("smoke/datagen") as w:

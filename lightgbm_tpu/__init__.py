@@ -15,6 +15,20 @@ Public API mirrors the reference python-package:
 
 __version__ = "0.1.0"
 
+import sys as _sys
+import time as _time
+
+# the package_import record's clock reads (obs.record_package_import)
+_marks = []  # graftlint: disable=module-mutable-state -- filled by _mark() while the package imports, deleted below
+
+
+def _mark(group):
+    _marks.append((group, _time.perf_counter()))  # graftlint: disable=naked-timer -- read before obs is imported; times HOST imports
+
+
+_jax_preimported = "jax" in _sys.modules
+_mark("entry")
+
 from .config import Config
 from .utils.log import Log, LightGBMError
 from . import obs
@@ -22,14 +36,17 @@ from . import obs
 try:  # full API surface; modules come online as the build proceeds
     from .basic import Booster, Dataset, register_logger
     from .engine import train, cv, CVBooster
+    _mark("core")
     from . import serve  # noqa: F401 — lgb.serve.PredictSession et al.
     from . import online  # noqa: F401 — lgb.online.OnlineTrainer et al.
+    _mark("serve_online")
     from .plotting import (  # noqa: F401
         create_tree_digraph,
         plot_importance,
         plot_metric,
         plot_tree,
     )
+    _mark("plotting")
     from .callback import (
         early_stopping,
         log_evaluation,
@@ -40,12 +57,16 @@ try:  # full API surface; modules come online as the build proceeds
     )
 except ImportError:  # pragma: no cover — bootstrap only
     pass
+_mark("core")
 
 try:  # sklearn wrappers are optional (sklearn itself may be absent)
     from .sklearn import LGBMModel, LGBMClassifier, LGBMRegressor, LGBMRanker
     _SKLEARN = ["LGBMModel", "LGBMClassifier", "LGBMRegressor", "LGBMRanker"]
 except ImportError:  # pragma: no cover
     _SKLEARN = []
+_mark("sklearn")
+obs.record_package_import(_marks, _jax_preimported)
+del _marks
 
 __all__ = [
     "Config",

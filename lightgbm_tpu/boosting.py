@@ -23,7 +23,7 @@ from .dataset import BinnedDataset
 from .learner import (SerialTreeLearner, TreeLog, assign_leaves,
                       leaf_values_by_row)
 from .metric import Metric, create_metrics
-from .obs import host_phase, trace_phase, track_jit
+from .obs import count_trees, host_phase, trace_phase, track_jit
 from .objective import ObjectiveFunction, create_objective
 from .tree import Tree
 from .utils.log import Log
@@ -318,28 +318,7 @@ class GBDT:
             with self._cache_lock:
                 self.models.append(tree)
             self._note_used_features(tree)
-            # eager-path growth counters (fused blocks count in _count_trees)
-            from .obs import telemetry
-            splits = tree.num_leaves - 1
-            telemetry.count("tree/trees")
-            telemetry.count("tree/splits", splits)
-            telemetry.count("tree/leaves", tree.num_leaves)
-            # launch accounting: one partition pass + one smaller-child
-            # histogram per split; rows layout adds a root histogram per
-            # tree (planes/resident fold the root into the pack)
-            spec = self.learner.traffic_spec()
-            root_hists = 0 if (spec and spec["work_layout"] != "rows") else 1
-            telemetry.count("learner/partition_launches", splits)
-            telemetry.count("learner/hist_launches", splits + root_hists)
-            telemetry.count("learner/scan_launches", splits)
-            if spec:
-                telemetry.gauge("traffic/work_layout", spec["work_layout"])
-                telemetry.gauge("traffic/partition_bytes_per_row",
-                                spec["partition_bytes_per_row"])
-                telemetry.gauge("traffic/hist_bytes_per_row",
-                                spec["hist_bytes_per_row"])
-                telemetry.gauge("traffic/effective_rows",
-                                spec.get("effective_rows", 0))
+            count_trees([tree])     # tree/*: what the fused loop counts
             if tree.num_leaves > 1:
                 any_nonconstant = True
         if self.config.obs_check_finite != "off":
@@ -537,11 +516,17 @@ class GBDT:
                 and not isinstance(self.learner, _MeshTreeLearner))
 
     def train_block(self, k: int) -> bool:
-        """Train k iterations fused in one launch (see fused.py)."""
-        if getattr(self, "_fused", None) is None:
-            from .fused import FusedTrainer
-            self._fused = FusedTrainer(self)
-        return self._fused.run(k)
+        """Train k iterations fused in one launch (see fused.py). The
+        phase is opened HERE, not around the engine's call: a caller that
+        wraps this method in an annotation of its own (the benchmark's
+        ``bench/train_block``) then holds ``lgbtpu/train_block`` and every
+        ``lgbtpu/fused_*`` phase inside it, and a moment between two of
+        them still lies under a name of the program's."""
+        with host_phase("lgbtpu/train_block"):
+            if getattr(self, "_fused", None) is None:
+                from .fused import FusedTrainer
+                self._fused = FusedTrainer(self)
+            return self._fused.run(k)
 
     def finish_fused(self, reason: str = "unspecified") -> bool:
         """Finalize any in-flight fused block (host trees + cegb state).

@@ -13,7 +13,7 @@ import numpy as np
 from .basic import Booster, Dataset
 from .callback import CallbackEnv, EarlyStopException, early_stopping, log_evaluation
 from .config import resolve_aliases
-from .obs import JobStart, host_phase, telemetry
+from .obs import JobStart, host_phase, report_timers, telemetry
 from .utils.log import Log, LightGBMError
 
 
@@ -31,6 +31,7 @@ def train(
 ) -> Booster:
     """Train a booster (reference: engine.py:14)."""
     job = JobStart()    # writes the job_start record at the first dispatch
+    telemetry.clear_records("fused_block")      # this job's blocks from here
     with host_phase("lgbtpu/train"):
         return _train(job, params, train_set, num_boost_round, valid_sets,
                       valid_names, fobj, feval, init_model, callbacks)
@@ -46,7 +47,6 @@ def _train(job, params, train_set, num_boost_round, valid_sets, valid_names,
     verbosity = int(params.get("verbosity", 1))
     job.verbose = verbosity > 0
 
-    from .utils.timer import global_timer
     if params.get("machines") or int(params.get("num_machines", 1)) > 1:
         Log.warning(
             "machines/num_machines configure the reference's socket/MPI "
@@ -113,8 +113,7 @@ def _train(job, params, train_set, num_boost_round, valid_sets, valid_names,
         try:
             while scheduled < end:
                 k = min(block, end - scheduled)
-                with host_phase("lgbtpu/train_block"):
-                    stopped = booster.inner.train_block(k)
+                stopped = booster.inner.train_block(k)
                 if stopped:
                     break
                 scheduled += k
@@ -184,7 +183,7 @@ def _train(job, params, train_set, num_boost_round, valid_sets, valid_names,
         booster.best_iteration = booster.inner.iter_
     with booster.inner._cache_lock:
         booster.inner.best_iteration = booster.best_iteration
-    global_timer.maybe_report()
+    report_timers()
     _ledger_record(booster)
     return booster
 

@@ -26,7 +26,8 @@ import numpy as np
 from . import obs_device, runtime
 from .config import Config
 from .learner import SerialTreeLearner, TreeLog, leaf_values_by_row
-from .obs import host_phase, sync, telemetry, trace_phase, track_jit
+from .obs import (count_trees, host_phase, monotonic, sync, telemetry,
+                  trace_phase, track_jit)
 
 # Process-wide cache of jitted block functions. A Booster's jitted callables
 # die with the Booster, so back-to-back train() calls with identical
@@ -37,6 +38,8 @@ from .obs import host_phase, sync, telemetry, trace_phase, track_jit
 # cached trace reads its array state from the call's operands.
 _BLOCK_CACHE: dict = {}  # graftlint: disable=module-mutable-state -- cross-Booster jit cache; keyed by shape fingerprint
 _BLOCK_CACHE_MAX = 64
+# the fused_block records a job keeps: its first and the newest 1,023
+_BLOCK_RECORDS = 1024
 
 
 def _fp_hash(x) -> str:
@@ -248,6 +251,7 @@ class FusedTrainer:
         # device-resident cegb feature-used mask
         self._pending = None
         self._cegb_used_dev = None
+        self._blocks_recorded = 0   # the next fused_block record's index
 
     def _fingerprint(self, k: int) -> tuple:
         """Everything that shapes the traced block computation but is not a
@@ -439,25 +443,28 @@ class FusedTrainer:
         with host_phase("lgbtpu/fused_block_fn"):
             fn = self._block_fn(k)
         prev = self._pending
-        # iter_ only advances when a block is FINALIZED (keeps iter_ and
-        # models consistent if finalization fails); schedule from iter_ plus
-        # the not-yet-finalized block's length
-        it0 = gbdt.iter_ + (prev[1] if prev is not None else 0)
-        pre_score = gbdt.train_score.score
-        pre_used = self._used_dev()
-        # host-side counters only — the dispatch stays async (no sync here;
-        # the real device wait is the logs transfer in _finalize)
-        telemetry.count("fused/blocks_dispatched")
-        telemetry.count("fused/iters_dispatched", k)
-        args = (pre_score, pre_used, gbdt._key, jnp.int32(it0),
-                self.learner.bins, self.learner.meta,
-                _obj_array_state(gbdt.objective))
+        with host_phase("lgbtpu/fused_args"):
+            # iter_ only advances when a block is FINALIZED (keeps iter_
+            # and models consistent if finalization fails); schedule from
+            # iter_ plus the not-yet-finalized block's length
+            it0 = gbdt.iter_ + (prev[1] if prev is not None else 0)
+            pre_score = gbdt.train_score.score
+            pre_used = self._used_dev()
+            # host-side counters only — the dispatch stays async (no sync
+            # here; the real device wait is the logs transfer in _finalize)
+            telemetry.count("fused/blocks_dispatched")
+            telemetry.count("fused/iters_dispatched", k)
+            args = (pre_score, pre_used, gbdt._key, jnp.int32(it0),
+                    self.learner.bins, self.learner.meta,
+                    _obj_array_state(gbdt.objective))
         with host_phase("lgbtpu/fused_dispatch"):
             (score, used), logs = fn.dispatch(*args)
+        dispatched_s = monotonic()
         job, gbdt._job_start = gbdt._job_start, None
         if job is not None:     # the first block of an lgb.train call
             job.dispatched("fused")
-        fn.after_call(args, {})     # compile count, cost capture
+        with host_phase("lgbtpu/fused_after_call"):
+            fn.after_call(args, {})     # compile count, cost capture
         gbdt.train_score.score = score
         self._cegb_used_dev = used
         if self.config.obs_check_finite != "off":
@@ -468,8 +475,9 @@ class FusedTrainer:
             # scan; a non-finite grad surfaces in the scores it produces).
             obs_device.check_finite("scores", (score,),
                                     self.config.obs_check_finite)
-        # pre_score/pre_used ride along for the rollback paths below
-        self._pending = (logs, k, pre_score, pre_used)
+        # pre_score/pre_used ride along for the rollback paths below, the
+        # first iteration and the dispatch's stamp for the block's record
+        self._pending = (logs, k, pre_score, pre_used, it0, dispatched_s)
         stopped = self._finalize(prev)
         if stopped:
             # previous block ended all-constant: drop the in-flight block
@@ -532,7 +540,7 @@ class FusedTrainer:
         in-flight successor block is dropped."""
         if pending is None:
             return False
-        logs, k, pre_score, pre_used = pending
+        logs, k, pre_score, pre_used, it0, dispatched_s = pending
         gbdt = self.gbdt
         K = gbdt.num_tree_per_iteration
         last_iter_constant = False
@@ -550,6 +558,7 @@ class FusedTrainer:
             # dispatched one executes.
             with host_phase("lgbtpu/fused_device_wait"):
                 sync(logs)
+            wait_end_s = monotonic()
             with host_phase("lgbtpu/fused_flush"):
                 host = jax.device_get(logs)
             with host_phase("lgbtpu/fused_host_trees"):
@@ -578,39 +587,43 @@ class FusedTrainer:
             # dispatched - finalized = the iterations in flight
             telemetry.count("fused/iters_finalized", k)
             obs_device.maybe_sample_hbm()   # block-boundary HBM watermark
-            self._count_trees(trees)
+            grown = count_trees(trees)
+            work = [t.work() for t in trees]
+            grown.update(row_visits=sum(w["row_visits"] for w in work),
+                         hist_rows=sum(w["hist_rows"] for w in work))
+        self._record_block(it0, k, dispatched_s, wait_end_s, grown)
         return last_iter_constant
 
-    def _count_trees(self, trees) -> None:
-        """Host-side growth/launch accounting for a finalized block. Runs
-        AFTER the logs transfer (no extra sync): splits/leaves come off the
-        already-fetched host trees; partition/histogram launch counts
-        follow the builder's contract — one partition pass and one
-        smaller-child histogram per split, plus one root histogram per
-        tree on the rows layout (planes/resident fold the root histogram
-        into the pack pass). ``tree/splits_categorical`` counts the splits
-        whose left side is a set of categories (``Tree.num_cat``)."""
-        splits = sum(t.num_leaves - 1 for t in trees)
-        leaves = sum(t.num_leaves for t in trees)
-        telemetry.count("tree/trees", len(trees))
-        telemetry.count("tree/splits", splits)
-        telemetry.count("tree/splits_categorical",
-                        sum(t.num_cat for t in trees))
-        telemetry.count("tree/leaves", leaves)
-        spec = self.learner.traffic_spec()
-        root_hists = 0 if (spec and spec["work_layout"] != "rows") \
-            else len(trees)
-        telemetry.count("learner/partition_launches", splits)
-        telemetry.count("learner/hist_launches", splits + root_hists)
-        telemetry.count("learner/scan_launches", splits)
-        if spec:
-            telemetry.gauge("traffic/work_layout", spec["work_layout"])
-            telemetry.gauge("traffic/partition_bytes_per_row",
-                            spec["partition_bytes_per_row"])
-            telemetry.gauge("traffic/hist_bytes_per_row",
-                            spec["hist_bytes_per_row"])
-            telemetry.gauge("traffic/effective_rows",
-                            spec.get("effective_rows", 0))
+    def _record_block(self, it0, k, dispatched_s, wait_end_s, grown) -> None:
+        """One ``fused_block`` record a finalized block: the job's timeline
+        on the host's ``time.perf_counter()``, with the growth of the
+        block's trees (``obs.count_trees``) and their work
+        (``tree.Tree.work``: ``row_visits``, the partition's rows, and
+        ``hist_rows``, the histogram kernel's, from counts the split log
+        already carried: no transfer and no sync of its own).
+
+        ``index`` counts this trainer's finalized blocks from 0,
+        ``first_iter`` and ``iters`` place the block in the model, ``rows``
+        is the training set's. ``dispatched_s`` is read when the block's
+        ``fn.dispatch`` returned, ``wait_end_s`` when ``sync(logs)``
+        returned, ``finalized_s`` at the end of ``lgbtpu/fused_commit``.
+        On a TPU the forced read of block i's logs is queued behind block
+        i + 1, which was dispatched before it: ``wait_end_s`` comes when
+        block i + 1 is done, so the difference of two consecutive records'
+        ``wait_end_s`` is the device's period a block (the later record's
+        successor's, to be exact), with no profiler; and the first record's
+        ``wait_end_s - dispatched_s`` spans blocks 0 and 1. A block that a
+        read API flushes has no successor in flight and waits for itself.
+        ``engine.train`` starts the list anew; it holds the job's first
+        record and its newest ``_BLOCK_RECORDS - 1``."""
+        telemetry.record(
+            "fused_block", keep=_BLOCK_RECORDS, index=self._blocks_recorded,
+            first_iter=it0, iters=k, rows=self.learner.dataset.num_data,
+            dispatched_s=dispatched_s, wait_end_s=wait_end_s,
+            finalized_s=monotonic(),
+            **{f: grown[f] for f in ("splits", "splits_categorical", "leaves",
+                                     "row_visits", "hist_rows")})
+        self._blocks_recorded += 1
 
     def _host_tree(self, host: BlockLogs, pick):
         from .tree import Tree

@@ -33,16 +33,20 @@ Three layers:
      the region runs on the host: on the device trace's clock, so idle
      device time is attributed to the span that held it;
    - a **timer** (``timer=`` name): accumulated seconds and call count in
-     :data:`telemetry` and in ``utils.timer.global_timer`` (D9 retires the
-     latter), read as window deltas by the benchmark's ``timer_delta``;
+     :data:`telemetry`, read as window deltas by the benchmark's
+     ``timer_delta``;
    - the **flight recorder** (``obs_trace.tracer``) when ``trace_spans=on``.
 
    Scope and annotation are metadata: they never change computed values.
 3. **Structured run counters** — the process-global :data:`telemetry`
    registry (counters / gauges / timers / record lists) instrumenting the
    dataset device caches, the fused pipeline, per-tree growth stats, every
-   ``auto`` knob resolution, and one ``job_start`` (:class:`JobStart`) and
-   one ``dataset_construct`` record per job. ``Booster.telemetry()``,
+   ``auto`` knob resolution, one ``job_start`` (:class:`JobStart`) and one
+   ``dataset_construct`` record per job, and the job's timeline: one
+   ``package_import`` and one ``runtime_start`` record a process
+   (:func:`record_package_import`, ``runtime.py``) and one ``fused_block``
+   record a finalized block (``fused.FusedTrainer``), every stamp a
+   ``time.perf_counter()`` second. ``Booster.telemetry()``,
    ``CallbackEnv.telemetry``, ``cli --dump-telemetry`` and the benchmark's
    readers all read :meth:`Telemetry.snapshot` / :meth:`Telemetry.records`.
 
@@ -58,8 +62,6 @@ import time
 from bisect import bisect_left
 from collections import defaultdict
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
-
-from .utils.timer import global_timer
 
 
 # ---------------------------------------------------------------------------
@@ -198,6 +200,7 @@ PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
     # nested labels of single ops (lgbtpu/ops/<kernel>), never outermost
     "lgbtpu/ops": ("device", "kernels", None),
     # -- host: before the loop
+    "lgbtpu/runtime_start": ("host", "runtime", "runtime/start"),
     "lgbtpu/construct": ("host", "host data", "construct/total"),
     "lgbtpu/construct_copy": ("host", "host data", "construct/copy"),
     "lgbtpu/construct_find_bins": ("host", "host data", "construct/find_bins"),
@@ -212,7 +215,9 @@ PHASES: Dict[str, Tuple[str, str, Optional[str]]] = {
     "lgbtpu/train_iter": ("host", "booster", "train/iter"),
     "lgbtpu/metric_eval": ("host", "booster", "train/metric_eval"),
     "lgbtpu/fused_block_fn": ("host", "booster", "fused/block_fn"),
+    "lgbtpu/fused_args": ("host", "booster", "fused/args"),
     "lgbtpu/fused_dispatch": ("host", "booster", "fused/dispatch"),
+    "lgbtpu/fused_after_call": ("host", "booster", "fused/after_call"),
     "lgbtpu/fused_device_wait": ("host", "booster", "fused/device_wait"),
     "lgbtpu/fused_flush": ("host", "booster", "fused/logs_transfer"),
     "lgbtpu/fused_host_trees": ("host", "booster", "fused/host_trees"),
@@ -238,7 +243,7 @@ def trace_phase(name: str, timer: Optional[str] = None) -> Iterator[None]:
 
     ``timer`` (host regions only: inside a jit trace it would time the
     trace) adds the region's wall seconds and one call to that telemetry
-    timer, and to ``global_timer`` under the same name.
+    timer.
 
     With ``trace_spans=on`` (obs_trace.tracer), host-side executions of
     the region additionally record a span into the flight recorder.
@@ -259,9 +264,7 @@ def trace_phase(name: str, timer: Optional[str] = None) -> Iterator[None]:
             yield
     finally:
         if timer is not None:
-            seconds = time.perf_counter() - t0
-            telemetry.add_time(timer, seconds)
-            global_timer.add(timer, seconds)
+            telemetry.add_time(timer, time.perf_counter() - t0)
         if sp is not None:
             obs_trace.tracer.end(sp)
 
@@ -551,9 +554,7 @@ class Telemetry:
     Thread-safe (the mesh learners and user callbacks may touch it from
     worker threads) and cheap: every mutation is a dict update under one
     lock, on host, never inside traced code. ``snapshot()`` returns a
-    plain JSON-serializable dict and folds in what only
-    ``utils.timer.global_timer`` holds (the phase timers that
-    :func:`trace_phase` feeds are in both, the registry's reading wins).
+    plain JSON-serializable dict.
     """
 
     def __init__(self) -> None:
@@ -609,13 +610,18 @@ class Telemetry:
         finally:
             self.observe(name, (time.perf_counter() - t0) * 1e3)
 
-    def record(self, name: str, dedupe_key=None, **payload) -> None:
+    def record(self, name: str, dedupe_key=None, keep: Optional[int] = None,
+               **payload) -> None:
         """Append a structured event to the ``name`` list. With
         ``dedupe_key``, an event carrying the same key is appended at most
         once (auto-knob resolutions re-run per build_kwargs call but the
-        registry keeps one record per distinct resolution)."""
+        registry keeps one record per distinct resolution). With ``keep``,
+        the list holds at most that many: its first event and the newest
+        ``keep - 1`` (one event a block of a job of any length)."""
         with self._lock:
             lst = self._records[name]
+            if keep is not None and len(lst) >= keep:
+                del lst[1]
             if dedupe_key is not None:
                 key = _jsonable(dedupe_key)
                 if any(r.get("_key") == key for r in lst):
@@ -632,6 +638,12 @@ class Telemetry:
         with self._lock:
             return list(self._records.get(name, []))
 
+    def clear_records(self, name: str) -> None:
+        """Start the ``name`` list anew (``engine.train`` does for the
+        job's ``fused_block`` records)."""
+        with self._lock:
+            self._records.pop(name, None)
+
     def histogram(self, name: str) -> Optional[Dict[str, Any]]:
         """Snapshot of one histogram (buckets + p50/p90/p99/p999), or
         None when nothing was observed under ``name``."""
@@ -639,7 +651,7 @@ class Telemetry:
             h = self._hists.get(name)
             return h.snapshot() if h is not None else None
 
-    def snapshot(self, include_global_timer: bool = True) -> Dict[str, Any]:
+    def snapshot(self) -> Dict[str, Any]:
         """JSON-serializable view of everything recorded so far."""
         with self._lock:
             timers = {k: round(v, 6) for k, v in self._timers.items()}
@@ -663,10 +675,6 @@ class Telemetry:
                 "histograms": {k: h.snapshot()
                                for k, h in self._hists.items()},
             }
-        if include_global_timer:
-            from .utils.timer import global_timer
-            for k, v in global_timer.times.items():
-                snap["timers"].setdefault(k, round(float(v), 6))
         for lst in snap["records"].values():
             for r in lst:
                 r.pop("_key", None)
@@ -678,19 +686,102 @@ class Telemetry:
         return snap
 
     def reset(self) -> None:
-        """Clear every counter/gauge/timer/record (tests, fresh benches).
-        ``utils.timer.global_timer`` is owned by its callers and is NOT
-        reset here."""
+        """Clear every counter/gauge/timer/record (tests, fresh benches)
+        but :data:`PROCESS_RECORDS`, which are written once a process and
+        could not be written again."""
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
             self._timers.clear()
             self._timer_calls.clear()
+            kept = {k: self._records[k] for k in PROCESS_RECORDS
+                    if k in self._records}
             self._records.clear()
+            self._records.update(kept)
             self._hists.clear()
 
 
+# records of the process, not of a job: written once, kept by reset()
+PROCESS_RECORDS = ("package_import", "runtime_start")
+
 telemetry = Telemetry()
+
+
+def record_package_import(marks: Sequence[Tuple[str, float]],
+                          jax_preimported: bool) -> None:
+    """The ``package_import`` record, written once at the end of
+    ``lightgbm_tpu/__init__.py``. ``marks`` are its clock reads, ``("entry",
+    t)`` before the first import and ``(group, t)`` after each group of
+    imports; ``elapsed_s`` runs from the first to the last. Where the
+    ``runtime_start`` record's stretch lies inside a group (an import that
+    brings the XLA backend up: ``runtime.start``), its seconds are
+    ``runtime_start_s`` here too and are taken off that group, so that
+    ``import_s`` = ``elapsed_s`` - ``runtime_start_s`` is Python's importing
+    alone and the ``<group>_s`` (``core_s``, ``serve_online_s``,
+    ``plotting_s``, ``sklearn_s``) are disjoint and sum to it.
+    ``jax_preimported``: jax was in ``sys.modules`` at entry, its own import
+    is then outside this record. ``process_age_s``: seconds from the OS's
+    start of the process to the entry (``/proc/self/stat`` against
+    ``CLOCK_BOOTTIME``; ``None`` where the OS gives neither)."""
+    entry, last = marks[0][1], marks[-1][1]
+    started = telemetry.records("runtime_start")
+    asked = started[0]["asked_s"] if started else None
+    inside = 0.0
+    parts: Dict[str, float] = defaultdict(float)
+    for (_, before), (group, t) in zip(marks, marks[1:]):
+        parts[group + "_s"] += t - before
+        if asked is not None and before <= asked < t:
+            inside = started[0]["runtime_start_s"]
+            parts[group + "_s"] -= inside
+    age = _process_age_s()
+    telemetry.record(
+        "package_import", import_s=last - entry - inside,
+        elapsed_s=last - entry, runtime_start_s=inside, entry_s=entry,
+        jax_preimported=bool(jax_preimported),
+        process_age_s=None if age is None
+        else age - (time.perf_counter() - entry), **parts)
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process, or None."""
+    try:
+        import os
+        with open("/proc/self/stat") as f:
+            # the 22nd field, counted from after the command's ")"
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+
+
+def count_trees(trees) -> Dict[str, int]:
+    """Growth of finished host trees, counted into ``tree/*`` and returned:
+    the one place both loops count (``FusedTrainer._finalize`` a block at a
+    time, ``GBDT.train_one_iter`` a tree)."""
+    sums = {"trees": len(trees),
+            "splits": sum(t.num_leaves - 1 for t in trees),
+            "splits_categorical": sum(t.num_cat for t in trees),
+            "leaves": sum(t.num_leaves for t in trees)}
+    for name, n in sums.items():
+        telemetry.count("tree/" + name, n)
+    return sums
+
+
+def report_timers() -> None:
+    """Log the registry's timers, longest first: at info level when
+    ``LIGHTGBM_TPU_TIMETAG=1`` (the reference's USE_TIMETAG report), else at
+    debug level."""
+    import os
+    from .utils.log import Log
+    say = Log.info if os.environ.get("LIGHTGBM_TPU_TIMETAG") == "1" \
+        else Log.debug
+    with telemetry._lock:
+        timers = dict(telemetry._timers)
+        calls = dict(telemetry._timer_calls)
+    say("LightGBM-TPU phase timers:")
+    for name in sorted(timers, key=timers.get, reverse=True):
+        say("  %-40s %10.4f s  (%d calls)", name, timers[name], calls[name])
 
 
 class TimerMark:

@@ -24,6 +24,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import runtime
 from ..obs import trace_phase
 
 NEG_INF = -jnp.inf
@@ -218,6 +219,13 @@ def find_best_split(hist: jax.Array, parent_sum: jax.Array,
     channels move to the front once, same search, same bits."""
     return find_best_split_planes(jnp.moveaxis(hist, -1, 0), parent_sum,
                                   meta, feature_mask, hp, **kw)
+
+
+# find_best_split_planes' default arguments are device scalars: defining it
+# brings the XLA backend up while the package imports. Asked here first, so
+# that the runtime's start has its own record and phase (runtime.start) and
+# the package_import record holds Python's importing alone.
+runtime.start()
 
 
 def find_best_split_planes(

@@ -13,13 +13,48 @@ import os
 
 import jax
 
+from .obs import host_phase, monotonic, telemetry
+
+_started = False    # the runtime_start record is written: all a later call reads
+
+
+def start() -> None:
+    """Bring the XLA backend up under its own name: one ``runtime_start``
+    record a process, host phase ``lgbtpu/runtime_start``. The first of this
+    function, :func:`on_tpu` and :func:`device_identity` to run times its
+    ``jax.devices()`` (on a TPU the runtime's start: the client, the chips);
+    every later call reads one flag. ``ops/split.py`` calls it while the
+    package imports, ahead of the device scalars of its default arguments,
+    which would bring the backend up unnamed. ``backend_was_up`` says
+    whether something had done so already (a caller that touched jax's
+    devices before importing the package; ``None`` where this jax does not
+    say): ``runtime_start_s`` then reads ~0 and times nothing."""
+    global _started
+    if _started:
+        return
+    _started = True
+    try:
+        from jax._src import xla_bridge
+        was_up = bool(xla_bridge.backends_are_initialized())
+    except Exception:
+        was_up = None
+    t0 = monotonic()
+    with host_phase("lgbtpu/runtime_start"):
+        devs = jax.devices()
+    telemetry.record("runtime_start",
+                     runtime_start_s=monotonic() - t0, asked_s=t0,
+                     backend_was_up=was_up, platform=devs[0].platform,
+                     device_kind=devs[0].device_kind, device_count=len(devs))
+
 
 def on_tpu() -> bool:
+    start()
     return jax.default_backend() == "tpu"
 
 
 def device_identity() -> dict:
     """The device as JAX reports it; stamped on every measured result."""
+    start()
     devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}

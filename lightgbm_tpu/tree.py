@@ -415,6 +415,24 @@ class Tree:
                     depth[~child] = node_depth[r] + 1
         return depth
 
+    def work(self) -> Dict[str, int]:
+        """What growing this tree cost, from the row counts it recorded:
+        ``row_visits``, every split's parent rows (what the partition
+        moved); ``hist_rows``, every split's smaller child's rows (the
+        histogram that was built: the sibling's comes by subtraction).
+        Two numpy sums over the node arrays, no Python loop a node: the
+        fused trainer asks at every finalized block."""
+        n = self.num_internal if self.num_leaves > 1 else 0
+        if n == 0:
+            return {"row_visits": 0, "hist_rows": 0}
+        left, right = self.left_child[:n], self.right_child[:n]
+        # one array a child index reads straight: node i at i, leaf j at ~j
+        # (a negative index counts from the end)
+        count = np.concatenate([self.internal_count[:n],
+                                self.leaf_count[:self.num_leaves][::-1]])
+        return {"row_visits": int(self.internal_count[:n].sum()),
+                "hist_rows": int(np.minimum(count[left], count[right]).sum())}
+
     # -------------------------------------------------------------- serialize
     def to_dict(self) -> Dict[str, Any]:
         d = {

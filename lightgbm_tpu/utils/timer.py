@@ -6,6 +6,11 @@ which accumulates per-phase wall time and prints a report at exit when built
 with USE_TIMETAG. Here the report is available programmatically and printed
 when ``LIGHTGBM_TPU_TIMETAG=1``.
 
+The package's own phase timers live in ``obs.telemetry`` (``obs.trace_phase``
+feeds them, ``obs.report_timers`` prints the ``LIGHTGBM_TPU_TIMETAG``
+report); nothing in the package feeds ``global_timer``. The class stays for
+scripts that time their own phases.
+
 Note: JAX dispatch is async — timers around jitted calls measure dispatch
 unless the caller block_until_ready()s. Use ``timed_sync`` for device phases.
 """

@@ -26,7 +26,6 @@ def main():
     from lightgbm_tpu import obs
     with obs.wall("profile/import") as w:
         import lightgbm_tpu as lgb
-        from lightgbm_tpu.utils.timer import global_timer
     t_import = w.seconds
 
     rng = np.random.RandomState(7)
@@ -49,30 +48,34 @@ def main():
         ds.construct()
     t_construct = wt.seconds
 
+    def phase_mark():
+        """The registry's host-phase timers now: ``grown()`` is a train's."""
+        return obs.TimerMark({t: t for _, _, t in obs.PHASES.values() if t})
+
     # every train wall ends in a forced 1-element transfer of the score
     # (obs.sync): block_until_ready alone does not reliably synchronize
-    global_timer.reset()
+    mark = phase_mark()
     with obs.wall("profile/warmup") as wt:
         wb = lgb.train(dict(params), ds, num_boost_round=BLOCK)
         obs.sync(wb.inner.train_score.score)
     t_warmup = wt.seconds
-    warm_t = dict(global_timer.times)
+    warm_t = mark.grown()
 
-    global_timer.reset()
+    mark = phase_mark()
     with obs.wall("profile/train") as wt:
         bst = lgb.train(dict(params), ds, num_boost_round=ITERS)
         obs.sync(bst.inner.train_score.score)
     t_train = wt.seconds
-    train_t = dict(global_timer.times)
+    train_t = mark.grown()
 
     # pure device time of one cached block: re-dispatch through the booster
     # machinery and block on the result
-    global_timer.reset()
+    mark = phase_mark()
     with obs.wall("profile/train_warm_block") as wt:
         bst2 = lgb.train(dict(params), ds, num_boost_round=BLOCK)
         obs.sync(bst2.inner.train_score.score)
     t_train1 = wt.seconds
-    one_t = dict(global_timer.times)
+    one_t = mark.grown()
 
     with obs.wall("profile/eval_train") as wt:
         (_, _, auc, _), = bst.eval_train()

@@ -401,7 +401,7 @@ def test_watcher_convergence_metrics(tmp_path):
     store.publish(_train(seed=3, rounds=8).model_to_string())
     assert w.poll_once()                       # jumps straight to head v2
     assert telemetry.counter("fleet/replica_polls") == polls0 + 1
-    snap = telemetry.snapshot(include_global_timer=False)
+    snap = telemetry.snapshot()
     assert snap["gauges"]["fleet/version_skew"] == 0
     hist = telemetry.histogram("fleet/publish_adopt_lag_ms")
     assert hist is not None and hist["count"] >= 1

@@ -3,7 +3,7 @@ bins, pandas inputs, plotting, timers.
 
 Reference analogs: Dataset::SaveBinaryFile/LoadFromBinFile, gbdt.cpp:277
 snapshot_freq, dataset_loader.cpp GetForcedBins, basic.py _data_from_pandas,
-plotting.py, common.h:931 global_timer.
+plotting.py, common.h:931 global_timer (the telemetry registry's timers).
 """
 import json
 import os
@@ -91,13 +91,22 @@ def test_plotting_smoke(rng):
     assert lgb.plot_metric(res) is not None
 
 
-def test_phase_timers(rng):
-    from lightgbm_tpu.utils.timer import global_timer
+def test_phase_timers(rng, monkeypatch):
+    """The phase timers live in the telemetry registry, and the
+    LIGHTGBM_TPU_TIMETAG report at the end of a job prints them."""
+    from lightgbm_tpu.obs import telemetry
+    from lightgbm_tpu.utils.log import Log
     X, y = _xy(rng, n=600)
-    lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1},
-              lgb.Dataset(X, label=y), num_boost_round=2,
-              valid_sets=[lgb.Dataset(X[:100], label=y[:100])])
-    rep = global_timer.report()
+    lines = []
+    monkeypatch.setenv("LIGHTGBM_TPU_TIMETAG", "1")
+    monkeypatch.setattr(Log, "info",
+                        staticmethod(lambda m, *a: lines.append(m % a if a else m)))
+    bst = lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1},
+                    lgb.Dataset(X, label=y), num_boost_round=2,
+                    valid_sets=[lgb.Dataset(X[:100], label=y[:100])])
+    timers = bst.telemetry()["timers"]
+    assert timers["train/iter"] > 0 and timers["train/booster_init"] > 0
+    rep = "\n".join(lines)
     assert "train/iter" in rep and "train/booster_init" in rep
 
 

@@ -33,6 +33,14 @@ PARAMS = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
           "verbosity": -1}
 
 
+def _work_of(trees):
+    """(row visits, histogram rows) of the trees, from the trees
+    (``Tree.work`` is held to a plain loop in tests/test_job_timeline.py)."""
+    work = [t.work() for t in trees]
+    return (sum(w["row_visits"] for w in work),
+            sum(w["hist_rows"] for w in work))
+
+
 # ---------------------------------------------------------------- registry
 
 def test_registry_snapshot_roundtrip():
@@ -47,7 +55,7 @@ def test_registry_snapshot_roundtrip():
     t.record("dd", dedupe_key=("x", 1), v=1)
     t.record("dd", dedupe_key=("x", 1), v=1)   # deduped
     t.record("dd", dedupe_key=("x", 2), v=2)
-    snap = t.snapshot(include_global_timer=False)
+    snap = t.snapshot()
     parsed = json.loads(json.dumps(snap))      # must survive json round-trip
     assert parsed["counters"]["a/b"] == 4
     assert parsed["gauges"]["g"] == 7
@@ -56,7 +64,7 @@ def test_registry_snapshot_roundtrip():
     assert parsed["records"]["ev"] == [{"knob": "k", "value": 1.5}]
     assert len(parsed["records"]["dd"]) == 2
     t.reset()
-    empty = t.snapshot(include_global_timer=False)
+    empty = t.snapshot()
     assert empty["counters"] == {} and empty["records"] == {}
 
 
@@ -116,12 +124,12 @@ def test_registry_histograms_in_snapshot():
     t = Telemetry()
     for v in (1.0, 5.0, 50.0):
         t.observe("lat_ms", v)
-    snap = t.snapshot(include_global_timer=False)
+    snap = t.snapshot()
     assert snap["histograms"]["lat_ms"]["count"] == 3
     assert t.histogram("lat_ms")["count"] == 3
     assert t.histogram("nope") is None
     t.reset()
-    assert t.snapshot(include_global_timer=False)["histograms"] == {}
+    assert t.snapshot()["histograms"] == {}
 
 
 _PROM_SAMPLE = re.compile(
@@ -208,7 +216,7 @@ def test_fleet_exposition_round_trips_every_family(tmp_path):
     assert w.maybe_heartbeat(force=True)
     trainer.close()
 
-    snap = telemetry.snapshot(include_global_timer=False)
+    snap = telemetry.snapshot()
     families, samples = parse_prometheus(obs.prometheus_text())
     # the run actually exercised the new convergence families
     for fam, kind in (("lgbtpu_fleet_replica_polls_total", "counter"),
@@ -252,7 +260,7 @@ def test_compile_listener_install_is_idempotent():
         return x * 3.0 + 1.0
 
     _fresh(np.arange(11.0)).block_until_ready()
-    c = telemetry.snapshot(include_global_timer=False)["counters"]
+    c = telemetry.snapshot()["counters"]
     # a doubled listener would count 2 per compile
     assert c.get("jit/backend_compiles", 0) == 1
 
@@ -274,11 +282,15 @@ def test_train_telemetry_counters_and_auto_records():
     assert c["fused/blocks_dispatched"] >= 1
     assert c["fused/iters_dispatched"] == 4
     assert c["fused/flush/train_end"] == 1
-    # per-tree growth + launch accounting
+    # per-tree growth + work accounting, against the trees themselves
     assert c["tree/trees"] == 4
     assert c["tree/leaves"] == c["tree/splits"] + c["tree/trees"]
-    assert c["learner/partition_launches"] == c["tree/splits"]
-    assert c["learner/hist_launches"] >= c["tree/splits"]
+    blocks = snap["records"]["fused_block"]
+    visits, hist = (sum(r[f] for r in blocks)
+                    for f in ("row_visits", "hist_rows"))
+    assert (visits, hist) == _work_of(bst.inner.models)
+    assert 0 < hist <= visits // 2
+    assert sum(r["splits"] for r in blocks) == c["tree/splits"]
     # phase timers nonzero after a CPU train
     assert snap["timers"].get("fused/dispatch", 0) > 0
     assert snap["timers"].get("fused/logs_transfer", 0) > 0
@@ -292,7 +304,6 @@ def test_train_telemetry_counters_and_auto_records():
                           "tpu_goss_compact"}
     for r in knobs.values():
         assert r["configured"] == "auto" and r["value"] and r["reason"]
-    assert "traffic/work_layout" in snap["gauges"]
 
 
 def test_second_train_hits_device_cache_and_bump_invalidates():
@@ -302,7 +313,7 @@ def test_second_train_hits_device_cache_and_bump_invalidates():
     lgb.train(dict(PARAMS), ds, num_boost_round=3)
     telemetry.reset()
     lgb.train(dict(PARAMS), ds, num_boost_round=3)
-    c = telemetry.snapshot(include_global_timer=False)["counters"]
+    c = telemetry.snapshot()["counters"]
     assert c.get("dataset/device_bins/hit", 0) > 0      # acceptance bar
     assert c.get("dataset/device_bins/miss", 0) == 0
     # bump_version invalidates: next train re-uploads
@@ -310,7 +321,7 @@ def test_second_train_hits_device_cache_and_bump_invalidates():
     binned.metadata.bump_version()
     telemetry.reset()
     lgb.train(dict(PARAMS), ds, num_boost_round=3)
-    c = telemetry.snapshot(include_global_timer=False)["counters"]
+    c = telemetry.snapshot()["counters"]
     assert c.get("dataset/device_bins/miss", 0) >= 1
 
 
@@ -320,7 +331,7 @@ def test_read_api_flush_reasons():
     telemetry.reset()
     bst = lgb.train(dict(PARAMS), ds, num_boost_round=3)
     bst.num_trees()
-    c = telemetry.snapshot(include_global_timer=False)["counters"]
+    c = telemetry.snapshot()["counters"]
     # train() itself flushed at train_end; num_trees after that finds no
     # in-flight block, so no fused/flush/num_trees is counted
     assert c["fused/flush/train_end"] == 1
@@ -330,7 +341,7 @@ def test_read_api_flush_reasons():
     bst2 = lgb.Booster(dict(PARAMS, tpu_iter_block=8), ds)
     bst2.inner.train_block(4)                  # dispatch, leave in flight
     bst2.inner.model_to_string()
-    c = telemetry.snapshot(include_global_timer=False)["counters"]
+    c = telemetry.snapshot()["counters"]
     assert c.get("fused/flush/model_to_string", 0) == 1
 
 
@@ -623,14 +634,14 @@ def test_compile_listener_times_trace_and_lowering_once():
     t0 = obs.monotonic()
     outer(jnp.arange(7.0)).block_until_ready()
     wall = obs.monotonic() - t0
-    t = telemetry.snapshot(include_global_timer=False)["timers"]
+    t = telemetry.snapshot()["timers"]
     assert 0 < t["jit/trace_s"] and 0 < t["jit/lower_s"]
     assert t["jit/trace_s"] + t["jit/lower_s"] \
         + t["jit/backend_compile_s"] <= wall
     before = dict(t)
     with obs.suppress_backend_compiles():
         outer.lower(jnp.arange(9.0)).compile()
-    t = telemetry.snapshot(include_global_timer=False)["timers"]
+    t = telemetry.snapshot()["timers"]
     assert {k: t[k] for k in before if k.startswith("jit/")} == \
         {k: v for k, v in before.items() if k.startswith("jit/")}
 

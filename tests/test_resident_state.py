@@ -393,17 +393,16 @@ def test_bench_phases_traffic_merge():
     sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
     from bench import _phases
 
-    class _T:
-        times = {"fused/block_fn": 0.5, "fused/dispatch": 0.3,
-                 "fused/logs_transfer": 0.15, "fused/host_trees": 0.05}
+    grown = {"fused/block_fn": 0.5, "fused/dispatch": 0.3,
+             "fused/logs_transfer": 0.15, "fused/host_trees": 0.05}
 
-    base = _phases(_T, 1.0)
+    base = _phases(grown, 1.0)
     traffic = {"work_layout": "resident", "partition_bytes_per_row": 40,
                "hist_bytes_per_row": 23}
-    got = _phases(_T, 1.0, traffic)
+    got = _phases(grown, 1.0, traffic)
     assert got["accounted_pct"] == base["accounted_pct"]
     assert got["other"] == base["other"]
     assert got["work_layout"] == "resident"
     assert got["partition_bytes_per_row_split"] == 40
     assert got["hist_gather_bytes_per_row"] == 23
-    assert _phases(_T, 1.0, None) == base
+    assert _phases(grown, 1.0, None) == base

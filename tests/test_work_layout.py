@@ -377,9 +377,8 @@ def test_bench_breakdown_accounting():
     from pathlib import Path
 
     sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    from bench import _phases
+    from bench import _phase_mark, _phases
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.utils.timer import global_timer
 
     rng = np.random.RandomState(3)
     n = 3000
@@ -390,9 +389,9 @@ def test_bench_breakdown_accounting():
     ds = lgb.Dataset(X, label=y)
     ds.construct()
     lgb.train(dict(params), ds, num_boost_round=5)   # warmup/compile
-    global_timer.reset()
+    mark = _phase_mark()
     t0 = time.time()
     lgb.train(dict(params), ds, num_boost_round=10)
     wall = time.time() - t0
-    ph = _phases(global_timer, wall)
+    ph = _phases(mark.grown(), wall)
     assert ph["accounted_pct"] >= 95.0, ph
