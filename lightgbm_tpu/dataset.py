@@ -15,7 +15,7 @@ design:
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
 
@@ -124,6 +124,37 @@ class Metadata:
         else:
             telemetry.count("dataset/device_%s/hit" % name)
         return getattr(self, key)[2]
+
+
+# ``learner.bundle_feature_view`` builds a bundled table's per-feature view in
+# one of two forms, chosen here from the groups alone (``bundle_view``).
+# ``runs`` copies every bundled feature's run of slots out of its column by a
+# product with a 0/1 matrix, and the product's operands (the matrix and what
+# comes out) must stay under VIEW_SEL_MAX_BYTES: read once a node search,
+# 8 MiB is 10 us of a v5e's HBM on top of the form's fixed 14 us a pair of
+# nodes, where the ``gather`` form costs 2.6 ns an index, features x bins of
+# them (PERF.md section 6, PR 38). A table at the limit whose widest feature
+# is a bundled one does as well gathered (~10,000 indices, 25 us), one with a
+# wider feature alone in its column still far worse; past the limit the
+# matrix grows with features x columns x widest bundled feature and the
+# gather does not, so such a table keeps the gather and holds no matrix.
+VIEW_SEL_MAX_BYTES = 8 << 20
+# Features alone in their column are cut out of it by one static slice each up
+# to this many (a select a feature in the view's one pass over (3, F, B));
+# more take one row gather with an index a feature.
+VIEW_ALONE_SLICES = 8
+
+
+class BundleView(NamedTuple):
+    """The static half of ``BinnedDataset.bundle_maps`` (hashable: it shapes
+    the traced view, the arrays are its operands)."""
+    form: str           # "runs" | "gather"
+    num_bin: int        # B, the widest feature's bins: the view is (3, F, B)
+    alone: int          # features alone in their device column
+    bundled: int        # features that share one
+    width: int          # the widest bundled feature's bins
+    sel_bytes: int      # runs: the selection matrix's and its product's bytes
+    slices: tuple       # runs, 1..VIEW_ALONE_SLICES alone: their columns, by feature
 
 
 @dataclass
@@ -261,28 +292,85 @@ class BinnedDataset:
     def group_num_bins(self) -> np.ndarray:
         return np.array([g.num_bins for g in self.groups], dtype=np.int32)
 
+    def bundle_view(self) -> BundleView:
+        """What ``learner.bundle_feature_view`` needs to know before it is
+        traced, from the groups alone: which form builds the (3, F, B) view
+        (``VIEW_SEL_MAX_BYTES``) and where the features that sit alone in
+        their column are."""
+        alone = tuple(gid for gid in self.feature_to_group.tolist()
+                      if len(self.groups[gid].feature_indices) == 1)
+        width = max((self.bin_mappers[j].num_bins
+                     for grp in self.groups if len(grp.feature_indices) > 1
+                     for j in grp.feature_indices), default=0)
+        slot_rows = int(self.group_num_bins().max()) if self.groups else 1
+        cols = self.num_features * width
+        # the bf16 matrix, and its f32 product with three terms of three
+        # channels of every device column
+        sel_bytes = 2 * slot_rows * cols + 4 * 9 * self.num_groups * cols
+        runs = sel_bytes <= VIEW_SEL_MAX_BYTES
+        return BundleView(
+            form="runs" if runs else "gather",
+            num_bin=int(self.feature_num_bins().max()), alone=len(alone),
+            bundled=self.num_features - len(alone), width=width,
+            sel_bytes=sel_bytes if runs else 0,
+            slices=alone if runs and len(alone) <= VIEW_ALONE_SLICES else ())
+
     def bundle_maps(self) -> Dict[str, np.ndarray]:
         """Static index maps between feature-bin space and bundle-bin space,
         used by the learner to reconstruct per-feature histogram views from
         bundled columns and to translate routing tables (reference analog:
         FeatureGroup bin offsets + Dataset::FixHistogram, dataset.h:503).
 
+        - has_rest (F,): feature lives in a multi-feature bundle — its
+          default bin must be recovered as parent_total - sum(own slots)
+        - dpos (F,): the feature's default bin index
+        - put (F, B): the cell is a bundled feature's default bin
+        - map_fb (F, Bm): bundle bin -> this feature's bin (its default bin
+          for bundle bins belonging to other sub-features / shared zero)
+        - group (F,), offset (F,), nbm1 (F,): routing arithmetic inputs
+
+        and what the form of ``bundle_view()`` reads. A bundled feature
+        owns ONE run of its column's slots, ``[offset, offset + nbm1)``, in
+        bin order with the default bin left out, and a feature alone in its
+        column owns the column's first ``num_bins`` slots as they are, so
+        the ``runs`` form places runs:
+
+        - sel (Bm, F * W) bfloat16, W the widest bundled feature's bins:
+          1 where column ``f * W + b`` is bundled feature f's bin b and the
+          row is the slot of f's column that holds it; a default bin, a bin
+          past ``num_bins`` and a feature alone in its column have no 1
+        - sel_mine (G, F * W): column ``f * W + b`` of ``sel`` belongs to
+          this device column (no column for a feature alone)
+        - alone_cell (F, B) int8, where any feature sits alone: > 0 over
+          such a feature's own bins; with at most ``VIEW_ALONE_SLICES`` of
+          them, 1 + the place of the feature's column in
+          ``bundle_view().slices``
+        - alone_col (F,): past ``VIEW_ALONE_SLICES``, the column of a
+          feature alone (0 for a bundled one)
+
+        and the ``gather`` form looks every (feature, bin) up:
+
         - proj (F, B): flat index into (num_groups * Bm) for each feature bin
           (meaningless where ``valid`` is False)
         - valid (F, B): feature bin has its own bundle slot (False for the
           shared default bin of multi-bundles and past num_bins)
-        - has_rest (F,): feature lives in a multi-feature bundle — its
-          default bin must be recovered as parent_total - sum(own slots)
-        - dpos (F,): the feature's default bin index
-        - map_fb (F, Bm): bundle bin -> this feature's bin (its default bin
-          for bundle bins belonging to other sub-features / shared zero)
-        - group (F,), offset (F,), nbm1 (F,): routing arithmetic inputs
         """
         F = self.num_features
         B = int(self.feature_num_bins().max()) if F else 1
         Bm = int(self.group_num_bins().max()) if self.groups else 1
+        view = self.bundle_view()
+        runs = view.form == "runs"
+        W = view.width
         proj = np.zeros((F, B), np.int32)
         valid = np.zeros((F, B), bool)
+        # only the form taken is filled: past the limit these would be large
+        sel = np.zeros((Bm, F, W) if runs else (0, 0, 0), bool)
+        sel_mine = np.zeros((self.num_groups, F, W) if runs else (0, 0, 0),
+                            bool)
+        alone_col = np.zeros(F, np.int32)
+        alone_cell = np.zeros((F, B), np.int8)
+        slice_of = {gid: k for k, gid in enumerate(view.slices)}
+        put = np.zeros((F, B), bool)
         has_rest = np.zeros(F, bool)
         dpos = np.zeros(F, np.int32)
         map_fb = np.zeros((F, Bm), np.int32)
@@ -305,15 +393,31 @@ class BinnedDataset:
                     map_fb[j, :] = m.default_bin
                     own = np.arange(nb)[bb_ids != d]
                     map_fb[j, off:off + nb - 1] = own
+                    if runs:
+                        sel[off + np.arange(nb - 1), j, own] = True
+                        sel_mine[gid, j] = True
+                    put[j, d] = True
                 else:
                     proj[j, :nb] = gid * Bm + bb_ids
                     valid[j, :nb] = True
                     map_fb[j, :min(nb, Bm)] = np.arange(min(nb, Bm))
                     map_fb[j, nb:] = nb - 1
-        return dict(proj=proj, valid=valid, has_rest=has_rest, dpos=dpos,
-                    map_fb=map_fb, group=self.feature_to_group.astype(np.int32),
+                    alone_col[j] = gid
+                    alone_cell[j, :nb] = 1 + slice_of.get(gid, 0)
+        maps = dict(has_rest=has_rest, dpos=dpos, put=put, map_fb=map_fb,
+                    group=self.feature_to_group.astype(np.int32),
                     offset=self.feature_group_offset.astype(np.int32),
                     nbm1=nbm1)
+        if not runs:
+            return dict(maps, proj=proj, valid=valid)
+        import jax.numpy as jnp
+        maps.update(sel=sel.reshape(Bm, F * W).astype(jnp.bfloat16),
+                    sel_mine=sel_mine.reshape(-1, F * W))
+        if view.alone:
+            maps.update(alone_cell=alone_cell)
+            if not view.slices:
+                maps.update(alone_col=alone_col)
+        return maps
 
 
 def _resolve_categorical(

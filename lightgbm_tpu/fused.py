@@ -272,7 +272,8 @@ class FusedTrainer:
             tuple(lrn.hp),
             (lrn.comm.axis, lrn.comm.mode, lrn.comm.top_k,
              lrn.comm.num_machines),
-            _fp_hash(lrn.bundle), _fp_hash(lrn._forced_splits()),
+            _fp_hash(lrn.bundle), lrn.bundle_view,
+            _fp_hash(lrn._forced_splits()),
             _fp_hash(lrn._constraint_sets()),
         )
 

@@ -32,7 +32,7 @@ import numpy as np
 
 from . import runtime
 from .config import Config
-from .dataset import BinnedDataset
+from .dataset import BinnedDataset, BundleView
 from .obs import trace_phase, track_jit
 from .ops.histogram import build_histogram, hist_bins
 from .ops.split import (
@@ -704,6 +704,7 @@ def build_tree_partitioned(
     hist_lo: int = 0,         # hi/lo einsum split width (0 = auto by F)
     num_bin_hist: Optional[int] = None,   # bundled-column bins (defaults num_bin)
     bundle: Optional[dict] = None,        # EFB maps (dataset.bundle_maps)
+    bundle_view: Optional[BundleView] = None,  # their static half
     constraint_sets: Optional[jax.Array] = None,   # (S, F) bool
     forced: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     part_kernel: str = "xla",  # xla | pallas (fused DMA kernel, TPU only)
@@ -779,6 +780,7 @@ def build_tree_partitioned(
             hist_chunk=hist_chunk, part_chunk=part_chunk,
             hist_mode=hist_mode, hist_lo=hist_lo,
             num_bin_hist=num_bin_hist, bundle=bundle,
+            bundle_view=bundle_view,
             constraint_sets=constraint_sets, forced=forced,
             part_kernel=part_kernel, hist_kernel=hist_kernel,
             work_layout=work_layout, goss_compact_rows=0,
@@ -968,7 +970,7 @@ def build_tree_partitioned(
         pad bins are cut off."""
         if bundle is None:
             return hg[..., :num_bin]
-        return bundle_feature_view(hg, total_sum, bundle, bm)
+        return bundle_feature_view(hg, total_sum, bundle, bm, bundle_view)
 
     def feat_views(hists, tot_g, tot_l):
         """The per-feature views of K nodes' histograms, (K, 3, F, B), for
@@ -1460,34 +1462,77 @@ def build_tree_partitioned(
     return log
 
 
-def bundle_feature_view(hg: jax.Array, total_sum: jax.Array,
-                        bundle: dict, num_bin_hist: int) -> jax.Array:
+def bundle_feature_view(hg: jax.Array, total_sum: jax.Array, bundle: dict,
+                        num_bin_hist: int, view: BundleView) -> jax.Array:
     """Bundled (3, G, Bp) histogram -> per-feature (3, F, B) view, both
-    channel-major, by the maps of ``BinnedDataset.bundle_maps`` (whose
-    ``proj`` counts ``num_bin_hist`` slots a device column).
+    channel-major, by ``BinnedDataset.bundle_maps`` (the arrays) and
+    ``bundle_view`` (the static half, which names the form).
 
-    Each sub-feature's own bundle slots are gathered; its shared default
-    bin is recovered as total - sum(own slots) — the reference's
-    FixHistogram contract (include/LightGBM/dataset.h:503).
-
-    The gather moves (g, h, count) triples by slot index out of the small
-    bundled histogram (G x Bp x 12 B: 30 KB at expo.train's G = 10): a
-    gather's price on a v5e follows its index count and slice shape, not its
-    bytes (PERF.md section 6, PR 31), and one triple an index is the form
-    PR 28 measured; what it hands the scan is channel-major.
+    Each feature's own slots are placed at its own bins, zeros elsewhere,
+    in one of two forms; then a bundled feature's shared default bin is
+    recovered as total - sum(own slots): the reference's FixHistogram
+    contract (include/LightGBM/dataset.h:503). Both forms hand that step
+    the same values, so the view is the same bit for bit.
     """
+    own = _own_slots_runs(hg, bundle, view) if view.form == "runs" \
+        else _own_slots_gather(hg, bundle, num_bin_hist)
+    rest = total_sum[:, None] - jnp.sum(own, axis=2)             # (3, F)
+    return jnp.where(bundle["put"], rest[:, :, None], own)
+
+
+def _own_slots_gather(hg, bundle, num_bin_hist):
+    """The view's own slots by an index a (feature, bin): F x B triples
+    gathered out of the bundled histogram (``proj`` counts ``num_bin_hist``
+    slots a device column). A gather's price on a v5e follows its index
+    count, not its bytes (PERF.md section 6, PR 31): 0.32 ms for
+    expo.train's 168,000 indices, of which 1,140 hold a slot, and 0.11 ms
+    more to turn the (F x B, 3) result channel-major. The form of a table
+    whose selection matrix would pass ``dataset.VIEW_SEL_MAX_BYTES``."""
     num_feat, num_bin = bundle["proj"].shape
     bp = hg.shape[-1]
     proj = bundle["proj"] // num_bin_hist * bp + bundle["proj"] % num_bin_hist
     flat = jnp.moveaxis(hg, 0, -1).reshape(-1, 3)                # (G*Bp, 3)
     fh = jnp.take(flat, proj.reshape(-1), axis=0).T \
         .reshape(3, num_feat, num_bin)
-    fh = fh * bundle["valid"][None]
-    rest = total_sum[:, None] - jnp.sum(fh, axis=2)              # (3, F)
-    dpos_oh = (jnp.arange(num_bin, dtype=jnp.int32)[None, :]
-               == bundle["dpos"][:, None])                        # (F, B)
-    put = dpos_oh & bundle["has_rest"][:, None]
-    return jnp.where(put[None], rest[:, :, None], fh)
+    return fh * bundle["valid"][None]
+
+
+def _own_slots_runs(hg, bundle, view):
+    """The view's own slots placed run by run, with no index a bin and no
+    array that has the channels minor.
+
+    A bundled feature's run leaves its column by a product with ``sel``,
+    the 0/1 matrix that also spreads the run around the default bin. The
+    product must copy, not round: the histogram goes in as three bfloat16
+    terms (hi, mid, lo: 3 x 8 mantissa bits hold an f32's 24), each term's
+    product with 1.0 is exact, every output holds one non-zero product,
+    and hi + mid + lo is the f32 again. All columns meet all of ``sel`` in
+    ONE product (3 terms x 3 channels x G rows are far under an MXU pass),
+    and each output column keeps its own device column's row.
+    A feature alone in its column is the column's row cut to its bins.
+    """
+    num_feat = bundle["dpos"].shape[0]
+    num_bin, width = view.num_bin, view.width
+    x = hg[..., :bundle["sel"].shape[0]]                         # (3, G, Bm)
+    # reduce_precision, not a round trip through bfloat16: XLA may
+    # elide f32 -> bf16 -> f32 (xla_allow_excess_precision)
+    hi = jax.lax.reduce_precision(x, 8, 7)
+    mid = jax.lax.reduce_precision(x - hi, 8, 7)
+    terms = jnp.stack([hi, mid, x - hi - mid]).astype(jnp.bfloat16)
+    prod = jnp.einsum("tcgs,sn->tcgn", terms, bundle["sel"],
+                      preferred_element_type=jnp.float32)
+    picked = jnp.sum(jnp.where(bundle["sel_mine"],
+                               prod[0] + prod[1] + prod[2], 0.0),
+                     axis=1)                                      # (3, F*W)
+    own = jnp.pad(picked.reshape(3, num_feat, width),
+                  ((0, 0), (0, 0), (0, num_bin - width)))
+    for k, col in enumerate(view.slices):
+        own = jnp.where(bundle["alone_cell"] == k + 1,
+                        hg[:, col, None, :num_bin], own)
+    if view.alone and not view.slices:
+        rows = jnp.take(hg[..., :num_bin], bundle["alone_col"], axis=1)
+        own = jnp.where(bundle["alone_cell"] > 0, rows, own)
+    return own
 
 
 @partial(jax.jit, static_argnames=("has_categorical",))
@@ -1709,10 +1754,11 @@ class SerialTreeLearner:
         self.bins = dataset.device_bins()
         self.num_bin_hist = int(max(2, dataset.group_num_bins().max()
                                     if dataset.num_groups else 2))
-        self.bundle = None
+        self.bundle = self.bundle_view = None
         if dataset.has_bundles:
             self.bundle = {k: jnp.asarray(v)
                            for k, v in dataset.bundle_maps().items()}
+            self.bundle_view = dataset.bundle_view()
         if self.hp.mono_advanced and not self.use_partition():
             Log.warning("monotone_constraints_method=advanced needs the "
                         "partitioned builder (max_bin <= 256); the dense "
@@ -2091,6 +2137,7 @@ class SerialTreeLearner:
                 hist_lo=int(config.tpu_hist_lo),
                 num_bin_hist=self.num_bin_hist,
                 bundle=self.bundle,
+                bundle_view=self.bundle_view,
                 part_kernel=part_kernel,
                 hist_kernel=hist_kernel,
                 work_layout=layout,
@@ -2111,6 +2158,12 @@ class SerialTreeLearner:
                 * hist_bins(self.num_bin_hist) * 12 / 1e9,
                 work_buffer_gb=float(np.prod(
                     self._work_buf_shape(kw), dtype=np.float64)) / 1e9)
+            if self.bundle_view is not None:
+                # which form builds the bundled table's per-feature view
+                # (dataset.VIEW_SEL_MAX_BYTES) and over what
+                bv = self.bundle_view
+                path.update(efb_view=bv.form, efb_alone=bv.alone,
+                            efb_bundled=bv.bundled, efb_sel_bytes=bv.sel_bytes)
             telemetry.record("learner_path",
                              dedupe_key=tuple(path.values()), **path)
         else:

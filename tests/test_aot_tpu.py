@@ -150,15 +150,26 @@ def test_default_lambdarank_block_compiles(topo, ds_rank):
     assert resolved(kw) == ("planes", "pallas", "pallas")
 
 
+_BUNDLED = {}
+
+
+def _bundled_block(topo, ds_onehot):
+    """expo.train's block at 200K rows, compiled once for both tests."""
+    if not _BUNDLED:
+        kw, c = compile_block(topo, ds_onehot, {
+            "objective": "binary", "min_data_in_leaf": 0,
+            "min_sum_hessian_in_leaf": 100})
+        _BUNDLED.update(kw=kw, c=c)
+    return _BUNDLED["kw"], _BUNDLED["c"]
+
+
 def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
     """The narrowest width the planes partition kernel meets: G = 10 device
     columns + 12 payload bytes pad to W = 32 (Higgs 64, MSLR 160), with the
     bundle maps as arguments and the view under its own phase."""
     binned = ds_onehot.construct()
     assert binned.has_bundles and len(binned.used_feature_indices) == 700
-    kw, c = compile_block(topo, ds_onehot, {
-        "objective": "binary", "min_data_in_leaf": 0,
-        "min_sum_hessian_in_leaf": 100})
+    kw, c = _bundled_block(topo, ds_onehot)
     assert resolved(kw) == ("planes", "pallas", "pallas")
     assert kw["bundle"] is not None and kw["num_bin_hist"] == 256
     _, width = partition.work_spec(binned.num_groups, False, kw["part_kernel"],
@@ -167,6 +178,47 @@ def test_default_bundled_block_compiles_at_32_planes(topo, ds_onehot):
     assert (binned.num_groups, width) == (10, 32)
     text = c.as_text()
     assert "lgbtpu/efb_view" in text and "partition_segment_planes_fused" in text
+
+
+def test_bundled_view_places_runs_and_gathers_no_bin(topo, ds_onehot):
+    """What PR 38 bought, read in the compiled text with no chip: until then
+    226 of expo.train's 572 device ms an iteration gathered F x B = 168,000
+    (g, h, count) triples out of the 30 KB bundled histogram at each of 509
+    node searches (``f32[168000,2,3]``, 1,140 of them a slot) and copied the
+    result channel-major through tiles padded 3 -> 128. In the ``runs`` form
+    no instruction under ``lgbtpu/efb_view`` is a gather, none holds an
+    array as long as the grid has cells along one axis, none over 64 KiB
+    has 3 as its layout's minor dimension (the pair's (2, 3, 700) default
+    bins, 17 KB, keep the layout XLA gave them in the parent too), the
+    bundled features' runs leave their columns in one product, and
+    ``route_table`` keeps the scope alive."""
+    kw, c = _bundled_block(topo, ds_onehot)
+    view = kw["bundle_view"]
+    assert (view.form, view.alone, view.bundled, view.width) == \
+        ("runs", 2, 698, 2)
+    assert set(kw["bundle"]) >= {"sel", "sel_mine", "put", "map_fb"} \
+        and not {"proj", "valid"} & set(kw["bundle"])
+    cells = 700 * view.num_bin
+    ops, bad = [], []
+    for ln in c.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \S+ ([\w\-]+)\(", ln)
+        if not m or "lgbtpu/efb_view" not in ln:
+            continue
+        ops.append((m.group(1), ln))
+        if m.group(1) == "gather":
+            bad.append(ln.strip()[:200])
+        for dt, dims, lay in _arrays_of(ln):
+            if max(dims) >= cells or (
+                    dims[lay[0]] == 3
+                    and _DT_BYTES[dt] * int(np.prod(dims)) > 1 << 16):
+                bad.append(ln.strip()[:200])
+    assert len(ops) > 20 and not bad, bad
+    # root and pair: the three bfloat16 terms of (3, 10, 256) against sel
+    assert sorted(ln.split(" = ")[1].split("{")[0] for op, ln in ops
+                  if op == "convolution") == \
+        ["f32[2,3,3,10,1400]", "f32[3,3,10,1400]"]
+    # the routing table's translation, 254 times a tree, is still a view op
+    assert [ln for _, ln in ops if "pred[256]" in ln]
 
 
 def test_categorical_block_compiles_with_the_pallas_router(topo, ds_coded):

@@ -146,7 +146,8 @@ def test_feature_view_equals_histograms_of_the_raw_columns(efb, table, clean):
     # the loop's channel-major (3, G, Bp) in, (3, F, B) out
     view = np.asarray(hist_fb3(bundle_feature_view(
         hist_planes(jnp.asarray(hist, jnp.float32)),
-        jnp.asarray(total, jnp.float32), maps, Bm), maps["proj"].shape[1]))
+        jnp.asarray(total, jnp.float32), maps, Bm, binned.bundle_view()),
+        maps["put"].shape[1]))
     raw = efb.raw_feature_histograms(efb.csc_of(table["X"]), gm, ghc)
     fixed = efb.feature_histograms(hist, total, gm)
     # the reference's two routes agree to float64 rounding
@@ -159,6 +160,40 @@ def test_feature_view_equals_histograms_of_the_raw_columns(efb, table, clean):
     # entered the difference (the node's total or the bin itself)
     scale = np.maximum(np.abs(raw[:, :, :2]), np.abs(total[:2])[None, None, :])
     assert np.all(np.abs(view[:, :, :2] - raw[:, :, :2]) <= 1e-6 * scale)
+
+
+def test_feature_view_of_the_cells_table_places_runs_bit_for_bit(
+        table, clean, monkeypatch):
+    """The cell's own layout (698 one-hot columns in eight bundles, two
+    numeric columns alone) takes the ``runs`` form, and on histograms of the
+    table's own rows the view is the (feature, bin) gather's, every float32
+    of it: a one-hot column owns one slot, so its default bin is a
+    difference of two numbers whatever the order of a sum."""
+    from lightgbm_tpu import dataset as D
+    from test_hist_planes import _gather_view
+    _, binned = clean
+    view = binned.bundle_view()
+    assert (view.form, view.alone, view.bundled, view.width, view.num_bin,
+            len(view.slices)) == ("runs", 2, 698, 2, 240, 2)
+    maps = {k: jnp.asarray(v) for k, v in binned.bundle_maps().items()}
+    monkeypatch.setattr(D, "VIEW_SEL_MAX_BYTES", 0)
+    ref_maps = {k: jnp.asarray(v) for k, v in binned.bundle_maps().items()}
+    monkeypatch.undo()
+    rng = np.random.RandomState(5)
+    ghc = np.stack([rng.normal(size=ROWS), rng.uniform(0.1, 1.0, ROWS),
+                    np.ones(ROWS)], axis=1).astype(np.float32)
+    G, Bm = binned.num_groups, int(binned.group_num_bins().max())
+    hist = np.zeros((G, Bm, 3), np.float32)
+    for g in range(G):
+        for c in range(3):
+            hist[g, :, c] = np.bincount(binned.binned[:, g], weights=ghc[:, c],
+                                        minlength=Bm)
+    hg = hist_planes(jnp.asarray(hist))
+    total = jnp.asarray(ghc.sum(axis=0, dtype=np.float64), jnp.float32)
+    got = np.asarray(bundle_feature_view(hg, total, maps, Bm, view))
+    want = np.asarray(_gather_view(hg, total, ref_maps, Bm))
+    assert got.shape == (3, 700, 240) and np.array_equal(got, want)
+    assert np.count_nonzero(got[2]) > 700
 
 
 def test_first_root_split_is_the_references(efb, table, clean):

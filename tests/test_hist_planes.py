@@ -91,7 +91,7 @@ def test_planes_scan_is_the_fb3_scan_bit_for_bit(rng, case, monkeypatch):
         parent = jnp.asarray(ghc.astype(np.float64).sum(axis=0), jnp.float32)
         maps = {k: jnp.asarray(v) for k, v in binned.bundle_maps().items()}
         planes = bundle_feature_view(hist_planes(jnp.asarray(hg)), parent,
-                                     maps, bm)
+                                     maps, bm, binned.bundle_view())
         b = planes.shape[2]
         meta = _meta([m.num_bins for m in
                       (binned.bin_mappers[j] for j in
@@ -218,3 +218,191 @@ def test_pool_round_trip_is_the_identity(rng, g, num_bin):
                              jnp.bool_(False))
     assert np.asarray(left).tobytes() == large.tobytes()
     assert np.asarray(right).tobytes() == np.asarray(small).tobytes()
+
+
+# ------------------------------------------- the bundled table's view (PR 38)
+
+def _layout(groups):
+    """A ``BinnedDataset`` that holds nothing but a bundle layout:
+    ``groups`` lists every device column's members as (feature, num_bins,
+    default_bin), laid out as ``dataset._make_groups`` does (slot 0 of a
+    shared column is the shared zero, a member owns the next num_bins - 1)."""
+    from types import SimpleNamespace
+    from lightgbm_tpu.dataset import BinnedDataset, FeatureGroupInfo
+    ds = BinnedDataset()
+    feats = sorted(m for g in groups for m in g)
+    assert [f for f, _, _ in feats] == list(range(len(feats)))
+    ds.bin_mappers = [SimpleNamespace(num_bins=nb, default_bin=d)
+                      for _, nb, d in feats]
+    ds.feature_to_group = np.zeros(len(feats), np.int32)
+    ds.feature_group_offset = np.zeros(len(feats), np.int32)
+    for gid, members in enumerate(groups):
+        offs, off = [], 1 if len(members) > 1 else 0
+        for f, nb, _ in members:
+            offs.append(off)
+            ds.feature_to_group[f], ds.feature_group_offset[f] = gid, off
+            off += nb - 1
+        ds.groups.append(FeatureGroupInfo(
+            [f for f, _, _ in members], offs,
+            off if len(members) > 1 else members[0][1]))
+    return ds
+
+
+def _view_layouts(name):
+    rng = np.random.RandomState(7)
+    if name == "expo_like":
+        # expo.train's table: 698 one-hot columns in eight bundles and two
+        # numeric columns alone, 240 and 200 bins
+        groups, f = [], 0
+        for k in (12, 31, 7, 22, 255, 58, 255, 58):
+            groups.append([(f + i, 2, 0) for i in range(k)])
+            f += k
+        return groups + [[(f, 240, 0)], [(f + 1, 200, 0)]]
+    if name.startswith("default_"):
+        # numerical members of 3-60 bins, the default bin first, in the
+        # middle or last
+        where = name[len("default_"):]
+        groups, f = [], 0
+        for widths in ((3, 60, 17, 5), (33, 8, 4, 21, 12), (60, 60), (9, 3)):
+            g = []
+            for nb in widths:
+                g.append((f, nb, {"first": 0, "middle": nb // 2,
+                                  "last": nb - 1}[where]))
+                f += 1
+            groups.append(g)
+        return groups
+    if name == "alone_beside_bundles":
+        # more features alone than dataset.VIEW_ALONE_SLICES, between the
+        # bundles' members: the row selection with an index a feature
+        groups, f = [], 0
+        for k in range(6):
+            groups.append([(f, int(rng.randint(2, 9)), 0),
+                           (f + 2, int(rng.randint(2, 9)), 1)])
+            groups.append([(f + 1, int(rng.randint(20, 64)), 0)])
+            groups.append([(f + 3, int(rng.randint(2, 64)), 0)])
+            f += 4
+        return groups
+    if name == "few_alone":
+        return [[(0, 4, 1), (3, 7, 0)], [(1, 31, 0)], [(2, 9, 3), (4, 2, 0)],
+                [(5, 12, 0)]]
+    if name == "out_of_order":
+        # a bundle's members follow neither feature-index order nor each
+        # other
+        return [[(5, 7, 2), (0, 3, 0), (3, 12, 11)], [(4, 2, 0), (1, 9, 4)],
+                [(2, 30, 0)], [(7, 5, 0), (6, 5, 4)]]
+    if name == "over_limit":
+        # 400 members, one of 61 bins among them: a matrix of 160 slots x
+        # 400 x 61 passes dataset.VIEW_SEL_MAX_BYTES and the table keeps
+        # the gather
+        groups, f = [], 0
+        for g in range(4):
+            wide = [(f, 61, 0)] if g == 0 else []
+            rest = [(f + len(wide) + i, 2, 0) for i in range(100 - len(wide))]
+            groups.append(wide + rest)
+            f += 100
+        return groups
+    raise AssertionError(name)
+
+
+def _gather_view(hg, total_sum, maps, num_bin_hist):
+    """The view as PR 28 wrote it and every commit to PR 37 ran it: one
+    (g, h, count) triple gathered an index of ``proj``, F x B of them. The
+    plain reference the program's view is held to, bit for bit."""
+    num_feat, num_bin = maps["proj"].shape
+    bp = hg.shape[-1]
+    proj = maps["proj"] // num_bin_hist * bp + maps["proj"] % num_bin_hist
+    flat = jnp.moveaxis(hg, 0, -1).reshape(-1, 3)
+    fh = jnp.take(flat, proj.reshape(-1), axis=0).T \
+        .reshape(3, num_feat, num_bin)
+    fh = fh * maps["valid"][None]
+    rest = total_sum[:, None] - jnp.sum(fh, axis=2)
+    dpos_oh = (jnp.arange(num_bin, dtype=jnp.int32)[None, :]
+               == maps["dpos"][:, None])
+    put = dpos_oh & maps["has_rest"][:, None]
+    return jnp.where(put[None], rest[:, :, None], fh)
+
+
+VIEW_LAYOUTS = {"expo_like": "runs", "default_first": "runs",
+                "default_middle": "runs", "default_last": "runs",
+                "alone_beside_bundles": "runs", "few_alone": "runs",
+                "out_of_order": "runs", "over_limit": "gather"}
+
+
+@pytest.mark.parametrize("mode", ["alone", "jit", "vmap_one_node",
+                                  "vmap_two_nodes_global_totals",
+                                  "vmap_two_nodes_local_totals"])
+@pytest.mark.parametrize("layout", sorted(VIEW_LAYOUTS))
+def test_bundle_view_is_the_gather_bit_for_bit(layout, mode, monkeypatch):
+    """``bundle_feature_view`` in the form ``bundle_view()`` names for the
+    layout against the (feature, bin) gather it replaced, ``np.array_equal``
+    on the f32 planes: alone, under ``jit`` and under ``vmap`` as
+    ``feat_views`` calls it (one node at the root, a pair of children; the
+    totals the nodes' own, or, as the voting learner's, other ones)."""
+    from lightgbm_tpu import dataset as D
+    ds = _layout(_view_layouts(layout))
+    view = ds.bundle_view()
+    assert view.form == VIEW_LAYOUTS[layout]
+    assert view.alone + view.bundled == ds.num_features
+    maps = {k: jnp.asarray(v) for k, v in ds.bundle_maps().items()}
+    assert ("sel" in maps, "proj" in maps) == (view.form == "runs",
+                                               view.form == "gather")
+    monkeypatch.setattr(D, "VIEW_SEL_MAX_BYTES", 0)
+    assert ds.bundle_view().form == "gather"
+    ref_maps = {k: jnp.asarray(v) for k, v in ds.bundle_maps().items()}
+    monkeypatch.undo()
+
+    g, bm = ds.num_groups, int(ds.group_num_bins().max())
+    nodes = 1 if mode in ("alone", "jit", "vmap_one_node") else 2
+    put = np.asarray(maps["put"])
+    one_slot = np.asarray(maps["nbm1"]) == 1
+
+    def new(h, t):
+        return bundle_feature_view(h, t, maps, bm, view)
+
+    def old(h, t):
+        return _gather_view(h, t, ref_maps, bm)
+
+    rng = np.random.RandomState(3)
+    for cells in ("every_f32", "exact_sums"):
+        hg = np.zeros((nodes, 3, g, hist_bins(bm)), np.float32)
+        for gid, grp in enumerate(ds.groups):
+            # the pad lanes past a column's bins stay zero, as the kernels'
+            shape = (nodes, 3, grp.num_bins)
+            if cells == "every_f32":
+                # all 24 bits of the mantissa, twenty binades, both signs:
+                # what the product's three bfloat16 terms must carry whole
+                v = (rng.randint(1 << 23, 1 << 24, shape) * 2.0
+                     ** rng.randint(-33, -13, shape)) * rng.choice((-1, 1),
+                                                                   shape)
+            else:
+                # multiples of 1 / 64 under 1024: up to 64 of them sum
+                # exactly in whatever order a backend's reduce takes them
+                v = rng.randint(-(1 << 16), 1 << 16, shape) / 64.0
+            hg[:, :, gid, :grp.num_bins] = v
+        if mode.endswith("local_totals"):
+            totals = rng.randint(-(1 << 16), 1 << 16, (nodes, 3)) / 64.0
+        else:
+            totals = hg[:, :, 0, :].sum(axis=-1)
+        hg, totals = jnp.asarray(hg), jnp.asarray(totals, jnp.float32)
+        if mode == "alone":
+            got, want = new(hg[0], totals[0]), old(hg[0], totals[0])
+        elif mode == "jit":
+            got, want = jax.jit(new)(hg[0], totals[0]), old(hg[0], totals[0])
+        else:
+            got = jax.jit(jax.vmap(new))(hg, totals)
+            want = jnp.stack([old(h, t) for h, t in zip(hg, totals)])
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.dtype == want.dtype == np.float32
+        assert got.shape == want.shape == (
+            (3, ds.num_features, view.num_bin) if got.ndim == 3
+            else (nodes, 3, ds.num_features, view.num_bin))
+        # a default bin over SEVERAL own slots is a float32 sum whose order
+        # is the backend's (XLA:CPU's fused reduce follows LLVM's
+        # vectorisation, and two jits of one function differ): held where
+        # no order can round, and every other cell, each a copy, always
+        if cells == "every_f32":
+            ordered = put & ~one_slot[:, None]
+            got, want = (np.where(ordered, 0, x) for x in (got, want))
+        assert np.array_equal(got, want), cells
+        # and every own slot really arrived (a view of zeros equals nothing)
+        assert np.count_nonzero(got) > ds.num_features
