@@ -363,10 +363,10 @@ class Config:
     tpu_work_layout: str = "auto"    # auto|rows|planes: training work
     #   buffer layout. rows = (2, Npad, W) row-major; planes = transposed
     #   (2, W, Npad) feature-major planes — each 128-lane tile carries 128
-    #   rows of ONE byte column (no dead lanes) and the root histogram is
-    #   folded into the pack pass. auto: planes on TPU at every width
-    #   whose chunk the planes kernels' VMEM holds (F <= 6,896; int8
-    #   excepted), rows elsewhere. Both layouts grow bit-identical trees.
+    #   rows of ONE byte column (no dead lanes). auto: planes on TPU
+    #   at every width whose chunk the planes kernels' VMEM holds
+    #   (F <= 6,896; int8 excepted), rows elsewhere. Both layouts grow
+    #   bit-identical trees.
     tpu_resident_state: str = "auto"  # auto|off|on: resident permuted
     #   training state (planes layout only). The bin planes live ONCE in a
     #   (F, Npad) resident buffer in original row order; the per-split

@@ -717,7 +717,7 @@ def build_tree_partitioned(
     # ~20 ms at 2M x 28; assign_leaves needs the transposed layout)
     work_layout: str = "rows",  # rows ((2, Npad, W) row-major) | planes
     # ((2, W, Npad) feature-major: 128-lane tiles carry 128 rows of ONE
-    # byte column, and the root histogram folds into the pack pass) |
+    # byte column) |
     # resident (planes family: bin planes live once in bins_res and the
     # slim 17-plane work buffer moves only route/ridx/g/h/c per split)
     bins_res: Optional[jax.Array] = None,  # (F, Npad) resident bin planes
@@ -814,13 +814,14 @@ def build_tree_partitioned(
                                 hist16_segment_q, hist16_segment_resident,
                                 hist_pallas_segment,
                                 hist_pallas_segment_planes)
-    from .ops.partition import (pack_planes_fold_root,
+    from .ops.partition import (pack_planes_write,
                                 pack_resident_fold_root, pack_rows,
                                 pack_rows_quantized, partition_segment,
                                 partition_segment_fused,
                                 partition_segment_planes,
                                 partition_segment_planes_fused, planes_npad,
-                                resident_bin_planes, write_route_plane)
+                                planes_pack_chunk, resident_bin_planes,
+                                write_route_plane)
 
     n, num_grp = bins.shape
     num_feat = int(meta.num_bins.shape[0])
@@ -846,10 +847,9 @@ def build_tree_partitioned(
             raise ValueError("tpu_work_layout=planes does not support int8 "
                              "quantized training (the learner gate keeps "
                              "auto on rows for int8)")
-        # transposed (2, W, Npad) plane pair. The pack pass ALSO produces
-        # the root histogram — iteration 0 never re-reads the full matrix
-        # (stale bytes in a carried buffer's guard lanes are never consumed:
-        # partitions only commit valid rows and histograms mask by count)
+        # transposed (2, W, Npad) plane pair (stale bytes in a carried
+        # buffer's guard lanes are never consumed: partitions only commit
+        # valid rows and histograms mask by count)
         if work_buf is not None:
             work = work_buf
         else:
@@ -882,13 +882,11 @@ def build_tree_partitioned(
                 return base_part(work, plane, start, cnt, jnp.int32(0),
                                  table, ch=ch)
         else:
-            from .ops.histogram import root_einsum_chunk
+            # the pack only writes; the root histogram is hist_of's over
+            # the packed root segment (below)
             with trace_phase("lgbtpu/pack"):
-                work, root_hist_loc = pack_planes_fold_root(
-                    work, bins, ghc, guard, num_bins=bm,
-                    exact=hist_mode != "bf16",
-                    chunk=root_einsum_chunk(num_grp, hist_chunk),
-                    lo_w=hist_lo)
+                work = pack_planes_write(work, bins, ghc, guard,
+                                         chunk=planes_pack_chunk(buf_width))
             part_fn = base_part
     else:
         pad = ((guard, guard), (0, 0))
@@ -1086,14 +1084,16 @@ def build_tree_partitioned(
         root_sum_loc = jnp.sum(ghc, axis=0) if root_sum_in is None \
             else root_sum_in
         root_sum = comm.root(root_sum_loc)
-    if planes:
+    if resident:
         # folded into the pack pass above: bit-identical accumulation to
-        # the XLA segment histogram over the root segment (same chunking,
-        # same einsum order). The Pallas hist_of adds the same exact
-        # products in another order: equal counts, sums within 1e-6 of a
-        # cell's sum of |terms| (tests/test_histogram.py)
+        # the planes path's segment histogram over the root segment (same
+        # chunking, same einsum order)
         root_hist = comm.hist(root_hist_loc)
     else:
+        # the segment histogram of the whole packed root segment, the
+        # children's path (one accumulation order for the root and both
+        # children), outside lgbtpu/histogram, which times the smaller
+        # children alone
         with trace_phase("lgbtpu/root_hist"):
             root_hist, work = hist_of(work, jnp.int32(0), jnp.int32(guard),
                                       jnp.int32(n))

@@ -215,11 +215,12 @@ def _hist16_chunk(cb, cgm, num_bins: int, exact: bool, lo_w: int = LO_W):
 # CHANNEL-MAJOR with the bins on the lanes: (3, F, Bp) f32, the planes of
 # (sum_grad, sum_hess, count), Bp = the bins padded to whole 128-lane tiles
 # (zeros past num_bins). Every segment histogram of this file, the root
-# histogram folded into the packs (ops/partition.py) and the learner's pool
-# hold it, and ops/split.py scans it plane by plane. An (F, B, 3) array,
-# channels minor, costs a v5e 0.35-0.56 ms for every op that touches 6.1 MB
-# of it (its tiles pad 3 -> 128): 822 of epsilon.train's 1,340 ms an
-# iteration before PR 36 (PERF.md section 6). The dense builder
+# histogram the resident pack folds in (ops/partition.py) and the
+# learner's pool hold it, and ops/split.py scans it plane by plane. An
+# (F, B, 3) array, channels minor, costs a v5e 0.35-0.56 ms for every op
+# that touches 6.1 MB of it (its tiles pad 3 -> 128): 822 of
+# epsilon.train's 1,340 ms an iteration went to such ops once (PERF.md
+# section 6). The dense builder
 # (build_histogram, learner.build_tree) keeps (F, B, C).
 
 
@@ -723,14 +724,6 @@ def planes_kernel_chunk(num_feat: int) -> int:
 EINSUM_OPERAND_BYTES = 80 << 20
 
 
-def _fit_einsum_operands(chunk: int, num_feat: int) -> int:
-    lo_w = auto_lo_w(num_feat)
-    row = num_feat * (256 // lo_w + 5 * lo_w) * 2       # bf16, 256 bins
-    while chunk > 128 and chunk * row > EINSUM_OPERAND_BYTES:
-        chunk //= 2
-    return chunk
-
-
 def einsum_chunk(num_feat: int) -> int:
     """Rows a pass of the XLA segment loops (``hist16_segment`` and its
     planes twin), from F alone. Measured on v5e (lo_w-tuned einsum):
@@ -741,18 +734,12 @@ def einsum_chunk(num_feat: int) -> int:
     147 / 74 / 37 / 18 MB of operands (my chip run, PR 32)."""
     if num_feat <= 64:
         return 4096
-    return _fit_einsum_operands(1024, num_feat)
-
-
-def root_einsum_chunk(num_feat: int, hist_chunk: int) -> int:
-    """Rows a pass of the planes pack, whose root histogram is the XLA
-    einsum: the segment histogram's own chunk while the einsum's operands
-    fit (8192 / 4096 / 8192 at F = 28 / 137 / 10: PR 29 measured the pack
-    falling as it grew), halved until they do past that (256 at F = 2,000:
-    the pack reads 155 ms there, its root einsum 136 of them; with the
-    transposed write kept at 1024 or 4096 lanes around 256-row einsum
-    passes 252 and 256; my chip run, PR 33)."""
-    return _fit_einsum_operands(hist_chunk, num_feat)
+    lo_w = auto_lo_w(num_feat)
+    row = num_feat * (256 // lo_w + 5 * lo_w) * 2       # bf16, 256 bins
+    chunk = 1024
+    while chunk > 128 and chunk * row > EINSUM_OPERAND_BYTES:
+        chunk //= 2
+    return chunk
 
 
 def _hist_pallas_kernel_planes(sref, work_in, work_ref, hist_ref, cin, bins_s,

@@ -311,52 +311,39 @@ def partition_segment_planes(
         return work, lcur - start
 
 
-def pack_planes_fold_root(work: jax.Array, bins: jax.Array, ghc: jax.Array,
-                          guard, *, num_bins: int, exact: bool, chunk: int,
-                          lo_w: int = 0):
-    """Planes pack pass with the root-node histogram FOLDED IN.
+def _write_planes_chunk(work, cb, cg, at):
+    """Write a (chunk, F) bins + (chunk, 3) f32 row-major chunk into plane 0
+    of the work buffer as its (F + 12, chunk) transposed columns at lane
+    ``at``."""
+    gb = jax.lax.bitcast_convert_type(cg, jnp.uint8) \
+        .reshape(cb.shape[0], GH_BYTES)
+    cw_t = jnp.concatenate([cb, gb], axis=1).T              # (W, chunk)
+    return jax.lax.dynamic_update_slice(work, cw_t[None], (0, 0, at))
 
-    One chunked loop reads (bins, ghc) once, writes the transposed planes
-    into ``work[0][:, guard + i*chunk : ...]`` and accumulates the root
-    histogram from the SAME row-major chunk — iteration 0 never re-reads
-    the packed matrix. Chunk boundaries and masking replicate
-    hist16_segment(work, 0, guard, n) exactly, so the folded histogram is
-    bit-identical to the rows path's root pass.
 
-    Returns (work, (3, F, Bp) root histogram), channel-major like every
-    segment histogram (ops/histogram.py hist_bins) — LOCAL, callers reduce
-    via comm.hist like any other segment histogram.
-    """
-    from .histogram import _hist16_chunk, _hist16_combine, auto_lo_w
-
+def pack_planes_write(work: jax.Array, bins: jax.Array, ghc: jax.Array,
+                      guard, *, chunk: int) -> jax.Array:
+    """The planes pack pass: ``work[0][:, guard : guard + n]`` gets the
+    transposed planes of (bins, ghc) (:func:`pack_planes`), ``chunk`` rows
+    a pass and the last ``n % chunk`` in one pass of their own, so nothing
+    is padded and nothing is written outside the segment. Nothing else is
+    computed: the learner builds the root histogram from the packed
+    segment afterwards, with the segment histogram of its children."""
     n, f = bins.shape
-    lo_w = lo_w or auto_lo_w(f)
-    sh = (num_bins + lo_w - 1) // lo_w
-    nch = 5 if exact else 3
-    nchunks = (n + chunk - 1) // chunk
-    npc = nchunks * chunk
-    binsp = jnp.pad(bins, ((0, npc - n), (0, 0)))
-    ghcp = jnp.pad(ghc, ((0, npc - n), (0, 0)))
+    chunk = min(chunk, n)
+    full = n // chunk
 
-    def body(i, carry):
-        work, acc = carry
+    def body(i, work):
         off = i * chunk
-        cb = jax.lax.dynamic_slice(binsp, (off, 0), (chunk, f))
-        cg = jax.lax.dynamic_slice(ghcp, (off, 0), (chunk, 3))
-        valid = jnp.arange(chunk, dtype=jnp.int32) < n - off
-        cgm = cg * valid[:, None].astype(jnp.float32)
-        acc = acc + _hist16_chunk(cb, cgm, num_bins, exact, lo_w)
-        gb = jax.lax.bitcast_convert_type(cg, jnp.uint8) \
-            .reshape(chunk, GH_BYTES)
-        cw_t = jnp.concatenate([cb, gb], axis=1).T          # (W, chunk)
-        work = jax.lax.dynamic_update_slice(
-            work, cw_t[None], (0, 0, guard + off))
-        return work, acc
+        cb = jax.lax.dynamic_slice(bins, (off, 0), (chunk, f))
+        cg = jax.lax.dynamic_slice(ghc, (off, 0), (chunk, 3))
+        return _write_planes_chunk(work, cb, cg, guard + off)
 
-    work, acc = jax.lax.fori_loop(
-        0, nchunks, body,
-        (work, jnp.zeros((f, sh, lo_w * nch), jnp.float32)))
-    return work, _hist16_combine(acc, num_bins, exact, lo_w)
+    work = jax.lax.fori_loop(0, full, body, work)
+    if n % chunk:
+        off = full * chunk
+        work = _write_planes_chunk(work, bins[off:], ghc[off:], guard + off)
+    return work
 
 
 # ---------------------------------------------------------------------------
@@ -434,8 +421,12 @@ def pack_resident_fold_root(work: jax.Array, bins: jax.Array, ghc: jax.Array,
                             lo_w: int = 0):
     """Resident-state pack pass with the root histogram folded in.
 
-    Mirrors :func:`pack_planes_fold_root` chunk-for-chunk (same
-    _hist16_chunk accumulation order -> bit-identical root histogram) but
+    One chunked loop reads (bins, ghc) once and accumulates the root
+    histogram from the SAME row-major chunk, so iteration 0 never re-reads
+    the matrix: the chunk grid and _hist16_chunk accumulation order of
+    hist16_segment_planes over the planes path's packed root segment, so
+    the root histogram is bit-identical to that path's. Returns (work,
+    (3, F, Bp) LOCAL root histogram; callers reduce it via comm.hist). It
     writes the SLIM planes: a zeroed route plane, row-index byte planes
     holding ABSOLUTE lane positions (guard offset included, so gathers need
     no offset arithmetic), and the g/h/c bytes. The bin planes live in the
@@ -605,6 +596,38 @@ def planes_part_chunk(row_w: int) -> int:
     past it."""
     ch = 1024
     while ch > 256 and row_w * ch > (512 << 10):
+        ch //= 2
+    return ch
+
+
+# lanes a pass of the write-only pack, and the bytes of W planes a pass
+PACK_CHUNK_MAX = 1 << 17
+PACK_CHUNK_BYTES = 32 << 20
+
+
+def planes_pack_chunk(row_w: int) -> int:
+    """Rows a pass of :func:`pack_planes_write`, from the padded plane
+    count W: the largest power of two to 131,072 whose (W, chunk) pass
+    stays within 32 MiB (131,072 to W = 256, 65,536 to 512, 16,384 at
+    2,016). The write costs a fixed time a pass besides its bytes, so it
+    falls as the pass grows, until the pass is some tens of MB: at W =
+    384 it was least at 25 MB and rose at 50; at W = 2,016 it still fell
+    at 33 MB and rose at 132 (66 was not timed); at W = 160 it still fell
+    at 42 MB. The cap of 32 MiB was picked between those points and was
+    not itself measured. The ceiling of 131,072 costs W = 64 and W = 160
+    0.4 ms a tree against 262,144. The block's temporaries do not follow
+    the chunk (the ``v5e:2x2`` compile, 4 to 32 MiB). Measured on a v5e,
+    the write alone on device data at each cell's (N, W), ms a tree at
+    chunks 4096 / 8192 / 16384 / 32768 / 65536 / 131072 / 262144: W = 64,
+    16.8M rows -, 20.18, 15.52, 13.10 (12.90), 11.91 (11.57), 11.00
+    (10.76), (10.35); W = 32, 13.75M: -, 12.67, 9.56, 7.86, 7.26, 6.83,
+    -; W = 160, 5.448M: 19.74, 18.36, 14.04, 12.15 (12.10), 11.08
+    (11.08), (10.49), (10.12); W = 384, 1M: 8.45, 7.89, 6.37, 5.71
+    (5.74), 5.11 (5.18), (5.25), (5.64); W = 2,016, 400K (chunks 1024 /
+    2048 / 4096 / 8192 / 16384 / 65536): 16.80, 16.22, 16.19, 15.45,
+    12.29, 13.89. In brackets: a later run on another machine."""
+    ch = PACK_CHUNK_MAX
+    while ch > 128 and row_w * ch > PACK_CHUNK_BYTES:
         ch //= 2
     return ch
 

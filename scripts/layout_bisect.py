@@ -5,7 +5,7 @@ The hardware harness behind the ``tpu_work_layout`` and
 partition in both layouts side by side.
 
 Measures the three hot paths the layout change touches — partition,
-segment histogram, and pack(+root fold) — under measurement discipline v2
+segment histogram, and pack + root histogram — under measurement discipline v2
 (PERF.md):
 
 - single process, A and B INTERLEAVED trial-by-trial (the device clock
@@ -140,10 +140,13 @@ def pack_make_planes(bins, ghc, guard, n, f_real, work_shape):
             def body(carry, _):
                 s, acc = carry
                 work = jnp.zeros(work_shape, jnp.uint8)
-                work, root = P.pack_planes_fold_root(
-                    work, b, g + s, guard, num_bins=256, exact=True,
-                    chunk=HCH)
-                return (s + 1.0, acc + root[0, 0, 0]), None
+                work = P.pack_planes_write(work, b, g + s, guard,
+                                           chunk=HCH)
+                h = hist16_segment_planes(work, jnp.int32(0),
+                                          jnp.int32(guard), jnp.int32(n),
+                                          num_bins=256, num_feat=f_real,
+                                          chunk=HCH)
+                return (s + 1.0, acc + h[0, 0, 0]), None
             (_, acc), _ = jax.lax.scan(body, (jnp.float32(0),
                                               jnp.float32(0)), None, length=k)
             return acc.reshape(1)
@@ -181,7 +184,7 @@ def main(n, f):
          hist_make(hist16_segment_planes, work_p, guard, n, f, 1)),
         ("pack+root/rows",
          pack_make_rows(bins, ghc, guard, n, f, work_r.shape)),
-        ("pack+root/planes(folded)",
+        ("pack+root/planes",
          pack_make_planes(bins, ghc, guard, n, f, work_p.shape)),
     ]
     res = obs.ab_interleaved(pairs, reps=REPS, k=K)

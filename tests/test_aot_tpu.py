@@ -343,6 +343,43 @@ def test_wide_block_keeps_bins_on_the_lanes_and_one_pool(topo):
                 if re.search(r"= %s\S* copy\(" % re.escape(pool), ln)]
 
 
+# temporaries of the three default blocks while the pack folded the root
+# histogram's XLA einsum in (the v5e:2x2 compile of that program)
+FOLD_TEMP_BYTES = {"binary": 35_868_672, "lambdarank": 360_753_152,
+                   "wide": 1_817_915_904}
+
+
+@pytest.mark.parametrize("block", sorted(FOLD_TEMP_BYTES))
+def test_root_histogram_is_the_kernels(topo, block, request):
+    """The planes pack used to build the root histogram with the XLA
+    one-hot einsum at 0.16-0.23 ns a (row, column), 37-93 % of
+    ``full_n_ms_per_iter`` in the four widest or longest cells. Now the
+    pack only writes the transposed planes and ONE Pallas histogram call
+    reads the packed root segment under ``lgbtpu/root_hist``. In the
+    binary (F = 28), lambdarank (F = 137) and wide (F = 2,000) default
+    blocks no dot, convolution, broadcast or bfloat16 array is left under
+    ``lgbtpu/pack``, and the temporaries are no larger than the fold's."""
+    if block == "wide":
+        _, c = _compile_wide_block(topo, 2000)
+    else:
+        ds = request.getfixturevalue(
+            "ds_binary" if block == "binary" else "ds_rank")
+        _, c = compile_block(topo, ds, {"objective": block})
+    packed, root = [], []
+    for ln in c.as_text().splitlines():
+        scope = re.search(r'op_name="[^"]*?lgbtpu/(pack|root_hist)\b', ln)
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w\-]+)\(", ln)
+        if scope and m:
+            (packed if scope.group(1) == "pack" else root).append(
+                (m.group(2), m.group(1), ln.strip()[:200]))
+    assert packed and not [ln for op, typ, ln in packed
+                           if op in ("dot", "convolution", "broadcast")
+                           or "bf16[" in typ]
+    calls = [ln for op, _, ln in root if op == "custom-call"]
+    assert len(calls) == 1 and "hist_pallas_segment_planes" in calls[0]
+    assert c.memory_analysis().temp_size_in_bytes <= FOLD_TEMP_BYTES[block]
+
+
 def test_planes_kernels_compile_alone_at_2016_planes(topo):
     """The two planes kernels at epsilon.train's W = 2,016 and the chunks
     the width rules give there, on the cell's own (2, 2016, ~402K) buffer:

@@ -437,27 +437,33 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
     assert bool(hits) == bool(warning), warnings_log
 
 
-@pytest.mark.parametrize("f,kernel_chunk,root_chunk,part", [
+@pytest.mark.parametrize("f,kernel_chunk,xla_chunk,part,pack", [
     # the three older cells: today's numbers, which PR 29 and PR 27 measured
-    (28, 8192, 8192, 1024), (137, 4096, 4096, 1024), (10, 8192, 8192, 1024),
+    (28, 8192, 4096, 1024, 131072), (137, 4096, 1024, 1024, 131072),
+    (10, 8192, 4096, 1024, 131072),
     # the band the chip timed at the narrow cells' values (PR 33)
-    (300, 4096, 1024, 1024), (500, 4096, 1024, 1024),
-    # epsilon.train: the chip chose at W = 2,016 (PR 33)
-    (2000, 1024, 256, 256),
+    (300, 4096, 1024, 1024, 65536), (500, 4096, 1024, 1024, 65536),
+    # bosch.train's 358 device columns, W = 384
+    (358, 4096, 1024, 1024, 65536),
+    # epsilon.train: the chip chose at W = 2,016 (the pack's chunk too)
+    (2000, 1024, 256, 256, 16384),
     # the widest table whose accumulator and output planes (PR 36) leave
     # VMEM a chunk, and past it
-    (6896, 128, 128, 256), (6897, 0, 128, 256),
+    (6896, 128, 128, 256, 4096), (6897, 0, 128, 256, 4096),
 ])
-def test_chunks_follow_the_width(f, kernel_chunk, root_chunk, part):
-    """The three static rules of the planes path: the histogram kernel's
-    chunk, the pack's (its root histogram is the XLA einsum, so it follows
-    the einsum's operand bytes) and the partition kernel's."""
+def test_chunks_follow_the_width(f, kernel_chunk, xla_chunk, part, pack):
+    """The static rules of the planes path: the histogram kernel's chunk,
+    the XLA einsum loop's (the CPU and mesh paths: its one-hot operands'
+    bytes), the pack's (a (W, chunk) pass within 32 MiB) and the partition
+    kernel's."""
     from lightgbm_tpu.ops import histogram as H
     assert H.planes_kernel_chunk(f) == kernel_chunk
-    assert H.root_einsum_chunk(
-        f, kernel_chunk or H.einsum_chunk(f)) == root_chunk
+    assert H.einsum_chunk(f) == xla_chunk
     w = P.work_spec(f, False, "pallas", 0, 0, layout="planes")[1]
     assert P.planes_part_chunk(w) == P.planes_part_chunk(f + 12) == part
+    assert P.planes_pack_chunk(w) == pack
+    assert pack * w <= P.PACK_CHUNK_BYTES < 2 * pack * w \
+        or pack == P.PACK_CHUNK_MAX
     if kernel_chunk:
         guard, _ = P.work_spec(f, False, "pallas", part, kernel_chunk,
                                layout="planes")

@@ -156,9 +156,8 @@ def test_kernel_writes_the_channel_major_planes(rng, f, num_bin, monkeypatch):
     ghc = jnp.asarray(ghc)
     npad = P.planes_npad(n, guard, "pallas")
     _, w_pl = P.work_spec(f, False, "pallas", CH, CH, layout="planes")
-    work, _ = P.pack_planes_fold_root(
-        jnp.zeros((2, w_pl, npad), jnp.uint8), bins, ghc, guard,
-        num_bins=num_bin, exact=True, chunk=CH)
+    work = P.pack_planes_write(jnp.zeros((2, w_pl, npad), jnp.uint8), bins,
+                               ghc, guard, chunk=CH)
     a = (jnp.int32(0), jnp.int32(guard + start), jnp.int32(cnt))
     got, _ = hist_pallas_segment_planes(work, *a, num_bins=num_bin,
                                         num_feat=f, chunk=chunk)

@@ -77,6 +77,11 @@ _KERNEL_CASES = [
     (1500, 10, 59, 57, 1000, 512, 4),
     (1500, 10, 59, 300, 1100, 256, 0),
     (1500, 28, 256, 7, 1400, 512, 16),
+    # the whole packed root segment, as the learner builds the root: the
+    # reference below is then the XLA paths' root histogram
+    (1500, 28, 255, 0, 1500, 256, 0),   # narrow: higgs.train's W = 64
+    (1300, 10, 256, 0, 1300, 256, 0),   # bundled: expo.train's 10 columns
+    (900, 300, 31, 0, 900, 256, 0),     # wide: W = 320 planes
 ]
 
 
@@ -100,9 +105,8 @@ def test_hist_pallas_planes_kernel_interpret(rng, n, f, num_bin, start, cnt,
     bins, ghc = _mk(rng, n, f=f, num_bin=num_bin)
     npad = P.planes_npad(n, guard, "pallas")
     _, w_pl = P.work_spec(f, False, "pallas", CH, CH, layout="planes")
-    work = jnp.zeros((2, w_pl, npad), jnp.uint8)
-    work, _ = P.pack_planes_fold_root(
-        work, bins, ghc, guard, num_bins=num_bin, exact=True, chunk=CH)
+    work = P.pack_planes_write(jnp.zeros((2, w_pl, npad), jnp.uint8),
+                               bins, ghc, guard, chunk=CH)
     a = (jnp.int32(0), jnp.int32(guard + start), jnp.int32(cnt))
     got, work_out = hist_pallas_segment_planes(
         work, *a, num_bins=num_bin, num_feat=f, chunk=chunk, lo_w=lo_w)
@@ -136,9 +140,8 @@ def test_hist_pallas_planes_kernel_interpret_bf16_channels(rng, monkeypatch):
     bins, ghc = _mk(rng, n, f=f, num_bin=num_bin)
     npad = P.planes_npad(n, guard, "pallas")
     _, w_pl = P.work_spec(f, False, "pallas", CH, CH, layout="planes")
-    work = jnp.zeros((2, w_pl, npad), jnp.uint8)
-    work, _ = P.pack_planes_fold_root(
-        work, bins, ghc, guard, num_bins=num_bin, exact=False, chunk=CH)
+    work = P.pack_planes_write(jnp.zeros((2, w_pl, npad), jnp.uint8),
+                               bins, ghc, guard, chunk=CH)
     a = (jnp.int32(0), jnp.int32(guard + start), jnp.int32(cnt))
     got, _ = hist_pallas_segment_planes(
         work, *a, num_bins=num_bin, num_feat=f, chunk=256, exact=False)
@@ -168,3 +171,25 @@ def test_hist_pallas_planes_raises_on_bad_shapes():
     with pytest.raises(ValueError, match="does not tile"):
         hist_pallas_segment_planes(work, *a, num_bins=256, num_feat=6,
                                    chunk=256, lo_w=2)
+
+
+@pytest.mark.parametrize("chunk", [128, 256, 512, 2048])
+def test_write_pack_leaves_the_packed_planes(rng, chunk):
+    """The pack writes plane 0 of the work buffer with the transposed
+    planes of (bins, ghc), byte for byte at every lane of the root segment,
+    whatever its chunk (a ragged last pass, one pass longer than the
+    segment), and zeros below them; nothing outside the segment or in
+    plane 1."""
+    n, f = 1100, 20
+    bins, ghc = _mk(rng, n, f=f, num_bin=64)
+    guard, w_pl = P.work_spec(f, False, "pallas", CH, CH, layout="planes")
+    work = jnp.zeros((2, w_pl, P.planes_npad(n, guard, "pallas")), jnp.uint8)
+    written = np.asarray(P.pack_planes_write(work, bins, ghc, guard,
+                                             chunk=chunk))
+    rows = f + P.GH_BYTES
+    assert np.array_equal(written[0, :rows, guard:guard + n],
+                          np.asarray(P.pack_planes(bins, ghc)))
+    assert not written[0, rows:].any()
+    assert not written[0, :, :guard].any()
+    assert not written[0, :, guard + n:].any()
+    assert not written[1].any()

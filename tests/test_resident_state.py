@@ -39,14 +39,17 @@ def _pack_pair(bins, ghc, num_bin, guard=G, part_kernel="xla"):
     work_r = jnp.zeros((2, w_rs, npad), jnp.uint8)
     work_r, root_r = P.pack_resident_fold_root(
         work_r, bins, ghc, guard, num_bins=num_bin, exact=True, chunk=CH)
-    work_p = jnp.zeros((2, w_pl, npad), jnp.uint8)
-    work_p, root_p = P.pack_planes_fold_root(
-        work_p, bins, ghc, guard, num_bins=num_bin, exact=True, chunk=CH)
+    work_p = P.pack_planes_write(jnp.zeros((2, w_pl, npad), jnp.uint8),
+                                 bins, ghc, guard, chunk=CH)
+    root_p = hist16_segment_planes(work_p, jnp.int32(0), jnp.int32(guard),
+                                   jnp.int32(n), num_bins=num_bin,
+                                   num_feat=f, chunk=CH)
     return res, work_r, root_r, work_p, root_p, npad
 
 
 def test_pack_resident_fold_root_matches_planes(rng):
-    """Same root histogram bits as the planes fold, ridx planes encoding
+    """Same root histogram bits as the planes path's (its segment
+    histogram over the packed root segment), ridx planes encoding
     absolute positions, and the g/h/c byte planes equal to the planes
     pack's payload planes."""
     n, f, num_bin = 1000, 6, 32

@@ -94,18 +94,23 @@ def test_hist16_segment_planes_bit_identical(rng):
     assert np.array_equal(hr.view(np.uint8), hp.view(np.uint8))
 
 
-def test_pack_planes_fold_root_matches_segment_hist(rng):
-    """The folded root histogram must be bit-identical to hist16_segment
-    over the packed root segment (same chunking and accumulation order)."""
+def test_pack_planes_write_root_matches_rows(rng):
+    """The planes pack's root histogram (the write-only pack, then the
+    planes segment histogram over the packed root segment) must be
+    bit-identical to the rows path's root pass (same chunking and
+    accumulation order), and the packed planes the transposed packed rows."""
     n, f, num_bin = 1000, 6, 32
     guard, width = P.work_spec(f, False, "xla", CH, CH, layout="planes")
     bins, ghc = _mk(rng, n, f=f, num_bin=num_bin, guard=guard)
     npad = P.planes_npad(n, guard, "xla")
-    work = jnp.zeros((2, width, npad), jnp.uint8)
-    work, root = P.pack_planes_fold_root(
-        work, jnp.asarray(bins[guard:guard + n]),
-        jnp.asarray(ghc[guard:guard + n]), guard,
-        num_bins=num_bin, exact=True, chunk=CH)
+    work = P.pack_planes_write(
+        jnp.zeros((2, width, npad), jnp.uint8),
+        jnp.asarray(bins[guard:guard + n]),
+        jnp.asarray(ghc[guard:guard + n]), guard, chunk=P.planes_pack_chunk(
+            width))
+    root = hist16_segment_planes(work, jnp.int32(0), jnp.int32(guard),
+                                 jnp.int32(n), num_bins=num_bin, num_feat=f,
+                                 chunk=CH)
     w_r = np.asarray(P.pack_rows(jnp.asarray(bins), jnp.asarray(ghc)))
     work_r = jnp.stack([jnp.asarray(w_r), jnp.zeros_like(jnp.asarray(w_r))])
     ref = np.asarray(hist16_segment(
@@ -113,7 +118,6 @@ def test_pack_planes_fold_root_matches_segment_hist(rng):
         num_bins=num_bin, num_feat=f, chunk=CH))
     assert np.array_equal(np.asarray(root).view(np.uint8),
                           ref.view(np.uint8))
-    # and the packed planes equal the transposed packed rows
     got = np.asarray(work)[0, :w_r.shape[1], :w_r.shape[0]]
     assert np.array_equal(got, w_r.T)
 
@@ -260,7 +264,7 @@ def test_tree_parity_layouts(n, f, leaves):
 
 def test_planes_carried_work_buf_parity(rng):
     """A planes buffer carried from a PREVIOUS tree (the fused-block
-    contract) must grow the same tree as a fresh zero buffer: the pack fold
+    contract) must grow the same tree as a fresh zero buffer: the pack
     rewrites every consumed lane, so last tree's leftovers are never read."""
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.dataset import construct_dataset
