@@ -318,7 +318,8 @@ class GBDT:
             with self._cache_lock:
                 self.models.append(tree)
             self._note_used_features(tree)
-            count_trees([tree])     # tree/*: what the fused loop counts
+            # tree/*: what the fused loop counts
+            count_trees([tree], self.train_set.num_data)
             if tree.num_leaves > 1:
                 any_nonconstant = True
         if self.config.obs_check_finite != "off":

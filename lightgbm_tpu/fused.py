@@ -588,7 +588,7 @@ class FusedTrainer:
             # dispatched - finalized = the iterations in flight
             telemetry.count("fused/iters_finalized", k)
             obs_device.maybe_sample_hbm()   # block-boundary HBM watermark
-            grown = count_trees(trees)
+            grown = count_trees(trees, self.learner.dataset.num_data)
             work = [t.work() for t in trees]
             grown.update(row_visits=sum(w["row_visits"] for w in work),
                          hist_rows=sum(w["hist_rows"] for w in work))

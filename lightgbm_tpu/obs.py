@@ -759,15 +759,20 @@ def _process_age_s() -> Optional[float]:
         return None
 
 
-def count_trees(trees) -> Dict[str, int]:
+def count_trees(trees, rows: int) -> Dict[str, int]:
     """Growth of finished host trees, counted into ``tree/*`` and returned:
     the one place both loops count (``FusedTrainer._finalize`` a block at a
-    time, ``GBDT.train_one_iter`` a tree)."""
+    time, ``GBDT.train_one_iter`` a tree). Of the trees that split,
+    ``inbag_rows`` sums the rows at the root (the bag's, where the job bags
+    its rows) and ``rows`` the training set's ``rows``."""
+    grown = [t for t in trees if t.num_leaves > 1]
     sums = {"trees": len(trees),
             "splits": sum(t.num_leaves - 1 for t in trees),
             "splits_categorical": sum(t.num_cat for t in trees),
             "splits_default_left": sum(t.num_default_left for t in trees),
-            "leaves": sum(t.num_leaves for t in trees)}
+            "leaves": sum(t.num_leaves for t in trees),
+            "inbag_rows": sum(int(t.internal_count[0]) for t in grown),
+            "rows": len(grown) * int(rows)}
     for name, n in sums.items():
         telemetry.count("tree/" + name, n)
     return sums

@@ -221,6 +221,32 @@ def test_bundled_view_places_runs_and_gathers_no_bin(topo, ds_onehot):
     assert [ln for _, ln in ops if "pred[256]" in ln]
 
 
+@pytest.fixture(scope="module")
+def ds_m5():
+    """The m5.train cell's grid at 200K rows: 73 columns, eight of them
+    categorical codes, NaN lags."""
+    data, cfg = _bench_table("m5-tweedie-2047", N_BIN)
+    ds = lgb.Dataset(data["X"], label=data["label"], params=cfg["params"])
+    ds.construct()
+    return ds, cfg
+
+
+def test_2047_leaf_bagged_block_compiles_with_the_pallas_router(topo, ds_m5):
+    """m5.train's block: trees of up to 2,047 leaves, a bag and a column
+    draw under ``lgbtpu/sample``, tweedie. The router's SMEM table holds
+    2,046 rounds of 19 int32 (155 KB), which Mosaic takes as it is."""
+    from lightgbm_tpu.obs import telemetry
+    ds, cfg = ds_m5
+    telemetry.reset()
+    kw, c = compile_block(topo, ds, dict(cfg["params"], tpu_iter_block=4), k=4)
+    assert kw["hp"].has_categorical
+    assert resolved(kw) == ("planes", "pallas", "pallas")
+    assert telemetry.records("learner_path")[-1]["route_kernel"] == "pallas_stream"
+    text = c.as_text()
+    assert "lgbtpu/route/route_rows" in text and "lgbtpu/sample" in text
+    assert c.memory_analysis().temp_size_in_bytes > 0
+
+
 def test_categorical_block_compiles_with_the_pallas_router(topo, ds_coded):
     """Until PR 35 ``has_categorical`` sent EVERY tree of such a job to the
     XLA ``fori_loop`` router (254 full-N rounds a tree, a categorical one an

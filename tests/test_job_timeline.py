@@ -245,7 +245,7 @@ def test_the_eager_loop_counts_the_same_growth():
                              if k.startswith("tree/")}
     assert set(tree_counters) == {"tree/" + n for n in (
         "trees", "splits", "splits_categorical", "splits_default_left",
-        "leaves")}
+        "leaves", "inbag_rows", "rows")}
 
 
 def test_a_job_keeps_its_first_block_and_its_newest(monkeypatch):
@@ -299,7 +299,7 @@ def test_the_timeline_leaves_every_model_bit_as_it_was(monkeypatch):
     X, y = _data(n=300, seed=4)
     params = dict(PARAMS, tpu_iter_block=2)
     with_it = lgb.train(dict(params), lgb.Dataset(X, label=y), num_boost_round=5)
-    monkeypatch.setattr(fused, "count_trees", lambda trees: {})
+    monkeypatch.setattr(fused, "count_trees", lambda trees, *a: {})
     monkeypatch.setattr(fused.FusedTrainer, "_record_block",
                         lambda self, *a: None)
     without = lgb.train(dict(params), lgb.Dataset(X, label=y), num_boost_round=5)
