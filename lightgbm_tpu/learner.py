@@ -1741,6 +1741,8 @@ class SerialTreeLearner:
             penalty=jnp.asarray(pen),
             cegb_coupled=jnp.asarray(cegb_coupled),
         )
+        cat_features = tuple(i for i, m in enumerate(dataset.bin_mappers)
+                             if m.bin_type == BIN_CATEGORICAL)
         self.hp = SplitHyper(
             lambda_l1=float(config.lambda_l1),
             lambda_l2=float(config.lambda_l2),
@@ -1754,7 +1756,8 @@ class SerialTreeLearner:
             max_cat_to_onehot=int(config.max_cat_to_onehot),
             min_data_per_group=float(config.min_data_per_group),
             path_smooth=float(config.path_smooth),
-            has_categorical=any(m.bin_type == BIN_CATEGORICAL for m in dataset.bin_mappers),
+            has_categorical=bool(cat_features),
+            cat_features=cat_features,
             has_monotone=dataset.monotone_constraints is not None,
             mono_intermediate=config.monotone_constraints_method
             in ("intermediate", "advanced"),
@@ -2172,6 +2175,8 @@ class SerialTreeLearner:
                 if tpu and pallas_routes(self.hp.has_categorical,
                                          self.num_bin) else "xla",
                 part_chunk=part_chunk, hist_chunk=hist_chunk,
+                # the columns the many-against-many search runs over
+                cat_scan_features=len(self.hp.cat_features),
                 hist_pool_gb=self.num_leaves * self.bins.shape[1]
                 * hist_bins(self.num_bin_hist) * 12 / 1e9,
                 work_buffer_gb=float(np.prod(

@@ -23,6 +23,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import runtime
 from ..obs import trace_phase
@@ -65,6 +66,11 @@ class SplitHyper(NamedTuple):
     min_data_per_group: float = 100.0
     path_smooth: float = 0.0
     has_categorical: bool = False
+    # the categorical columns of the (3, F, B) view, sorted: the
+    # many-against-many search runs over these alone (a column of any other
+    # kind has no group, so its gains there are -inf whatever it holds).
+    # Empty with ``has_categorical``: every column.
+    cat_features: tuple = ()
     has_monotone: bool = False
     # monotone constraint propagation method: basic bounds children by the
     # split midpoint; intermediate by the sibling's output
@@ -189,15 +195,69 @@ def _split_gain_pair(gl, hl, cl, gr, hr, cr, hp: SplitHyper, *,
 
 
 # A bin's place in the sorted order of its column is a COUNT while the
-# (F, B, B) compare is small: how many bins sort before it, ties by bin index,
-# which is the stable sort's order. On a v5e the two argsorts of a node
-# search, the gather of the histogram by their order and the scatter of the
-# winner's table took 35 of expo_cat.train's 253 ms an iteration (chip run,
-# PR 35; an XLA gather costs 5-15 ns an index there); the compare and a
-# one-hot select are elementwise. Past the limit (F > 64 at 256 bins) XLA on
-# a CPU would hold the select in memory (it does not fuse it into its sum,
-# PR 31: 24 B a cell), and the sorts stay. Both forms give the same bits.
+# (C, B, B) compare over the C categorical columns is small: how many bins
+# sort before it, ties by bin index, which is the stable sort's order. On a
+# v5e the two argsorts of a node search, the gather of the histogram by their
+# order and the scatter of the winner's table took 35 of expo_cat.train's
+# 253 ms an iteration (an XLA gather costs 5-15 ns an index there); the
+# compare and a one-hot select are elementwise. Past the limit (C > 64 at
+# 256 bins) XLA on a CPU would hold the select in memory (it does not fuse
+# it into its sum: 24 B a cell), and the sorts stay. Both forms give the
+# same bits, so a wide table with a few categorical columns keeps the count
+# whatever its width.
 _COUNT_MAX_CELLS = 1 << 22
+
+
+def _col_runs(cols: tuple, num_feat: int) -> tuple:
+    """The sorted static columns ``cols`` (empty: all ``num_feat``) as
+    (start, stop) runs of consecutive indices, so that taking them and
+    putting them back are slices and a concatenation, never a gather."""
+    runs = []
+    for c in cols or range(num_feat):
+        if runs and runs[-1][1] == c:
+            runs[-1][1] = c + 1
+        else:
+            runs.append([c, c + 1])
+    return tuple(map(tuple, runs))
+
+
+def _cat_width(c: int) -> int:
+    """The width the many-against-many search runs at for ``c`` columns:
+    XLA:TPU lays the pair's (2, 2, W, B) rank count out with the columns
+    minor only where W divides 8 or 8 divides W (v5e compiles of W = 1-24,
+    72, 73); at any other width it puts the two sort directions on the
+    lanes. One pair search on a v5e: 0.18 ms at six columns against 0.08
+    at eight, 0.72 at 73 against 0.14 at 72."""
+    return 1 << (c - 1).bit_length() if c <= 4 else -(-c // 8) * 8
+
+
+def _take_cols(x: jax.Array, runs: tuple, axis: int) -> jax.Array:
+    """The columns of ``runs`` along ``axis``, in order."""
+    if runs == ((0, x.shape[axis]),):
+        return x
+    return jnp.concatenate([jax.lax.slice_in_dim(x, a, b, axis=axis)
+                            for a, b in runs], axis=axis)
+
+
+def _put_cols(xc: jax.Array, runs: tuple, num_feat: int, axis: int) -> jax.Array:
+    """:func:`_take_cols` undone: ``xc``'s columns at their places along
+    ``axis`` of ``num_feat``, every other column -inf."""
+    if runs == ((0, num_feat),):
+        return xc
+
+    def filled(n):
+        shape = list(xc.shape)
+        shape[axis] = n
+        return jnp.full(shape, NEG_INF, xc.dtype)
+
+    parts, at, done = [], 0, 0
+    for a, b in runs + ((num_feat, num_feat),):
+        if a > done:
+            parts.append(filled(a - done))
+        if b > a:
+            parts.append(jax.lax.slice_in_dim(xc, at, at + b - a, axis=axis))
+        at, done = at + b - a, b
+    return jnp.concatenate(parts, axis=axis)
 
 
 def _stable_rank(keys: jax.Array, b_iota: jax.Array) -> jax.Array:
@@ -312,7 +372,6 @@ def find_best_split_planes(
             extra_l2 = hp.cat_l2
             # candidate categories exclude the trailing other/missing bin
             cat_bin_ok = meta.is_categorical[:, None] & (b_iota[None, :] < meta.num_bins[:, None] - 1)
-            g_b, h_b, c_b = hist
 
             # one-vs-rest (reference: one-hot when #cats <= max_cat_to_onehot)
             num_cats = meta.num_bins - 1
@@ -325,35 +384,45 @@ def find_best_split_planes(
             oh_ok = (left[2] >= hp.min_data_in_leaf) & (right[2] >= hp.min_data_in_leaf) \
                 & (left[1] >= hp.min_sum_hessian_in_leaf) \
                 & (right[1] >= hp.min_sum_hessian_in_leaf) \
-                & cat_bin_ok & use_onehot[:, None] & (c_b > 0)
+                & cat_bin_ok & use_onehot[:, None] & (left[2] > 0)
             oh_gain = jnp.where(oh_ok, oh_gain - parent_gain, NEG_INF)
 
             # many-vs-many: sort categories by g/(h+cat_smooth), scan prefixes
-            # (reference: FindBestThresholdCategoricalInner sorted scan)
-            group_ok = cat_bin_ok & (c_b >= hp.min_data_per_group) & ~use_onehot[:, None]
+            # (reference: FindBestThresholdCategoricalInner sorted scan), over
+            # the C categorical columns alone: a static take, (3, C, B), and
+            # zero columns that hold no group up to the width W
+            runs = _col_runs(hp.cat_features, num_feat)
+            hist_c = _take_cols(hist, runs, 1)
+            group_ok = _take_cols(cat_bin_ok & ~use_onehot[:, None], runs, 0) \
+                & (hist_c[2] >= hp.min_data_per_group)
+            num_cat = hist_c.shape[1]
+            width = _cat_width(num_cat)
+            if width > num_cat:
+                hist_c = jnp.pad(hist_c, ((0, 0), (0, width - num_cat), (0, 0)))
+                group_ok = jnp.pad(group_ok, ((0, width - num_cat), (0, 0)))
             # the two sort keys of a column: ascending and, negated, descending;
             # a bin that is no group sorts last either way
-            ratio = g_b / (h_b + hp.cat_smooth)
+            ratio = hist_c[0] / (hist_c[1] + hp.cat_smooth)
             keys2 = jnp.stack([jnp.where(group_ok, ratio, jnp.inf),
                                jnp.where(group_ok, -ratio, jnp.inf)], axis=0)
-            by_count = num_feat * num_bin * num_bin <= _COUNT_MAX_CELLS
+            by_count = width * num_bin * num_bin <= _COUNT_MAX_CELLS
             if by_count:
-                rank2 = _stable_rank(keys2, b_iota)                      # (2, F, B)
+                rank2 = _stable_rank(keys2, b_iota)                      # (2, W, B)
             else:
                 order2 = jnp.argsort(keys2, axis=2)
-            n_groups = jnp.sum(group_ok, axis=1)                         # (F,)
+            n_groups = jnp.sum(group_ok, axis=1)                         # (W,)
 
             def mvm_gains():
-                # both sort directions in ONE stacked (2, F, B) eval, same
+                # both sort directions in ONE stacked (2, W, B) eval, same
                 # collapse as the numerical missing-direction pair above
                 if by_count:
                     # the bin of rank k, by a one-hot select: one term a sum
                     at = rank2[:, :, None, :] == b_iota[None, None, :, None]
                     h_sorted = jnp.sum(jnp.where(
-                        at[None], hist[:, None, :, None, :], 0.0), axis=4)
+                        at[None], hist_c[:, None, :, None, :], 0.0), axis=4)
                 else:
-                    h_sorted = jnp.take_along_axis(hist[:, None], order2[None],
-                                                   axis=3)             # (3, 2, F, B)
+                    h_sorted = jnp.take_along_axis(hist_c[:, None], order2[None],
+                                                   axis=3)             # (3, 2, W, B)
                 # prefix of k+1 bins: a product with a triangle of ones (the MXU
                 # in f32, the same op for both forms) where a cumsum over 256
                 # becomes a two-level reduce-window that XLA gives no op_name
@@ -375,7 +444,9 @@ def find_best_split_planes(
                     & (left[2] >= hp.min_data_in_leaf) & (right[2] >= hp.min_data_in_leaf) \
                     & (left[1] >= hp.min_sum_hessian_in_leaf) \
                     & (right[1] >= hp.min_sum_hessian_in_leaf)
-                return jnp.where(ok, gain - parent_gain, NEG_INF)
+                # back to (2, F, B): every other column -inf
+                gains = jnp.where(ok, gain - parent_gain, NEG_INF)
+                return _put_cols(gains[:, :num_cat], runs, num_feat, 1)
 
             mvm_asc, mvm_desc = mvm_gains()
             num_gain = jnp.where(meta.is_categorical[:, None], NEG_INF, num_gain)
@@ -432,10 +503,16 @@ def find_best_split_planes(
             return b_iota == tbin, jnp.bool_(False)
 
         def tbl_mvm(direction):
-            # the first (tbin + 1) bins of the winner's sorted order go left
+            # the first (tbin + 1) bins of the winner's sorted order go left;
+            # the winner's row of the (2, W, B) order, in range for any feat
+            fc = feat
+            if num_cat < num_feat:
+                pos = np.zeros(num_feat, np.int32)
+                pos[list(hp.cat_features)] = np.arange(num_cat)
+                fc = jnp.asarray(pos, jnp.int32)[feat]
             if by_count:
-                return rank2[direction, feat] <= tbin, jnp.bool_(False)
-            row = order2[direction, feat]
+                return rank2[direction, fc] <= tbin, jnp.bool_(False)
+            row = order2[direction, fc]
             tbl = jnp.zeros((num_bin,), bool).at[row].set(b_iota <= tbin)
             return tbl, jnp.bool_(False)
 

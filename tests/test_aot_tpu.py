@@ -27,7 +27,7 @@ from jax.sharding import SingleDeviceSharding
 import lightgbm_tpu as lgb
 from bench import make_higgs_like, make_mslr_like
 from lightgbm_tpu import fused, runtime
-from lightgbm_tpu.ops import partition
+from lightgbm_tpu.ops import partition, split
 
 pytestmark = pytest.mark.slow
 
@@ -505,3 +505,59 @@ def test_serving_predict_compiles(topo, small_model):
         jax.ShapeDtypeStruct((65_536, X.shape[1]), jnp.float32, sharding=sh),
         _abstract(pack, sh), num_class=1, has_cat=has_cat,
         has_linear=has_linear).compile()
+
+
+def test_m5_categorical_search_runs_over_the_categorical_columns(topo, ds_m5):
+    """m5.train's table has 73 columns, eight of them categorical (67 and
+    six at these 200K rows, where some columns hold one value): the
+    many-against-many search (the rank count's (F, B, B) compare, the
+    one-hot select of the sorted histogram) runs over the categorical ones
+    alone, so no array under ``lgbtpu/cat_scan`` has an (F, B, B) shape,
+    and none holds more elements than the pair's (2, 3, 2, W, B, B) select
+    at the search's width W (``split._cat_width``: 8 for six columns)."""
+    from lightgbm_tpu.ops.binning import BIN_CATEGORICAL
+    ds, cfg = ds_m5
+    binned = ds.construct()
+    f = binned.num_features
+    b = max(m.num_bins for m in binned.bin_mappers)
+    cats = tuple(i for i, m in enumerate(binned.bin_mappers) if m.bin_type == BIN_CATEGORICAL)
+    assert len(cats) < f
+    kw, c = compile_block(topo, ds, dict(cfg["params"], tpu_iter_block=4), k=4)
+    assert kw["hp"].cat_features == cats
+    lines = [ln for ln in c.as_text().splitlines() if "lgbtpu/cat_scan" in ln]
+    assert lines
+    shapes = [tuple(int(d) for d in dims.split(",") if d)
+              for ln in lines
+              for dims in re.findall(r"\b(?:f32|s32|pred|bf16|u32|s8)\[([\d,]*)\]",
+                                     ln.split(", metadata=")[0])]
+    assert not [s for s in shapes if (f, b, b) in zip(s, s[1:], s[2:])]
+    # the pair's one-hot select: 2 nodes x 3 channels x 2 directions x W
+    w = split._cat_width(len(cats))
+    assert max(np.prod(s, dtype=np.int64) for s in shapes) <= 12 * w * b * b
+
+
+@pytest.mark.parametrize("c,b", [(3, 100), (6, 255), (7, 100), (12, 255), (73, 100)])
+def test_rank_count_keeps_the_columns_minor(topo, c, b):
+    """At a width that neither divides 8 nor is a multiple of it, XLA:TPU
+    lays the pair's (2, 2, W, B) rank count out with the two sort
+    directions on the lanes, and the pair search costs 2-5x on a v5e
+    (expo_cat.train's six columns, m5.train's 73). The search runs at
+    ``split._cat_width(C)``, where the columns stay minor of the
+    directions: no (2, 2, W, B) s32 array with the directions minor."""
+    cat = np.ones(c, bool)
+    meta = split.FeatureMeta(
+        num_bins=jnp.full(c, b, jnp.int32), movable_missing=jnp.zeros(c, bool),
+        missing_bin=jnp.zeros(c, jnp.int32), is_categorical=jnp.asarray(cat),
+        monotone=jnp.zeros(c, jnp.int8), penalty=jnp.ones(c, jnp.float32),
+        cegb_coupled=jnp.zeros(c, jnp.float32))
+    hp = split.SplitHyper(min_data_in_leaf=1, has_categorical=True,
+                          cat_features=tuple(range(c)))
+    pair = jax.vmap(lambda h, p: split.find_best_split_planes(
+        h, p, meta, jnp.ones(c, bool), hp))
+    sds = SingleDeviceSharding(topo.devices[0])
+    text = jax.jit(pair).lower(
+        jax.ShapeDtypeStruct((2, 3, c, b), jnp.float32, sharding=sds),
+        jax.ShapeDtypeStruct((2, 3), jnp.float32, sharding=sds)).compile().as_text()
+    w = split._cat_width(c)
+    layouts = re.findall(r"= s32\[2,2,%d,%d\]\{([0-9,]+)[:}]" % (w, b), text)
+    assert layouts and not [lay for lay in layouts if lay.startswith("1,")], layouts

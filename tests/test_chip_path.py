@@ -437,6 +437,23 @@ def test_auto_resolution(name, tpu, f, params, expect, warning, monkeypatch,
     assert bool(hits) == bool(warning), warnings_log
 
 
+@pytest.mark.parametrize("f,params,want", [
+    (73, {"categorical_feature": "0,1,2,3,4,5,6,7"}, 8),   # m5.train's shape
+    (73, {}, 0)], ids=["m5_shaped", "numerical"])
+def test_learner_path_counts_the_categorical_search_columns(f, params, want,
+                                                            monkeypatch):
+    """``cat_scan_features``: the columns the many-against-many search runs
+    over, the learner's ``SplitHyper.cat_features``; 0 without any."""
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    obs.telemetry.reset()
+    lrn = _learner(f, params)
+    lrn.build_kwargs()
+    (rec,) = obs.telemetry.records("learner_path")
+    assert rec["cat_scan_features"] == want
+    assert lrn.hp.cat_features == tuple(range(want))
+    assert lrn.hp.has_categorical == bool(want)
+
+
 @pytest.mark.parametrize("f,kernel_chunk,xla_chunk,part,pack", [
     # the three older cells: today's numbers, which PR 29 and PR 27 measured
     (28, 8192, 4096, 1024, 131072), (137, 4096, 1024, 1024, 131072),

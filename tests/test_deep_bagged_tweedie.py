@@ -206,3 +206,79 @@ def test_the_bag_and_router_counters_grow_once_a_tree(trained, loop):
     assert counters["tree/inbag_rows"] == sum(int(t.internal_count[0]) for t in trees)
     assert counters["tree/rows"] == ROWS * len(trees)
     assert counters == trained["eager" if loop == "fused" else "fused"][2]
+
+
+# the categorical columns of a 40-column table, in three runs: four of 300,
+# 60, 3 and 7 codes, then two, then the last column
+WIDE_CATS = (0, 1, 2, 3, 17, 18, 29, 39)
+
+
+def _wide_table(n=12_000, seed=12):
+    """40 columns, 8 of them categorical codes (two of at most
+    ``max_cat_to_onehot`` categories), the rest numbers with NaN lags."""
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, 40).astype(np.float32)
+    for c, k in zip(WIDE_CATS, (300, 60, 3, 7, 40, 4, 25, 90)):
+        X[:, c] = rng.randint(0, k, n)
+    lam = np.exp(0.8 * np.sin(X[:, 0]) + 0.4 * (X[:, 1] % 5 == 2)
+                 + 0.3 * X[:, 5] - 0.2 * (X[:, 17] % 3) + 0.2 * X[:, 9])
+    y = (rng.poisson(lam) * (rng.rand(n) < 0.6)).astype(np.float32)
+    X[rng.rand(n) < 0.1, 4:8] = np.nan
+    return X, y
+
+
+def _train_both_searches():
+    """Train the wide job twice, searching many against many over the
+    categorical columns alone and over every column (``cat_features``
+    empty); run in a child process by the test below."""
+    import json
+    from lightgbm_tpu import learner
+    objective.RegressionTweedie.get_gradients = _numpy_tweedie_gradients
+    X, y = _wide_table()
+    params = dict(PARAMS, categorical_feature=list(WIDE_CATS))
+    real = learner.SplitHyper
+    out = []
+    for every in (False, True):
+        if every:
+            learner.SplitHyper = lambda **kw: real(**dict(kw, cat_features=()))
+        fused._BLOCK_CACHE.clear()
+        bst = lgb.train(dict(params), lgb.Dataset(X, label=y, params=dict(params)),
+                        num_boost_round=ROUNDS)
+        hp = bst.inner.learner.hp
+        out.append(dict(has_categorical=hp.has_categorical,
+                        cat_features=list(hp.cat_features),
+                        leaves=[t.num_leaves for t in bst.inner.models],
+                        num_cat=sum(t.num_cat for t in bst.inner.models),
+                        text=bst.model_to_string()))
+    print(json.dumps(out))
+
+
+def test_the_categorical_search_over_its_columns_alone_grows_the_same_trees():
+    """The learner names the table's categorical columns to the search
+    (``SplitHyper.cat_features``), which runs many against many over them
+    alone: the model text is byte for byte that of the same job searching
+    every column (the empty tuple), bagged, half the columns drawn, past
+    256 leaves. The gradients come from numpy, as in ``trained``.
+
+    The two jobs run in a child process whose XLA:CPU emits no FMA
+    (``--xla_cpu_max_isa=SSE4_2``): on a CPU that has one, XLA contracts
+    ``a * b + c`` inside a fused kernel, so the same numerical gain, whose
+    arithmetic neither search changes, rounds one ulp apart in the two
+    programs' fusions, and a near tie past the 600th split flips. Every
+    operation rounded on its own, the two programs give the same bits."""
+    import json
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
+        os.environ.get("XLA_FLAGS", "") + " --xla_cpu_max_isa=SSE4_2").strip())
+    code = ("import sys; sys.path.insert(0, %r); sys.path.insert(0, %r); "
+            "import test_deep_bagged_tweedie as t; t._train_both_searches()"
+            % (os.path.dirname(BENCH), os.path.dirname(os.path.abspath(__file__))))
+    run = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                         text=True, timeout=600)
+    assert run.returncode == 0, run.stderr[-4000:]
+    compact, every = json.loads(run.stdout.strip().splitlines()[-1])
+    assert compact["has_categorical"] and every["has_categorical"]
+    assert compact["cat_features"] == list(WIDE_CATS) and every["cat_features"] == []
+    assert all(256 < n <= PARAMS["num_leaves"] for n in compact["leaves"])
+    assert compact["num_cat"] > 0
+    assert compact["text"] == every["text"]

@@ -259,3 +259,101 @@ def test_count_form_trains_the_sorted_forms_trees(rng, monkeypatch):
     fused._BLOCK_CACHE.clear()
     assert texts[0] == texts[1]
     assert "cat_threshold=" in texts[0]
+
+
+# ---- the many-against-many search over the categorical columns alone
+
+# the columns' kinds (1 categorical) in the (F, B) view; their bins follow
+_LAYOUTS = {"start": [1, 1, 1, 0, 0, 0], "middle": [0, 0, 1, 1, 0, 1, 0],
+            "end": [0, 0, 0, 1, 1], "all": [1, 1, 1, 1]}
+_BINS = [40, 64, 13, 30, 64, 25, 50]
+_COMPACT = [(layout, form, "plain") for layout in _LAYOUTS
+            for form in ("count", "sorted")] + \
+    [("middle", form, variant) for form in ("count", "sorted")
+     for variant in ("onehot_beside", "nan_keys", "feature_gains",
+                     "rand_threshold", "numerical_winner")]
+
+
+@pytest.mark.parametrize("layout,form,variant", _COMPACT,
+                         ids=["-".join(c) for c in _COMPACT])
+def test_compact_search_gives_every_columns_split_bit_for_bit(
+        rng, layout, form, variant, monkeypatch):
+    """``cat_features`` names the categorical columns, and the search over
+    them alone gives the SplitInfo of the search over every column (the
+    empty tuple), every field and every bit: a column of another kind has
+    no group, so its many-against-many gains are -inf either way."""
+    from lightgbm_tpu.ops import split
+    if form == "sorted":
+        monkeypatch.setattr(split, "_COUNT_MAX_CELLS", 0)
+    is_cat = np.asarray(_LAYOUTS[layout], bool)
+    num_bins = list(_BINS[:len(is_cat)])
+    hp_kw = dict(min_data_in_leaf=1, min_sum_hessian_in_leaf=1e-3,
+                 has_categorical=True, min_data_per_group=50.0)
+    if variant == "onehot_beside":
+        num_bins[2] = 4            # 3 categories + the other bin: one-hot
+    if variant == "nan_keys":
+        hp_kw.update(cat_smooth=0.0)
+    if variant == "numerical_winner":
+        hp_kw.update(cat_l2=1e9)
+    cats = tuple(int(i) for i in np.flatnonzero(is_cat))
+    meta = _meta(num_bins, is_cat=is_cat)
+    kinds = set()
+    for draw in range(6):
+        hist, parent = _cat_hist(rng, num_bins, ties=False)
+        if variant == "nan_keys":
+            for i in cats:          # groups without hessian: 0 / 0 keys
+                hist[i, 1:3, :2] = 0.0
+                hist[i, 1:3, 2] = 200.0
+            parent = hist[0].sum(axis=0)
+            for i in range(1, len(num_bins)):
+                hist[i, 0] += parent - hist[i].sum(axis=0)
+        kw = {}
+        if variant == "feature_gains":
+            kw["want_feature_gains"] = True
+        if variant == "rand_threshold":
+            kw["rand_threshold"] = jnp.asarray(
+                rng.randint(0, 12, len(num_bins)), jnp.int32)
+        if draw == 0 and variant != "numerical_winner":
+            mask = is_cat                            # a categorical winner
+        elif draw == 1 and variant == "onehot_beside":
+            mask = np.arange(len(num_bins)) == 2     # the one-hot column's
+        else:
+            mask = rng.rand(len(num_bins)) < 0.9
+        mask = jnp.asarray(mask)
+        every, compact = (
+            find_best_split(jnp.asarray(hist), jnp.asarray(parent), meta,
+                            mask, SplitHyper(cat_features=c, **hp_kw), **kw)
+            for c in ((), cats))
+        if variant == "feature_gains":
+            assert np.array_equal(np.asarray(every), np.asarray(compact))
+            continue
+        for name, a, b in zip(split.SplitInfo._fields, every, compact):
+            assert np.array_equal(np.asarray(a), np.asarray(b),
+                                  equal_nan=True), name
+        kinds.add(int(every.kind))
+    if variant == "numerical_winner":
+        assert kinds == {0}, kinds
+    elif variant != "feature_gains":
+        assert kinds & {2, 3}, kinds      # many-against-many winners
+        assert variant != "onehot_beside" or 1 in kinds, kinds
+
+
+def test_column_runs_take_and_put_back():
+    """The static take of the categorical columns and its inverse are
+    slices and a concatenation; an empty tuple is every column."""
+    from lightgbm_tpu.ops import split
+    assert split._col_runs((), 4) == ((0, 4),)
+    assert split._col_runs((0, 1, 4, 6, 7), 9) == ((0, 2), (4, 5), (6, 8))
+    x = jnp.arange(2 * 9 * 3).reshape(2, 9, 3).astype(jnp.float32)
+    runs = split._col_runs((0, 1, 4, 6, 7), 9)
+    taken = split._take_cols(x, runs, 1)
+    assert np.array_equal(np.asarray(taken),
+                          np.asarray(x)[:, [0, 1, 4, 6, 7]])
+    back = np.asarray(split._put_cols(taken, runs, 9, 1))
+    want = np.full((2, 9, 3), -np.inf, np.float32)
+    want[:, [0, 1, 4, 6, 7]] = np.asarray(x)[:, [0, 1, 4, 6, 7]]
+    assert np.array_equal(back, want)
+    assert split._take_cols(x, split._col_runs((), 9), 1) is x
+    # the search's width: a divisor or a multiple of 8
+    assert [split._cat_width(c) for c in (1, 2, 3, 4, 5, 6, 8, 9, 73)] == \
+        [1, 2, 4, 4, 8, 8, 8, 16, 80]
